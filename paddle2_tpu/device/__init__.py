@@ -188,13 +188,9 @@ def synchronize(device=None):
 # ----------------------------------------------------------- memory stats
 
 def _mem_stats(device=None) -> dict:
-    import jax
-    d = _device_of(device)
-    try:
-        stats = d.memory_stats()
-        return stats or {}
-    except Exception:
-        return {}
+    # the CPU backend reports None (no allocator stats); anything a
+    # device raises propagates
+    return _device_of(device).memory_stats() or {}
 
 
 def memory_allocated(device=None) -> int:

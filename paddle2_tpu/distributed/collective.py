@@ -23,12 +23,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: lives under experimental
-    from jax.experimental.shard_map import shard_map
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = shard_map  # old-jax shim for jax.shard_map callers
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from ..framework.tensor import Tensor
@@ -1111,18 +1106,12 @@ def hierarchical_pmean(v, ici_axes, dcn_axes):
 
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """``shard_map`` with the output-replication check disabled — the
-    ONE version-tolerant wrapper for programs whose results are
-    replicated in VALUE but typed device-varying (hierarchical
-    reductions, collective-matmul rings): old jax spells the knob
-    ``check_rep``, new jax ``check_vma``. Uses this module's already
-    version-shimmed ``shard_map`` import."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    """``shard_map`` with the varying-manual-axes check disabled — for
+    programs whose results are replicated in VALUE but typed
+    device-varying (hierarchical reductions, collective-matmul
+    rings)."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 __all__ += ["hierarchical_psum", "hierarchical_pmean",

@@ -40,63 +40,33 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: lives under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import mesh as mesh_mod
 
 __all__ = ["pipeline_spmd", "pipeline_spmd_1f1b", "pipeline_spmd_vpp"]
 
-# --- old-jax compatibility -------------------------------------------------
-# jax < 0.6 has neither lax.pvary/lax.pcast nor the vma type system the
-# varying-marks below talk to. The schedules themselves are plain
-# psum/ppermute programs that old jax runs fine — so on such builds the
-# varying-marks degrade to identity and shard_map skips the replication
-# check it cannot express (`check_rep=False`). On modern jax nothing
-# changes: the pvary path and the default rep check run exactly as
-# before.
-_HAS_VMA = hasattr(jax.lax, "pvary") or hasattr(jax.lax, "pcast")
-
-
-def _pvary(v, axes):
-    if not axes:
-        return v
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(v, tuple(axes))
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(v, tuple(axes), to="varying")
-    return v
-
-
-def _vma_of(v):
-    if hasattr(jax, "typeof"):
-        return getattr(jax.typeof(v), "vma", frozenset())
-    return frozenset()
+def _vary_over(v, axes):
+    """Mark every leaf of ``v`` device-varying over those of ``axes``
+    it is not ALREADY varying over (dp-sharded inputs arrive
+    dp-varying; ``pcast`` rejects redundant axes)."""
+    def leaf(x):
+        missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+    return jax.tree_util.tree_map(leaf, v)
 
 
 def _axis_size(name):
-    # one shared resolution (trace-bound axis first, installed-mesh
-    # fallback on old jax) — see mesh.traced_axis_size
     return mesh_mod.traced_axis_size(name)
 
 
-def _shard_map(*args, **kwargs):
-    if not _HAS_VMA:
-        kwargs.setdefault("check_rep", False)
-    return shard_map(*args, **kwargs)
-
-
 def _claim_mean(g, axis):
-    """Finalize a grad whose cross-``axis`` reduction modern jax already
-    performed via the pvary-transpose auto-psum: there the values are
-    equal across the axis and pmean merely CLAIMS the invariance for
-    the out_specs. Old jax has no vma transpose — each shard still
-    holds its LOCAL (1/degree-scaled) contribution, so the reduction
-    must be issued for real: psum of the scaled locals IS the mean."""
-    return (jax.lax.pmean if _HAS_VMA else jax.lax.psum)(g, axis)
+    """Finalize a grad whose cross-``axis`` reduction the
+    varying-transpose auto-psum already performed: the values are equal
+    across the axis and pmean merely CLAIMS the invariance for the
+    out_specs."""
+    return jax.lax.pmean(g, axis)
 
 
 def _local_body(params, x_micro, *, stage_fn, n_stages, n_micro, axis):
@@ -128,7 +98,7 @@ def _local_body(params, x_micro, *, stage_fn, n_stages, n_micro, axis):
     # the carry becomes device-varying (ppermute / stage writes): mark the
     # replicated initial values as varying so scan's carry types match
     def _varying(v):
-        return _pvary(v, (axis,))
+        return _vary_over(v, (axis,))
 
     (act, outs), _ = jax.lax.scan(tick, (_varying(zero), _varying(outs0)),
                                   jnp.arange(T))
@@ -172,7 +142,7 @@ def pipeline_spmd(stage_fn: Callable, stacked_params, x_micro,
             stacked_params)
         body = partial(_local_body, stage_fn=stage_fn, n_stages=S,
                        n_micro=M, axis=mesh_axis)
-        fn = jax.jit(_shard_map(
+        fn = jax.jit(shard_map(
             body, mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=P()))
@@ -213,11 +183,7 @@ def _f1b_body(params, shared, x_micro, labels_micro, *, stage_fn, loss_fn,
     vaxes = (axis,) + tuple(tp_axes) + ((dp_axis,) if dp_axis else ())
 
     def _vary(v):
-        """pvary only the axes v is not ALREADY varying over (dp-sharded
-        inputs arrive dp-varying; pvary rejects redundant axes)."""
-        cur = _vma_of(v)
-        missing = tuple(a for a in vaxes if a not in cur)
-        return _pvary(v, missing) if missing else v
+        return _vary_over(v, vaxes)
 
     tp_scale = 1.0
     for a in tp_axes:
@@ -351,8 +317,8 @@ def _f1b_body(params, shared, x_micro, labels_micro, *, stage_fn, loss_fn,
             # with the update math of already-reduced buckets. Bitwise
             # identical (pmean of a concatenation == concatenation of
             # pmeans).
-            from ..bucket import bucketed_pmean, bucketed_psum
-            fused = bucketed_pmean if _HAS_VMA else bucketed_psum
+            from ..bucket import bucketed_pmean
+            fused = bucketed_pmean
             grads = fused(grads, dp_axis, float(grad_bucket_bytes))
         else:
             grads = jax.tree_util.tree_map(
@@ -409,9 +375,7 @@ def _vpp_body(params, shared, x_micro, labels_micro, *, stage_fn, loss_fn,
         seed_scale = seed_scale / _axis_size(dp_axis)
 
     def _varying(v):
-        cur = _vma_of(v)
-        missing = tuple(a for a in vaxes if a not in cur)
-        return _pvary(v, missing) if missing else v
+        return _vary_over(v, vaxes)
 
     def chunk_params(v):
         return jax.tree_util.tree_map(lambda a: a[v], p_chunks)
@@ -497,8 +461,8 @@ def _vpp_body(params, shared, x_micro, labels_micro, *, stage_fn, loss_fn,
         # invariance for the out_specs — exactly like _f1b_body
         losses = jax.lax.pmean(losses, dp_axis)
         if grad_bucket_bytes:
-            from ..bucket import bucketed_pmean, bucketed_psum
-            fused = bucketed_pmean if _HAS_VMA else bucketed_psum
+            from ..bucket import bucketed_pmean
+            fused = bucketed_pmean
             grads = fused(grads, dp_axis, float(grad_bucket_bytes))
         else:
             grads = jax.tree_util.tree_map(
@@ -569,7 +533,7 @@ def pipeline_spmd_vpp(stage_fn: Callable, stacked_params, x_micro,
                        dp_axis=dp_axis,
                        grad_bucket_bytes=grad_bucket_bytes)
         data_spec = P() if dp_axis is None else P(None, dp_axis)
-        fn = jax.jit(_shard_map(
+        fn = jax.jit(shard_map(
             body, mesh=mesh,
             in_specs=(param_specs, shared_specs, data_spec, data_spec),
             out_specs=(P(), param_specs)))
@@ -722,7 +686,7 @@ def pipeline_spmd_1f1b(stage_fn: Callable, stacked_params, x_micro,
                        tp_axes=tp_axes, grad_extra=grad_extra,
                        dp_axis=dp_axis, grad_bucket_bytes=grad_bucket_bytes)
         data_spec = P() if dp_axis is None else P(None, dp_axis)
-        fn = jax.jit(_shard_map(
+        fn = jax.jit(shard_map(
             body, mesh=mesh,
             in_specs=(param_specs, shared_specs, data_spec, data_spec),
             out_specs=(P(), param_specs)))

@@ -89,14 +89,13 @@ def _parse(argv: Optional[List[str]] = None):
                         "gates the full kill->first-step MTTR on top")
     p.add_argument("--compile_cache_dir", default=None,
                    help="persistent XLA compilation cache directory "
-                        "forwarded to workers (PADDLE2_TPU_CACHE_DIR / "
-                        "FLAGS_compilation_cache_dir). Defaults to a "
-                        "job-scoped directory whenever the launcher "
-                        "can respawn workers (--max_restarts > 0 or a "
-                        "rendezvous master): the ~19s compile+first-"
-                        "step is pure MTTR on every respawn/rescale, "
-                        "and a warm cache turns the recovery recompile "
-                        "into a cache read ('none' disables)")
+                        "forwarded to workers (PADDLE2_TPU_CACHE_DIR); "
+                        "yields to JAX_COMPILATION_CACHE_DIR. Default: "
+                        "the workers' own fixed <checkout>/.jax_cache, "
+                        "which is on — compile+first-step is pure MTTR "
+                        "on every respawn/rescale, and a warm cache "
+                        "turns the recovery recompile into a cache "
+                        "read ('none' disables)")
     p.add_argument("--metrics_dir", default=None,
                    help="always-on metrics plane directory forwarded "
                         "to workers as PADDLE_METRICS_DIR: every rank "
@@ -240,8 +239,12 @@ def _worker_env(args, local_rank: int, generation: int = 0) -> dict:
         # launcher's detect->respawn span is charged to
         env["PADDLE_MTTR_BUDGET"] = str(args.mttr_budget)
     cache = _compile_cache_dir(args)
-    if cache and "PADDLE2_TPU_CACHE_DIR" not in os.environ \
-            and "FLAGS_compilation_cache_dir" not in os.environ:
+    # precedence: JAX_COMPILATION_CACHE_DIR (JAX reads it; nothing else
+    # is set), then the operator's exported choice, then the option
+    if cache is not None and not any(
+            k in os.environ for k in (
+                "JAX_COMPILATION_CACHE_DIR", "PADDLE2_TPU_CACHE_DIR",
+                "FLAGS_compilation_cache_dir")):
         env["PADDLE2_TPU_CACHE_DIR"] = cache
     if args.metrics_dir and "PADDLE_METRICS_DIR" not in os.environ:
         # workers auto-enable on import (PADDLE_TRAINER_ID guard);
@@ -263,20 +266,18 @@ def _worker_env(args, local_rank: int, generation: int = 0) -> dict:
 
 
 def _compile_cache_dir(args) -> Optional[str]:
-    """Resolve the persistent-compilation-cache dir workers inherit.
-    Explicit ``--compile_cache_dir`` wins ('none' disables); otherwise
-    any launcher that can RESPAWN workers gets a job-scoped default —
-    every respawn/rescale recompiles the full train step, which a warm
-    cache reduces from ~19s to a file read, so the elastic restart path
-    turns the cache on by default."""
-    if args.compile_cache_dir is not None:
-        if str(args.compile_cache_dir).lower() in ("none", "off", ""):
-            return None
-        return args.compile_cache_dir
-    if args.max_restarts > 0 or args.rdzv_master or args.elastic_rescale:
-        return os.path.join(tempfile.gettempdir(),
-                            f"p2t_xla_cache_{args.job_id}")
-    return None
+    """The ``PADDLE2_TPU_CACHE_DIR`` workers inherit: the explicit
+    ``--compile_cache_dir`` ('none' = '' = off), else ``None`` — the
+    workers' own default is the one fixed path in the checkout
+    (``flags.DEFAULT_COMPILE_CACHE_DIR``), ON, so a respawned worker's
+    recompile is a cache read without the launcher inventing a
+    directory (a path built from a temporary directory or a job id
+    never hits on a fresh machine)."""
+    if args.compile_cache_dir is None:
+        return None
+    if str(args.compile_cache_dir).lower() in ("none", "off", ""):
+        return ""
+    return args.compile_cache_dir
 
 
 def _spawn(args, generation: int = 0,
@@ -775,8 +776,25 @@ def _elastic_agent(args) -> int:
             master.shutdown()
 
 
+def _reject_shared_chips(args) -> None:
+    """On a TPU host one process drives every local chip (a mesh over
+    ``jax.devices()``), and a chip belongs to one process at a time:
+    several local workers would all claim all chips and fail or hang
+    against each other. Refuse that layout instead of sharing
+    silently."""
+    from ...flags import _env_platform
+    if args.nproc_per_node > 1 \
+            and _env_platform(os.environ).startswith("tpu"):
+        raise SystemExit(
+            f"--nproc_per_node {args.nproc_per_node} on a TPU host: "
+            "every local process would claim the same chips. Use "
+            "--nproc_per_node 1 (one process, a mesh over the host's "
+            "chips); JAX_PLATFORMS=cpu runs a multi-process CPU gang.")
+
+
 def launch(argv: Optional[List[str]] = None) -> int:
     args = _parse(argv)
+    _reject_shared_chips(args)
     if args.preflight and not _run_preflight():
         return QUARANTINED_EXIT_CODE
     if args.rdzv_master:

@@ -76,14 +76,11 @@ def traced_axis_size(name: str) -> int:
     """Degree of mesh axis ``name`` as seen INSIDE a traced
     shard_map/pmap body: prefers ``jax.lax.axis_size`` (the axis bound
     in the trace — correct even for a caller-constructed Mesh that was
-    never installed via :func:`init_mesh`), falling back to the
-    installed mesh on old jax without the API. The ONE axis-size
+    never installed via :func:`init_mesh`). The ONE axis-size
     resolution shared by the hierarchical collectives, the compiled
     pipelines, and the collective-matmul kernels."""
     import jax
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(name))
-    return axis_size(name)
+    return int(jax.lax.axis_size(name))
 
 
 def group_size(axes: Sequence[str]) -> int:

@@ -56,14 +56,8 @@ def _maybe_init_jax_distributed() -> bool:
             or os.environ.get("PADDLE_TRAINERS_NUM") or 1)
     if not addr or n <= 1:
         return False
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return True
-    try:  # older jax: probe the global client instead
-        from jax._src import distributed as _jd
-        if _jd.global_state.client is not None:
-            return True
-    except Exception:
-        pass
     pid = int(os.environ.get("JAX_PROCESS_ID")
               or os.environ.get("PADDLE_TRAINER_ID") or 0)
     jax.distributed.initialize(coordinator_address=addr,

@@ -44,6 +44,18 @@ def spawn(func, args: Iterable[Any] = (), nprocs: int = -1,
     exactly as under the CLI launcher."""
     if nprocs < 1:
         nprocs = int(os.environ.get("PADDLE_TRAINERS_NUM", 1))
+    # a chip belongs to one process at a time (and a parent that has
+    # touched JAX holds it): several workers on a TPU host would fail
+    # or hang against each other — same rule as the CLI launcher
+    from ..flags import _env_platform
+    platform = str(options.get("env", {}).get("JAX_PLATFORMS", "")) \
+        or _env_platform(os.environ)
+    if nprocs > 1 and platform.lower().startswith("tpu"):
+        raise RuntimeError(
+            f"spawn(nprocs={nprocs}) on a TPU host: every worker would "
+            "claim the same chips. Drive the host's chips from one "
+            "process (a mesh over jax.devices()), or pass "
+            "env={'JAX_PLATFORMS': 'cpu'} for a CPU gang.")
     with tempfile.NamedTemporaryFile("wb", suffix=".pkl",
                                      delete=False) as f:
         pickle.dump((func, tuple(args)), f)

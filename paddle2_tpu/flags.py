@@ -90,48 +90,59 @@ def flag_value(name: str) -> Any:
 # Core flags (subsystem-specific flags are defined where they are used).
 define_flag("check_nan_inf", False,
             "Per-op nan/inf checking in eager mode (nan_inf_utils parity).")
-define_flag("enable_api_kernel_fallback", True,
-            "Fall back to CPU execution when an op has no device lowering.")
 define_flag("eager_vjp_cache", True,
             "Cache per-op linearized VJP computations keyed on shapes/dtypes.")
 define_flag("log_level", 0, "Framework verbosity (VLOG-style).")
+# -- persistent compilation cache --------------------------------------
+# One rule, so the cache can be placed from outside and never moves:
+#   1. JAX_COMPILATION_CACHE_DIR set -> JAX reads it itself and this
+#      package never touches jax_compilation_cache_dir;
+#   2. else FLAGS_compilation_cache_dir / PADDLE2_TPU_CACHE_DIR (the
+#      launcher's --compile_cache_dir), '' = off;
+#   3. else ONE fixed path inside the checkout, on by default. The path
+#      is part of every cache key, so it is never built from a temporary
+#      directory, a pid, a job id or the time. (tests/conftest.py turns
+#      the default off for the test run, explicitly, with rule 2.)
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """The directory the persistent XLA cache uses ('' = off)."""
+    return os.environ.get(COMPILE_CACHE_ENV) \
+        or str(flag_value("compilation_cache_dir") or "")
+
+
 def _apply_compilation_cache(path: str) -> None:
     import jax
-    # empty REALLY disables (clears a previously-set directory)
-    jax.config.update("jax_compilation_cache_dir", path or None)
-    if path:
-        # min compile time gates what is worth persisting; the elastic
-        # restart path (and tests) override via env — a respawned
-        # worker wants EVERY train-step executable cached, since each
-        # one is pure MTTR on the next recovery
-        min_s = float(os.environ.get("PADDLE2_TPU_CACHE_MIN_COMPILE_S",
-                                     "1.0"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_s)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except Exception:
-            pass
+    from jax.experimental.compilation_cache import compilation_cache
+    if not os.environ.get(COMPILE_CACHE_ENV):
+        # empty REALLY disables (clears a previously-set directory)
+        jax.config.update("jax_compilation_cache_dir", path or None)
+    # min compile time gates what is worth persisting; the elastic
+    # restart path (and tests) override via env — a respawned worker
+    # wants EVERY train-step executable cached, since each one is pure
+    # MTTR on the next recovery
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(os.environ.get("PADDLE2_TPU_CACHE_MIN_COMPILE_S", "1.0")))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # the in-process cache singleton latches its configuration on first
-    # compile: without a reset, enabling the directory AFTER anything
-    # has compiled (the elastic restart path re-enables it at resume
-    # time) would silently leave the persistent cache off
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
+    # compile: without a reset, changing the directory AFTER anything
+    # has compiled would silently leave the old setting in force
+    compilation_cache.reset_cache()
 
 
 define_flag("compilation_cache_dir", os.environ.get(
-    "PADDLE2_TPU_CACHE_DIR", ""),
+    "PADDLE2_TPU_CACHE_DIR", DEFAULT_COMPILE_CACHE_DIR),
     "Persistent XLA compilation cache directory: repeat runs skip the "
-    "30s+ first-compile of large programs (the executor program-cache "
-    "persistence analog). Empty disables.",
+    "first-compile of large programs. Defaults to the fixed "
+    "<checkout>/.jax_cache; empty disables. Yields to "
+    "JAX_COMPILATION_CACHE_DIR, which JAX reads itself.",
     on_change=_apply_compilation_cache)
-if _REGISTRY["compilation_cache_dir"].value:
-    _apply_compilation_cache(_REGISTRY["compilation_cache_dir"].value)
+_apply_compilation_cache(_REGISTRY["compilation_cache_dir"].value)
 
 
 define_flag("conv_prefer_channels_last", False,
@@ -140,15 +151,6 @@ define_flag("conv_prefer_channels_last", False,
             "end-to-end (XLA's layout assignment already optimizes the "
             "NCHW graph) — off by default; a knob for conv-heavy models "
             "where it measures better.")
-define_flag("pallas_layer_norm", False,
-            "Route last-axis affine LayerNorm through the fused Pallas "
-            "kernel (kernels/pallas_ln.py) on TPU. Measured 0.30 vs "
-            "0.44 ms/LN ISOLATED at [8192,1024] bf16 fwd+bwd on v5e, "
-            "but 241 vs 229 ms/step on the GPT bench — the custom-call "
-            "boundary blocks XLA's fusion with the surrounding "
-            "residual/matmul ops and the remat policy re-runs the "
-            "opaque forward in backward. Off by default; a knob for "
-            "LN-dominated models.")
 define_flag("max_program_cache_size", 32,
             "Guard-miss budget per to_static function: beyond this many "
             "compiled variants the function falls back to eager "
@@ -172,11 +174,16 @@ define_flag("fused_optimizer_step", False,
 # -- XLA comm/compute-overlap knobs (multichip) -----------------------------
 # The latency-hiding scheduler and async collectives are what turn the
 # bucketed grad reduces and ZeRO-3 prefetch gathers from SERIAL wire
-# time into overlapped wire time. They are compiler-process-wide
-# XLA_FLAGS, so they are NEVER applied implicitly: only
-# apply_multichip_xla_env() (called by launchers / hybrid_mesh for
-# multichip TPU runs) mutates the environment, and only before backend
-# init — a single-chip CPU test compile never sees them.
+# time into overlapped wire time. They are options of the TPU runtime,
+# which reads them from LIBTPU_INIT_ARGS when it loads — NOT from
+# XLA_FLAGS: jaxlib's own parser (which also runs on a TPU host, for the
+# CPU client) knows none of them and aborts the process on an unknown
+# XLA_FLAGS token. Every token below is accepted by the installed
+# libtpu (0.0.34) from LIBTPU_INIT_ARGS. They are process-wide, so they
+# are NEVER applied implicitly: only apply_multichip_xla_env() (called
+# by launchers / hybrid_mesh for multichip TPU runs) mutates the
+# environment, and only before backend init — a single-chip CPU test
+# compile never sees them.
 define_flag("xla_latency_hiding_scheduler", True,
             "Schedule XLA collectives with the latency-hiding scheduler "
             "so in-flight collectives overlap independent compute "
@@ -191,8 +198,9 @@ define_flag("xla_async_collectives", True,
             "apply_multichip_xla_env() before backend init; no-op on "
             "CPU.")
 
-# flag name -> XLA_FLAGS tokens it expands to (tokens carry explicit
-# ={true|false} so disabling a knob can OVERRIDE an operator default)
+# flag name -> LIBTPU_INIT_ARGS tokens it expands to (tokens carry
+# explicit ={true|false} so disabling a knob can OVERRIDE an operator
+# default)
 _XLA_PERF_FLAG_TOKENS = {
     "xla_latency_hiding_scheduler": (
         "--xla_tpu_enable_latency_hiding_scheduler={v}",
@@ -208,7 +216,8 @@ _XLA_PERF_FLAG_TOKENS = {
 
 
 def multichip_xla_flag_tokens() -> List[str]:
-    """The XLA_FLAGS tokens the current knob values expand to."""
+    """The LIBTPU_INIT_ARGS tokens the current knob values expand
+    to."""
     out: List[str] = []
     for name, tokens in _XLA_PERF_FLAG_TOKENS.items():
         v = "true" if flag_value(name) else "false"
@@ -254,12 +263,16 @@ def _env_platform(env) -> str:
     return ""
 
 
+TPU_RUNTIME_ARGS_ENV = "LIBTPU_INIT_ARGS"
+
+
 def apply_multichip_xla_env(env=None, platform: Optional[str] = None
                             ) -> str:
-    """Append the overlap-scheduling XLA flags to ``env['XLA_FLAGS']``
-    and return the resulting string.
+    """Append the overlap-scheduling flags to
+    ``env['LIBTPU_INIT_ARGS']`` (where the TPU runtime reads them) and
+    return the resulting string. ``XLA_FLAGS`` is never touched.
 
-    Guard rails, because XLA_FLAGS is process-wide: (a) NO-OP unless
+    Guard rails, because the variable is process-wide: (a) NO-OP unless
     the target platform is TPU — ``platform`` explicit, else detected
     from env vars without touching jax, so a CPU test process is never
     mutated; (b) idempotent — a token already present (from the
@@ -267,13 +280,13 @@ def apply_multichip_xla_env(env=None, platform: Optional[str] = None
     operator's existing value WINS over the knob default."""
     env = os.environ if env is None else env
     plat = (platform or _env_platform(env) or "").lower()
+    existing = env.get(TPU_RUNTIME_ARGS_ENV, "")
     if not plat.startswith("tpu"):
-        return env.get("XLA_FLAGS", "")
-    existing = env.get("XLA_FLAGS", "")
+        return existing
     have = {t.split("=", 1)[0] for t in existing.split() if t}
     added = [t for t in multichip_xla_flag_tokens()
              if t.split("=", 1)[0] not in have]
     if added:
-        env["XLA_FLAGS"] = " ".join(([existing] if existing else [])
-                                    + added)
-    return env.get("XLA_FLAGS", "")
+        env[TPU_RUNTIME_ARGS_ENV] = " ".join(
+            ([existing] if existing else []) + added)
+    return env.get(TPU_RUNTIME_ARGS_ENV, "")

@@ -75,12 +75,8 @@ def autotune_mode() -> str:
     env = os.environ.get(AUTOTUNE_MODE_ENV, "").strip().lower()
     if env in ("model", "measure"):
         return env
-    try:
-        import jax
-        platform = jax.devices()[0].platform.lower()
-    except Exception:
-        platform = "cpu"
-    return "measure" if platform not in ("", "cpu") else "model"
+    from ..kernels._platform import on_tpu
+    return "measure" if on_tpu() else "model"
 
 
 def _tie_rng():
@@ -212,7 +208,7 @@ class RematCandidate:
     recompute_flops: float
     recompute_bytes: float
     offload_bytes: float = 0.0
-    wired: bool = True      # False: jax on this host can't express it
+    wired: bool = True      # False: excluded from the search
 
     def overhead_s(self, peak_flops: float, hbm_bps: float,
                    offload_bps: float) -> float:
@@ -244,15 +240,6 @@ class RematPlan:
         compiled entry."""
         return ("remat", self.policy, self.granularity,
                 self.use_recompute)
-
-
-def _offload_supported() -> bool:
-    try:
-        import jax
-        return hasattr(jax.checkpoint_policies,
-                       "save_and_offload_only_these_names")
-    except Exception:
-        return False
 
 
 def gpt_remat_candidates(hidden: int, ffn: int, num_heads: int,
@@ -360,8 +347,7 @@ def search_remat_policy(*, hidden: int, num_layers: int, num_heads: int,
         cands.append(RematCandidate(
             "offload_dots", "offload", t * H * a,
             dots_c.recompute_flops, dots_c.recompute_bytes,
-            offload_bytes=dots_c.saved_bytes,
-            wired=_offload_supported()))
+            offload_bytes=dots_c.saved_bytes))
     # residual stream between layers rides on top of every policy
     residual = t * H * a
     L = int(num_layers)

@@ -112,10 +112,8 @@ def fused_rotary_position_embedding(q, k=None, v=None, sin=None, cos=None,
         cos_a = cos_a[pos].reshape(B * S, D)
         sin_a = sin_a[pos].reshape(B * S, D)
 
-    try:
-        on_accel = jax.devices()[0].platform.lower() != "cpu"
-    except Exception:
-        on_accel = False
+    from ...kernels._platform import on_tpu
+    on_accel = on_tpu()
 
     def rot_one(arr):
         if not use_neox_rotary_style and on_accel:

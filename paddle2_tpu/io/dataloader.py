@@ -455,13 +455,12 @@ class DataLoader:
         elif self.use_shared_memory and \
                 self.collate_fn is default_collate_fn:
             # multiprocess + C++ shm ring: Python decode escapes the GIL
-            # (reference dataloader_iter.py:368 design); falls back to the
-            # thread prefetcher when the native lib can't build
-            try:
-                from .shm_loader import ShmProcessIter
-                it = ShmProcessIter(self, remaining)
-            except (RuntimeError, OSError):
-                it = None
+            # (reference dataloader_iter.py:368 design). The ring is
+            # built from shm_ring.cpp on first use; a build or shm
+            # failure raises here — use_shared_memory=False is the way
+            # to ask for the thread prefetcher
+            from .shm_loader import ShmProcessIter
+            it = ShmProcessIter(self, remaining)
         if it is None:
             it = _PrefetchIter(self, iter(remaining))
         self._active = (batches, start, it)

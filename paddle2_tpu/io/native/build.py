@@ -31,9 +31,9 @@ def _compile() -> str:
 
 
 def load_shm_ring():
-    """Returns the bound ctypes library, building it if needed; raises
-    RuntimeError when no toolchain is available (callers fall back to the
-    thread-pool loader)."""
+    """Returns the bound ctypes library, building it from
+    ``shm_ring.cpp`` when it is missing or older than the source (git
+    commits no binary); raises RuntimeError when it cannot be built."""
     global _lib
     with _lock:
         if _lib is not None:

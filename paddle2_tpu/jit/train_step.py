@@ -351,8 +351,8 @@ class TrainStepProgram:
         program variant); steady state never re-enters."""
         import logging
         import time as _time
-        from ..flags import flag_value
-        cache_dir = str(flag_value("compilation_cache_dir") or "")
+        from ..flags import compile_cache_dir
+        cache_dir = compile_cache_dir()
         tally = {"hit": 0, "miss": 0}
 
         class _CacheTap(logging.Handler):

@@ -8,8 +8,7 @@ package stays cheap):
 * ``pallas_flash`` — the tiled online-softmax flash kernel.
 * ``pallas_fused`` — fused AdamW/momentum STEP kernels (bitwise eager
   twins, in-place aliased), rmsnorm, rope.
-* ``pallas_matmul`` — int8 weight-only / int8xint8 / fp8-shaped matmul
+* ``pallas_matmul`` — int8/int4 weight-only and int8xint8 matmul
   kernels with analytic error bounds (ISSUE 10).
-* ``pallas_ln`` — fused LayerNorm (flag-gated).
 * ``fused_ce`` — chunked fused head + cross-entropy.
 """

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from ..framework.tensor import Tensor
 from ..ops.dispatch import apply_op, ensure_tensor
+from ._platform import on_tpu
 
 
 def _sdpa_xla(q, k, v, bias=None, causal=False, scale=None, dropout_p=0.0,
@@ -106,15 +107,44 @@ def set_flash_enabled(flag: bool) -> None:
 def use_pallas(q_shape) -> bool:
     if not flash_enabled():
         return False
-    try:
-        dev = jax.devices()[0]
-    except Exception:
-        return False
-    if dev.platform.lower() == "cpu":
+    if not on_tpu():
         return False
     # Pallas wins once the S*S score matrix stops fitting in VMEM-friendly
     # tiles; below that XLA's fusion is already near-roofline.
     return q_shape[1] >= 1024
+
+
+def _per_shard(fn, q_shape):
+    """Run the flash kernel per shard under a multi-device mesh.
+
+    The chip's partitioner cannot split a Mosaic kernel ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call
+    in a shard_map" — what a GSPMD-partitioned jit over a sharded batch
+    or tensor-parallel heads draws), so the kernel is wrapped in
+    ``shard_map``: batch over the data axes, heads over the model axes,
+    each device seeing its LOCAL batch and heads. Axes that divide
+    neither stay replicated. Inside an enclosing ``shard_map`` (the
+    compiled pipelines) the operands are already local."""
+    from ..distributed import mesh as mesh_mod
+    from ..distributed.spec_layout import SpecLayout, installed_layout
+    mesh = mesh_mod.get_mesh(auto_init=False)
+    if mesh is None or mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return fn
+    # the distributed layer owns the axis names: the layout installed
+    # with the mesh, else the default one (dp / sharding / mp)
+    layout = installed_layout() or SpecLayout()
+
+    def axes_for(names, dim):
+        axes = tuple(a for a in names if mesh.shape.get(a, 1) > 1)
+        return axes if axes and dim % math.prod(
+            mesh.shape[a] for a in axes) == 0 else None
+
+    spec = jax.sharding.PartitionSpec(
+        axes_for((layout.data_axis, layout.fsdp_axis), q_shape[0]), None,
+        axes_for((layout.tp_axis,), q_shape[2]), None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
@@ -149,7 +179,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         def fn(q, k, v):
             return flash_attention_bshd(q, k, v, causal=causal,
                                         block_q=bq, block_k=bk)
-        return apply_op("flash_attention", fn, tuple(tensors), {})
+        return apply_op("flash_attention",
+                        _per_shard(fn, tuple(query.shape)),
+                        tuple(tensors), {})
 
     def fn(q, k, v, *mask):
         bias = mask[0] if mask else None

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._platform import interpret_default
+
 # 1024-tiles measured best on v5e for the GPT bench (scores tile of
 # 1024x1024 f32 = 4MB sits comfortably in VMEM; fewer grid steps beats
 # finer tiling until S is long enough that autotune picks smaller blocks).
@@ -41,13 +43,6 @@ DEFAULT_BLOCK_K = int(_os.environ.get("FLAGS_flash_block_k", 1024))
 BWD_BLOCK_Q = int(_os.environ.get("FLAGS_flash_bwd_block_q", 0)) or None
 BWD_BLOCK_K = int(_os.environ.get("FLAGS_flash_bwd_block_k", 0)) or None
 NEG_INF = float("-inf")
-
-
-def _interpret_default() -> bool:
-    try:
-        return jax.devices()[0].platform.lower() == "cpu"
-    except Exception:
-        return True
 
 
 def _fit_block(s: int, want: int):
@@ -844,7 +839,7 @@ def flash_attention_varlen_packed(q, k, v, seg_q, off_q, seg_k, off_k,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     block_q = block_q or DEFAULT_BLOCK_Q
     block_k = block_k or DEFAULT_BLOCK_K
     cfg = (float(scale), int(block_q), int(block_k), bool(interpret))
@@ -870,7 +865,7 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
         from .attention import _sdpa_xla
         return _sdpa_xla(q, k, v, causal=causal, scale=scale)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     cfg = (float(scale), bool(causal), int(block_q), int(block_k),
            bool(interpret))
     fn = _cached_jit(("bshd",) + cfg, lambda: (

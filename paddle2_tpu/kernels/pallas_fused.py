@@ -19,12 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret_default() -> bool:
-    try:
-        return jax.devices()[0].platform.lower() == "cpu"
-    except Exception:
-        return True
+from ._platform import interpret_default
 
 
 # ------------------------------------------------------------ fused adamw
@@ -60,7 +55,7 @@ def fused_adamw(param, grad, m, v, master, lr, beta1=0.9, beta2=0.999,
     (fused_adam_kernel.cu parity): param bf16/f32, master+moments f32.
     Returns (new_param, new_m, new_v, new_master)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     n = param.size
     flat = lambda a: a.reshape(-1)
     p1, g1, m1, v1, w1 = (flat(a) for a in (param, grad, m, v, master))
@@ -172,7 +167,7 @@ def fused_adamw_step(param, grad, m, v, lr, step, beta1=0.9,
     `weight_decay=0.0` skips the decay subtract entirely (the eager
     `if wd and decay` branch)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     t = step.astype(jnp.float32)
     # eager-twin scalar staging: 1-b computed in python f64 (the eager
     # closure constant), bias corrections at runtime from the weak-f32
@@ -233,7 +228,7 @@ def fused_momentum_step(param, grad, velocity, lr, momentum=0.9,
     equal to `Momentum._update_one` (+ the pre-update l2 fold) on f32
     state."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     sc = jnp.stack([lr.astype(jnp.float32), jnp.float32(momentum),
                     jnp.float32(weight_decay)])
     blk = block or min(param.size, 1 << 17)
@@ -351,7 +346,7 @@ def fused_rms_norm(x, weight, epsilon=1e-6, block_rows=512, interpret=None):
     """RMSNorm over the last dim in one pallas pass (fwd + custom bwd);
     any leading shape. Differentiable."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     shape = x.shape
     H = shape[-1]
     key = ("rmsnorm", float(epsilon), int(block_rows), bool(interpret))
@@ -387,7 +382,7 @@ def fused_rope(x, cos, sin, block_rows=256, interpret=None):
     jvp/transpose of the underlying computation is not available — use
     the custom vjp below)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     B, S, H, D = x.shape
     rows = B * S
     x2 = x.reshape(rows, H, D)

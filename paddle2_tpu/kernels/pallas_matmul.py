@@ -26,14 +26,12 @@ Two dtype families:
 * **int8 x int8** — both operands int8, int32 MXU accumulation (2x the
   bf16 rate on v5e), dequantized at the epilogue: the
   ``QuantedInferenceLinear`` full-int8 path as a Pallas kernel.
-* **fp8-shaped** (:func:`fp8_matmul`) — where the jax build exposes
-  ``float8_e4m3fn``, the same tiling with fp8 operand casts; gated by
-  :func:`fp8_supported` and never chosen implicitly.
 
-Dispatch: :func:`int8_weight_only_matmul` runs the Pallas kernel on TPU
-for aligned shapes and falls back to the numerically-equivalent XLA
-lowering elsewhere (CPU/CI, ragged shapes) — both produce the same
-dequantized product, so the analytic bound gates BOTH lowerings.
+Dispatch (``interpret=None``): the Pallas kernel on a TPU for aligned
+shapes, the numerically-equivalent XLA lowering elsewhere (CPU/CI,
+ragged shapes) — both produce the same dequantized product, so the
+analytic bound gates BOTH lowerings. An explicit ``interpret=False``
+is the compiled kernel or an error, never the XLA dot.
 """
 
 from __future__ import annotations
@@ -46,23 +44,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._platform import on_tpu
+
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_K = 512
 
 
-def _interpret_default() -> bool:
-    try:
-        return jax.devices()[0].platform.lower() == "cpu"
-    except Exception:
-        return True
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform.lower() == "tpu"
-    except Exception:
-        return False
+def _pallas_interpret(aligned: bool, interpret: Optional[bool],
+                      what: str) -> Optional[bool]:
+    """Resolve a wrapper's ``interpret=`` into the flag its
+    ``pallas_call`` takes, or ``None`` for the XLA lowering.
+    ``interpret=False`` means the COMPILED kernel or an error — never
+    the XLA dot; ``None`` picks the compiled kernel on a TPU for
+    aligned shapes and the XLA lowering elsewhere; ``True`` interprets
+    aligned shapes."""
+    if interpret is False and not aligned:
+        raise ValueError(
+            f"{what}: interpret=False asks for the compiled Pallas "
+            "kernel, which needs block-aligned operands")
+    if not aligned:
+        return None
+    if interpret is None:
+        return False if on_tpu() else None
+    return bool(interpret)
 
 
 # ------------------------------------------------------------ primitives
@@ -189,6 +194,7 @@ def _wo_pallas(x2, w_int8, scale, qmax, out_dtype, bm, bn, bk,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="int8_weight_only_matmul",
     )(x2, w_int8, scale.reshape(1, N))
 
 
@@ -218,9 +224,10 @@ def int8_weight_only_matmul(x, w_int8, w_scale, bias=None,
     m = 1
     for d in lead:
         m *= int(d)
-    aligned = wo_supported(m, K, N, block_m, block_n, block_k)
-    use_pallas = aligned and (interpret is True or _on_tpu())
-    if use_pallas:
+    interpret = _pallas_interpret(
+        wo_supported(m, K, N, block_m, block_n, block_k), interpret,
+        "int8_weight_only_matmul")
+    if interpret is not None:
         x2 = x.reshape(m, K)
         # with a bias the kernel keeps its epilogue in f32 so the bias
         # folds in BEFORE the single output cast — the same rounding
@@ -229,9 +236,7 @@ def int8_weight_only_matmul(x, w_int8, w_scale, bias=None,
         out_dtype = jnp.float32 if bias is not None else x.dtype
         out = _wo_pallas(x2, w_int8, jnp.asarray(w_scale, jnp.float32),
                          qmax, out_dtype, min(block_m, m),
-                         min(block_n, N), min(block_k, K),
-                         bool(interpret) if interpret is not None
-                         else _interpret_default())
+                         min(block_n, N), min(block_k, K), interpret)
         out = out.reshape(lead + (N,))
         if bias is not None:
             out = (out + bias).astype(x.dtype)
@@ -255,8 +260,11 @@ def _i8i8_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+    # precision pinned: the package-wide "highest" default is an f32
+    # notion the chip's compiler refuses on integer operands
     acc_ref[...] += jax.lax.dot_general(
         x_ref[:], w_ref[:], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -274,9 +282,10 @@ def int8_matmul(x_int8, w_int8,
     ``dot_general`` with int32 accumulation elsewhere."""
     M, K = x_int8.shape
     N = w_int8.shape[1]
-    aligned = wo_supported(M, K, N, block_m, block_n, block_k)
-    use_pallas = aligned and (interpret is True or _on_tpu())
-    if not use_pallas:
+    interpret = _pallas_interpret(
+        wo_supported(M, K, N, block_m, block_n, block_k), interpret,
+        "int8_matmul")
+    if interpret is None:
         return jax.lax.dot_general(
             x_int8, w_int8, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
@@ -292,8 +301,8 @@ def int8_matmul(x_int8, w_int8,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=bool(interpret) if interpret is not None
-        else _interpret_default(),
+        interpret=interpret,
+        name="int8_matmul",
     )(x_int8, w_int8)
 
 
@@ -420,32 +429,10 @@ def collective_matmul_traffic(payload_bytes: float, tp: int,
     return t
 
 
-# ------------------------------------------------------------- fp8-shaped
-def fp8_supported() -> bool:
-    """True when this jax build carries the fp8 dtypes (the kernels are
-    SHAPE-compatible with fp8 — actual fp8 MXU rate needs v5p+)."""
-    return hasattr(jnp, "float8_e4m3fn")
-
-
-def fp8_matmul(x, w, interpret: Optional[bool] = None):
-    """fp8-shaped matmul: both operands cast to ``float8_e4m3fn``,
-    accumulated in f32. Opt-in only (caller owns the accuracy story);
-    raises where the dtype does not exist."""
-    if not fp8_supported():
-        raise NotImplementedError(
-            "fp8_matmul: this jax build has no float8_e4m3fn dtype")
-    f8 = jnp.float8_e4m3fn
-    out = jax.lax.dot_general(
-        x.astype(f8), w.astype(f8),
-        (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return out.astype(x.dtype)
-
-
 __all__ = ["channel_absmax", "quantize_channelwise",
            "weight_quant_error_bound", "int8_weight_only_matmul",
            "int4_weight_only_matmul", "pack_int4", "unpack_int4",
-           "int8_matmul", "fp8_matmul", "fp8_supported", "wo_supported",
+           "int8_matmul", "wo_supported",
            "allgather_matmul", "matmul_allgather",
            "collective_matmul_traffic",
            "DEFAULT_BLOCK_M", "DEFAULT_BLOCK_N", "DEFAULT_BLOCK_K"]

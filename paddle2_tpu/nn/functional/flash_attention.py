@@ -103,11 +103,9 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
 
     # packed pallas path: the ragged batch stays ONE [T, H, D] packed
     # sequence with per-row segment ids — no O(B*Smax^2) densify
+    from ...kernels._platform import on_tpu
     from ...kernels.attention import flash_enabled
-    try:
-        on_accel = jax.devices()[0].platform.lower() != "cpu"
-    except Exception:
-        on_accel = False
+    on_accel = on_tpu()
     head_dim = int(q.shape[-1])
     if (on_accel and flash_enabled() and drop_key is None
             and head_dim <= 256):   # pallas kernel range (supported())

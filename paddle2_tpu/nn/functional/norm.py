@@ -69,20 +69,6 @@ def batch_norm(x, running_mean, running_var, weight=None, bias=None,
     return out
 
 
-def _use_pallas_ln(x, n_axes, has_w, has_b) -> bool:
-    from ...flags import flag_value
-    if not flag_value("pallas_layer_norm") or n_axes != 1 \
-            or not (has_w and has_b):
-        return False
-    try:
-        if jax.devices()[0].platform.lower() == "cpu":
-            return False
-    except Exception:
-        return False
-    from ...kernels import pallas_ln
-    return pallas_ln.supported(tuple(x.shape))
-
-
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
                name=None) -> Tensor:
     x = ensure_tensor(x)
@@ -99,17 +85,6 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
     if has_b:
         tensors.append(ensure_tensor(bias))
 
-    if _use_pallas_ln(x, n_axes, has_w, has_b):
-        # fused one-pass Pallas kernel (kernels/pallas_ln.py); routed
-        # through a cached jit wrapper — an eager pallas closure would
-        # re-run the Mosaic compiler on every call
-        from ...kernels import pallas_ln
-        from ...kernels.pallas_flash import _cached_jit
-        key = ("pallas_ln", tuple(x.shape), str(x._data.dtype),
-               float(epsilon))
-        fn = _cached_jit(key, lambda: _pallas_ln_fn(epsilon))
-        return apply_op("layer_norm", fn, tuple(tensors), {})
-
     def fn(a, *wb):
         mean = jnp.mean(a, axis=axes, keepdims=True)
         var = jnp.var(a, axis=axes, keepdims=True)
@@ -121,14 +96,6 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
             out = out + wb[i]
         return out.astype(a.dtype)
     return apply_op("layer_norm", fn, tuple(tensors), {})
-
-
-def _pallas_ln_fn(epsilon):
-    from ...kernels.pallas_ln import fused_layer_norm
-
-    def run(a, w, b):
-        return fused_layer_norm(a, w, b, float(epsilon))
-    return run
 
 
 def rms_norm(x, weight=None, epsilon=1e-6, name=None) -> Tensor:

@@ -55,11 +55,13 @@ CHIP_HBM_GB: Dict[str, float] = {
     "v4": 32.0, "v5p": 95.0,
     "v6 lite": 32.0, "v6e": 32.0, "trillium": 32.0,
 }
-_DEFAULT_PEAK = (197e12, 819e9)          # v5e-assumed
-_DEFAULT_HBM_GB = 16.0
-# CPU fallback: a deliberately round nominal figure so MFU numbers off
-# accelerators are obviously synthetic rather than silently wrong
+# CPU entry: a deliberately round nominal figure, so the virtual-clock
+# drills stay deterministic and MFU numbers off accelerators are
+# obviously synthetic; the drills plan memory against the 16 GB chip
+# they model. A TPU whose device_kind is in neither table is an error,
+# never a default.
 _CPU_PEAK = (1e11, 5e10)
+_CPU_HBM_GB = 16.0
 
 PEAK_ENV = "PADDLE_PEAK_TFLOPS"
 HBM_ENV = "PADDLE_HBM_GBPS"
@@ -108,36 +110,44 @@ def host_link_bps(override_gbps=None) -> float:
     return float(os.environ.get(HOST_ENV, DEFAULT_HOST_GBPS)) * 1e9
 
 
+def _device_kind(device) -> Tuple[str, str]:
+    """``(platform, lower-cased device_kind)`` of ``device`` (default:
+    jax device 0)."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    return (device.platform.lower(),
+            (getattr(device, "device_kind", "") or "").lower())
+
+
+def _unknown_chip(kind: str, table: str, env: str) -> RuntimeError:
+    return RuntimeError(
+        f"device_kind {kind!r} is not in cost_model.{table}: add it "
+        f"with its published figures, or set {env}")
+
+
 def chip_peak(device=None) -> Tuple[float, float, str]:
     """(peak_flops, hbm_bytes_per_s, label) for ``device`` (default:
-    jax device 0; falls back to the CPU nominal figure without jax)."""
+    jax device 0): the table entry of its ``device_kind``, the CPU
+    nominal figure on the CPU backend, an error for a chip the table
+    does not know. ``PADDLE_PEAK_TFLOPS`` / ``PADDLE_HBM_GBPS``
+    override."""
     env_peak = os.environ.get(PEAK_ENV)
     env_hbm = os.environ.get(HBM_ENV)
     if env_peak and env_hbm:
         return (float(env_peak) * 1e12, float(env_hbm) * 1e9,
                 "env-override")
-    kind = ""
-    try:
-        if device is None:
-            import jax
-            device = jax.devices()[0]
-        kind = getattr(device, "device_kind", "") or ""
-        platform = getattr(device, "platform", "").lower()
-    except Exception:
-        platform = "cpu"
-    low = kind.lower()
+    platform, low = _device_kind(device)
     peak, hbm, label = None, None, ""
     for key, (p, h) in CHIP_PEAKS.items():
         if key in low:
             peak, hbm, label = p, h, key
             break
     if peak is None:
-        if platform in ("", "cpu"):
-            (peak, hbm), label = \
-                _CPU_PEAK, f"cpu-nominal({low or 'unknown'})"
-        else:
-            (peak, hbm), label = \
-                _DEFAULT_PEAK, f"v5e-assumed({low or 'unknown'})"
+        if platform != "cpu":
+            raise _unknown_chip(low, "CHIP_PEAKS",
+                                f"{PEAK_ENV} and {HBM_ENV}")
+        (peak, hbm), label = _CPU_PEAK, f"cpu-nominal({low or 'unknown'})"
     # each override applies independently (an operator may know only
     # one of the two figures for an odd deployment)
     if env_peak:
@@ -175,11 +185,17 @@ def program_cost(entry, call_args: Sequence[Any]) -> Optional[Dict[str, float]]:
 def abstractify(call_args: Sequence[Any]) -> List[Any]:
     """Shape/dtype skeleton of ``call_args`` — safe to hold across a
     donating dispatch (the concrete buffers die with the donation) and
-    accepted by ``jit(...).lower``."""
+    accepted by ``jit(...).lower``. An array placed over SEVERAL
+    devices keeps its sharding, so re-lowering and compiling gives the
+    partitioned program the dispatch ran (``lowered.cost_analysis()``
+    reads the program before partitioning and is unchanged by it)."""
     import jax
 
     def _one(a):
         if hasattr(a, "shape") and hasattr(a, "dtype"):
+            sh = getattr(a, "sharding", None)
+            if sh is not None and len(sh.device_set) > 1:
+                return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
         return a
     return jax.tree_util.tree_map(_one, list(call_args))
@@ -716,23 +732,19 @@ def pipeline_bubble_fraction(pp: int, microbatches: int,
 
 def chip_hbm_gb(device=None) -> float:
     """HBM capacity (GB) of ``device`` (default: jax device 0), from
-    the generation table; ``PADDLE_HBM_CAPACITY_GB`` overrides, CPU /
-    unknown falls back to the v5e 16 GB figure."""
+    the generation table; ``PADDLE_HBM_CAPACITY_GB`` overrides. The CPU
+    backend gets the 16 GB of the chip its drills model; a chip the
+    table does not know is an error."""
     env = os.environ.get("PADDLE_HBM_CAPACITY_GB")
     if env:
         return float(env)
-    kind = ""
-    try:
-        if device is None:
-            import jax
-            device = jax.devices()[0]
-        kind = (getattr(device, "device_kind", "") or "").lower()
-    except Exception:
-        pass
+    platform, kind = _device_kind(device)
     for key, gb in CHIP_HBM_GB.items():
         if key in kind:
             return gb
-    return _DEFAULT_HBM_GB
+    if platform != "cpu":
+        raise _unknown_chip(kind, "CHIP_HBM_GB", "PADDLE_HBM_CAPACITY_GB")
+    return _CPU_HBM_GB
 
 
 class PhasedStepCost:

@@ -17,7 +17,7 @@ Host/device split:
   bookkeeping (free list, per-sequence id lists, high-water mark) —
   cheap python between decode steps, never traced.
 * :class:`PagedKVCache` owns the device pools — one
-  ``[layers, num_blocks, block_size, heads, head_dim]`` array for K
+  ``[layers, num_blocks, block_size, heads * head_dim]`` array for K
   and one for V — and the jnp scatter/gather helpers the compiled
   decode program uses. The pools are donated through the decode
   program, so appends are in-place on device.
@@ -351,7 +351,13 @@ class BlockTable:
 
 class PagedKVCache:
     """Device pools for a whole model: K and V, each
-    ``[num_layers, num_blocks, block_size, num_heads, head_dim]``.
+    ``[num_layers, num_blocks, block_size, num_heads * head_dim]`` —
+    token-major, a token's heads merged into one lane-dense row: the
+    chip pads the minor dimension to 128 lanes, so a separate
+    ``head_dim`` 64 axis would double the pool in HBM, and the paged
+    kernel's DMA addresses ``[block_size, 128]``-lane pages of exactly
+    this shape. A new token is one contiguous row, so the decode append
+    is a plain row scatter the compiler updates in place.
 
     Pools start zeroed; stale data in freed blocks is harmless — the
     paged-attention kernel masks every slot past a sequence's context
@@ -366,7 +372,8 @@ class PagedKVCache:
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
         self.dtype = jnp.dtype(dtype)
-        shape = (num_layers, num_blocks, block_size, num_heads, head_dim)
+        shape = (num_layers, num_blocks, block_size,
+                 num_heads * head_dim)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
 
@@ -391,12 +398,12 @@ class PagedKVCache:
     def scatter_decode(pool, layer, phys, slot, new_kv):
         """Write one new token per sequence into ONE layer's lane:
         ``pool[layer, phys[b], slot[b]] = new_kv[b]``.
-        pool: [L, N, bs, H, D]; phys/slot: int32 [B]; new_kv:
+        pool: [L, N, bs, H*D]; phys/slot: int32 [B]; new_kv:
         [B, H, D]. Traced inside the compiled decode program (which
         donates the pool), per layer — the decode loop appends each
         layer's K/V right where it is produced."""
-        return pool.at[:, phys, slot].set(new_kv) if layer is None \
-            else pool.at[layer, phys, slot].set(new_kv)
+        return pool.at[layer, phys, slot].set(
+            new_kv.reshape(new_kv.shape[0], -1))
 
     @staticmethod
     def scatter_prefill(pool, layer_kv, block_row, n_tokens, block_size,
@@ -405,7 +412,7 @@ class PagedKVCache:
         jitted scatter with the pool DONATED — the eager per-page
         ``.at[].set`` loop this replaces copied the ENTIRE pool once
         per page per lane (O(pool x pages) allocator traffic at
-        production pool sizes). pool: [L, N, bs, H, D]; layer_kv:
+        production pool sizes). pool: [L, N, bs, H*D]; layer_kv:
         [L, T, H, D] (T >= n_tokens when the prefill ran padded);
         block_row: int array [n_pages] physical ids. ``start`` skips
         the leading positions — a prefix-cache hit must NOT rewrite
@@ -429,7 +436,7 @@ class PagedKVCache:
             n = int(n_tokens)
             fn = jax.jit(
                 lambda p, kv, ph, sl: p.at[:, ph, sl].set(
-                    kv[:, start:n]),
+                    kv[:, start:n].reshape(kv.shape[0], n - start, -1)),
                 donate_argnums=(0,))
             if len(_PREFILL_SCATTER_CACHE) > 1024:
                 _PREFILL_SCATTER_CACHE.clear()
@@ -455,11 +462,11 @@ class PagedKVCache:
 
     @staticmethod
     def gather_dense(pool_layer, block_row, n_pages):
-        """Dense [n_pages*bs, H, D] view of one sequence's K or V via
+        """Dense [n_pages*bs, H*D] rows of one sequence's K or V via
         its block table — the reference path's gather."""
         import jax.numpy as jnp
         idx = jnp.asarray(block_row[:n_pages], jnp.int32)
-        g = pool_layer[idx]                      # [P, bs, H, D]
+        g = pool_layer[idx]                      # [P, bs, H*D]
         return g.reshape((-1,) + g.shape[2:])
 
 

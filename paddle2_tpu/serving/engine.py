@@ -237,7 +237,15 @@ class ServingEngine:
         from ..models.gpt import GPTForCausalLM
         loaded = jit_load(artifact_path, params_path=params_path)
         model = GPTForCausalLM(gpt_config)
-        model.set_state_dict(loaded.state_dict())
+        state = loaded.state_dict()
+        # the artifact's dtypes are the serving dtypes: a bf16
+        # (amp O2) artifact is served in bf16, not widened back to the
+        # rebuilt architecture's f32 defaults
+        for name, t in model.state_dict().items():
+            saved = state.get(name)
+            if saved is not None and saved.dtype != t._data.dtype:
+                t._replace_data(t._data.astype(saved.dtype))
+        model.set_state_dict(state)
         return model
 
     # -- KV tier I/O (ISSUE 16) ------------------------------------------

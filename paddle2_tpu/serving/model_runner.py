@@ -203,7 +203,7 @@ class PagedGPTRunner:
                         block_tables):
             # ids [B,1] int32; positions [B] int32 (0-based slot of the
             # NEW token); block_tables [B,P] int32. Pools
-            # [L, N, bs, H, D], donated.
+            # [L, N, bs, H*D], donated.
             B = batch
             phys = jnp.take_along_axis(
                 block_tables, (positions // block_size)[:, None],
@@ -225,10 +225,12 @@ class PagedGPTRunner:
                                                k._data[:, 0])
                     v_pool = _C.scatter_decode(v_pool, li, phys, slot,
                                                v._data[:, 0])
+                    # the whole pool rides in; the layer is a static
+                    # block index, never a sliced-out copy
                     attn = paged_attention_decode(
-                        q._data, k_pool[li], v_pool[li], block_tables,
+                        q._data, k_pool, v_pool, block_tables,
                         ctx, interpret=self.interpret,
-                        pages_per_split=self.split_pages)
+                        pages_per_split=self.split_pages, layer=li)
                     a = block.attn.out_proj(
                         Tensor(attn.reshape(B, 1, nh * hd)))
                     x = x + block.dropout(a)

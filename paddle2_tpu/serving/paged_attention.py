@@ -10,51 +10,58 @@ compute DMA source blocks before the body runs (the
 ``PrefetchScalarGridSpec`` pattern from the official TPU paged
 kernels) — and gathers K/V blocks into VMEM.
 
+The pools are token-major with a token's heads merged into one
+lane-dense row, ``[layers, num_blocks, block_size, H*D]``, and a page
+is a ``[block_size, 128]``-lane block of it: the only page shapes the
+chip's DMA accepts have their last two dimensions whole or multiples
+of (8, 128) — a 1-head slice of a ``[bs, H, D]`` block is refused, and
+a separate ``D`` 64 axis would be padded to 128 lanes, doubling the
+pool in HBM. With ``D`` 64 a 128-lane page carries TWO heads (a *lane
+group*); the query rides as an ``[8, 128]`` tile whose row ``r`` holds
+head ``r`` of the group in its own lanes and zeros elsewhere, so ONE
+dot per page gives every head of the group its own score row and ONE
+dot gives every head its own output lanes. Scratch is PAGE-major
+(``[P, 8, bs]`` scores, ``[P, bs, 128]`` values) so a page lands on the
+untiled leading axis and no store starts at an unaligned lane offset.
+The layer is a static block index into the whole-model pool, so a
+decode program never slices (copies) a layer out of it. Both bodies
+compile for a v5e chip at serving widths
+(``tests/test_chip_compile.py``).
+
 Two bodies behind ONE dispatcher (:func:`paged_attention_decode`):
 
-* **Single-split (global softmax)** — the PR 9 body: per-page score
-  dots write into one ``[8, n_pages*block_size]`` score buffer and
-  the softmax runs ONCE over the full row. Numerics contract (the
-  serving acceptance gate): bitwise identical in fp32 to
+* **Single-split (global softmax)** — per-page score dots fill the
+  score buffer and the softmax runs ONCE over the whole context:
+  ``dot(q, k) * scale`` -> mask with ``finfo.min`` -> ``max / exp /
+  sum / divide`` in f32 -> ``dot(p, v)``, the op sequence of
   :func:`paged_attention_reference` (dense gather through the same
-  table) which in turn is bitwise identical to
-  ``nn.functional.flash_attention`` on the contiguously gathered K/V —
-  all three run the *same op sequence*: ``dot(q, k) * scale`` -> mask
-  with ``finfo.min`` -> ``jax.nn.softmax(f32)`` -> ``dot(p, v)``, the
-  exact arithmetic of ``kernels/attention._sdpa_xla``. Pad slots hold
-  ``finfo.min`` scores (exactly-0.0 probability), and context lengths
-  are kept multiples of 8 so padded-width reductions group lanes
-  identically. VMEM scales with the context: scores ``8 x S`` + V
-  ``S x D`` — ~1.1 MB at S 2048 / D 128 f32, but ~17.8 MB at S 32768 /
-  D 128, PAST the ~16 MB/core budget: this body cannot serve 32k
-  contexts, which is exactly what the split body exists for.
+  table) and of ``kernels/attention._sdpa_xla``. Scores are f32 for
+  every input dtype. The kernel reduces per page and then across
+  pages where the reference reduces one ``[1, S]`` row, so fp32
+  agreement is a few ulp (tests: ``rtol=atol=2e-6``), not bitwise.
+  Pad slots hold ``finfo.min`` scores (exactly-0.0 probability).
+  VMEM scales with the context (:func:`decode_scratch_vmem_bytes`):
+  past :data:`VMEM_FIT_BUDGET` this body is not dispatched, and at
+  twice that the compiler refuses it — 32k contexts are what the
+  split body exists for.
 
-* **Split-K flash-decode (online softmax)** — ISSUE 14 / ROADMAP
-  item 4: the context is carved into splits of ``pages_per_split``
-  pages; each split runs the flash recurrence epilogue over its own
-  bounded score row (running max ``m``, denominator ``l``, and the
-  UNNORMALIZED value accumulator ``o`` — the ``pallas_flash.py``
-  pattern) and emits ``(m_i, l_i, o_i)`` partials; a tiny cross-split
-  reduction (:func:`_merge_splits`, jitted XLA) rescales by
-  ``exp(m_i - max m)`` and normalizes once. VMEM is bounded by the
-  SPLIT, not the context — any context length fits — and the splits
-  are independent (flash-decode parallelism on real hardware; the
-  in-kernel grid runs them sequentially per core). Acceptance:
-  bitwise (fp32) == :func:`paged_attention_split_reference` (the
-  dense twin that mirrors the split body's op sequence one-for-one),
-  allclose (1-ulp class) vs the global-softmax reference — the
-  per-split rescaling legally reassociates the reductions, so
-  bitwise-vs-global is not claimable, which is why SHORT contexts
-  keep dispatching to the single-split body and its stricter chain.
+* **Split-K flash-decode** — ISSUE 14: the context is carved into
+  splits of ``pages_per_split`` pages; each split runs the flash
+  epilogue over its own bounded scores (max ``m``, denominator ``l``,
+  UNNORMALIZED value accumulator ``o``) and emits ``(m_i, l_i, o_i)``
+  partials; a tiny cross-split reduction (:func:`_merge_splits`,
+  jitted XLA) rescales by ``exp(m_i - max m)`` and normalizes once.
+  VMEM is bounded by the SPLIT, not the context — any context length
+  fits. Acceptance: a few ulp (fp32) vs
+  :func:`paged_attention_split_reference` (the dense twin of the
+  split body's op sequence) and vs the global-softmax reference.
 
 Dispatch: ``pages_per_split=None`` (the default) picks the
-single-split body whenever its scratch fits the VMEM budget —
-bitwise-identical behavior to PR 9 at every context the PR 9 kernel
-could serve — and falls over to split-K with an auto-halved split
-width beyond it (:func:`auto_pages_per_split`). The deterministic
-accounting (:func:`decode_scratch_vmem_bytes`,
-:func:`modeled_decode_latency_s`) is what ``bench.py
---serving-throughput`` gates the 32k story on.
+single-split body whenever its scratch fits the VMEM budget and falls
+over to split-K with an auto-halved split width beyond it
+(:func:`auto_pages_per_split`). The deterministic accounting
+(:func:`decode_scratch_vmem_bytes`, :func:`modeled_decode_latency_s`)
+is what ``bench.py --serving-throughput`` gates the 32k story on.
 """
 
 from __future__ import annotations
@@ -68,12 +75,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..kernels.pallas_flash import NEG_INF, _interpret_default
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernel loads on every jax this repo meets
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from ..kernels._platform import interpret_default
+from ..kernels.pallas_flash import NEG_INF
 
 __all__ = ["paged_attention_decode", "paged_attention_reference",
            "paged_attention_split_reference", "gathered_dense_kv",
@@ -81,11 +84,13 @@ __all__ = ["paged_attention_decode", "paged_attention_reference",
            "auto_pages_per_split", "modeled_decode_latency_s",
            "VMEM_BYTES", "VMEM_FIT_BUDGET"]
 
-# v5e-class VMEM per core (the pallas guide's ~16 MB/core figure) and
-# the fraction a decode body may claim for its score/value scratch —
-# q/k/v tiles, the compiler's own spills, and double-buffering share
-# the rest. Both are accounting constants (deterministic on every
-# host), not runtime probes.
+# Scoped VMEM one kernel may claim on a v5e core: the limit the chip's
+# compiler enforces ("Scoped allocation with size 16.02M and limit
+# 16.00M", the refusal a 2048-page single-softmax body draws — see
+# tests/test_chip_compile.py, which compiles a body AT the fit budget),
+# and the share of it a decode body's score/value scratch may take —
+# the double-buffered q/k/v/o blocks and the compiler's own temporaries
+# share the rest.
 VMEM_BYTES = 16 * 2 ** 20
 VMEM_FIT_BUDGET = VMEM_BYTES // 2
 
@@ -93,95 +98,141 @@ VMEM_FIT_BUDGET = VMEM_BYTES // 2
 def _precision(dtype):
     # mirror ops.linalg._mxu_precision: bf16/f16 pinned to DEFAULT so
     # the MXU keeps its native-rate path; f32 inherits the global
-    # setting — the same choice _sdpa_xla makes, which the bitwise
-    # contract depends on
+    # setting — the same choice _sdpa_xla and the dense references make
     if jnp.dtype(dtype) in (jnp.bfloat16, jnp.float16):
         return jax.lax.Precision.DEFAULT
     return None
+
+
+def _page_scores(q_ref, k_ref, scale, first_col, ctx, fill):
+    """One page's masked scores ``[R, bs]`` f32 — row ``r`` is head
+    ``r`` of the lane group (its query is zero outside its own lanes,
+    so the other heads' keys contribute exact zeros). The whole tile
+    rides the dot: a 1-row slice of an 8-row tile is a layout the
+    chip's compiler refuses."""
+    q = q_ref[...]                                # (R, W)
+    k = k_ref[...]                                # (bs, W)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        precision=_precision(q.dtype),
+        preferred_element_type=jnp.float32) * scale
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + first_col
+    return jnp.where(cols < ctx, s, fill)
+
+
+def _page_sum(x, op):
+    """Reduce a per-page ``[P, R, bs]`` buffer over pages and slots
+    (lanes first, then the leading page axis) -> ``[1, R, 1]``."""
+    return op(op(x, axis=2, keepdims=True), axis=0, keepdims=True)
+
+
+def _weighted_values(p, v_buf):
+    """``sum_j p[j] @ v[j]``: ``[P, R, bs] x [P, bs, W] -> [R, W]`` f32
+    (a page-batched dot, then the page sum); row ``r``'s answer is in
+    head ``r``'s lanes."""
+    o = jax.lax.dot_general(
+        p.astype(v_buf.dtype), v_buf[...], (((2,), (1,)), ((0,), (0,))),
+        precision=_precision(v_buf.dtype),
+        preferred_element_type=jnp.float32)       # (P, 8, D)
+    return jnp.sum(o, axis=0)
 
 
 def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                    s_buf, v_buf, *, scale, block_size, n_pages):
     b = pl.program_id(0)
     j = pl.program_id(2)
+    fill = jnp.finfo(jnp.float32).min
 
     @pl.when(j == 0)
     def _init():
         # dead/pad slots: finfo.min scores (exactly-0 probability after
         # the f32 softmax) and zero V
-        s_buf[:] = jnp.full_like(s_buf, jnp.finfo(s_buf.dtype).min)
-        v_buf[:] = jnp.zeros_like(v_buf)
+        s_buf[...] = jnp.full_like(s_buf, fill)
+        v_buf[...] = jnp.zeros_like(v_buf)
 
     ctx = len_ref[b]
 
     @pl.when(j * block_size < ctx)
     def _gather():
-        # the score dot runs on a SINGLE query row: the gemm's row
-        # count changes XLA's reduction grouping (an 8-row dot drifts
-        # ~1 ulp from the 1-row dot flash_attention's decode einsum
-        # collapses to), and the bitwise contract hinges on matching
-        # it exactly. The tile itself stays 8 rows for TPU sublane
-        # layout; rows 1..7 are dead weight.
-        q = q_ref[0, 0][:1]                   # (1, D) native dtype
-        k = k_ref[0, :, 0, :]                 # (bs, D)
-        v = v_ref[0, :, 0, :]                 # (bs, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            precision=_precision(q.dtype)) * scale
-        cols = jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1) + j * block_size
-        s = jnp.where(cols < ctx, s, jnp.finfo(s.dtype).min)
-        s_buf[:1, pl.ds(j * block_size, block_size)] = \
-            s.astype(s_buf.dtype)
-        v_buf[pl.ds(j * block_size, block_size), :] = v
+        # scratch is PAGE-major ([P, 8, bs] / [P, bs, D]): the page
+        # index lands on the untiled leading axis, so no store ever
+        # starts at an unaligned lane offset
+        s_buf[j] = _page_scores(q_ref, k_ref, scale, j * block_size,
+                                ctx, fill)
+        v_buf[j] = v_ref[...]
 
     @pl.when(j == n_pages - 1)
     def _finalize():
-        # ONE global softmax over the assembled row — the same
-        # softmax(f32)-then-matmul sequence as _sdpa_xla, NOT the
-        # online-softmax recurrence (whose per-block rescaling would
-        # round differently and break the bitwise contract)
-        probs = jax.nn.softmax(
-            s_buf[:1].astype(jnp.float32), axis=-1).astype(o_ref.dtype)
-        o = jax.lax.dot_general(
-            probs, v_buf[:], (((1,), (0,)), ((), ())),
-            precision=_precision(o_ref.dtype))       # (1, D)
-        o_ref[0, 0] = jnp.broadcast_to(o, o_ref.shape[2:]) \
-            .astype(o_ref.dtype)
+        # ONE global softmax over the assembled scores — max, exp,
+        # sum, divide, the op sequence of jax.nn.softmax(f32) — then
+        # probs @ V; NOT the online-softmax recurrence (whose per-block
+        # rescaling is a different rounding chain)
+        s = s_buf[...]                            # (P, R, bs) f32
+        e = jnp.exp(s - _page_sum(s, jnp.max))
+        probs = e / _page_sum(e, jnp.sum)
+        o_ref[...] = _weighted_values(probs, v_buf).astype(o_ref.dtype)
 
 
 # ------------------------------------------------- VMEM / cost accounting
-def decode_scratch_vmem_bytes(ctx_pad: int, head_dim: int,
-                              dtype="float32") -> int:
-    """VMEM scratch bytes a SINGLE-SPLIT decode body needs for a
-    padded context of ``ctx_pad`` keys: the ``[8, S]`` score buffer
-    plus the ``[S, D]`` gathered-V buffer (scores ride at f32 in the
-    split body; this accounting uses the wider of score/input dtype so
-    the figure upper-bounds both bodies)."""
-    it = max(jnp.dtype(dtype).itemsize, 4)
-    return (8 * ctx_pad + ctx_pad * head_dim) * it
+def _tile_pad(n: int, tile: int) -> int:
+    return -(-int(n) // tile) * tile
+
+
+def _lane_group(num_heads: int, head_dim: int):
+    """``(heads per group, query rows R, lane width W)`` of one page:
+    a 128-lane block carries ``128 // D`` heads; a ``D`` that is a
+    multiple of 128 is its own block; anything else takes the whole
+    ``H*D`` row (small test models)."""
+    if head_dim % 128 == 0:
+        hg = 1
+    elif 128 % head_dim == 0 and num_heads % (128 // head_dim) == 0:
+        hg = 128 // head_dim
+    else:
+        hg = num_heads
+    return hg, _tile_pad(hg, 8), hg * head_dim
+
+
+def decode_scratch_vmem_bytes(n_pages: int, block_size: int,
+                              head_dim: int, dtype="float32",
+                              num_heads: int = None) -> int:
+    """VMEM scratch bytes a decode body needs for ``n_pages`` pages,
+    as the chip lays them out: the page-major ``[P, R, bs]`` f32 score
+    buffer plus the ``[P, bs, W]`` gathered-V buffer, each page padded
+    to whole (sublane x 128-lane) tiles — a 16-slot page still takes a
+    full 128-lane row. Without ``num_heads`` the standard 128-lane
+    group is assumed (``R`` 8, ``W`` ``max(D, 128)``)."""
+    it = jnp.dtype(dtype).itemsize
+    sublane = 8 * 4 // it                 # f32 8, bf16 16, int8 32
+    if num_heads is None:
+        rows, width = 8, max(int(head_dim), 128)
+    else:
+        _, rows, width = _lane_group(num_heads, head_dim)
+    scores = rows * _tile_pad(block_size, 128) * 4
+    values = (_tile_pad(block_size, sublane) * _tile_pad(width, 128)
+              * it)
+    return int(n_pages) * (scores + values)
 
 
 def fits_single_softmax(n_pages: int, block_size: int, head_dim: int,
-                        dtype="float32",
-                        budget: int = None) -> bool:
-    """Can the PR 9 global-softmax body serve this context at all?
-    False at 32k (D 128): its whole-context scratch blows the VMEM
-    budget — the feasibility half of the bench's 32k gate."""
+                        dtype="float32", budget: int = None,
+                        num_heads: int = None) -> bool:
+    """Can the global-softmax body serve this context at all? False
+    at 32k: its whole-context scratch blows the VMEM budget — the
+    feasibility half of the bench's 32k gate."""
     if budget is None:
         budget = VMEM_FIT_BUDGET
-    return decode_scratch_vmem_bytes(n_pages * block_size, head_dim,
-                                     dtype) <= budget
+    return decode_scratch_vmem_bytes(n_pages, block_size, head_dim,
+                                     dtype, num_heads) <= budget
 
 
 def auto_pages_per_split(n_pages: int, block_size: int, head_dim: int,
-                         dtype="float32",
-                         budget: int = None) -> int:
+                         dtype="float32", budget: int = None,
+                         num_heads: int = None) -> int:
     """Largest halving of ``n_pages`` whose per-split scratch fits the
     VMEM budget (deterministic — no device probing)."""
     pps = max(int(n_pages), 1)
-    while pps > 1 and not fits_single_softmax(pps, block_size, head_dim,
-                                              dtype, budget):
+    while pps > 1 and not fits_single_softmax(
+            pps, block_size, head_dim, dtype, budget, num_heads):
         pps = -(-pps // 2)
     return pps
 
@@ -224,7 +275,7 @@ def modeled_decode_latency_s(ctx_tokens: int, num_heads: int,
             "flops": flops, "n_splits": n_splits,
             "pages_per_split": pps,
             "scratch_vmem_bytes": decode_scratch_vmem_bytes(
-                pps * block_size, head_dim, dtype)}
+                pps, block_size, head_dim, dtype)}
 
 
 # ------------------------------------------- split-K flash-decode body
@@ -232,7 +283,7 @@ def _decode_kernel_split(bt_ref, len_ref, q_ref, k_ref, v_ref,
                          o_ref, m_ref, l_ref, s_buf, v_buf, *,
                          scale, block_size, pages_per_split, n_pages):
     """One (batch, head, split) program: gather the split's pages,
-    then the flash epilogue over the split's bounded score row —
+    then the flash epilogue over the split's bounded scores —
     ``m_i = max``, ``p = exp(s - m_i)``, ``l_i = sum p``,
     ``o_i = p @ V`` (UNNORMALIZED) — written out as partials for the
     cross-split merge. A fully-dead split (every page past the
@@ -245,42 +296,27 @@ def _decode_kernel_split(bt_ref, len_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j == 0)
     def _init():
-        s_buf[:] = jnp.full_like(s_buf, NEG_INF)
-        v_buf[:] = jnp.zeros_like(v_buf)
+        s_buf[...] = jnp.full_like(s_buf, NEG_INF)
+        v_buf[...] = jnp.zeros_like(v_buf)
 
     ctx = len_ref[b]
 
     @pl.when((jg * block_size < ctx) & (jg < n_pages))
     def _gather():
-        # single query row, same discipline as the global body: the
-        # per-row dot's reduction grouping is what the bitwise
-        # contract vs the split reference is stated over
-        q = q_ref[0, 0][:1]                   # (1, D) native dtype
-        k = k_ref[0, :, 0, :]                 # (bs, D)
-        v = v_ref[0, :, 0, :]                 # (bs, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            precision=_precision(q.dtype)) * scale
-        cols = jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1) + jg * block_size
-        s = jnp.where(cols < ctx, s.astype(jnp.float32), NEG_INF)
-        s_buf[:1, pl.ds(j * block_size, block_size)] = s
-        v_buf[pl.ds(j * block_size, block_size), :] = v
+        s_buf[j] = _page_scores(q_ref, k_ref, scale, jg * block_size,
+                                ctx, NEG_INF)
+        v_buf[j] = v_ref[...]
 
     @pl.when(j == pages_per_split - 1)
     def _partial():
-        s = s_buf[:1]                               # (1, S_split) f32
-        m = jnp.max(s, axis=1, keepdims=True)       # -inf when dead
+        s = s_buf[...]                              # (P, R, bs) f32
+        m = _page_sum(s, jnp.max)                   # -inf when dead
         safe_m = jnp.where(m == NEG_INF, 0.0, m)
-        p = jnp.exp(s - safe_m)
-        p = jnp.where(s == NEG_INF, 0.0, p)
-        l = jnp.sum(p, axis=1, keepdims=True)
-        o = jax.lax.dot_general(
-            p.astype(v_buf.dtype), v_buf[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # (1, D) f32
-        o_ref[0, 0, 0] = jnp.broadcast_to(o, o_ref.shape[3:])
-        m_ref[0, 0, 0] = jnp.broadcast_to(m, m_ref.shape[3:])
-        l_ref[0, 0, 0] = jnp.broadcast_to(l, l_ref.shape[3:])
+        p = jnp.where(s == NEG_INF, 0.0, jnp.exp(s - safe_m))
+        l = _page_sum(p, jnp.sum)
+        o_ref[...] = _weighted_values(p, v_buf)     # (R, W) f32
+        m_ref[...] = jnp.broadcast_to(m[0], m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l[0], l_ref.shape)
 
 
 def _merge_splits(o_parts, m, l, out_dtype):
@@ -296,84 +332,110 @@ def _merge_splits(o_parts, m, l, out_dtype):
     return (o / l_safe[..., None]).astype(out_dtype)
 
 
+def _spread_query(q, hg: int, rows: int, dtype):
+    """``[B, H, D] -> [B, G, R, hg*D]``: row ``r`` of group ``g`` holds
+    head ``g*hg + r`` in lanes ``[r*D, (r+1)*D)`` and zeros elsewhere;
+    rows past ``hg`` are zero padding up to the 8-row tile."""
+    B, H, D = q.shape
+    eye = jnp.eye(hg, dtype=q.dtype)[None, None, :, :, None]
+    spread = (q.reshape(B, H // hg, hg, 1, D) * eye).reshape(
+        B, H // hg, hg, hg * D)
+    return jnp.pad(spread, ((0, 0), (0, 0), (0, rows - hg),
+                            (0, 0))).astype(dtype)
+
+
+def _own_lanes(x, hg: int, head_dim: int):
+    """Inverse of :func:`_spread_query` on a kernel output
+    ``[..., R, hg*D]``: keep row ``r``'s own lanes -> ``[..., hg, D]``."""
+    lead = x.shape[:-2]
+    x = x[..., :hg, :].reshape(lead + (hg, hg, head_dim))
+    return jnp.diagonal(x, axis1=-3, axis2=-2).swapaxes(-1, -2)
+
+
 def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
                            scale=None, interpret=None,
-                           pages_per_split=None):
+                           pages_per_split=None, layer=0):
     """Paged decode attention.
 
     q: ``[B, 1, H, D]`` (paddle layout) — one new token per sequence.
-    k_pool/v_pool: ``[num_blocks, block_size, H, D]`` shared pools.
+    k_pool/v_pool: the whole model's shared pools
+    ``[L, num_blocks, block_size, H*D]`` (a token's heads merged into
+    one row); ``layer`` names the (static) layer to read. A caller
+    holding one layer's pool passes ``pool[None]``.
     block_tables: int32 ``[B, n_pages]`` physical block ids per
     sequence (pad rows with the garbage block).
     ctx_lens: int32 ``[B]`` valid keys per sequence (including the
     token just appended). Returns ``[B, 1, H, D]``.
 
     ``pages_per_split``: split-K width for the flash-decode body.
-    ``None`` auto-dispatches — the PR 9 single-split global-softmax
-    body (and its bitwise chain) whenever its whole-context scratch
-    fits the VMEM budget, else :func:`auto_pages_per_split`. An
-    explicit value forces split-K whenever more than one split
-    results.
+    ``None`` auto-dispatches — the single-split global-softmax body
+    whenever its whole-context scratch fits the VMEM budget, else
+    :func:`auto_pages_per_split`. An explicit value forces split-K
+    whenever more than one split results.
     """
     B, _, H, D = q.shape
-    n_blocks, bs, _, _ = k_pool.shape
+    layer = int(layer)
+    bs = k_pool.shape[2]
     n_pages = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     if pages_per_split is None:
-        pps = (n_pages if fits_single_softmax(n_pages, bs, D, q.dtype)
-               else auto_pages_per_split(n_pages, bs, D, q.dtype))
+        fit = (bs, D, k_pool.dtype, None, H)
+        pps = (n_pages if fits_single_softmax(n_pages, *fit)
+               else auto_pages_per_split(n_pages, *fit))
     else:
         pps = max(1, min(int(pages_per_split), n_pages))
-    # q rides as [B, H, 8, D]: 8 identical rows satisfy the TPU
-    # sublane-tiling minimum; row 0 is the answer
-    qr = jnp.broadcast_to(jnp.swapaxes(q, 1, 2), (B, H, 8, D))
+    hg, rows, width = _lane_group(H, D)
+    groups = H // hg
+    qr = _spread_query(q[:, 0], hg, rows, k_pool.dtype)
     bt = jnp.asarray(block_tables, jnp.int32)
     ln = jnp.asarray(ctx_lens, jnp.int32)
     if pps < n_pages:
-        return _paged_decode_split(qr, k_pool, v_pool, bt, ln,
-                                   float(scale), pps, interpret)
-    s_pad = n_pages * bs
+        out = _paged_decode_split(qr, k_pool, v_pool, bt, ln, layer,
+                                  float(scale), pps, hg, D, interpret)
+        return out.astype(q.dtype)[:, None]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, 8, D),
-                         lambda b, h, j, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 8, D),
-                               lambda b, h, j, bt, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((8, s_pad), q.dtype),
-            pltpu.VMEM((s_pad, D), q.dtype),
-        ],
-    )
+    def page():
+        return pl.BlockSpec((None, None, bs, width),
+                            lambda b, g, j, bt, ln:
+                            (layer, bt[b, j], 0, g))
+
+    def tile():
+        return pl.BlockSpec((None, None, rows, width),
+                            lambda b, g, j, bt, ln: (b, g, 0, 0))
+
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale),
                           block_size=bs, n_pages=n_pages),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 8, D), q.dtype),
-        compiler_params=_CompilerParams(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, groups, n_pages),
+            in_specs=[tile(), page(), page()],
+            out_specs=tile(),
+            scratch_shapes=[
+                pltpu.VMEM((n_pages, rows, bs), jnp.float32),
+                pltpu.VMEM((n_pages, bs, width), v_pool.dtype),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, groups, rows, width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode",
     )(bt, ln, qr, k_pool, v_pool)
-    return out[:, :, 0][:, None]        # [B, H, 8, D] -> [B, 1, H, D]
+    # [B, G, R, W] -> [B, G, hg, D] -> [B, 1, H, D]
+    return _own_lanes(out, hg, D).reshape(B, 1, H, D)
 
 
-def _paged_decode_split(qr, k_pool, v_pool, bt, ln, scale, pps,
-                        interpret):
+def _paged_decode_split(qr, k_pool, v_pool, bt, ln, layer, scale, pps,
+                        hg, head_dim, interpret):
     """Split-K driver: pad the table out to whole splits, run the
-    flash-decode body per (batch, head, split), merge the partials in
-    one tiny jitted XLA reduction."""
-    B, H, _, D = qr.shape
-    _, bs, _, _ = k_pool.shape
+    flash-decode body per (batch, lane group, split), merge the
+    partials in one tiny jitted XLA reduction. Returns ``[B, H, D]``
+    f32."""
+    B, groups, rows, width = qr.shape
+    bs = k_pool.shape[2]
     n_pages = bt.shape[1]
     n_splits = -(-n_pages // pps)
     pad_pages = n_splits * pps
@@ -381,52 +443,52 @@ def _paged_decode_split(qr, k_pool, v_pool, bt, ln, scale, pps,
         # padded pages point at block 0 (the garbage block); the
         # in-kernel (jg < n_pages) guard keeps them out of the scores
         bt = jnp.pad(bt, ((0, 0), (0, pad_pages - n_pages)))
-    s_split = pps * bs
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H, n_splits, pps),
-        in_specs=[
-            pl.BlockSpec((1, 1, 8, D),
-                         lambda b, h, sp, j, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, sp, j, bt, ln:
-                         (bt[b, sp * pps + j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, sp, j, bt, ln:
-                         (bt[b, sp * pps + j], 0, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, 8, D),
-                         lambda b, h, sp, j, bt, ln: (b, h, sp, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 8, 128),
-                         lambda b, h, sp, j, bt, ln: (b, h, sp, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 8, 128),
-                         lambda b, h, sp, j, bt, ln: (b, h, sp, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((8, s_split), jnp.float32),
-            pltpu.VMEM((s_split, D), qr.dtype),
-        ],
-    )
+    def part(lanes):
+        return pl.BlockSpec((None, None, None, rows, lanes),
+                            lambda b, g, sp, j, bt, ln: (b, g, sp, 0, 0))
+
+    def page():
+        return pl.BlockSpec((None, None, bs, width),
+                            lambda b, g, sp, j, bt, ln:
+                            (layer, bt[b, sp * pps + j], 0, g))
+
+    def partial_shape(lanes):
+        return jax.ShapeDtypeStruct((B, groups, n_splits, rows, lanes),
+                                    jnp.float32)
+
     o_parts, m, l = pl.pallas_call(
         functools.partial(_decode_kernel_split, scale=scale,
                           block_size=bs, pages_per_split=pps,
                           n_pages=n_pages),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, n_splits, 8, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, n_splits, 8, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, n_splits, 8, 128), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, groups, n_splits, pps),
+            in_specs=[
+                pl.BlockSpec((None, None, rows, width),
+                             lambda b, g, sp, j, bt, ln: (b, g, 0, 0)),
+                page(), page(),
+            ],
+            out_specs=[part(width), part(128), part(128)],
+            scratch_shapes=[
+                pltpu.VMEM((pps, rows, bs), jnp.float32),
+                pltpu.VMEM((pps, bs, width), v_pool.dtype),
+            ]),
+        out_shape=[partial_shape(width), partial_shape(128),
+                   partial_shape(128)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
+        name="paged_decode_split",
     )(bt, ln, qr, k_pool, v_pool)
-    out = _merge_split_jit(str(jnp.dtype(qr.dtype)))(
-        o_parts[:, :, :, 0, :], m[:, :, :, 0, 0], l[:, :, :, 0, 0])
-    return out[:, None]                     # [B, H, D] -> [B, 1, H, D]
+    # per-head partials: [B, G, S, R, *] -> [B, H, S, *]
+    heads = B, groups * hg, n_splits
+    o_parts = jnp.swapaxes(_own_lanes(o_parts, hg, head_dim), 2, 3) \
+        .reshape(heads + (head_dim,))
+    m, l = (jnp.swapaxes(x[:, :, :, :hg, 0], 2, 3).reshape(heads)
+            for x in (m, l))
+    return _merge_split_jit("float32")(o_parts, m, l)
 
 
 @functools.lru_cache(maxsize=None)
@@ -435,38 +497,40 @@ def _merge_split_jit(out_dtype: str):
                                      out_dtype=jnp.dtype(out_dtype)))
 
 
-def gathered_dense_kv(pool, block_tables):
+def gathered_dense_kv(pool, block_tables, num_heads: int):
     """Dense ``[B, n_pages*block_size, H, D]`` view of every
-    sequence's K or V through its block table (one vectorized
-    gather)."""
-    g = pool[jnp.asarray(block_tables, jnp.int32)]   # [B, P, bs, H, D]
-    return g.reshape(g.shape[:1] + (-1,) + g.shape[3:])
+    sequence's K or V through its block table (one vectorized gather
+    over one layer's ``[N, bs, H*D]`` pool)."""
+    g = pool[jnp.asarray(block_tables, jnp.int32)]   # [B, P, bs, H*D]
+    return g.reshape(g.shape[0], -1, num_heads,
+                     g.shape[-1] // num_heads)
 
 
-# reference programs cached per (shape, dtype, scale): the bitwise
-# contract is a COMPILED-program property — eager per-op dispatch lets
-# XLA compile each op alone and round reductions differently (observed
-# 1-ulp drift CPU-side), so the reference always runs jitted
+# reference programs cached per (shape, dtype, scale): eager per-op
+# dispatch lets XLA compile each op alone and round reductions
+# differently (observed 1-ulp drift CPU-side), so the reference always
+# runs jitted
 _REF_CACHE: dict = {}
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
                               scale=None):
-    """Dense reference: gather K/V through the block table, then run
-    the kernel's exact op sequence — per (sequence, head) single-row
-    2-D dots, ``finfo.min`` pad mask, one ``jax.nn.softmax(f32)`` —
-    compiled as ONE jitted program. Bitwise-equal (fp32) to the
-    kernel (the loops mirror its grid steps one-for-one) and to a
-    jitted ``nn.functional.flash_attention`` on H=1 slices of the
-    contiguous K/V: at H=1 the dense path's batched einsum collapses
-    to the same 2-D ``dot_general``, while an H-batched gemm is free
-    to reassociate its reduction (observed 1-ulp drift on XLA CPU) —
-    which is also why this reference loops heads instead of batching
-    them. The flash equality is exact when the context is
-    block-aligned (equal reduction widths); at ragged contexts the
-    padded-width softmax/out reductions may regroup and drift 1 ulp
-    vs the exact-width dense path — kernel-vs-reference stays bitwise
-    regardless, since both run at the padded width."""
+    """Dense reference over ONE layer's ``[N, bs, H*D]`` pool: gather
+    K/V through the block table, then the single-softmax body's op
+    sequence — per (sequence, head) single-row 2-D dots, ``finfo.min``
+    pad mask, one ``jax.nn.softmax(f32)`` — compiled as ONE jitted
+    program. The kernel reduces per page and then across pages where
+    this reduces one ``[1, S]`` row, so fp32 agreement is a few ulp
+    (``tests/test_serving.py`` ``KERNEL_TOL``, rtol = atol = 2e-6),
+    not bitwise. It IS bitwise-equal (fp32) to a jitted
+    ``nn.functional.flash_attention`` on H=1 slices of the contiguous
+    K/V at block-aligned contexts: at H=1 the dense path's batched
+    einsum collapses to the same 2-D ``dot_general``, while an
+    H-batched gemm is free to reassociate its reduction (observed
+    1-ulp drift on XLA CPU) — which is why this reference loops heads
+    instead of batching them. At ragged contexts the padded-width
+    softmax/out reductions may regroup and drift 1 ulp vs the
+    exact-width dense path."""
     B, _, H, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -488,15 +552,17 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
 def paged_attention_split_reference(q, k_pool, v_pool, block_tables,
                                     ctx_lens, scale=None,
                                     pages_per_split=1):
-    """Dense twin of the SPLIT-K body: gather K/V through the block
-    table, then mirror the split kernel's op sequence one-for-one —
-    per-page single-row score dots, per-split ``max/exp/sum`` and the
-    unnormalized ``p @ V`` partial dot (f32 accumulation), then the
-    exact :func:`_merge_splits` reduction — compiled as ONE jitted
-    program. Bitwise-equal (fp32) to the split kernel by construction;
-    vs the global-softmax :func:`paged_attention_reference` it is
-    1-ulp class (the per-split rescaling reassociates the softmax
-    reductions), which the tests assert as tight allclose."""
+    """Dense twin of the SPLIT-K body over ONE layer's
+    ``[N, bs, H*D]`` pool: gather K/V through the block table, then the
+    split kernel's op sequence — per-page single-row score dots,
+    per-split ``max/exp/sum`` and the unnormalized ``p @ V`` partial
+    dot (f32 accumulation), then the exact :func:`_merge_splits`
+    reduction the kernel path runs. The kernel reduces per page and
+    then across the split's pages where this reduces one row per
+    split, so fp32 agreement with the kernel is a few ulp (``KERNEL_TOL``,
+    rtol = atol = 2e-6), as it is vs the global-softmax
+    :func:`paged_attention_reference` (the per-split rescaling
+    reassociates the softmax reductions)."""
     B, _, H, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -531,8 +597,8 @@ def _split_partials_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
     """Dense mirror of the split kernel's per-(batch, head, split)
     partial computation: returns ``(o_parts [B,H,S,D] f32,
     m [B,H,S] f32, l [B,H,S] f32)``."""
-    kd = gathered_dense_kv(k_pool, block_tables)     # [B, S_pad, H, D]
-    vd = gathered_dense_kv(v_pool, block_tables)
+    kd = gathered_dense_kv(k_pool, block_tables, H)  # [B, S_pad, H, D]
+    vd = gathered_dense_kv(v_pool, block_tables, H)
     prec = _precision(q.dtype)
     bs = k_pool.shape[1]
     n_pages = block_tables.shape[1]
@@ -594,8 +660,8 @@ def _split_partials_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
 
 def _reference_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
                     scale, B, H):
-    kd = gathered_dense_kv(k_pool, block_tables)     # [B, S_pad, H, D]
-    vd = gathered_dense_kv(v_pool, block_tables)
+    kd = gathered_dense_kv(k_pool, block_tables, H)  # [B, S_pad, H, D]
+    vd = gathered_dense_kv(v_pool, block_tables, H)
     prec = _precision(q.dtype)
     s_pad = kd.shape[1]
     out = []

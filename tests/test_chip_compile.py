@@ -1,0 +1,282 @@
+"""Every Pallas kernel, compiled for a described TPU v5e chip.
+
+The CPU tests interpret the kernels; only the chip's compiler can
+refuse a block shape the DMA cannot address, a store at an unaligned
+lane offset or a scratch past the scoped-VMEM limit. That compiler is
+installed here and compiles for a chip that is described, not attached
+(``topologies.get_topology_desc``), with ``interpret=False`` passed
+explicitly, at the widths the main paths run. A compile that passes is
+not a chip run — ``chip_smoke.py`` is — but it guards every later PR at
+no chip time.
+
+ONE file, and the topology is described inside a module-scoped fixture:
+only one process at a time may load the TPU library, so it must not be
+touched at import, in a ``skipif``/``parametrize`` argument or in
+``conftest.py`` (every xdist worker imports every test file).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle2_tpu.kernels import pallas_flash, pallas_fused, pallas_matmul
+from paddle2_tpu.serving import paged_attention as pa
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology executable can be written to the persistent
+    # cache but not read back without a chip: keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, fn, *avals):
+    """Compile ``fn`` for the described chip; the Mosaic kernel must be
+    IN the compiled program."""
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in avals]
+    compiled = jax.jit(fn).lower(*avals).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# ---------------------------------------------------------------- flash
+@pytest.mark.parametrize("seq", [1024, 4096])
+def test_flash_fwd_bwd(one_chip, seq):
+    """The trainer's attention: [8, S, 16, 64] bf16 causal, forward and
+    backward (S 4096 walks several k blocks per q block)."""
+    qkv = ((8 if seq == 1024 else 2, seq, 16, 64), BF16)
+
+    def loss(q, k, v):
+        o = pallas_flash.flash_attention_bshd(q, k, v, causal=True,
+                                              interpret=False)
+        return o.astype(F32).sum()
+
+    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+
+
+def test_flash_varlen_packed_fwd_bwd(one_chip):
+    """The varlen dispatch (nn.functional.flash_attn_unpadded on TPU)."""
+    T, H, D = 2048, 16, 64
+    seg = ((T,), jnp.int32)
+
+    def loss(q, k, v, sq, oq, sk, ok):
+        o = pallas_flash.flash_attention_varlen_packed(
+            q, k, v, sq, oq, sk, ok, interpret=False)
+        return o.astype(F32).sum()
+
+    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+             ((T, H, D), BF16), ((T, H, D), BF16), ((T, H, D), BF16),
+             seg, seg, seg, seg)
+
+
+def test_flash_inside_a_partitioned_program(topo, monkeypatch):
+    """dp2 x mp2 over the four described chips: the partitioner cannot
+    split a Mosaic kernel, so the public attention op runs it per shard
+    — the kernel in the compiled program sees its LOCAL batch (8/dp)
+    and heads (16/mp). The dispatch asks JAX for the platform, which is
+    the CPU here: the test steers that one query to the chip's branch."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle2_tpu.distributed import mesh as mesh_mod
+    from paddle2_tpu.framework.tensor import Tensor
+    from paddle2_tpu.kernels import _platform
+    from paddle2_tpu.kernels.attention import scaled_dot_product_attention
+    monkeypatch.setattr(_platform, "device_platform", lambda: "tpu")
+    # the mesh hybrid_mesh(dp=2, tp=2) builds (what chip_smoke
+    # --four-chips trains on): dp x pp x sharding x mp = 2 x 1 x 1 x 2
+    from paddle2_tpu.distributed.spec_layout import SpecLayout
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 1, 1, 2),
+                tuple(SpecLayout().mesh_axes()))
+    prev = mesh_mod.get_mesh(auto_init=False)
+    mesh_mod.set_mesh(mesh)
+    try:
+        qkv = jax.ShapeDtypeStruct(
+            (8, 1024, 16, 64), BF16,
+            sharding=NamedSharding(mesh, P("dp", None, "mp", None)))
+
+        def loss(q, k, v):
+            o = scaled_dot_product_attention(
+                Tensor(q), Tensor(k), Tensor(v), is_causal=True)
+            return o._data.astype(F32).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            qkv, qkv, qkv).compile().as_text()
+    finally:
+        mesh_mod.set_mesh(prev)
+    kernels = [ln for ln in text.splitlines()
+               if "tpu_custom_call" in ln and "custom-call(" in ln]
+    assert kernels
+    assert all("bf16[4,8,1024,64]" in ln for ln in kernels)
+    assert not any("bf16[8,16,1024,64]" in ln for ln in kernels)
+
+
+# ---------------------------------------------------------------- paged
+def _paged(pps):
+    return functools.partial(pa.paged_attention_decode, interpret=False,
+                             pages_per_split=pps, layer=1)
+
+
+def _paged_avals(batch, n_blocks, n_pages, dtype=BF16, heads=16,
+                 head_dim=64, bs=16, layers=2):
+    pool = ((layers, n_blocks, bs, heads * head_dim), dtype)
+    return (((batch, 1, heads, head_dim), dtype), pool, pool,
+            ((batch, n_pages), jnp.int32), ((batch,), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+def test_paged_decode_single_softmax(one_chip, dtype):
+    """What chip_smoke serves: H 16, D 64 (two heads per 128-lane
+    page), block 16, a 4096-block pool, 64 pages (1024 tokens), batch
+    8."""
+    _compile(one_chip, _paged(None),
+             *_paged_avals(8, 4096, 64, dtype))
+
+
+def test_paged_decode_split_k_32k(one_chip):
+    """A 32k context (2048 pages) auto-dispatches to split-K."""
+    assert not pa.fits_single_softmax(2048, 16, 64, BF16)
+    _compile(one_chip, _paged(None), *_paged_avals(8, 20000, 2048))
+
+
+def test_paged_decode_single_softmax_at_vmem_budget(one_chip):
+    """The VMEM accounting is the compiler's, not an assumption: the
+    widest context ``fits_single_softmax`` admits compiles as ONE
+    softmax, and twice the whole limit is refused."""
+    per_page = pa.decode_scratch_vmem_bytes(1, 16, 64, BF16, 16)
+    at_budget = pa.VMEM_FIT_BUDGET // per_page
+    assert pa.fits_single_softmax(at_budget, 16, 64, BF16, None, 16)
+    assert not pa.fits_single_softmax(at_budget + 1, 16, 64, BF16,
+                                      None, 16)
+    _compile(one_chip, _paged(at_budget),
+             *_paged_avals(2, 4096, at_budget))
+    over = 2 * pa.VMEM_BYTES // per_page
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(one_chip, _paged(over), *_paged_avals(2, 8192, over))
+
+
+# ---------------------------------------------------------------- fused
+def test_fused_adamw_step(one_chip):
+    """One [1024, 4096] f32 leaf (an MLP weight's master copy)."""
+    leaf = ((1024, 4096), F32)
+
+    def step(p, g, m, v, lr, t):
+        return pallas_fused.fused_adamw_step(
+            p, g, m, v, lr, t, weight_decay=0.01, interpret=False)
+
+    _compile(one_chip, step, leaf, leaf, leaf, leaf, ((), F32),
+             ((), jnp.int32))
+
+
+def test_fused_adamw_multi_precision(one_chip):
+    n = ((1024 * 4096,), F32)
+
+    def step(p, g, m, v, master, lr):
+        return pallas_fused.fused_adamw(p, g, m, v, master, lr,
+                                        interpret=False)
+
+    _compile(one_chip, step, ((1024 * 4096,), BF16), n, n, n, n,
+             ((), F32))
+
+
+def test_fused_momentum_step(one_chip):
+    leaf = ((1024, 4096), F32)
+
+    def step(p, g, v, lr):
+        return pallas_fused.fused_momentum_step(
+            p, g, v, lr, nesterov=True, weight_decay=1e-4,
+            interpret=False)
+
+    _compile(one_chip, step, leaf, leaf, leaf, ((), F32))
+
+
+def test_fused_rms_norm_fwd_bwd(one_chip):
+    def loss(x, w):
+        return pallas_fused.fused_rms_norm(
+            x, w, interpret=False).astype(F32).sum()
+
+    _compile(one_chip, jax.grad(loss, argnums=(0, 1)),
+             ((8, 1024, 2048), BF16), ((2048,), BF16))
+
+
+def test_fused_rope(one_chip):
+    def rope(x, cos, sin):
+        return pallas_fused.fused_rope(x, cos, sin, interpret=False)
+
+    _compile(one_chip, rope, ((8, 1024, 16, 128), BF16),
+             ((1024, 128), F32), ((1024, 128), F32))
+
+
+# --------------------------------------------------------------- matmul
+HEAD = dict(m=8192, k=1024, n=32768)        # the quantized lm_head
+
+
+def test_int8_matmul(one_chip):
+    """interpret=False through the PUBLIC wrapper is the compiled
+    kernel (never the XLA dot it takes off-TPU by default)."""
+    def mm(x, w):
+        return pallas_matmul.int8_matmul(x, w, interpret=False)
+
+    _compile(one_chip, mm, ((HEAD["m"], HEAD["k"]), jnp.int8),
+             ((HEAD["k"], HEAD["n"]), jnp.int8))
+
+
+def test_int8_weight_only_matmul(one_chip):
+    def mm(x, w, s):
+        return pallas_matmul.int8_weight_only_matmul(x, w, s,
+                                                     interpret=False)
+
+    _compile(one_chip, mm, ((HEAD["m"], HEAD["k"]), BF16),
+             ((HEAD["k"], HEAD["n"]), jnp.int8), ((HEAD["n"],), F32))
+
+
+def test_int4_weight_only_matmul(one_chip):
+    def mm(x, w, s):
+        return pallas_matmul.int4_weight_only_matmul(x, w, s,
+                                                     interpret=False)
+
+    _compile(one_chip, mm, ((HEAD["m"], HEAD["k"]), BF16),
+             ((HEAD["k"], HEAD["n"] // 2), jnp.uint8),
+             ((HEAD["n"],), F32))
+
+
+def test_explicit_compiled_kernel_never_takes_the_xla_dot():
+    """``interpret=False`` is the compiled kernel or an error, never
+    the XLA lowering (no topology needed: neither case reaches the
+    chip's compiler): operands the blocks do not divide are refused by
+    the wrapper, aligned ones by the CPU backend this process runs."""
+    x = jnp.zeros((300, 130), jnp.int8)
+    w = jnp.zeros((130, 33), jnp.int8)
+    with pytest.raises(ValueError, match="interpret=False"):
+        pallas_matmul.int8_matmul(x, w, interpret=False)
+    with pytest.raises(ValueError, match="interpret=False"):
+        pallas_matmul.int8_weight_only_matmul(
+            x.astype(F32), w, jnp.ones((33,), F32), interpret=False)
+    with pytest.raises(ValueError, match="interpret mode"):
+        pallas_matmul.int8_matmul(x[:256], w, interpret=False)
+    # the default stays the XLA lowering off-TPU
+    assert pallas_matmul.int8_matmul(x, w).shape == (300, 33)

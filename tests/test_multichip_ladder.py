@@ -28,8 +28,7 @@ from paddle2_tpu.observability.cost_model import (
     pipeline_bubble_fraction, wire_bytes)
 
 
-# the shared version-tolerant wrapper (check_rep vs check_vma, and the
-# jax.shard_map vs jax.experimental import shim live in ONE place)
+# shard_map with the varying-manual-axes check off
 from paddle2_tpu.distributed.collective import (  # noqa: E402
     shard_map_unchecked as _sm)
 
@@ -252,9 +251,6 @@ class TestHierarchicalCollectives:
         ident = self._run(lambda v: hierarchical_psum(v, (), ()), x)
         assert np.array_equal(ident, np.asarray(x))
 
-    @pytest.mark.skipif(not hasattr(jax.lax, "axis_size"),
-                        reason="old jax resolves axis sizes from the "
-                               "installed mesh only")
     def test_caller_constructed_mesh_not_installed(self):
         # the mean divisor and pad count must come from the axes BOUND
         # IN THE TRACE: a Mesh built by hand (never routed through

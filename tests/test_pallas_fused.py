@@ -166,57 +166,6 @@ class TestFusedRopeAPI:
                                    rtol=1e-4, atol=1e-5)
 
 
-class TestPallasLayerNorm:
-    """kernels/pallas_ln.py fused LN: fwd + recompute-stats bwd parity
-    vs the analytic reference (interpret mode on CPU)."""
-
-    def test_fwd_bwd_parity(self):
-        import jax
-        import jax.numpy as jnp
-        from paddle2_tpu.kernels.pallas_ln import (fused_layer_norm,
-                                                   supported)
-        rs = np.random.RandomState(0)
-        N, H = 64, 256
-        assert supported((N, H))
-        x = jnp.asarray(rs.randn(N, H).astype(np.float32))
-        g = jnp.asarray(rs.rand(H).astype(np.float32) + 0.5)
-        b = jnp.asarray(rs.randn(H).astype(np.float32) * 0.1)
-
-        def ref(x, g, b):
-            m = x.mean(-1, keepdims=True)
-            v = ((x - m) ** 2).mean(-1, keepdims=True)
-            return (x - m) * jax.lax.rsqrt(v + 1e-5) * g + b
-
-        out = fused_layer_norm(x, g, b, 1e-5)
-        np.testing.assert_allclose(np.asarray(out),
-                                   np.asarray(ref(x, g, b)),
-                                   rtol=1e-5, atol=1e-5)
-
-        do = jnp.asarray(rs.randn(N, H).astype(np.float32))
-        dx, dg, db = jax.vjp(
-            lambda *a: fused_layer_norm(*a, 1e-5), x, g, b)[1](do)
-        rx, rg_, rb = jax.vjp(ref, x, g, b)[1](do)
-        np.testing.assert_allclose(np.asarray(dx), np.asarray(rx),
-                                   rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(dg), np.asarray(rg_),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(db), np.asarray(rb),
-                                   rtol=1e-4, atol=1e-4)
-
-    def test_3d_and_unsupported_shapes(self):
-        import jax.numpy as jnp
-        from paddle2_tpu.kernels.pallas_ln import (fused_layer_norm,
-                                                   supported)
-        rs = np.random.RandomState(1)
-        x = jnp.asarray(rs.randn(2, 8, 128).astype(np.float32))
-        g = jnp.ones((128,), jnp.float32)
-        b = jnp.zeros((128,), jnp.float32)
-        out = fused_layer_norm(x, g, b, 1e-5)
-        assert out.shape == (2, 8, 128)
-        assert not supported((16, 100))   # lane-unaligned H
-        assert not supported((128,))      # 1-D
-
-
 def test_fused_adamw_step_eager_order_twin():
     """fused_adamw_step (the ISSUE-10 STEP kernel, distinct from the
     fuse-everything fused_adamw above) replicates the eager op ORDER:

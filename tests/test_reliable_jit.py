@@ -682,36 +682,61 @@ class TestLauncherPlumbing:
     def test_worker_env_cache_and_budget(self, monkeypatch):
         from paddle2_tpu.distributed.launch.main import (_parse,
                                                          _worker_env)
-        monkeypatch.delenv("PADDLE2_TPU_CACHE_DIR", raising=False)
-        monkeypatch.delenv("FLAGS_compilation_cache_dir",
-                           raising=False)
-        # elastic launchers auto-enable a job-scoped cache + forward
-        # the MTTR budget
+        for k in ("PADDLE2_TPU_CACHE_DIR", "FLAGS_compilation_cache_dir",
+                  "JAX_COMPILATION_CACHE_DIR"):
+            monkeypatch.delenv(k, raising=False)
+        # elastic launchers forward the MTTR budget and invent NO cache
+        # directory: the workers' own default is the fixed in-checkout
+        # path, on — never a temp-dir/job-id path that cannot hit on a
+        # fresh machine
         args = _parse(["--max_restarts", "2", "--mttr_budget", "30",
                        "--job_id", "jobX", "x.py"])
         env = _worker_env(args, 0)
         assert env["PADDLE_MTTR_BUDGET"] == "30.0"
-        assert env["PADDLE2_TPU_CACHE_DIR"].endswith(
-            "p2t_xla_cache_jobX")
-        # a plain one-shot launch stays cache-off
+        assert "PADDLE2_TPU_CACHE_DIR" not in env
+        # a plain one-shot launch: same
         env = _worker_env(_parse(["x.py"]), 0)
         assert "PADDLE2_TPU_CACHE_DIR" not in env
-        # explicit dir wins; 'none' disables even with restarts
+        # explicit dir is forwarded; 'none' forwards the OFF value
         env = _worker_env(_parse(["--compile_cache_dir", "/o/cache",
                                   "x.py"]), 0)
         assert env["PADDLE2_TPU_CACHE_DIR"] == "/o/cache"
         env = _worker_env(_parse(["--max_restarts", "2",
                                   "--compile_cache_dir", "none",
                                   "x.py"]), 0)
-        assert "PADDLE2_TPU_CACHE_DIR" not in env
+        assert env["PADDLE2_TPU_CACHE_DIR"] == ""
 
     def test_operator_cache_env_not_clobbered(self, monkeypatch):
         from paddle2_tpu.distributed.launch.main import (_parse,
                                                          _worker_env)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setenv("PADDLE2_TPU_CACHE_DIR", "/operator/choice")
-        args = _parse(["--max_restarts", "1", "x.py"])
+        args = _parse(["--compile_cache_dir", "/o/cache", "x.py"])
         env = _worker_env(args, 0)
         assert env["PADDLE2_TPU_CACHE_DIR"] == "/operator/choice"
+
+    def test_option_yields_to_jax_cache_env(self, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: the launcher sets no other
+        directory, even with an explicit --compile_cache_dir."""
+        from paddle2_tpu.distributed.launch.main import (_parse,
+                                                         _worker_env)
+        monkeypatch.delenv("PADDLE2_TPU_CACHE_DIR", raising=False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        env = _worker_env(_parse(["--compile_cache_dir", "/o/cache",
+                                  "x.py"]), 0)
+        assert "PADDLE2_TPU_CACHE_DIR" not in env
+        assert env["JAX_COMPILATION_CACHE_DIR"] == "/placed/outside"
+
+    def test_shared_chips_rejected_on_tpu_host(self, monkeypatch):
+        from paddle2_tpu.distributed.launch.main import (
+            _parse, _reject_shared_chips)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        with pytest.raises(SystemExit, match="nproc_per_node"):
+            _reject_shared_chips(_parse(["--nproc_per_node", "4",
+                                         "x.py"]))
+        _reject_shared_chips(_parse(["--nproc_per_node", "1", "x.py"]))
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        _reject_shared_chips(_parse(["--nproc_per_node", "4", "x.py"]))
 
 
 @pytest.mark.slow

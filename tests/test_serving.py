@@ -48,23 +48,35 @@ def _fragmented_setup(rng, bs, ctx_lens, H, D, num_blocks=32):
             kp[blk, :hi - lo] = ks[lo:hi]
             vp[blk, :hi - lo] = vs[lo:hi]
     q = rng.normal(size=(B, 1, H, D)).astype(np.float32)
-    return q, kp, vp, tables, dense_k, dense_v
+    # the pools merge a token's heads into one row: [N, bs, H*D]
+    return (q, kp.reshape(num_blocks, bs, H * D),
+            vp.reshape(num_blocks, bs, H * D), tables, dense_k, dense_v)
+
+
+# kernel vs dense reference, fp32: the kernel reduces per page and then
+# across pages ([P, 8, bs] scratch, lane-group dots — the layout the
+# chip's compiler accepts) where the reference reduces one [1, S] row —
+# the same op sequence under a different summation order, so agreement
+# is a few ulp, not bitwise (ROADMAP D8(c))
+KERNEL_TOL = dict(rtol=2e-6, atol=2e-6)
 
 
 @pytest.mark.parametrize("bs", [16, 64])
-def test_paged_decode_bitwise_vs_reference_fragmented(bs):
-    """ACCEPTANCE: kernel output bitwise (fp32) == dense reference
-    across block sizes {16, 64}, ragged context lengths, and
+def test_paged_decode_matches_reference_fragmented(bs):
+    """ACCEPTANCE: kernel output == dense reference to KERNEL_TOL
+    (fp32) across block sizes {16, 64}, ragged context lengths, and
     fragmented (non-contiguous, shuffled) block tables."""
     rng = np.random.default_rng(0)
     ctx = [24, 8, 72]                       # ragged, 8-row-aligned
     q, kp, vp, tables, _, _ = _fragmented_setup(rng, bs, ctx, H=2, D=16)
-    out = paged_attention_decode(jnp.asarray(q), jnp.asarray(kp),
-                                 jnp.asarray(vp), tables, np.asarray(ctx))
+    out = paged_attention_decode(jnp.asarray(q), jnp.asarray(kp)[None],
+                                 jnp.asarray(vp)[None], tables,
+                                 np.asarray(ctx))
     ref = paged_attention_reference(jnp.asarray(q), jnp.asarray(kp),
                                     jnp.asarray(vp), tables,
                                     np.asarray(ctx))
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **KERNEL_TOL)
     assert np.isfinite(np.asarray(out)).all()
 
 
@@ -130,10 +142,11 @@ def test_paged_decode_bf16_allclose():
     bs, B, H, D = 16, 2, 2, 16
     ctx = [24, 40]
     tables = np.asarray([[2, 5, 0], [7, 3, 9]], np.int32)
-    kp = jnp.asarray(rng.normal(size=(16, bs, H, D)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(16, bs, H, D)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=(16, bs, H * D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=(16, bs, H * D)), jnp.bfloat16)
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.bfloat16)
-    out = paged_attention_decode(q, kp, vp, tables, np.asarray(ctx))
+    out = paged_attention_decode(q, kp[None], vp[None], tables,
+                                 np.asarray(ctx))
     ref = paged_attention_reference(q, kp, vp, tables, np.asarray(ctx))
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -151,13 +164,13 @@ def test_paged_decode_ignores_physical_placement():
     q = rng.normal(size=(1, 1, H, D)).astype(np.float32)
     outs = []
     for blocks in ([1, 2, 3], [9, 4, 7]):
-        kp = np.zeros((12, bs, H, D), np.float32)
-        vp = np.zeros((12, bs, H, D), np.float32)
+        kp = np.zeros((12, bs, H * D), np.float32)
+        vp = np.zeros((12, bs, H * D), np.float32)
         for i, blk in enumerate(blocks):
-            kp[blk] = ks[i * bs:(i + 1) * bs]
-            vp[blk] = vs[i * bs:(i + 1) * bs]
+            kp[blk] = ks[i * bs:(i + 1) * bs].reshape(bs, H * D)
+            vp[blk] = vs[i * bs:(i + 1) * bs].reshape(bs, H * D)
         outs.append(np.asarray(paged_attention_decode(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), jnp.asarray(kp)[None], jnp.asarray(vp)[None],
             np.asarray([blocks], np.int32), np.asarray([c]))))
     assert np.array_equal(outs[0], outs[1])
 
@@ -203,7 +216,8 @@ def test_paged_cache_scatter_gather_roundtrip():
     row = np.asarray([3, 5], np.int64)
     pool = PagedKVCache.scatter_prefill(cache.k, kv, row, 7, 4)
     dense = PagedKVCache.gather_dense(pool[0], row, 2)
-    assert np.array_equal(np.asarray(dense[:7]), np.asarray(kv[0]))
+    assert np.array_equal(np.asarray(dense[:7]),
+                          np.asarray(kv[0]).reshape(7, 8))
 
 
 # -------------------------------------------------------------- scheduler
@@ -408,6 +422,37 @@ def test_engine_from_jit_save_artifact(tiny_model, tmp_path):
     r3 = eng2.submit(prompt, max_new_tokens=4)
     _drain(eng2)
     assert eng2.sequence(r3).generated == live.sequence(r2).generated
+
+
+def test_engine_serves_artifact_in_its_saved_dtypes(tmp_path):
+    """A bf16 (amp O2) artifact is served in bf16 — not widened back to
+    the rebuilt architecture's f32 defaults — with a bf16 KV pool, and
+    still matches generate token-for-token."""
+    from paddle2_tpu import inference
+    from paddle2_tpu.models.gpt import GPTForCausalLM, gpt_tiny
+    paddle.seed(3)
+    cfg = gpt_tiny(use_scan=False)
+    model = paddle.amp.decorate(GPTForCausalLM(cfg), level="O2",
+                                dtype="bfloat16")
+    model.eval()
+    path = str(tmp_path / "bf16_artifact")
+    paddle.jit.save(model, path)
+    conf = inference.Config(path)
+    conf.enable_continuous_batching(block_size=8, num_blocks=32,
+                                    max_batch=4, max_model_len=64,
+                                    kv_dtype="bfloat16")
+    eng = conf.create_serving_engine(gpt_config=cfg)
+    want = {n: str(p._data.dtype) for n, p in model.named_parameters()}
+    got = {n: str(p._data.dtype) for n, p in eng.model.named_parameters()}
+    assert got == want and "bfloat16" in set(got.values())
+    assert eng.cache.k.dtype == jnp.bfloat16
+    prompt = list(range(3, 16))              # decode crosses a page
+    rid = eng.submit(prompt, max_new_tokens=6)
+    _drain(eng)
+    ref = np.asarray(model.generate(
+        np.asarray([prompt], np.int32), max_new_tokens=6,
+        temperature=0.0)._data)[0, len(prompt):].tolist()
+    assert eng.sequence(rid).generated == ref
 
 
 def test_engine_rejects_stacked_blocks():

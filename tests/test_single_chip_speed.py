@@ -69,14 +69,10 @@ class TestRematSearch:
 
     def test_offload_candidate_wins_on_fast_link(self):
         """With an (absurdly) fast host link and a budget only the
-        minimal-HBM candidates fit, offload beats full recompute —
-        and is only ever chosen when this jax can express it."""
+        minimal-HBM candidates fit, offload beats full recompute."""
         plan = _search(7.0, offload_gbps=1e6)
-        if autotune._offload_supported():
-            assert plan.policy == "offload_dots"
-            assert plan.granularity == "offload"
-        else:
-            assert plan.policy == "save_nothing"
+        assert plan.policy == "offload_dots"
+        assert plan.granularity == "offload"
 
     def test_offload_never_chosen_when_not_wired(self):
         plan = _search(7.0, offload_gbps=1e6, allow_offload=False)
@@ -372,22 +368,6 @@ class TestInt8Matmul:
         w_i8, scale = pm.quantize_channelwise(w, 8, axis=1)
         y = pm.int8_weight_only_matmul(x, w_i8, scale)
         assert y.shape == (7, 33)
-
-    def test_fp8_gated(self):
-        x = jnp.asarray(np.random.RandomState(4).randn(8, 16),
-                        jnp.float32)
-        w = jnp.asarray(np.random.RandomState(5).randn(16, 8),
-                        jnp.float32)
-        if pm.fp8_supported():
-            y = pm.fp8_matmul(x, w)
-            assert y.shape == (8, 8)
-            # fp8 e4m3 has ~2 decimal digits: loose sanity band only
-            np.testing.assert_allclose(
-                np.asarray(y), np.asarray(x) @ np.asarray(w),
-                rtol=0.2, atol=0.5)
-        else:
-            with pytest.raises(NotImplementedError):
-                pm.fp8_matmul(x, w)
 
     def test_channel_absmax_shared_primitive(self):
         """The observers and the kernels must reduce through ONE
