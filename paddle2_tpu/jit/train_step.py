@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from ..framework import random as fr
 from ..framework.tensor import Tensor
 from ..observability import metrics as _metrics
+from ..profiler import build as _build_span, span as _span
 from .functional import (_collect_state, _guard_key, _rebound_call,
                          _split_tensors, _trace_lock)
 
@@ -139,11 +140,55 @@ class TrainStepProgram:
         return len(self._compiled)
 
     def __call__(self, *args, **kwargs) -> Tensor:
-        with _trace_lock:
-            return self._call(args, kwargs)
+        with _trace_lock, _span("train.step") as step_span:
+            return self._call(args, kwargs, step_span)
 
     # -- internals -------------------------------------------------------
-    def _call(self, args, kwargs):
+    def _call(self, args, kwargs, step_span):
+        # host spans (profiler.span; PERF.md lists them): train.prepare
+        # -> train.dispatch (holding `build` on a new program) ->
+        # train.rebind, all inside the caller's train.step
+        with _span("train.prepare"):
+            (entry, built_now, call_args, opt, opt_params, buffers,
+             args_t, has_scaler) = self._prepare(args, kwargs)
+        step_span.set_metadata(built=int(built_now))
+
+        pl = _metrics._ACTIVE
+        with _span("train.dispatch"):
+            if pl is not None:
+                pl.phase_enter("compute")
+            try:
+                if built_now:
+                    out = self._first_call(entry, call_args)
+                else:
+                    out = entry(*call_args)
+            finally:
+                if pl is not None:
+                    pl.phase_exit()
+
+        with _span("train.rebind"):
+            if self._instrument:
+                (loss, aux, new_params, new_states, post_buffers,
+                 new_accum) = out
+                self.last_aux = aux
+            else:
+                loss, new_params, new_states, post_buffers, new_accum = out
+            for p, a in zip(opt_params, new_params):
+                p._replace_data(a)
+            for p, s in zip(opt_params, new_states):
+                opt._states[id(p)] = s
+            for b, a in zip(buffers, post_buffers):
+                b._replace_data(a)
+            if self._accum_k > 1:
+                self._accum_buffers = list(new_accum)
+            if pl is not None:
+                self._note_step_metrics(pl, args_t, has_scaler)
+        return Tensor(loss, stop_gradient=True)
+
+    def _prepare(self, args, kwargs):
+        """Everything the host does before the dispatch: gather state,
+        split the arguments, key the program cache (building the entry
+        on a miss) and make the step's scalars."""
         opt = self.inner_optimizer
         all_params, buffers = _collect_state(self.layers)
         opt_params = [p for p in opt._parameter_list()
@@ -271,47 +316,8 @@ class TrainStepProgram:
 
         self.last_build_s = None
         self.last_build_cache_hit = None
-        if built_now:
-            _metrics.inc("train_step_compiles_total")
-            if self.collect_cost:
-                from ..observability import cost_model as _cm
-                self.last_entry = entry
-                self.last_abstract_args = _cm.abstractify(call_args)
-                self.last_cost = _cm.program_cost(
-                    entry, self.last_abstract_args)
-                self.last_cost_flops = (
-                    None if not self.last_cost
-                    else self.last_cost.get("flops"))
-        pl = _metrics._ACTIVE
-        if pl is not None:
-            pl.phase_enter("compute")
-        try:
-            if built_now and self._instrument:
-                out = self._timed_first_call(entry, call_args)
-            else:
-                out = entry(*call_args)
-        finally:
-            if pl is not None:
-                pl.phase_exit()
-
-        if self._instrument:
-            (loss, aux, new_params, new_states, post_buffers,
-             new_accum) = out
-            self.last_aux = aux
-        else:
-            loss, new_params, new_states, post_buffers, new_accum = out
-
-        for p, a in zip(opt_params, new_params):
-            p._replace_data(a)
-        for p, s in zip(opt_params, new_states):
-            opt._states[id(p)] = s
-        for b, a in zip(buffers, post_buffers):
-            b._replace_data(a)
-        if k > 1:
-            self._accum_buffers = list(new_accum)
-        if pl is not None:
-            self._note_step_metrics(pl, args_t, has_scaler)
-        return Tensor(loss, stop_gradient=True)
+        return (entry, built_now, call_args, opt, opt_params, buffers,
+                args_t, has_scaler)
 
     def _note_step_metrics(self, pl, args_t, has_scaler: bool) -> None:
         """Close this dispatch's step window: tokens/samples inferred
@@ -338,59 +344,45 @@ class TrainStepProgram:
                      len(self._compiled))
         pl.step_end(tokens=tokens, samples=samples, loss_scale=scale)
 
-    def _timed_first_call(self, entry, call_args):
-        """Execute a FRESHLY BUILT entry blocking, timing compile +
-        first step — the span that is pure MTTR on every respawn — and
-        detect whether the persistent XLA cache served the executable.
-        Hit detection listens to the compiler's own CACHE HIT/MISS log
-        records during the call: counting cache FILES would misreport a
-        sub-threshold compile (below
-        ``jax_persistent_cache_min_compile_time_secs`` nothing is
-        written, so "no new file" does NOT mean "served from cache").
-        Only the instrumented path pays this (one blocking step per new
-        program variant); steady state never re-enters."""
-        import logging
+    def _first_call(self, entry, call_args):
+        """First use of a FRESHLY BUILT entry, inside the ``build``
+        span: the call traces, lowers and compiles (or reads the
+        persistent cache), and the span's record in
+        ``profiler.builds()`` says how long each took, from JAX's own
+        monitoring events. With ``collect_cost`` the program is lowered
+        once more for ``cost_analysis`` afterwards (``build.cost``). The
+        instrumented path also BLOCKS on the result: compile + first
+        step is pure MTTR on every respawn, kept in ``last_build_s``
+        with ``last_build_cache_hit`` (None = the cache was not
+        consulted — "unknown" is never reported as a hit). Steady state
+        never re-enters."""
         import time as _time
-        from ..flags import compile_cache_dir
-        cache_dir = compile_cache_dir()
-        tally = {"hit": 0, "miss": 0}
-
-        class _CacheTap(logging.Handler):
-            def emit(self, record):
-                try:
-                    msg = record.getMessage()
-                except Exception:
-                    return
-                # jax logs the miss ALL-CAPS and the hit sentence-case
-                # (jax/_src/compiler.py) — match case-insensitively so
-                # a style change in either doesn't blind the tap
-                low = msg.lower()
-                if "persistent compilation cache hit" in low:
-                    tally["hit"] += 1
-                elif "persistent compilation cache miss" in low:
-                    tally["miss"] += 1
-
-        logger = logging.getLogger("jax._src.compiler")
-        tap = _CacheTap(level=logging.DEBUG)
-        prev_level = logger.level
-        if cache_dir:
-            logger.addHandler(tap)
-            if not logger.isEnabledFor(logging.DEBUG):
-                logger.setLevel(logging.DEBUG)
-        try:
+        _metrics.inc("train_step_compiles_total")
+        sig = "x".join(str(d) for d in call_args[4][0].shape) \
+            if call_args[4] else ""
+        if self.collect_cost:
+            from ..observability import cost_model as _cm
+            self.last_entry = entry
+            # taken BEFORE the call: it donates its arguments
+            self.last_abstract_args = _cm.abstractify(call_args)
+        with _build_span("train_step", sig) as b:
             t0 = _time.perf_counter()
             out = entry(*call_args)
-            jax.block_until_ready(out)
-            self.last_build_s = _time.perf_counter() - t0
-        finally:
-            if cache_dir:
-                logger.removeHandler(tap)
-                logger.setLevel(prev_level)
-        if cache_dir and (tally["hit"] or tally["miss"]):
-            self.last_build_cache_hit = tally["miss"] == 0
-        # else: compiler logged nothing (cache off for this backend, or
-        # log plumbing changed) — leave None, "unknown" must never be
-        # reported as a hit
+            if self._instrument:
+                jax.block_until_ready(out)
+                self.last_build_s = _time.perf_counter() - t0
+            if self.collect_cost:
+                # AFTER the call, as model_runner.decode has it: the
+                # call's tracing and lowering are booked as trace_s /
+                # lower_s, and cost_s is the second lowering alone
+                with b.cost():
+                    self.last_cost = _cm.program_cost(
+                        entry, self.last_abstract_args)
+                    self.last_cost_flops = (
+                        None if not self.last_cost
+                        else self.last_cost.get("flops"))
+        if self._instrument:
+            self.last_build_cache_hit = b.record["cache_hit"]
         return out
 
     def _build(self, template, opt_params, frozen, buffers, need_clip,
@@ -570,7 +562,10 @@ class TrainStepProgram:
             )
             if instrument:
                 out_shardings = (out_shardings[0], None) + out_shardings[1:]
-        return jax.jit(pure_step_instrumented if instrument else pure_step,
+        step_fn = pure_step_instrumented if instrument else pure_step
+        # the HLO module's name in a device trace: jit_p2t_train_step
+        step_fn.__name__ = "p2t_train_step"
+        return jax.jit(step_fn,
                        donate_argnums=(0, 1, 3, 8) if donate else (),
                        out_shardings=out_shardings)
 

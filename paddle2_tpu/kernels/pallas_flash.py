@@ -18,6 +18,15 @@ TPU-native tiled online-softmax kernel:
 
 Layout contract matches the reference flash API: (batch, seq, heads, dim).
 Compute is f32 on the MXU regardless of input dtype (bf16 in, f32 softmax).
+
+Every ``pallas_call`` has a ``name=``: a device trace shows the kernel
+under it, and the benchmark's readers find it by it. The compile cache
+sees this file too: a kernel's Mosaic payload holds this file's path and
+the line numbers of the kernel's body, and the payload is in JAX's
+compile-cache key. So any edit that moves lines here makes every
+program that holds a flash kernel miss the cache once — while a
+``jax.named_scope`` added anywhere else is debug info, is stripped from
+the key, and is NOT seen (PERF.md section 6, the empty-cache rule).
 """
 
 from __future__ import annotations
@@ -218,6 +227,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
                                     lambda b, h: (b, h, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
                        jax.ShapeDtypeStruct((B, H, Sq, 128), jnp.float32)],
+            name="flash_fwd",
             interpret=interpret,
         )(q, k, v)
         return o, lse[..., 0]
@@ -250,6 +260,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return o, lse[..., 0]
@@ -416,6 +427,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             out_shape=[jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
                        jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
                        jax.ShapeDtypeStruct((B, H, Sk, D), v.dtype)],
+            name="flash_bwd",
             interpret=interpret,
         )(q, k, v, do, lse_b, delta_b)
         return dq, dk, dv
@@ -448,6 +460,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse_b, delta_b)
 
@@ -470,6 +483,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse_b, delta_b)
     return dq, dk, dv
@@ -697,6 +711,7 @@ def _varlen_fwd(q, k, v, sq, oq, sk, ok, scale, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_fwd_varlen",
         interpret=interpret,
     )(q, k, v, _lane(sq, Tq), _lane(oq, Tq), _lane_k(sk, Tk),
       _lane_k(ok, Tk))
@@ -751,6 +766,7 @@ def _varlen_bwd(q, k, v, o, lse, do, sq, oq, sk, ok, scale, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_bwd_dkv_varlen",
         interpret=interpret,
     )(q, k, v, do, lse_b, delta_b, sq_l, oq_l, sk_l, ok_l)
 
@@ -780,6 +796,7 @@ def _varlen_bwd(q, k, v, o, lse, do, sq, oq, sk, ok, scale, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_bwd_dq_varlen",
         interpret=interpret,
     )(q, k, v, do, lse_b, delta_b, sq_l, oq_l, sk_l, ok_l)
     return dq, dk, dv
@@ -843,10 +860,13 @@ def flash_attention_varlen_packed(q, k, v, seg_q, off_q, seg_k, off_k,
     block_q = block_q or DEFAULT_BLOCK_Q
     block_k = block_k or DEFAULT_BLOCK_K
     cfg = (float(scale), int(block_q), int(block_k), bool(interpret))
-    fn = _cached_jit(("varlen",) + cfg, lambda: (
-        lambda q, k, v, sq, oq, sk, ok: jnp.swapaxes(_flash_varlen(
-            jnp.swapaxes(q, 0, 1)[None], jnp.swapaxes(k, 0, 1)[None],
-            jnp.swapaxes(v, 0, 1)[None], sq, oq, sk, ok, *cfg)[0], 0, 1)))
+    def builder():
+        def flash_varlen(q, k, v, sq, oq, sk, ok):
+            return jnp.swapaxes(_flash_varlen(
+                jnp.swapaxes(q, 0, 1)[None], jnp.swapaxes(k, 0, 1)[None],
+                jnp.swapaxes(v, 0, 1)[None], sq, oq, sk, ok, *cfg)[0], 0, 1)
+        return flash_varlen
+    fn = _cached_jit(("varlen",) + cfg, builder)
     return fn(q, k, v, jnp.asarray(seg_q, jnp.int32),
               jnp.asarray(off_q, jnp.int32),
               jnp.asarray(seg_k, jnp.int32),
@@ -868,8 +888,11 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
         interpret = interpret_default()
     cfg = (float(scale), bool(causal), int(block_q), int(block_k),
            bool(interpret))
-    fn = _cached_jit(("bshd",) + cfg, lambda: (
-        lambda q, k, v: jnp.swapaxes(
-            _flash(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                   jnp.swapaxes(v, 1, 2), *cfg), 1, 2)))
+    def builder():
+        def flash_bshd(q, k, v):
+            return jnp.swapaxes(
+                _flash(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                       jnp.swapaxes(v, 1, 2), *cfg), 1, 2)
+        return flash_bshd
+    fn = _cached_jit(("bshd",) + cfg, builder)
     return fn(q, k, v)

@@ -85,6 +85,7 @@ def fused_adamw(param, grad, m, v, master, lr, beta1=0.9, beta2=0.999,
             jax.ShapeDtypeStruct((npad,), jnp.float32),
             jax.ShapeDtypeStruct((npad,), jnp.float32),
         ],
+        name="fused_adamw",
         interpret=interpret,
     )(p1, g1, m1, v1, w1, sc)
     unflat = lambda a, like: a[:n].reshape(param.shape).astype(like.dtype) \
@@ -194,6 +195,7 @@ def fused_adamw_step(param, grad, m, v, lr, step, beta1=0.9,
         out_shape=[jax.ShapeDtypeStruct((npad,), jnp.float32)] * 3,
         # layout pinning: update in place — no staging copies
         input_output_aliases={0: 0, 2: 1, 3: 2},
+        name="fused_adamw_step",
         interpret=interpret,
     )(p1, g1, m1, v1, sc)
     shape = param.shape
@@ -246,6 +248,7 @@ def fused_momentum_step(param, grad, velocity, lr, momentum=0.9,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((npad,), jnp.float32)] * 2,
         input_output_aliases={0: 0, 2: 1},
+        name="fused_momentum",
         interpret=interpret,
     )(p1, g1, v1, sc)
     shape = param.shape
@@ -277,6 +280,7 @@ def _rmsnorm_fwd(x, w, eps, block_rows, interpret):
                    pl.BlockSpec((br, 128), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, H), x.dtype),
                    jax.ShapeDtypeStruct((R, 128), jnp.float32)],
+        name="rmsnorm_fwd",
         interpret=interpret,
     )(x, w.reshape(1, H))
     return o, r[:, 0]
@@ -315,6 +319,7 @@ def _rmsnorm_bwd(x, w, r, do, block_rows, interpret):
                    pl.BlockSpec((8, H), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, H), x.dtype),
                    jax.ShapeDtypeStruct((R // br * 8, H), jnp.float32)],
+        name="rmsnorm_bwd",
         interpret=interpret,
     )(x, w.reshape(1, H), r2, do)
     return dx, dw_part[::8].sum(axis=0).astype(w.dtype)
@@ -352,9 +357,10 @@ def fused_rms_norm(x, weight, epsilon=1e-6, block_rows=512, interpret=None):
     key = ("rmsnorm", float(epsilon), int(block_rows), bool(interpret))
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(lambda x2, w: _rmsnorm(x2, w, float(epsilon),
-                                            int(block_rows),
-                                            bool(interpret)))
+        def rmsnorm(x2, w):
+            return _rmsnorm(x2, w, float(epsilon), int(block_rows),
+                            bool(interpret))
+        fn = jax.jit(rmsnorm)
         _JIT_CACHE[key] = fn
     return fn(x.reshape(-1, H), weight).reshape(shape)
 
@@ -405,6 +411,7 @@ def fused_rope(x, cos, sin, block_rows=256, interpret=None):
                 in_specs=[xspec, cspec, cspec],
                 out_specs=xspec,
                 out_shape=jax.ShapeDtypeStruct((rows, H, D), a.dtype),
+                name="rope",
                 interpret=interpret,
             )(a, c, s)
 

@@ -164,7 +164,10 @@ class StackedLayerStack(*_layer_base()):
                 return out._data, None
             if wrap_body is not None:
                 body = wrap_body(body)
-            final, _ = jax.lax.scan(body, x._data, stacked)
+            # "blocks" names the scan's own plumbing in a device trace
+            # (slicing the stacked leaves, stacking the residuals)
+            with jax.named_scope("blocks"):
+                final, _ = jax.lax.scan(body, x._data, stacked)
             return Tensor(final, stop_gradient=x.stop_gradient)
         if tracing:
             # traced but scan disallowed (e.g. dropout needs a DISTINCT
@@ -261,5 +264,6 @@ def scan_layer_stack(layers: Sequence, x: Tensor,
 
     if wrap_body is not None:
         body = wrap_body(body)
-    final, _ = jax.lax.scan(body, x._data, stacked)
+    with jax.named_scope("blocks"):     # as StackedLayerStack.forward
+        final, _ = jax.lax.scan(body, x._data, stacked)
     return Tensor(final, stop_gradient=x.stop_gradient)

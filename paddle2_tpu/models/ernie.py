@@ -93,9 +93,16 @@ class ErnieLayer(nn.Layer):
         self.drop = nn.Dropout(cfg.hidden_dropout_prob)
 
     def forward(self, x, attn_bias=None):
-        x = self.ln_1(x + self.drop(self.attn(x, attn_bias)))
-        x = self.ln_2(x + self.drop(self.down(F.gelu(self.up(x)))))
-        return x
+        # the same scope names as models/gpt.py (metadata only): each
+        # sublayer with its residual add and its post-norm
+        with jax.named_scope("attn"):
+            h = x + self.drop(self.attn(x, attn_bias))
+            with jax.named_scope("norm"):
+                x = self.ln_1(h)
+        with jax.named_scope("mlp"):
+            h = x + self.drop(self.down(F.gelu(self.up(x))))
+            with jax.named_scope("norm"):
+                return self.ln_2(h)
 
 
 class ErnieModel(nn.Layer):
@@ -121,11 +128,12 @@ class ErnieModel(nn.Layer):
 
     def forward(self, input_ids, token_type_ids=None, attention_mask=None):
         b, s = input_ids.shape
-        pos = Tensor(jnp.arange(s, dtype=jnp.int32)[None, :])
-        x = self.word_emb(input_ids) + self.pos_emb(pos)
-        if token_type_ids is not None:
-            x = x + self.type_emb(token_type_ids)
-        x = self.drop(self.emb_ln(x))
+        with jax.named_scope("embed"):
+            pos = Tensor(jnp.arange(s, dtype=jnp.int32)[None, :])
+            x = self.word_emb(input_ids) + self.pos_emb(pos)
+            if token_type_ids is not None:
+                x = x + self.type_emb(token_type_ids)
+            x = self.drop(self.emb_ln(x))
         attn_bias = None
         if attention_mask is not None:
             m = attention_mask._data if isinstance(attention_mask, Tensor) \
@@ -191,12 +199,13 @@ class ErnieForSequenceClassification(nn.Layer):
     def forward(self, input_ids, token_type_ids=None, attention_mask=None,
                 labels=None):
         _, pooled = self.ernie(input_ids, token_type_ids, attention_mask)
-        logits = self.classifier(self.drop(pooled))
-        if labels is None:
-            return logits
-        loss = F.cross_entropy(logits.astype("float32"),
-                               labels.reshape([-1]))
-        return logits, loss
+        with jax.named_scope("head_ce"):
+            logits = self.classifier(self.drop(pooled))
+            if labels is None:
+                return logits
+            loss = F.cross_entropy(logits.astype("float32"),
+                                   labels.reshape([-1]))
+            return logits, loss
 
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())

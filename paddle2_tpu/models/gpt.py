@@ -274,14 +274,25 @@ class GPTBlock(nn.Layer):
         return out
 
     def forward(self, x, cache=None):
-        if cache is not None:
-            a, new_cache = self.attn(self._ln(self.ln_1, x), cache=cache)
+        # jax.named_scope = the layer boundaries a device trace shows:
+        # attn and mlp, each with its pre-norm (attn/norm, mlp/norm)
+        # and its residual add; metadata only, the arithmetic is what
+        # it was
+        new_cache = None
+        with jax.named_scope("attn"):
+            with jax.named_scope("norm"):
+                h = self._ln(self.ln_1, x)
+            if cache is not None:
+                a, new_cache = self.attn(h, cache=cache)
+            else:
+                a = self.attn(h)
             x = x + self.dropout(a)
-            x = x + self.dropout(self.mlp(self._ln(self.ln_2, x)))
-            return _seq_constrain(x, self.cfg), new_cache
-        x = x + self.dropout(self.attn(self._ln(self.ln_1, x)))
-        x = x + self.dropout(self.mlp(self._ln(self.ln_2, x)))
-        return _seq_constrain(x, self.cfg)
+        with jax.named_scope("mlp"):
+            with jax.named_scope("norm"):
+                h = self._ln(self.ln_2, x)
+            x = x + self.dropout(self.mlp(h))
+        x = _seq_constrain(x, self.cfg)
+        return (x, new_cache) if cache is not None else x
 
 
 class GPTModel(nn.Layer):
@@ -391,16 +402,18 @@ class GPTModel(nn.Layer):
 
     def forward(self, input_ids):
         b, s = input_ids.shape
-        pos = Tensor(jnp.arange(s, dtype=jnp.int32)[None, :])
-        x = self.wte(input_ids) + self.wpe(pos)
-        x = _seq_constrain(self.drop(x), self.cfg)
+        with jax.named_scope("embed"):
+            pos = Tensor(jnp.arange(s, dtype=jnp.int32)[None, :])
+            x = self.wte(input_ids) + self.wpe(pos)
+            x = _seq_constrain(self.drop(x), self.cfg)
         use_rc, gran = (self._resolved_remat(b, s) if self.training
                         else (False, None))
         if self._can_scan(x):
             x = self._scan_blocks(x, use_rc, gran)
         else:
             x = self._fallback_loop(x, use_rc, gran)
-        return self.ln_f(x)
+        with jax.named_scope("norm"):
+            return self.ln_f(x)
 
     def _can_scan(self, x) -> bool:
         cfg = self.cfg
@@ -454,10 +467,11 @@ class GPTModel(nn.Layer):
         appending to per-layer (k, v) caches. caches: list of per-block
         tuples (() on the first/prefill call)."""
         b, s = input_ids.shape
-        pos = Tensor(jnp.arange(position_offset, position_offset + s,
-                                dtype=jnp.int32)[None, :])
-        x = self.wte(input_ids) + self.wpe(pos)
-        x = _seq_constrain(self.drop(x), self.cfg)
+        with jax.named_scope("embed"):
+            pos = Tensor(jnp.arange(position_offset, position_offset + s,
+                                    dtype=jnp.int32)[None, :])
+            x = self.wte(input_ids) + self.wpe(pos)
+            x = _seq_constrain(self.drop(x), self.cfg)
         new_caches = []
         if self.cfg.stacked_blocks:
             for i, cache in enumerate(caches):
@@ -467,7 +481,8 @@ class GPTModel(nn.Layer):
             for block, cache in zip(self.h, caches):
                 x, c = block(x, cache=cache)
                 new_caches.append(c)
-        return self.ln_f(x), new_caches
+        with jax.named_scope("norm"):
+            return self.ln_f(x), new_caches
 
 
 class GPTForCausalLM(nn.Layer):
@@ -521,20 +536,22 @@ class GPTForCausalLM(nn.Layer):
 
     def forward(self, input_ids, labels=None):
         hidden = self.gpt(input_ids)
-        if (labels is not None and self.cfg.fused_head_loss
-                and not self.cfg.tensor_parallel):
-            from ..incubate.nn.functional import fused_linear_cross_entropy
-            w = (self.gpt.wte.weight.T if self.cfg.tie_word_embeddings
-                 else self.lm_head.weight)
-            loss = fused_linear_cross_entropy(hidden, w, labels)
-            return None, loss
-        logits = self._head(hidden)
-        if labels is None:
-            return logits
-        loss = F.cross_entropy(
-            logits.reshape([-1, self.cfg.vocab_size]).astype("float32"),
-            labels.reshape([-1]))
-        return logits, loss
+        with jax.named_scope("head_ce"):
+            if (labels is not None and self.cfg.fused_head_loss
+                    and not self.cfg.tensor_parallel):
+                from ..incubate.nn.functional import \
+                    fused_linear_cross_entropy
+                w = (self.gpt.wte.weight.T if self.cfg.tie_word_embeddings
+                     else self.lm_head.weight)
+                loss = fused_linear_cross_entropy(hidden, w, labels)
+                return None, loss
+            logits = self._head(hidden)
+            if labels is None:
+                return logits
+            loss = F.cross_entropy(
+                logits.reshape([-1, self.cfg.vocab_size]).astype("float32"),
+                labels.reshape([-1]))
+            return logits, loss
 
     def num_params(self) -> int:
         return sum(p.size for p in self.parameters())

@@ -33,6 +33,16 @@ def _clip_arrays(grad_clip, grads, need_clip_flags):
             for g, c in zip(grads, need_clip_flags)]
 
 
+def _in_optimizer_scope(update):
+    """``update`` traced under ``jax.named_scope("optimizer")``: clip's
+    global norm and the per-parameter update carry that scope in a
+    device trace. Metadata only — the arithmetic is untouched."""
+    def scoped(params, grads, states, lr, step):
+        with jax.named_scope("optimizer"):
+            return update(params, grads, states, lr, step)
+    return scoped
+
+
 class Optimizer:
     _hyper: Dict[str, float] = {}
 
@@ -218,7 +228,7 @@ class Optimizer:
             fused = self._fused_update_builder(need_clip_flags,
                                                decay_flags)
             if fused is not None:
-                return fused
+                return _in_optimizer_scope(fused)
         apply_one = self._apply_one
         grad_clip = self._grad_clip
 
@@ -230,7 +240,7 @@ class Optimizer:
                 new_params.append(np_)
                 new_states.append(ns_)
             return new_params, new_states
-        return update
+        return _in_optimizer_scope(update)
 
     def _make_update_fn(self, need_clip_flags, decay_flags, donate: bool):
         # donate the OPTIMIZER STATES (master weights + moments, ~3x model

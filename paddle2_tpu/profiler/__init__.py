@@ -7,10 +7,20 @@ TPU-native design: the heavyweight device timeline comes from jax.profiler
 reference's CUPTI tracer), while host-side op records + RecordEvent spans
 are collected in-process and exported as a chrome://tracing JSON, the same
 artifact the reference's chrometracing_logger.cc writes.
+
+The program's OWN host spans are written with one primitive,
+:func:`span`: a ``jax.profiler.TraceAnnotation`` named ``"p2t:" +
+name``. Whatever profiler session is active (``Profiler`` here, a bare
+``jax.profiler.start_trace``) gets them in its ``.xplane.pb`` on the
+device ops' clock; with none a span costs what an inactive TraceMe
+costs, so call sites are unconditional. :func:`build` is the span
+around the first use of a newly made ``jax.jit`` entry and also feeds
+the always-on :func:`builds` log.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
@@ -19,9 +29,150 @@ import time
 from enum import Enum
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
+import jax
+
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
-           "SortedKeys", "SummaryView", "benchmark", "merge_traces"]
+           "SortedKeys", "SummaryView", "benchmark", "merge_traces",
+           "span", "build", "builds"]
+
+SPAN_PREFIX = "p2t:"
+
+
+def span(name: str, **counts):
+    """The program's one host span: a context manager that IS a
+    ``jax.profiler.TraceAnnotation`` named ``"p2t:" + name``; ``counts``
+    (host-known ints and short strings) become the event's stats, and a
+    count known only at the end goes through ``set_metadata(**counts)``
+    on the entered span. Rules for call sites: never inside a per-row
+    or per-token loop, never a device read to compute a count, never
+    inside a jitted function."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **counts)
+
+
+# -- the build log ---------------------------------------------------------
+# Programs are built tens of times per process and mostly before any
+# profiler session, so every build also leaves one record here, filled
+# from JAX's own monitoring events between the span's start and end.
+_BUILD_PARTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+# the newest MAX_BUILDS records: a server that meets new shapes for weeks
+# (one scatter program per distinct prompt length) must not grow it
+MAX_BUILDS = 1024
+_builds: collections.deque = collections.deque(maxlen=MAX_BUILDS)
+# the open build of THIS thread: JAX's monitoring callbacks fire on the
+# thread that compiles, so two threads building at once (engines beside
+# a trainer) each fill their own record
+_open = threading.local()
+_listen_lock = threading.Lock()
+_listening = False
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    b = getattr(_open, "build", None)
+    if b is None or b._in_cost:
+        return
+    part = _BUILD_PARTS.get(event)
+    if part is not None:
+        end = time.perf_counter()
+        b._intervals[part].append((end - duration, end))
+    elif event == _CACHE_READ:
+        b._cache_read_s += duration
+
+
+def _on_event(event: str, **_) -> None:
+    b = getattr(_open, "build", None)
+    if b is None or b._in_cost:
+        return
+    if event == _CACHE_HIT:
+        b._hits += 1
+    elif event == _CACHE_MISS:
+        b._misses += 1
+
+
+def _union_s(intervals) -> float:
+    """Seconds covered by ``intervals``: a jit traced inside another
+    reports its own duration too, and must not count twice."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+class build:
+    """``with profiler.build(program, sig) as b:`` around the first use
+    of a newly made ``jax.jit`` entry (the call that traces, lowers and
+    compiles or reads the cache), ``with b.cost():`` around a
+    ``cost_analysis`` lowering inside it. Writes the ``build`` /
+    ``build.cost`` spans and appends ``{program, sig, trace_s, lower_s,
+    compile_s, cache_hit, cache_read_s, cost_s, total_s}`` to
+    :func:`builds`. ``compile_s`` is JAX's backend-compile event, which
+    on a persistent-cache hit is the read (``cache_read_s`` of it);
+    ``cache_hit`` is None when the cache was not consulted."""
+
+    def __init__(self, program: str, sig: str = ""):
+        self.record: Dict = {"program": program, "sig": sig}
+        self._span = span("build", program=program, sig=sig)
+        self._intervals: Dict[str, list] = {
+            part: [] for part in _BUILD_PARTS.values()}
+        self._hits = self._misses = 0
+        self._cache_read_s = self._cost_s = 0.0
+        self._in_cost = False
+
+    def __enter__(self):
+        global _listening
+        with _listen_lock:
+            if not _listening:
+                import jax.monitoring as mon
+                mon.register_event_duration_secs_listener(_on_duration)
+                mon.register_event_listener(_on_event)
+                _listening = True
+        self._outer = getattr(_open, "build", None)
+        _open.build = self
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        total = time.perf_counter() - self._t0
+        self._span.__exit__(exc_type, exc, tb)
+        _open.build = self._outer
+        if exc_type is None:
+            rec = self.record
+            for part, intervals in self._intervals.items():
+                rec[part] = _union_s(intervals)
+            rec["cache_hit"] = (self._misses == 0
+                                if self._hits or self._misses else None)
+            rec["cache_read_s"] = self._cache_read_s
+            rec["cost_s"] = self._cost_s
+            rec["total_s"] = total
+            _builds.append(rec)
+        return False
+
+    @contextlib.contextmanager
+    def cost(self):
+        t0 = time.perf_counter()
+        self._in_cost = True
+        try:
+            with span("build.cost"):
+                yield
+        finally:
+            self._in_cost = False
+            self._cost_s += time.perf_counter() - t0
+
+
+def builds() -> List[Dict]:
+    """The programs built in this process so far, oldest first (the
+    newest ``MAX_BUILDS`` of them)."""
+    return list(_builds)
 
 
 class ProfilerTarget(Enum):
@@ -97,12 +248,6 @@ class _Collector:
 _collector = _Collector()
 
 
-# True while a jax.profiler device trace is running (set by
-# Profiler._sync_device_trace): RecordEvent mirrors its spans into the
-# xprof timeline only when there IS one to land in
-_device_trace_active = False
-
-
 class RecordEvent:
     """User-annotated span (reference utils.py RecordEvent / the
     nvtx-range analog). Usable as context manager or begin()/end().
@@ -110,9 +255,10 @@ class RecordEvent:
     One annotation, three correlated timelines:
 
     * the host chrome trace (always, when a Profiler is recording);
-    * the xprof device timeline — when a ``jax.profiler`` trace is
-      active the span also opens a ``TraceAnnotation``, so user marks
-      line up against the XLA execution rows in TensorBoard;
+    * the device timeline — the mark opens :func:`span`, so ANY active
+      ``jax.profiler`` session (this module's ``Profiler`` or a bare
+      ``jax.profiler.start_trace``) gets it as ``p2t:<name>`` beside
+      the XLA execution rows;
     * the flight-recorder ring — ``user_span`` events carry the name
       and duration into crash dumps, so a post-mortem can say WHICH
       phase of the step the gang died in.
@@ -121,17 +267,11 @@ class RecordEvent:
     def __init__(self, name: str, event_type=None):
         self.name = name
         self._start: Optional[float] = None
-        self._annotation = None
+        self._span = None
 
     def begin(self):
-        if _device_trace_active:
-            try:
-                import jax
-                self._annotation = jax.profiler.TraceAnnotation(
-                    self.name)
-                self._annotation.__enter__()
-            except Exception:
-                self._annotation = None
+        self._span = span(self.name)
+        self._span.__enter__()
         from ..distributed.fault_tolerance import flight_recorder
         flight_recorder.record("user_span_begin", name=self.name)
         self._start = time.perf_counter()
@@ -141,12 +281,8 @@ class RecordEvent:
             dur = time.perf_counter() - self._start
             _collector.add(self.name, "user", self._start, dur)
             self._start = None
-            if self._annotation is not None:
-                try:
-                    self._annotation.__exit__(None, None, None)
-                except Exception:
-                    pass
-                self._annotation = None
+            self._span.__exit__(None, None, None)
+            self._span = None
             from ..distributed.fault_tolerance import flight_recorder
             flight_recorder.record("user_span_end", name=self.name,
                                    dur_s=round(dur, 6))
@@ -272,13 +408,9 @@ class Profiler:
 
     def _sync_device_trace(self):
         """xprof tracing follows the scheduler: device capture runs only
-        inside RECORD windows (skip_first/closed steps stay untraced).
-        The module-level ``_device_trace_active`` flag tracks the trace
-        state so RecordEvent spans mirror into the xprof timeline."""
-        global _device_trace_active
+        inside RECORD windows (skip_first/closed steps stay untraced)."""
         if self._timer_only:
             return
-        import jax
         want = self._recording()
         have = self._jax_trace_dir is not None
         if want and not have:
@@ -286,7 +418,6 @@ class Profiler:
                 self._jax_trace_dir = os.environ.get(
                     "PADDLE2_TPU_XPROF_DIR", "/tmp/paddle2_tpu_xprof")
                 jax.profiler.start_trace(self._jax_trace_dir)
-                _device_trace_active = True
             except Exception:
                 self._jax_trace_dir = None
         elif not want and have:
@@ -295,7 +426,6 @@ class Profiler:
             except Exception:
                 pass
             self._jax_trace_dir = None
-            _device_trace_active = False
 
     def step(self, num_samples: Optional[int] = None):
         now = time.perf_counter()
@@ -311,15 +441,12 @@ class Profiler:
         self._sync_device_trace()
 
     def stop(self):
-        global _device_trace_active
         if self._jax_trace_dir is not None:
             try:
-                import jax
                 jax.profiler.stop_trace()
             except Exception:
                 pass
             self._jax_trace_dir = None
-            _device_trace_active = False
         self._events = list(_collector.events)
         _collector.enabled = False
         if self._on_trace_ready is not None:

@@ -432,16 +432,24 @@ class PagedKVCache:
         key = (tuple(pool.shape), str(pool.dtype),
                tuple(layer_kv.shape), start, int(n_tokens))
         fn = _PREFILL_SCATTER_CACHE.get(key)
-        if fn is None:
-            n = int(n_tokens)
-            fn = jax.jit(
-                lambda p, kv, ph, sl: p.at[:, ph, sl].set(
-                    kv[:, start:n].reshape(kv.shape[0], n - start, -1)),
-                donate_argnums=(0,))
-            if len(_PREFILL_SCATTER_CACHE) > 1024:
-                _PREFILL_SCATTER_CACHE.clear()
-            _PREFILL_SCATTER_CACHE[key] = fn
-        return fn(pool, layer_kv, phys, slot)
+        if fn is not None:
+            return fn(pool, layer_kv, phys, slot)
+        n = int(n_tokens)
+
+        # the function's name is the HLO module's in a device trace
+        def p2t_kv_scatter_prefill(p, kv, ph, sl):
+            with jax.named_scope("kv_write"):
+                return p.at[:, ph, sl].set(
+                    kv[:, start:n].reshape(kv.shape[0], n - start, -1))
+
+        fn = jax.jit(p2t_kv_scatter_prefill, donate_argnums=(0,))
+        if len(_PREFILL_SCATTER_CACHE) > 1024:
+            _PREFILL_SCATTER_CACHE.clear()
+        _PREFILL_SCATTER_CACHE[key] = fn
+        from ..profiler import build
+        with build("kv_scatter_prefill",
+                   f"{layer_kv.shape[1]}:{start}:{n}"):
+            return fn(pool, layer_kv, phys, slot)
 
     @staticmethod
     def copy_block(pool, src: int, dst: int):

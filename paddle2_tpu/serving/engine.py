@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Sequence as Seq, Tuple
 
 import numpy as np
 
+from ..profiler import span as _span
 from .block_cache import (BlockAllocator, HostKVTier, PagedKVCache,
                           PrefixCache, blocks_for_tokens, GARBAGE_BLOCK)
 from .model_runner import PagedGPTRunner
@@ -290,6 +291,17 @@ class ServingEngine:
         ``trace_id`` is the stable id the request-tracing plane keys
         this request's span tree by (the failover router stamps its
         fleet-global id; default: this engine's request id)."""
+        # `req` joins this span with the request's `prefill`: the time
+        # to its first token, on the trace's clock, is from here to the
+        # end of that prefill's `prefill.readback`
+        with _span("submit") as sp:
+            rid = self._submit(prompt, max_new_tokens, arrival_t,
+                               priority, deadline_s, trace_id)
+            sp.set_metadata(req=rid)
+        return rid
+
+    def _submit(self, prompt, max_new_tokens, arrival_t, priority,
+                deadline_s, trace_id) -> int:
         self._check_alive()
         prompt = [int(t) for t in prompt]
         if not prompt:
@@ -469,12 +481,28 @@ class ServingEngine:
         stamps when each sequence may join the decode batch — the sim
         sets it to the prefill LANE's completion time, which is the
         whole point of disaggregation: decode never waits on it."""
-        from ..observability import metrics
         self._check_alive()
-        out = []
-        for seq in self.scheduler.admit(now):
-            n = len(seq.tokens)
-            tok, k_stack, v_stack = self.runner.prefill(seq.tokens)
+        with _span("admit"):
+            with _span("admit.schedule"):
+                admitted = self.scheduler.admit(now)
+            out = [self._prefill_admitted(seq, now, ready_at_fn)
+                   for seq in admitted]
+            self._gauge()
+        return out
+
+    def _prefill_admitted(self, seq: Sequence, now: float,
+                          ready_at_fn) -> dict:
+        """Prefill one admitted sequence, scatter its K/V into its
+        blocks, sample its next token and mark it running."""
+        from ..observability import metrics
+        n = len(seq.tokens)
+        padded = self.runner.prefill_padded_len(n)
+        with _span("prefill", req=seq.req_id, tokens=n, padded=padded):
+            with _span("prefill.dispatch"):
+                tok, k_stack, v_stack = self.runner.prefill_dispatch(
+                    seq.tokens)
+            with _span("prefill.readback"):
+                tok = int(tok[0])       # the host waits for the device
             row = np.asarray(seq.table.blocks, np.int64)
             # prefix-cache hit: the leading cached positions' KV is
             # ALREADY in the pool (and shared — rewriting it would
@@ -483,76 +511,74 @@ class ServingEngine:
             # the tail's hidden states need the prefix context, and
             # the first generated token comes from the last position.
             start = min(seq.prefix_cached_tokens, n)
-            self.cache.k = PagedKVCache.scatter_prefill(
-                self.cache.k, k_stack, row, n, self.cache.block_size,
-                start=start)
-            self.cache.v = PagedKVCache.scatter_prefill(
-                self.cache.v, v_stack, row, n, self.cache.block_size,
-                start=start)
-            seq.table.num_tokens = n
-            seq.tokens.append(tok)
-            padded = self.runner.prefill_padded_len(n)
-            cost = self.runner.prefill_cost(padded)
-            info = {"seq": seq, "prompt_tokens": n, "padded_len": padded,
-                    "cost": cost}
-            if self.host_tier is not None and cost and start > 0:
-                # tiering charges the clock for the UNCACHED tail only
-                # (linear token scaling of the full-prompt cost): the
-                # cached prefix's KV already exists, and a real system
-                # with paged-context prefill skips its compute. The
-                # full prefill still RUNS (exactness — the tail's
-                # hidden states need the prefix context); only the
-                # modeled charge shrinks. Off-tier engines keep the
-                # PR 13 full-charge behavior bitwise.
-                # a FULL-prompt hit still computes the last position
-                # (the first generated token's logits need it), so the
-                # charge floors at one token — never the flopless
-                # zero-dict that would trip the clock fallback
-                frac = (n - min(start, n - 1)) / n
-                info["charged_cost"] = {k: v * frac
-                                        for k, v in cost.items()}
-            ready = (ready_at_fn(info) if ready_at_fn is not None
-                     else now)
-            # tier-fetch stall: host-tier promotions pay the shared
-            # offload link, peer fetches carry their modeled DCN
-            # seconds from the registry's cost decision — both land
-            # AFTER the prefill interval so the decomposition's
-            # spill_fetch component never overlaps prefill_s
-            host_blocks = getattr(seq, "kv_fetched_host", 0)
-            peer_blocks = getattr(seq, "kv_fetched_peer", 0)
-            fetch_s = (host_blocks * self.cache.block_bytes
-                       / self.host_link_bps
-                       + getattr(seq, "kv_peer_fetch_s", 0.0))
-            seq.ready_at = ready + fetch_s
-            if seq.first_token_t is None:
-                seq.first_token_t = seq.ready_at
-                metrics.observe("serving_ttft_s",
-                                max(0.0, seq.first_token_t
-                                    - seq.request.arrival_t))
-            self.scheduler.mark_running(seq)
-            # prefill span: admission -> first-token-ready on the
-            # prefill lane (lane queueing included — the decode lane
-            # never waits on it). `end` is the EXACT lane stamp so
-            # a finish-at-prefill closes the sum bitwise.
-            _flight_record(event="prefill", req=seq.req_id,
-                           tid=seq.trace_id, t=now, end=ready,
-                           engine=self.engine_id, tokens=n,
-                           padded=padded)
-            if fetch_s:
-                _flight_record(event="spill_fetch", req=seq.req_id,
-                               tid=seq.trace_id, t=ready,
-                               end=seq.ready_at, engine=self.engine_id,
-                               host_blocks=host_blocks or None,
-                               peer_blocks=peer_blocks or None)
-            metrics.inc("serving_prefill_tokens_total", n)
-            if seq.done:
-                # its only token materializes when the prefill LANE
-                # finishes — finishing at the admission instant would
-                # stamp finish_t before first_token_t
-                self.scheduler.finish(seq, seq.ready_at)
-            out.append(info)
-        self._gauge()
-        return out
+            with _span("prefill.scatter"):
+                self.cache.k = PagedKVCache.scatter_prefill(
+                    self.cache.k, k_stack, row, n, self.cache.block_size,
+                    start=start)
+                self.cache.v = PagedKVCache.scatter_prefill(
+                    self.cache.v, v_stack, row, n, self.cache.block_size,
+                    start=start)
+        seq.table.num_tokens = n
+        seq.tokens.append(tok)
+        cost = self.runner.prefill_cost(padded)
+        info = {"seq": seq, "prompt_tokens": n, "padded_len": padded,
+                "cost": cost}
+        if self.host_tier is not None and cost and start > 0:
+            # tiering charges the clock for the UNCACHED tail only
+            # (linear token scaling of the full-prompt cost): the
+            # cached prefix's KV already exists, and a real system
+            # with paged-context prefill skips its compute. The
+            # full prefill still RUNS (exactness — the tail's
+            # hidden states need the prefix context); only the
+            # modeled charge shrinks. Off-tier engines keep the
+            # PR 13 full-charge behavior bitwise.
+            # a FULL-prompt hit still computes the last position
+            # (the first generated token's logits need it), so the
+            # charge floors at one token — never the flopless
+            # zero-dict that would trip the clock fallback
+            frac = (n - min(start, n - 1)) / n
+            info["charged_cost"] = {k: v * frac
+                                    for k, v in cost.items()}
+        ready = (ready_at_fn(info) if ready_at_fn is not None
+                 else now)
+        # tier-fetch stall: host-tier promotions pay the shared
+        # offload link, peer fetches carry their modeled DCN
+        # seconds from the registry's cost decision — both land
+        # AFTER the prefill interval so the decomposition's
+        # spill_fetch component never overlaps prefill_s
+        host_blocks = getattr(seq, "kv_fetched_host", 0)
+        peer_blocks = getattr(seq, "kv_fetched_peer", 0)
+        fetch_s = (host_blocks * self.cache.block_bytes
+                   / self.host_link_bps
+                   + getattr(seq, "kv_peer_fetch_s", 0.0))
+        seq.ready_at = ready + fetch_s
+        if seq.first_token_t is None:
+            seq.first_token_t = seq.ready_at
+            metrics.observe("serving_ttft_s",
+                            max(0.0, seq.first_token_t
+                                - seq.request.arrival_t))
+        self.scheduler.mark_running(seq)
+        # prefill span: admission -> first-token-ready on the
+        # prefill lane (lane queueing included — the decode lane
+        # never waits on it). `end` is the EXACT lane stamp so
+        # a finish-at-prefill closes the sum bitwise.
+        _flight_record(event="prefill", req=seq.req_id,
+                       tid=seq.trace_id, t=now, end=ready,
+                       engine=self.engine_id, tokens=n,
+                       padded=padded)
+        if fetch_s:
+            _flight_record(event="spill_fetch", req=seq.req_id,
+                           tid=seq.trace_id, t=ready,
+                           end=seq.ready_at, engine=self.engine_id,
+                           host_blocks=host_blocks or None,
+                           peer_blocks=peer_blocks or None)
+        metrics.inc("serving_prefill_tokens_total", n)
+        if seq.done:
+            # its only token materializes when the prefill LANE
+            # finishes — finishing at the admission instant would
+            # stamp finish_t before first_token_t
+            self.scheduler.finish(seq, seq.ready_at)
+        return info
 
     # -- block-table integrity --------------------------------------------
     def _validate_tables(self, active: List[Sequence],
@@ -625,9 +651,24 @@ class ServingEngine:
         step info dict, or None when nothing is ready. Raises
         :class:`~.reliability.EngineFailedError` when the engine is
         (or chaos makes it) dead."""
+        self._check_alive()
+        # host spans of one tick (profiler.span; PERF.md lists them):
+        # decode holds select -> build_batch -> dispatch (holding
+        # readback) -> emit; a tick with nothing ready ends inside
+        # select
+        with _span("decode"):
+            with _span("decode.select"):
+                picked = self._select_decode_rows(now)
+            if picked is None:
+                return None
+            return self._decode_rows(now, *picked)
+
+    def _select_decode_rows(self, now: float):
+        """Who decodes this tick: the ready running sequences whose
+        tables validate and whose next slots (drafts included) could
+        be reserved. Returns (active, drafts, victims) or None."""
         from ..distributed.fault_tolerance import chaos
         from ..observability import metrics
-        self._check_alive()
         active = [s for s in self.scheduler.running()
                   if getattr(s, "ready_at", 0.0) <= now]
         if not active:
@@ -657,7 +698,6 @@ class ServingEngine:
             raise EngineFailedError(
                 f"engine {self.engine_id} killed by chaos at decode "
                 f"step {self.decode_steps + 1}")
-        cfg = self.scheduler.config
         # -- speculative drafts (host, deterministic): each sequence
         # may contribute 1 + k chunk rows to this round's verify batch.
         # spec=None degenerates to EXACTLY the PR 9 single-row step —
@@ -692,23 +732,55 @@ class ServingEngine:
                           if k in {id(s) for s in active}}
             if not active:
                 return None
-        rows = []                      # (seq, token, position)
-        for s in active:
-            p0 = s.num_cached
-            rows.append((s, s.tokens[p0], p0))
-            for i, d in enumerate(drafts.get(id(s), ())):
-                rows.append((s, d, p0 + 1 + i))
-        b_bucket = cfg.batch_bucket(len(rows))
-        p_bucket = self.scheduler.decode_bucket(active)[1]
-        ids = np.zeros((b_bucket, 1), np.int32)
-        positions = np.zeros((b_bucket,), np.int32)
-        tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
-        for i, (s, tok_in, pos) in enumerate(rows):
-            ids[i, 0] = tok_in
-            positions[i] = pos
-            tables[i] = s.table.padded(p_bucket)
-        with metrics.phase("compute"):
+        return active, drafts, victims
+
+    def _decode_rows(self, now: float, active: List[Sequence],
+                     drafts: Dict[int, List[int]],
+                     victims: list) -> dict:
+        from ..observability import metrics
+        cfg = self.scheduler.config
+        with _span("decode.build_batch"):
+            rows = []                      # (seq, token, position)
+            ctx_tokens = 0
+            for s in active:
+                p0 = s.num_cached
+                ctx_tokens += p0
+                rows.append((s, s.tokens[p0], p0))
+                for i, d in enumerate(drafts.get(id(s), ())):
+                    rows.append((s, d, p0 + 1 + i))
+            b_bucket = cfg.batch_bucket(len(rows))
+            p_bucket = self.scheduler.decode_bucket(active)[1]
+            ids = np.zeros((b_bucket, 1), np.int32)
+            positions = np.zeros((b_bucket,), np.int32)
+            tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
+            for i, (s, tok_in, pos) in enumerate(rows):
+                ids[i, 0] = tok_in
+                positions[i] = pos
+                tables[i] = s.table.padded(p_bucket)
+        # runner.decode is the one call (H2D, the program, and inside
+        # it the decode.readback span in which the host waits)
+        with metrics.phase("compute"), \
+                _span("decode.dispatch", rows=len(rows),
+                      row_bucket=b_bucket, page_bucket=p_bucket,
+                      ctx_tokens=ctx_tokens,
+                      blocks_in_use=self.allocator.used_count,
+                      blocks_total=self.config.num_blocks,
+                      evicted=len(victims)):
             toks = self.runner.decode(self.cache, ids, positions, tables)
+        with _span("decode.emit"):
+            return self._emit_decoded(now, active, drafts, victims,
+                                      len(rows), (b_bucket, p_bucket),
+                                      toks)
+
+    def _emit_decoded(self, now: float, active: List[Sequence],
+                      drafts: Dict[int, List[int]], victims: list,
+                      n_rows_total: int, bucket: Tuple[int, int],
+                      toks) -> dict:
+        """Append the step's tokens, finish what is done, count."""
+        from ..distributed.fault_tolerance import chaos
+        from ..observability import metrics
+        cfg = self.scheduler.config
+        b_bucket, p_bucket = bucket
         cost = self.runner.decode_cost((b_bucket, p_bucket))
         modeled_s = None
         if cost and "flops" in cost:
@@ -749,7 +821,7 @@ class ServingEngine:
                        t=now, dur=modeled_s or 0.0,
                        tids=step_tids or None,
                        step=self.decode_steps, batch=len(active),
-                       rows=len(rows) if drafts else None,
+                       rows=n_rows_total if drafts else None,
                        bucket=[b_bucket, p_bucket])
         emitted_total = 0
         accepted_total = 0

@@ -29,6 +29,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..profiler import build as _build_span, span as _span
 from .paged_attention import paged_attention_decode
 
 __all__ = ["PagedGPTRunner", "PREFILL_PAD"]
@@ -149,7 +150,10 @@ class PagedGPTRunner:
         from ..framework.tensor import Tensor
         model = self.model
 
-        def pure_prefill(weight_arrays, ids, last_idx):
+        # the function's name is the HLO module's (jit_p2t_prefill);
+        # the named scopes are the model's own plus head_ce / sample /
+        # kv_write, as in the decode program below
+        def p2t_prefill(weight_arrays, ids, last_idx):
             # ids: [1, padded_len] int32; last_idx: int32 scalar index
             # of the real last token (causal masking makes the padded
             # tail invisible to every real row)
@@ -158,35 +162,48 @@ class PagedGPTRunner:
                 n_layers = model.cfg.num_layers
                 hidden, caches = model.gpt.decode_step(
                     Tensor(ids), [() for _ in range(n_layers)], 0)
-                h_last = jnp.take_along_axis(
-                    hidden._data, last_idx.reshape(1, 1, 1), axis=1)
-                logits = model._head(Tensor(h_last))
-            tok = jnp.argmax(logits._data[:, -1], axis=-1).astype(jnp.int32)
-            k_stack = jnp.stack([c[0]._data[0] for c in caches])
-            v_stack = jnp.stack([c[1]._data[0] for c in caches])
+                with jax.named_scope("head_ce"):
+                    h_last = jnp.take_along_axis(
+                        hidden._data, last_idx.reshape(1, 1, 1), axis=1)
+                    logits = model._head(Tensor(h_last))
+            with jax.named_scope("sample"):
+                tok = jnp.argmax(logits._data[:, -1],
+                                 axis=-1).astype(jnp.int32)
+            with jax.named_scope("kv_write"):
+                k_stack = jnp.stack([c[0]._data[0] for c in caches])
+                v_stack = jnp.stack([c[1]._data[0] for c in caches])
             return tok, k_stack, v_stack        # [L, padded_len, H, D]
 
-        return jax.jit(pure_prefill)
+        return jax.jit(p2t_prefill)
 
-    def prefill(self, token_ids: List[int]):
-        """Run one sequence's prompt; returns (first_token:int,
-        k_stack, v_stack) with stacks ``[L, padded_len, H, D]`` — the
-        caller scatters rows ``[:len(token_ids)]`` into blocks."""
+    def prefill_dispatch(self, token_ids: List[int]):
+        """Pad one sequence's prompt, move it to the device and call
+        its prefill program (built, inside a ``build`` span, on first
+        use of the padded length). Returns (first token ``[1]`` still
+        on the device, k_stack, v_stack) with stacks ``[L, padded_len,
+        H, D]`` — the caller scatters rows ``[:len(token_ids)]`` into
+        blocks and reads the token back."""
         import jax.numpy as jnp
         n = len(token_ids)
         padded = self.prefill_padded_len(n)
-        fn = self._prefill_programs.get(padded)
-        if fn is None:
-            fn = self._build_prefill(padded)
-            self._prefill_programs[padded] = fn
         ids = np.zeros((1, padded), np.int32)
         ids[0, :n] = token_ids
-        tok, k_stack, v_stack = fn(self._weights(), jnp.asarray(ids),
-                                   jnp.asarray(n - 1, jnp.int32))
-        if padded not in self._prefill_costs:
-            self._prefill_costs[padded] = self._cost_of(
-                fn, (self._weights(), jnp.asarray(ids),
-                     jnp.asarray(n - 1, jnp.int32)))
+        args = (self._weights(), jnp.asarray(ids),
+                jnp.asarray(n - 1, jnp.int32))
+        fn = self._prefill_programs.get(padded)
+        if fn is not None:
+            return fn(*args)
+        fn = self._prefill_programs[padded] = self._build_prefill(padded)
+        with _build_span("prefill", str(padded)) as b:
+            out = fn(*args)
+            with b.cost():
+                self._prefill_costs[padded] = self._cost_of(fn, args)
+        return out
+
+    def prefill(self, token_ids: List[int]):
+        """:meth:`prefill_dispatch` with the first token read back:
+        (first_token:int, k_stack, v_stack)."""
+        tok, k_stack, v_stack = self.prefill_dispatch(token_ids)
         return int(tok[0]), k_stack, v_stack
 
     # -- decode ----------------------------------------------------------
@@ -199,8 +216,8 @@ class PagedGPTRunner:
         model = self.model
         nh, hd = self.num_heads, self.head_dim
 
-        def pure_decode(weight_arrays, k_pool, v_pool, ids, positions,
-                        block_tables):
+        def p2t_decode(weight_arrays, k_pool, v_pool, ids, positions,
+                       block_tables):
             # ids [B,1] int32; positions [B] int32 (0-based slot of the
             # NEW token); block_tables [B,P] int32. Pools
             # [L, N, bs, H*D], donated.
@@ -210,57 +227,79 @@ class PagedGPTRunner:
                 axis=1)[:, 0]
             slot = positions % block_size
             ctx = positions + 1
+            scope = jax.named_scope     # GPTBlock.forward's names
             with self._swapped(weight_arrays), core.no_grad(), \
                     fr.scoped_rng(jax.random.PRNGKey(0)):
-                pos_t = Tensor(positions[:, None].astype(jnp.int32))
-                x = model.gpt.wte(Tensor(ids)) + model.gpt.wpe(pos_t)
+                with scope("embed"):
+                    pos_t = Tensor(positions[:, None].astype(jnp.int32))
+                    x = model.gpt.wte(Tensor(ids)) + model.gpt.wpe(pos_t)
                 for li, block in enumerate(model.gpt.h):
-                    ln1 = block.ln_1(x)
-                    qkv = block.attn.qkv(ln1)
-                    # head-major fused split, as GPTAttention.forward
-                    qkv = qkv.reshape([B, 1, nh, 3, hd])
-                    q, k, v = qkv.unbind(axis=3)
+                    with scope("attn"):
+                        with scope("norm"):
+                            ln1 = block.ln_1(x)
+                        qkv = block.attn.qkv(ln1)
+                        # head-major fused split, as GPTAttention.forward
+                        qkv = qkv.reshape([B, 1, nh, 3, hd])
+                        q, k, v = qkv.unbind(axis=3)
                     from .block_cache import PagedKVCache as _C
-                    k_pool = _C.scatter_decode(k_pool, li, phys, slot,
-                                               k._data[:, 0])
-                    v_pool = _C.scatter_decode(v_pool, li, phys, slot,
-                                               v._data[:, 0])
-                    # the whole pool rides in; the layer is a static
-                    # block index, never a sliced-out copy
-                    attn = paged_attention_decode(
-                        q._data, k_pool, v_pool, block_tables,
-                        ctx, interpret=self.interpret,
-                        pages_per_split=self.split_pages, layer=li)
-                    a = block.attn.out_proj(
-                        Tensor(attn.reshape(B, 1, nh * hd)))
-                    x = x + block.dropout(a)
-                    x = x + block.dropout(block.mlp(block.ln_2(x)))
-                x = model.gpt.ln_f(x)
-                logits = model._head(x)
-            tok = jnp.argmax(logits._data[:, -1], axis=-1).astype(jnp.int32)
+                    with scope("kv_write"):
+                        k_pool = _C.scatter_decode(k_pool, li, phys, slot,
+                                                   k._data[:, 0])
+                        v_pool = _C.scatter_decode(v_pool, li, phys, slot,
+                                                   v._data[:, 0])
+                    with scope("attn"):
+                        # the whole pool rides in; the layer is a static
+                        # block index, never a sliced-out copy
+                        attn = paged_attention_decode(
+                            q._data, k_pool, v_pool, block_tables,
+                            ctx, interpret=self.interpret,
+                            pages_per_split=self.split_pages, layer=li)
+                        a = block.attn.out_proj(
+                            Tensor(attn.reshape(B, 1, nh * hd)))
+                        x = x + block.dropout(a)
+                    with scope("mlp"):
+                        with scope("norm"):
+                            ln2 = block.ln_2(x)
+                        x = x + block.dropout(block.mlp(ln2))
+                with scope("norm"):
+                    x = model.gpt.ln_f(x)
+                with scope("head_ce"):
+                    logits = model._head(x)
+            with scope("sample"):
+                tok = jnp.argmax(logits._data[:, -1],
+                                 axis=-1).astype(jnp.int32)
             return tok, k_pool, v_pool
 
-        return jax.jit(pure_decode, donate_argnums=(1, 2))
+        return jax.jit(p2t_decode, donate_argnums=(1, 2))
 
     def decode(self, cache, ids, positions, block_tables):
-        """One decode step over a bucketed batch. ``cache`` is the
-        :class:`~.block_cache.PagedKVCache` whose pools are donated
+        """One decode step over a bucketed batch: move it to the
+        device, call its decode program (built, inside a ``build``
+        span, on first use of the bucket), read the tokens back (span
+        ``decode.readback``: the host waits out the step). ``cache`` is
+        the :class:`~.block_cache.PagedKVCache` whose pools are donated
         and replaced. Returns int32 next tokens ``[B]``."""
         import jax.numpy as jnp
         B, n_pages = block_tables.shape
         key = (B, n_pages)
-        fn = self._decode_programs.get(key)
-        if fn is None:
-            fn = self._build_decode(B, n_pages, cache.block_size)
-            self._decode_programs[key] = fn
         args = (self._weights(), cache.k, cache.v,
                 jnp.asarray(ids, jnp.int32),
                 jnp.asarray(positions, jnp.int32),
                 jnp.asarray(block_tables, jnp.int32))
-        if key not in self._decode_costs:
-            self._decode_costs[key] = self._cost_of(fn, args)
-        tok, cache.k, cache.v = fn(*args)
-        return np.asarray(tok)
+        fn = self._decode_programs.get(key)
+        if fn is not None:
+            tok, cache.k, cache.v = fn(*args)
+        else:
+            from ..observability.cost_model import abstractify, program_cost
+            fn = self._decode_programs[key] = self._build_decode(
+                B, n_pages, cache.block_size)
+            shapes = abstractify(args)      # the call donates the pools
+            with _build_span("decode", f"{B}x{n_pages}") as b:
+                tok, cache.k, cache.v = fn(*args)
+                with b.cost():
+                    self._decode_costs[key] = program_cost(fn, shapes)
+        with _span("decode.readback"):
+            return np.asarray(tok)
 
     # -- deterministic cost accounting (PR 7 cost model) -----------------
     @staticmethod

@@ -16,6 +16,7 @@ touched at import, in a ``skipif``/``parametrize`` argument or in
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,13 +55,22 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(one_chip, fn, *avals):
+def _compile(one_chip, fn, *avals, kernels=()):
     """Compile ``fn`` for the described chip; the Mosaic kernel must be
-    IN the compiled program."""
+    IN the compiled program, under the name its ``pallas_call`` gives
+    it (``kernels``: what a device trace will call the custom calls),
+    never under the name of an enclosing lambda."""
     avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
              for s, d in avals]
     compiled = jax.jit(fn).lower(*avals).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "_lambda_" not in text
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    for name in kernels:
+        assert any(re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", ln)
+                   for ln in calls), (name, [ln[:60] for ln in calls])
     return compiled
 
 
@@ -76,7 +86,9 @@ def test_flash_fwd_bwd(one_chip, seq):
                                               interpret=False)
         return o.astype(F32).sum()
 
-    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv,
+             kernels=(["flash_fwd", "flash_bwd"] if seq == 1024 else
+                      ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"]))
 
 
 def test_flash_varlen_packed_fwd_bwd(one_chip):
@@ -91,7 +103,9 @@ def test_flash_varlen_packed_fwd_bwd(one_chip):
 
     _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
              ((T, H, D), BF16), ((T, H, D), BF16), ((T, H, D), BF16),
-             seg, seg, seg, seg)
+             seg, seg, seg, seg,
+             kernels=["flash_fwd_varlen", "flash_bwd_dkv_varlen",
+                      "flash_bwd_dq_varlen"])
 
 
 def test_flash_inside_a_partitioned_program(topo, monkeypatch):
@@ -154,7 +168,7 @@ def test_paged_decode_single_softmax(one_chip, dtype):
     page), block 16, a 4096-block pool, 64 pages (1024 tokens), batch
     8."""
     _compile(one_chip, _paged(None),
-             *_paged_avals(8, 4096, 64, dtype))
+             *_paged_avals(8, 4096, 64, dtype), kernels=["paged_decode"])
 
 
 def test_paged_decode_split_k_32k(one_chip):
@@ -189,7 +203,7 @@ def test_fused_adamw_step(one_chip):
             p, g, m, v, lr, t, weight_decay=0.01, interpret=False)
 
     _compile(one_chip, step, leaf, leaf, leaf, leaf, ((), F32),
-             ((), jnp.int32))
+             ((), jnp.int32), kernels=["fused_adamw_step"])
 
 
 def test_fused_adamw_multi_precision(one_chip):
@@ -200,7 +214,7 @@ def test_fused_adamw_multi_precision(one_chip):
                                         interpret=False)
 
     _compile(one_chip, step, ((1024 * 4096,), BF16), n, n, n, n,
-             ((), F32))
+             ((), F32), kernels=["fused_adamw"])
 
 
 def test_fused_momentum_step(one_chip):
@@ -211,7 +225,8 @@ def test_fused_momentum_step(one_chip):
             p, g, v, lr, nesterov=True, weight_decay=1e-4,
             interpret=False)
 
-    _compile(one_chip, step, leaf, leaf, leaf, ((), F32))
+    _compile(one_chip, step, leaf, leaf, leaf, ((), F32),
+             kernels=["fused_momentum"])
 
 
 def test_fused_rms_norm_fwd_bwd(one_chip):
@@ -220,7 +235,8 @@ def test_fused_rms_norm_fwd_bwd(one_chip):
             x, w, interpret=False).astype(F32).sum()
 
     _compile(one_chip, jax.grad(loss, argnums=(0, 1)),
-             ((8, 1024, 2048), BF16), ((2048,), BF16))
+             ((8, 1024, 2048), BF16), ((2048,), BF16),
+             kernels=["rmsnorm_fwd", "rmsnorm_bwd"])
 
 
 def test_fused_rope(one_chip):
@@ -228,7 +244,7 @@ def test_fused_rope(one_chip):
         return pallas_fused.fused_rope(x, cos, sin, interpret=False)
 
     _compile(one_chip, rope, ((8, 1024, 16, 128), BF16),
-             ((1024, 128), F32), ((1024, 128), F32))
+             ((1024, 128), F32), ((1024, 128), F32), kernels=["rope"])
 
 
 # --------------------------------------------------------------- matmul

@@ -185,50 +185,50 @@ class TestRecordEventCorrelation:
         finally:
             flight_recorder.disable()
 
-    def test_trace_annotation_when_device_trace_active(self, monkeypatch):
-        """With a device trace flagged active the span opens a
-        jax.profiler.TraceAnnotation (and survives its absence)."""
-        opened = []
-
-        class FakeAnnotation:
-            def __init__(self, name):
-                opened.append(name)
-
-            def __enter__(self):
-                return self
-
-            def __exit__(self, *exc):
-                opened.append("closed")
-                return False
-
+    def test_trace_annotation_when_device_trace_active(self, tmp_path):
+        """RecordEvent opens ``profiler.span``: a mark is written into
+        ANY active profiler session — here a bare ``jax.profiler`` one,
+        which no ``Profiler`` object knows of — as a ``p2t:`` row."""
         import jax
-        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
-                            FakeAnnotation)
-        monkeypatch.setattr(profiler, "_device_trace_active", True)
-        with RecordEvent("annotated"):
-            pass
-        assert opened == ["annotated", "closed"]
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with RecordEvent("annotated"):
+                pass
+            ev = RecordEvent("begin_end")
+            ev.begin()
+            ev.end()
+        finally:
+            jax.profiler.stop_trace()
+        assert _program_spans(tmp_path) == ["p2t:annotated",
+                                            "p2t:begin_end"]
 
-    def test_no_annotation_when_no_device_trace(self, monkeypatch):
-        # a raising fake would be swallowed by RecordEvent.begin's
-        # defensive except — record openings instead so a regression
-        # that ignores _device_trace_active actually fails
-        opened = []
-
-        class FakeAnnotation:
-            def __init__(self, name):
-                opened.append(name)
-
-            def __enter__(self):
-                return self
-
-            def __exit__(self, *exc):
-                return False
-
+    def test_no_annotation_when_no_device_trace(self, tmp_path):
+        """...and exactly then: a mark made while no session is active
+        is in no trace, a session started afterwards holds only the
+        marks made inside it."""
         import jax
-        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
-                            FakeAnnotation)
-        monkeypatch.setattr(profiler, "_device_trace_active", False)
-        with RecordEvent("plain"):
+        with RecordEvent("before_any_session"):
             pass
-        assert opened == []
+        assert not os.listdir(tmp_path)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with RecordEvent("inside"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        with RecordEvent("after_the_session"):
+            pass
+        assert _program_spans(tmp_path) == ["p2t:inside"]
+
+
+def _program_spans(trace_dir):
+    """Names of the program's spans in the trace under ``trace_dir``,
+    in time order."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    events = [e for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith(profiler.SPAN_PREFIX)]
+    return [e.name for e in sorted(events, key=lambda e: e.start_ns)]
