@@ -213,36 +213,54 @@ def phase_trainer(size: dict, tally: CacheTally,
 # -------------------------------------------------------------- server
 def kernel_parity(size: dict) -> dict:
     """Both paged-decode bodies against the dense reference at the
-    served head geometry, on this device."""
+    served head geometry, on this device: at the serving cell's decode
+    shape (64 rows x a full table of pages, contexts a quarter to the
+    whole of the model's length, on a shuffled pool) and at a ragged
+    one (a context of 1, partly filled pages, contexts that end just
+    before, on and just after a compute block's edge)."""
     import jax.numpy as jnp
     from paddle2_tpu.serving.paged_attention import (
         paged_attention_decode, paged_attention_reference)
     H, D, bs = size["heads"], size["hidden"] // size["heads"], 16
+    seq = size["seq"]
+    n_pages = seq // bs
     rng = np.random.default_rng(0)
-    n_blocks, ctx = 64, [40, 200]
-    n_pages = -(-max(ctx) // bs)
-    tables = rng.permutation(np.arange(1, n_blocks))[:2 * n_pages] \
-        .reshape(2, n_pages).astype(np.int32)
-    kp = jnp.asarray(rng.normal(size=(n_blocks, bs, H * D)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(n_blocks, bs, H * D)), jnp.bfloat16)
-    q = jnp.asarray(rng.normal(size=(2, 1, H, D)), jnp.bfloat16)
-    ref = np.asarray(paged_attention_reference(
-        q, kp, vp, tables, np.asarray(ctx)), np.float32)
+    edge = min(256, seq // 2)
+    cases = {
+        "cell": rng.integers(seq // 4, seq + 1, 64),
+        "ragged": np.asarray([1, 17, 40, edge - 1, edge, edge + 1,
+                              seq - bs - 1, seq]),
+    }
     err = {}
-    for name, pps in (("single", None), ("split", 4)):
-        out = np.asarray(paged_attention_decode(
-            q, kp[None], vp[None], tables, np.asarray(ctx),
-            pages_per_split=pps),
-            np.float32)
-        if out.shape != ref.shape or not np.isfinite(out).all():
-            raise AssertionError(f"paged {name}: bad output")
-        err[name] = float(np.abs(out - ref).max())
-        # bf16 probabilities and outputs: 2^-8 relative steps on O(1)
-        # values — the tolerance tests/test_serving.py uses for bf16
-        if err[name] > 2e-2:
-            raise AssertionError(
-                f"paged {name} kernel off the dense reference by "
-                f"{err[name]}")
+    for case, ctx in cases.items():
+        rows = len(ctx)
+        n_blocks = rows * n_pages + 1
+        # every row's pages are its own, scattered over the pool
+        tables = (rng.permutation(np.arange(1, n_blocks))
+                  .reshape(rows, n_pages).astype(np.int32))
+        kp = jnp.asarray(rng.normal(size=(n_blocks, bs, H * D)),
+                         jnp.bfloat16)
+        vp = jnp.asarray(rng.normal(size=(n_blocks, bs, H * D)),
+                         jnp.bfloat16)
+        q = jnp.asarray(rng.normal(size=(rows, 1, H, D)), jnp.bfloat16)
+        # the reference unrolls rows x heads: eight rows a call
+        ref = np.concatenate([np.asarray(paged_attention_reference(
+            q[r:r + 8], kp, vp, tables[r:r + 8], ctx[r:r + 8]),
+            np.float32) for r in range(0, rows, 8)])
+        for name, pps in (("single", None), ("split", 4)):
+            out = np.asarray(paged_attention_decode(
+                q, kp[None], vp[None], tables, ctx,
+                pages_per_split=pps), np.float32)
+            if out.shape != ref.shape or not np.isfinite(out).all():
+                raise AssertionError(f"paged {name} ({case}): bad output")
+            gap = err[f"{case}.{name}"] = float(np.abs(out - ref).max())
+            # bf16 probabilities and outputs: 2^-8 relative steps on
+            # O(1) values — the tolerance tests/test_serving.py uses
+            # for bf16
+            if gap > 2e-2:
+                raise AssertionError(
+                    f"paged {name} kernel ({case}) off the dense "
+                    f"reference by {gap}")
     return err
 
 

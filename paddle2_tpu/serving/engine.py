@@ -748,8 +748,16 @@ class ServingEngine:
                 rows.append((s, s.tokens[p0], p0))
                 for i, d in enumerate(drafts.get(id(s), ())):
                     rows.append((s, d, p0 + 1 + i))
+            # pages that hold a key of some row (a row at position p
+            # attends over p + 1 keys): what the kernel moves, against
+            # the rows x page_bucket table it is handed
+            live_pages = sum(blocks_for_tokens(pos + 1,
+                                               self.config.block_size)
+                             for _, _, pos in rows)
             b_bucket = cfg.batch_bucket(len(rows))
             p_bucket = self.scheduler.decode_bucket(active)[1]
+            kernel_ppb = self.runner.kernel_pages_per_block(self.cache,
+                                                            p_bucket)
             ids = np.zeros((b_bucket, 1), np.int32)
             positions = np.zeros((b_bucket,), np.int32)
             tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
@@ -762,7 +770,8 @@ class ServingEngine:
         with metrics.phase("compute"), \
                 _span("decode.dispatch", rows=len(rows),
                       row_bucket=b_bucket, page_bucket=p_bucket,
-                      ctx_tokens=ctx_tokens,
+                      ctx_tokens=ctx_tokens, live_pages=live_pages,
+                      kernel_pages_per_block=kernel_ppb,
                       blocks_in_use=self.allocator.used_count,
                       blocks_total=self.config.num_blocks,
                       evicted=len(victims)):

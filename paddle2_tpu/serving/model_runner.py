@@ -248,8 +248,8 @@ class PagedGPTRunner:
                         v_pool = _C.scatter_decode(v_pool, li, phys, slot,
                                                    v._data[:, 0])
                     with scope("attn"):
-                        # the whole pool rides in; the layer is a static
-                        # block index, never a sliced-out copy
+                        # the whole pool rides in; the layer is an index
+                        # the kernel's copies take, never a sliced-out copy
                         attn = paged_attention_decode(
                             q._data, k_pool, v_pool, block_tables,
                             ctx, interpret=self.interpret,
@@ -271,6 +271,14 @@ class PagedGPTRunner:
             return tok, k_pool, v_pool
 
         return jax.jit(p2t_decode, donate_argnums=(1, 2))
+
+    def kernel_pages_per_block(self, cache, n_pages: int) -> int:
+        """Pages the paged kernel gathers per step in the decode
+        program of this page bucket (count on ``decode.dispatch``)."""
+        from .paged_attention import kernel_pages_per_block
+        return kernel_pages_per_block(
+            n_pages, cache.k.shape[2], self.num_heads, self.head_dim,
+            cache.k.dtype, self.split_pages)
 
     def decode(self, cache, ids, positions, block_tables):
         """One decode step over a bucketed batch: move it to the

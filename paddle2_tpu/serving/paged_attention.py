@@ -4,46 +4,49 @@ The decode-side half of PagedAttention (Kwon et al. SOSP'23) on the
 flash kernel's machinery (``kernels/pallas_flash.py``): at decode each
 sequence contributes ONE query token and attends over its whole cached
 prefix, whose K/V live scattered across fixed-size blocks of the
-shared pool (``serving/block_cache.py``). The kernel walks the
-sequence's block table — scalar-prefetched so the index maps can
-compute DMA source blocks before the body runs (the
-``PrefetchScalarGridSpec`` pattern from the official TPU paged
-kernels) — and gathers K/V blocks into VMEM.
+shared pool (``serving/block_cache.py``). The sequence's block table
+and context length are scalar-prefetched (``PrefetchScalarGridSpec``),
+so the kernel knows where every page lies before it moves one.
 
 The pools are token-major with a token's heads merged into one
-lane-dense row, ``[layers, num_blocks, block_size, H*D]``, and a page
-is a ``[block_size, 128]``-lane block of it: the only page shapes the
-chip's DMA accepts have their last two dimensions whole or multiples
-of (8, 128) — a 1-head slice of a ``[bs, H, D]`` block is refused, and
-a separate ``D`` 64 axis would be padded to 128 lanes, doubling the
-pool in HBM. With ``D`` 64 a 128-lane page carries TWO heads (a *lane
-group*); the query rides as an ``[8, 128]`` tile whose row ``r`` holds
-head ``r`` of the group in its own lanes and zeros elsewhere, so ONE
-dot per page gives every head of the group its own score row and ONE
-dot gives every head its own output lanes. Scratch is PAGE-major
-(``[P, 8, bs]`` scores, ``[P, bs, 128]`` values) so a page lands on the
-untiled leading axis and no store starts at an unaligned lane offset.
-The layer is a static block index into the whole-model pool, so a
-decode program never slices (copies) a layer out of it. Both bodies
-compile for a v5e chip at serving widths
-(``tests/test_chip_compile.py``).
+lane-dense row, ``[layers, num_blocks, block_size, H*D]``: a page of
+ALL heads is one contiguous ``[block_size, H*D]`` block (32 KB at 16
+slots x 16 heads x 64 in bf16), and a separate ``D`` 64 axis would be
+padded to 128 lanes, doubling the pool in HBM. The layer is an index
+into the whole-model pool (a scalar the copies take in the single
+body, a static block index in the split body), so a decode program
+never slices (copies) a layer out of it. Both bodies compile for a
+v5e chip at serving widths (``tests/test_chip_compile.py``).
 
 Two bodies behind ONE dispatcher (:func:`paged_attention_decode`):
 
-* **Single-split (global softmax)** — per-page score dots fill the
-  score buffer and the softmax runs ONCE over the whole context:
+* **Single-split (global softmax)** — one grid step per sequence. The
+  pools enter un-blocked (``memory_space=pl.ANY``) and the kernel
+  walks the row's LIVE pages (``ceil(ctx / block_size)``; dead pages
+  and the garbage block behind them never move) in *compute blocks*
+  of many pages: per page one async copy of K into half of a double
+  buffer and one of V to its place in a context-resident buffer,
+  driven by the block table, started one block ahead — the last block
+  of a row starts the first block of the next, so the copy latency is
+  paid once a call. All heads ride one query tile (row ``h`` holds
+  head ``h`` in its own lanes of the ``H*D`` row and zeros elsewhere),
+  so ONE dot per block gives every head its lane-dense ``[R, tokens]``
+  score rows and ONE dot gives every head its own output lanes. The
+  block size follows from the shapes (:func:`_pages_per_block`), not
+  from a caller. Then the softmax runs ONCE over the whole context:
   ``dot(q, k) * scale`` -> mask with ``finfo.min`` -> ``max / exp /
   sum / divide`` in f32 -> ``dot(p, v)``, the op sequence of
   :func:`paged_attention_reference` (dense gather through the same
   table) and of ``kernels/attention._sdpa_xla``. Scores are f32 for
-  every input dtype. The kernel reduces per page and then across
-  pages where the reference reduces one ``[1, S]`` row, so fp32
+  every input dtype. The kernel reduces per block and then across
+  blocks where the reference reduces one ``[1, S]`` row, so fp32
   agreement is a few ulp (tests: ``rtol=atol=2e-6``), not bitwise.
   Pad slots hold ``finfo.min`` scores (exactly-0.0 probability).
-  VMEM scales with the context (:func:`decode_scratch_vmem_bytes`):
-  past :data:`VMEM_FIT_BUDGET` this body is not dispatched, and at
-  twice that the compiler refuses it — 32k contexts are what the
-  split body exists for.
+  VMEM scales with the context (scores and the resident V:
+  :func:`decode_scratch_vmem_bytes`): past :data:`VMEM_FIT_BUDGET`
+  this body is not dispatched, and at twice the whole limit the
+  compiler refuses it — 32k contexts are what the split body exists
+  for.
 
 * **Split-K flash-decode** — ISSUE 14: the context is carved into
   splits of ``pages_per_split`` pages; each split runs the flash
@@ -55,6 +58,12 @@ Two bodies behind ONE dispatcher (:func:`paged_attention_decode`):
   fits. Acceptance: a few ulp (fp32) vs
   :func:`paged_attention_split_reference` (the dense twin of the
   split body's op sequence) and vs the global-softmax reference.
+  This body still gathers ONE ``[block_size, 128]``-lane page of one
+  *lane group* per grid step through a ``BlockSpec`` (with ``D`` 64 a
+  128-lane page carries TWO heads; the query rides as an ``[8, 128]``
+  tile) into PAGE-major scratch (``[P, 8, bs]`` scores, ``[P, bs,
+  128]`` values): about 0.19 us a page on a v5e whatever its size
+  (PERF.md section 6, PR 24). No benchmark cell reaches it.
 
 Dispatch: ``pages_per_split=None`` (the default) picks the
 single-split body whenever its scratch fits the VMEM budget and falls
@@ -81,7 +90,8 @@ from ..kernels.pallas_flash import NEG_INF
 __all__ = ["paged_attention_decode", "paged_attention_reference",
            "paged_attention_split_reference", "gathered_dense_kv",
            "decode_scratch_vmem_bytes", "fits_single_softmax",
-           "auto_pages_per_split", "modeled_decode_latency_s",
+           "auto_pages_per_split", "kernel_pages_per_block",
+           "modeled_decode_latency_s",
            "VMEM_BYTES", "VMEM_FIT_BUDGET"]
 
 # Scoped VMEM one kernel may claim on a v5e core: the limit the chip's
@@ -137,40 +147,121 @@ def _weighted_values(p, v_buf):
     return jnp.sum(o, axis=0)
 
 
-def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   s_buf, v_buf, *, scale, block_size, n_pages):
+def _block_softmax(s):
+    """Global softmax over a ``[n_blocks, R, T]`` f32 score buffer —
+    ``max / exp / sum / divide``, the op sequence of
+    ``jax.nn.softmax(f32)``; NOT the online-softmax recurrence (whose
+    per-block rescaling is a different rounding chain)."""
+    e = jnp.exp(s - _page_sum(s, jnp.max))
+    return e / _page_sum(e, jnp.sum)
+
+
+def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, s_buf, slot_ref, sem, *, scale,
+                   block_size, pages_per_block, n_pages, batch):
+    """One grid step = one sequence, all heads: walk the row's LIVE
+    pages in compute blocks of ``pages_per_block`` pages. A block's K
+    pages land in one half of the ``k_buf`` double buffer and its V
+    pages at their place in the context-resident ``v_buf`` while the
+    block before it is scored; the row's last block starts the NEXT
+    row's first block, so the copy latency is exposed once per call,
+    not once per row. That one block of V arrives while this row's is
+    still being read, so the first block of every odd row lives in a
+    spare block behind the context's."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    layer = layer_ref[0]
+    ppb, bs = pages_per_block, block_size
+    tokens = ppb * bs
+    width = k_buf.shape[-1]
     fill = jnp.finfo(jnp.float32).min
 
-    @pl.when(j == 0)
-    def _init():
-        # dead/pad slots: finfo.min scores (exactly-0 probability after
-        # the f32 softmax) and zero V
-        s_buf[...] = jnp.full_like(s_buf, fill)
+    def live_pages(row):
+        return jnp.minimum((len_ref[row] + bs - 1) // bs, n_pages)
+
+    def v_page(row, i):
+        # where block i of this row starts in v_buf
+        spare = v_buf.shape[0] - ppb
+        return jnp.where((i == 0) & (row % 2 == 1), spare, i * ppb)
+
+    def page_copies(row, i, kslot, j):
+        # page j of compute block i: one contiguous [bs, H*D] copy
+        # each for K and V, straight out of the whole-model pool
+        blk = bt_ref[row, i * ppb + j]
+        return (pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                      k_buf.at[kslot, j], sem.at[kslot]),
+                pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                      v_buf.at[v_page(row, i) + j],
+                                      sem.at[kslot]))
+
+    def each_live_page(row, i, kslot, act):
+        # dead pages (and the garbage block behind them) never move
+        n_live = jnp.clip(live_pages(row) - i * ppb, 0, ppb)
+
+        def one(j, carry):
+            for cp in page_copies(row, i, kslot, j):
+                act(cp)
+            return carry
+        jax.lax.fori_loop(0, n_live, one, 0)
+
+    def start(row, i, kslot):
+        each_live_page(row, i, kslot, lambda cp: cp.start())
+
+    def wait(row, i, kslot):
+        each_live_page(row, i, kslot, lambda cp: cp.wait())
+
+    @pl.when(b == 0)
+    def _prime():
+        # a dead page of a live block is multiplied by an exactly-0
+        # probability: whatever the buffer holds there must be finite
         v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
 
     ctx = len_ref[b]
+    # a row without a key still takes one (empty) step: it is the step
+    # that starts the next row's copies; its output is 0, as before
+    n_blocks = jnp.maximum((live_pages(b) + ppb - 1) // ppb, 1)
+    # dead blocks: finfo.min scores (exactly-0 probability after the
+    # f32 softmax)
+    s_buf[...] = jnp.full_like(s_buf, fill)
+    q = q_ref[...]                                # (R, H*D)
 
-    @pl.when(j * block_size < ctx)
-    def _gather():
-        # scratch is PAGE-major ([P, 8, bs] / [P, bs, D]): the page
-        # index lands on the untiled leading axis, so no store ever
-        # starts at an unaligned lane offset
-        s_buf[j] = _page_scores(q_ref, k_ref, scale, j * block_size,
-                                ctx, fill)
-        v_buf[j] = v_ref[...]
+    def score_block(i, kslot):
+        nxt = 1 - kslot
 
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        # ONE global softmax over the assembled scores — max, exp,
-        # sum, divide, the op sequence of jax.nn.softmax(f32) — then
-        # probs @ V; NOT the online-softmax recurrence (whose per-block
-        # rescaling is a different rounding chain)
-        s = s_buf[...]                            # (P, R, bs) f32
-        e = jnp.exp(s - _page_sum(s, jnp.max))
-        probs = e / _page_sum(e, jnp.sum)
-        o_ref[...] = _weighted_values(probs, v_buf).astype(o_ref.dtype)
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start(b, i + 1, nxt)
+
+        @pl.when((i + 1 == n_blocks) & (b + 1 < batch))
+        def _next_row():
+            start(b + 1, 0, nxt)
+
+        wait(b, i, kslot)
+        k = k_buf[kslot].reshape(tokens, width)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            precision=_precision(q.dtype),
+            preferred_element_type=jnp.float32) * scale   # (R, T)
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
+            + i * tokens
+        s_buf[i] = jnp.where(cols < ctx, s, fill)
+        return nxt
+
+    slot_ref[0] = jax.lax.fori_loop(0, n_blocks, score_block,
+                                    slot_ref[0])
+    s_buf[...] = _block_softmax(s_buf[...])
+
+    def weigh_block(i, acc):
+        v = v_buf[pl.ds(v_page(b, i), ppb)].reshape(tokens, width)
+        return acc + jax.lax.dot_general(
+            s_buf[i].astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            precision=_precision(v.dtype),
+            preferred_element_type=jnp.float32)           # (R, H*D)
+
+    o = jax.lax.fori_loop(0, n_blocks, weigh_block,
+                          jnp.zeros(o_ref.shape, jnp.float32))
+    o_ref[...] = jnp.where(ctx > 0, o, 0.0).astype(o_ref.dtype)
 
 
 # ------------------------------------------------- VMEM / cost accounting
@@ -192,25 +283,62 @@ def _lane_group(num_heads: int, head_dim: int):
     return hg, _tile_pad(hg, 8), hg * head_dim
 
 
+def _page_vmem_bytes(block_size, width, dtype) -> int:
+    """One ``[bs, W]`` page of K or V in VMEM, padded to whole
+    (sublane x 128-lane) tiles."""
+    it = jnp.dtype(dtype).itemsize
+    sublane = 8 * 4 // it                 # f32 8, bf16 16, int8 32
+    return _tile_pad(block_size, sublane) * _tile_pad(width, 128) * it
+
+
+def _group_scratch_bytes(n_pages, block_size, rows, width, dtype) -> int:
+    """The split body's page-major scratch for one lane group: the
+    ``[P, R, bs]`` f32 score buffer — a 16-slot page still takes a
+    full 128-lane row — plus the ``[P, bs, W]`` gathered-V buffer."""
+    scores = rows * _tile_pad(block_size, 128) * 4
+    return int(n_pages) * (scores
+                           + _page_vmem_bytes(block_size, width, dtype))
+
+
+# What one compute block's K pages should weigh: large enough that a
+# block's copies take about a microsecond (far above the cost of a
+# loop step), small enough that a row's ragged tail wastes little of
+# the two dots.
+_BLOCK_TARGET_BYTES = 2 ** 20
+
+
+def _pages_per_block(n_pages: int, block_size: int, width: int,
+                     dtype) -> int:
+    """Pages per compute block of the single-softmax body, from what
+    the code can see: a block's tokens fill whole 128-lane score rows,
+    its K pages weigh about :data:`_BLOCK_TARGET_BYTES`, the K double
+    buffer stays within an eighth of the scoped VMEM, and no block is
+    wider than the table."""
+    lane_dense = 128 // math.gcd(128, int(block_size))
+    page_bytes = int(block_size) * int(width) * jnp.dtype(dtype).itemsize
+    want = min(_BLOCK_TARGET_BYTES, VMEM_BYTES // 16) // page_bytes
+    want = min(want, _tile_pad(n_pages, lane_dense))
+    return max(want // lane_dense, 1) * lane_dense
+
+
 def decode_scratch_vmem_bytes(n_pages: int, block_size: int,
                               head_dim: int, dtype="float32",
                               num_heads: int = None) -> int:
-    """VMEM scratch bytes a decode body needs for ``n_pages`` pages,
-    as the chip lays them out: the page-major ``[P, R, bs]`` f32 score
-    buffer plus the ``[P, bs, W]`` gathered-V buffer, each page padded
-    to whole (sublane x 128-lane) tiles — a 16-slot page still takes a
-    full 128-lane row. Without ``num_heads`` the standard 128-lane
-    group is assumed (``R`` 8, ``W`` ``max(D, 128)``)."""
-    it = jnp.dtype(dtype).itemsize
-    sublane = 8 * 4 // it                 # f32 8, bf16 16, int8 32
+    """VMEM scratch bytes that scale with the context, as the chip
+    lays them out. With ``num_heads``: the single-softmax body, all
+    heads at once — the lane-dense ``[R, tokens]`` f32 scores plus
+    the context-resident V buffer ``[P, bs, H*D]`` (the K double
+    buffer and V's spare block are one compute block each whatever
+    the context, and live in the other half of the VMEM).
+    Without ``num_heads``: the split body's scratch for one standard
+    128-lane group (``R`` 8, ``W`` ``max(D, 128)``), which is what the
+    planners price."""
     if num_heads is None:
-        rows, width = 8, max(int(head_dim), 128)
-    else:
-        _, rows, width = _lane_group(num_heads, head_dim)
-    scores = rows * _tile_pad(block_size, 128) * 4
-    values = (_tile_pad(block_size, sublane) * _tile_pad(width, 128)
-              * it)
-    return int(n_pages) * (scores + values)
+        return _group_scratch_bytes(n_pages, block_size, 8,
+                                    max(int(head_dim), 128), dtype)
+    scores = _tile_pad(num_heads, 8) * int(block_size) * 4
+    return int(n_pages) * (scores + _page_vmem_bytes(
+        block_size, num_heads * int(head_dim), dtype))
 
 
 def fits_single_softmax(n_pages: int, block_size: int, head_dim: int,
@@ -228,11 +356,17 @@ def fits_single_softmax(n_pages: int, block_size: int, head_dim: int,
 def auto_pages_per_split(n_pages: int, block_size: int, head_dim: int,
                          dtype="float32", budget: int = None,
                          num_heads: int = None) -> int:
-    """Largest halving of ``n_pages`` whose per-split scratch fits the
-    VMEM budget (deterministic — no device probing)."""
+    """Largest halving of ``n_pages`` whose per-split scratch — the
+    split body's, for one lane group of ``num_heads`` x ``head_dim``
+    (the standard group without ``num_heads``) — fits the VMEM budget
+    (deterministic — no device probing)."""
+    if budget is None:
+        budget = VMEM_FIT_BUDGET
+    rows, width = ((8, max(int(head_dim), 128)) if num_heads is None
+                   else _lane_group(num_heads, head_dim)[1:])
     pps = max(int(n_pages), 1)
-    while pps > 1 and not fits_single_softmax(
-            pps, block_size, head_dim, dtype, budget, num_heads):
+    while pps > 1 and _group_scratch_bytes(
+            pps, block_size, rows, width, dtype) > budget:
         pps = -(-pps // 2)
     return pps
 
@@ -352,6 +486,30 @@ def _own_lanes(x, hg: int, head_dim: int):
     return jnp.diagonal(x, axis1=-3, axis2=-2).swapaxes(-1, -2)
 
 
+def _split_width(n_pages, block_size, num_heads, head_dim, dtype,
+                 pages_per_split):
+    """Pages per split; ``n_pages`` means the single-softmax body."""
+    if pages_per_split is not None:
+        return max(1, min(int(pages_per_split), n_pages))
+    fit = (block_size, head_dim, dtype, None, num_heads)
+    if fits_single_softmax(n_pages, *fit):
+        return n_pages
+    return auto_pages_per_split(n_pages, *fit)
+
+
+def kernel_pages_per_block(n_pages: int, block_size: int, num_heads: int,
+                           head_dim: int, dtype,
+                           pages_per_split=None) -> int:
+    """Pages :func:`paged_attention_decode` gathers per step at these
+    shapes: the compute block of the single-softmax body, 1 where it
+    dispatches to split-K (one page per grid step)."""
+    if _split_width(n_pages, block_size, num_heads, head_dim, dtype,
+                    pages_per_split) < n_pages:
+        return 1
+    return _pages_per_block(n_pages, block_size, num_heads * head_dim,
+                            dtype)
+
+
 def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
                            scale=None, interpret=None,
                            pages_per_split=None, layer=0):
@@ -381,51 +539,70 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
         scale = 1.0 / math.sqrt(D)
     if interpret is None:
         interpret = interpret_default()
-    if pages_per_split is None:
-        fit = (bs, D, k_pool.dtype, None, H)
-        pps = (n_pages if fits_single_softmax(n_pages, *fit)
-               else auto_pages_per_split(n_pages, *fit))
-    else:
-        pps = max(1, min(int(pages_per_split), n_pages))
-    hg, rows, width = _lane_group(H, D)
-    groups = H // hg
-    qr = _spread_query(q[:, 0], hg, rows, k_pool.dtype)
+    pps = _split_width(n_pages, bs, H, D, k_pool.dtype, pages_per_split)
     bt = jnp.asarray(block_tables, jnp.int32)
     ln = jnp.asarray(ctx_lens, jnp.int32)
     if pps < n_pages:
+        hg, rows, _ = _lane_group(H, D)
+        qr = _spread_query(q[:, 0], hg, rows, k_pool.dtype)
         out = _paged_decode_split(qr, k_pool, v_pool, bt, ln, layer,
                                   float(scale), pps, hg, D, interpret)
         return out.astype(q.dtype)[:, None]
 
-    def page():
-        return pl.BlockSpec((None, None, bs, width),
-                            lambda b, g, j, bt, ln:
-                            (layer, bt[b, j], 0, g))
+    return _decode_single(
+        q, k_pool, v_pool, bt, ln, jnp.asarray(layer, jnp.int32),
+        scale=float(scale), interpret=interpret,
+        ppb=_pages_per_block(n_pages, bs, H * D, k_pool.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb"))
+def _decode_single(q, k_pool, v_pool, bt, ln, layer, *, scale, interpret,
+                   ppb):
+    """The single-softmax body's call, jitted with the layer a traced
+    scalar: the 24 calls of a decode program are ONE traced and lowered
+    kernel called 24 times, not 24 (0.2 s each to trace and lower on
+    the serving host, and the same again for the cost analysis)."""
+    B, _, H, D = q.shape
+    bs = k_pool.shape[2]
+    n_pages = bt.shape[1]
+    # all heads ride ONE query tile: row h holds head h in its own
+    # lanes of the merged H*D row
+    rows, width = _tile_pad(H, 8), H * D
+    qr = _spread_query(q[:, 0], H, rows, k_pool.dtype)[:, 0]
+    n_blocks = -(-n_pages // ppb)
 
     def tile():
-        return pl.BlockSpec((None, None, rows, width),
-                            lambda b, g, j, bt, ln: (b, g, 0, 0))
+        return pl.BlockSpec((None, rows, width),
+                            lambda b, bt, ln, layer: (b, 0, 0))
 
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=float(scale),
-                          block_size=bs, n_pages=n_pages),
+        functools.partial(_decode_kernel, scale=scale, block_size=bs,
+                          pages_per_block=ppb, n_pages=n_pages, batch=B),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, groups, n_pages),
-            in_specs=[tile(), page(), page()],
+            num_scalar_prefetch=3,
+            grid=(B,),
+            # the pools stay where they are; the layer is an index the
+            # copies take, so no program slices a layer out
+            in_specs=[tile(), pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=tile(),
             scratch_shapes=[
-                pltpu.VMEM((n_pages, rows, bs), jnp.float32),
-                pltpu.VMEM((n_pages, bs, width), v_pool.dtype),
+                pltpu.VMEM((2, ppb, bs, width), k_pool.dtype),
+                pltpu.VMEM(((n_blocks + 1) * ppb, bs, width),
+                           v_pool.dtype),
+                pltpu.VMEM((n_blocks, rows, ppb * bs), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,)),
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, groups, rows, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, rows, width), q.dtype),
+        # rows run in order: each starts the copies of the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
-    )(bt, ln, qr, k_pool, v_pool)
-    # [B, G, R, W] -> [B, G, hg, D] -> [B, 1, H, D]
-    return _own_lanes(out, hg, D).reshape(B, 1, H, D)
+    )(bt, ln, layer.reshape(1), qr, k_pool, v_pool)
+    # [B, R, H*D] -> own lanes [B, H, D] -> [B, 1, H, D]
+    return _own_lanes(out, H, D).reshape(B, 1, H, D)
 
 
 def _paged_decode_split(qr, k_pool, v_pool, bt, ln, layer, scale, pps,

@@ -171,6 +171,27 @@ def test_paged_decode_single_softmax(one_chip, dtype):
              *_paged_avals(8, 4096, 64, dtype), kernels=["paged_decode"])
 
 
+@pytest.mark.parametrize("dtype", [BF16, F32])
+def test_paged_decode_serving_cell_shape(one_chip, dtype):
+    """What `gpt2m-serve-longdoc-backlog` runs: ONE decode program of 64
+    rows x 64 pages over the whole model's 24-layer, 4096-block pool,
+    the last layer a static index — the single-softmax body (not
+    split-K) with the compute block the code picks, and no copy of
+    anything pool-sized around it. bf16 KV is the cell's; float32 KV is
+    what an engine over a float32 artifact holds."""
+    pool = "[24,4096,16,1024]"
+    avals = _paged_avals(64, 4096, 64, dtype, layers=24)
+    assert pa.kernel_pages_per_block(64, 16, 16, 64, dtype) == \
+        (32 if dtype == BF16 else 16)
+    fn = functools.partial(pa.paged_attention_decode, interpret=False,
+                           layer=23)
+    text = _compile(one_chip, fn, *avals,
+                    kernels=["paged_decode"]).as_text()
+    assert "paged_decode_split" not in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and pool in ln]
+
+
 def test_paged_decode_split_k_32k(one_chip):
     """A 32k context (2048 pages) auto-dispatches to split-K."""
     assert not pa.fits_single_softmax(2048, 16, 64, BF16)

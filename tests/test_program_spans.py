@@ -157,9 +157,26 @@ def test_counts_equal_the_engines_own_info(traced):
     assert first["blocks_total"] == 32
     assert 0 < first["blocks_in_use"] <= 32
     assert set(first) == {"rows", "row_bucket", "page_bucket", "ctx_tokens",
+                          "live_pages", "kernel_pages_per_block",
                           "blocks_in_use", "blocks_total", "evicted"}
     steps = [s[3]["built"] for s in traced["spans"] if s[0] == "train.step"]
     assert steps == [1, 0]
+
+
+def test_dispatch_counts_what_the_paged_kernel_moves(traced):
+    """``live_pages``: pages that hold a key of some row. The two rows
+    sit at positions 5 and 3 (their prompts are cached), so they attend
+    over 6 and 4 keys = 2 + 1 pages of 4 slots, out of the rows x
+    page_bucket table the kernel is handed. ``kernel_pages_per_block``:
+    the compute block the kernel chose — with 4-slot pages, the 32
+    pages that fill one 128-lane score row."""
+    dispatch = [s[3] for s in traced["spans"] if s[0] == "decode.dispatch"]
+    first = dispatch[0]
+    assert first["live_pages"] == 2 + 1
+    assert first["live_pages"] <= first["row_bucket"] * first["page_bucket"]
+    assert first["kernel_pages_per_block"] == 128 // 4
+    # one more key a row each tick: 7 and 5 keys, 2 + 2 pages
+    assert dispatch[1]["live_pages"] == 2 + 2
 
 
 def test_build_log_one_record_per_program(traced):

@@ -19,6 +19,7 @@ from paddle2_tpu.serving import (
     SeqState, ServingEngine, ContinuousBatchingScheduler,
     blocks_for_tokens, paged_attention_decode, paged_attention_reference,
     poisson_trace, simulate_predictor_baseline, simulate_serving)
+from paddle2_tpu.serving import paged_attention as pa
 from paddle2_tpu.serving.simulate import cost_seconds
 
 
@@ -59,6 +60,26 @@ def _fragmented_setup(rng, bs, ctx_lens, H, D, num_blocks=32):
 # the same op sequence under a different summation order, so agreement
 # is a few ulp, not bitwise (ROADMAP D8(c))
 KERNEL_TOL = dict(rtol=2e-6, atol=2e-6)
+BF16_TOL = dict(rtol=2e-2, atol=2e-2)
+
+
+def _pool_blocks(ctx_lens, bs):
+    """A pool that holds these contexts, the garbage block and a few
+    blocks nobody names."""
+    return sum(blocks_for_tokens(c, bs) for c in ctx_lens) + 9
+
+
+def _decode_vs_reference(q, kp, vp, tables, ctx, dtype="float32"):
+    q, kp, vp = (jnp.asarray(x, dtype) for x in (q, kp, vp))
+    out = paged_attention_decode(q, kp[None], vp[None], tables,
+                                 np.asarray(ctx))
+    ref = paged_attention_reference(q, kp, vp, tables, np.asarray(ctx))
+    assert out.dtype == q.dtype
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    tol = KERNEL_TOL if dtype == "float32" else BF16_TOL
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol)
+    return out
 
 
 @pytest.mark.parametrize("bs", [16, 64])
@@ -69,15 +90,79 @@ def test_paged_decode_matches_reference_fragmented(bs):
     rng = np.random.default_rng(0)
     ctx = [24, 8, 72]                       # ragged, 8-row-aligned
     q, kp, vp, tables, _, _ = _fragmented_setup(rng, bs, ctx, H=2, D=16)
-    out = paged_attention_decode(jnp.asarray(q), jnp.asarray(kp)[None],
-                                 jnp.asarray(vp)[None], tables,
-                                 np.asarray(ctx))
-    ref = paged_attention_reference(jnp.asarray(q), jnp.asarray(kp),
-                                    jnp.asarray(vp), tables,
-                                    np.asarray(ctx))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               **KERNEL_TOL)
-    assert np.isfinite(np.asarray(out)).all()
+    _decode_vs_reference(q, kp, vp, tables, ctx)
+
+
+# the three ways a token's merged H*D row meets the 128-lane tile: two
+# heads a tile (the serving cell's widths), one head a tile, and a
+# whole row narrower than a tile
+HEAD_SHAPES = {"h16xd64": (16, 64), "h2xd128": (2, 128), "h2xd16": (2, 16)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bs", [8, 16, 64])
+@pytest.mark.parametrize("shape", sorted(HEAD_SHAPES))
+def test_paged_decode_head_shapes_block_sizes_dtypes(shape, bs, dtype):
+    """Every shape the one body takes: a context of 600 (which at the
+    cell's widths spans several compute blocks, the last one partly
+    dead), a context of 1 and a ragged one, on a shuffled pool."""
+    H, D = HEAD_SHAPES[shape]
+    ctx = [600, 1, 37]
+    rng = np.random.default_rng(5)
+    q, kp, vp, tables, _, _ = _fragmented_setup(
+        rng, bs, ctx, H=H, D=D, num_blocks=_pool_blocks(ctx, bs))
+    _decode_vs_reference(q, kp, vp, tables, ctx, dtype)
+
+
+@pytest.mark.parametrize("block_bytes", [None, 1])
+def test_paged_decode_spans_compute_blocks_ragged_tail(block_bytes,
+                                                       monkeypatch):
+    """The gather walks compute blocks of ``_pages_per_block`` pages:
+    contexts that end inside a block's last page (700), exactly on a
+    block's edge (512), one key (1), and one block and a bit (300) —
+    at the cell's widths with the block size the code picks (16 pages
+    of float32), and with the smallest lane-dense block (8 pages), so
+    that a row walks up to six of them."""
+    if block_bytes is not None:
+        monkeypatch.setattr(pa, "_BLOCK_TARGET_BYTES", block_bytes)
+    bs, H, D = 16, 16, 64
+    ctx = [700, 1, 512, 300]
+    n_pages = blocks_for_tokens(max(ctx), bs)
+    ppb = pa._pages_per_block(n_pages, bs, H * D, "float32")
+    assert ppb == (16 if block_bytes is None else 8)
+    assert n_pages > 2 * ppb and (700 // bs) % ppb  # ragged last block
+    rng = np.random.default_rng(6)
+    q, kp, vp, tables, _, _ = _fragmented_setup(
+        rng, bs, ctx, H=H, D=D, num_blocks=_pool_blocks(ctx, bs))
+    _decode_vs_reference(q, kp, vp, tables, ctx)
+
+
+@pytest.mark.parametrize("pps", [None, 3])
+def test_paged_decode_never_reads_dead_pages(pps):
+    """Poison every block that no live page names — the garbage block
+    and every table entry past a row's context included — with NaN:
+    the output is finite and bitwise what the clean pool gives, for
+    the single-softmax body and for split-K."""
+    bs, H, D = 16, 16, 64
+    ctx = [300, 1, 37, 600]
+    rng = np.random.default_rng(7)
+    q, kp, vp, tables, _, _ = _fragmented_setup(
+        rng, bs, ctx, H=H, D=D, num_blocks=_pool_blocks(ctx, bs))
+    live = {int(tables[b, j]) for b, c in enumerate(ctx)
+            for j in range(blocks_for_tokens(c, bs))}
+    assert GARBAGE_BLOCK not in live
+    dead = [n for n in range(kp.shape[0]) if n not in live]
+    kbad, vbad = kp.copy(), vp.copy()
+    kbad[dead] = np.nan
+    vbad[dead] = np.nan
+
+    def run(k, v):
+        return np.asarray(paged_attention_decode(
+            jnp.asarray(q), jnp.asarray(k)[None], jnp.asarray(v)[None],
+            tables, np.asarray(ctx), pages_per_split=pps))
+    clean, poisoned = run(kp, vp), run(kbad, vbad)
+    assert np.isfinite(poisoned).all()
+    assert np.array_equal(clean, poisoned)
 
 
 @pytest.mark.parametrize("bs", [16, 64])

@@ -132,6 +132,26 @@ def test_vmem_accounting_32k_gate():
                                   "float32")
 
 
+def test_kernel_pages_per_block_follows_the_dispatch():
+    """The count the engine writes on ``decode.dispatch``: the compute
+    block of the single-softmax body — from the shapes alone: a
+    lane-dense multiple of ``128 / block_size`` pages, about a MiB of K
+    a block — and 1 wherever the call goes to split-K, whose body still
+    gathers one page per grid step."""
+    ppb = pa.kernel_pages_per_block
+    # the serving cell: 32 KB pages of bf16, 64 KB of float32
+    assert ppb(64, 16, 16, 64, "bfloat16") == 32
+    assert ppb(64, 16, 16, 64, "float32") == 16
+    # never wider than the table, never under one 128-lane score row
+    assert ppb(3, 16, 2, 16, "float32") == 8
+    assert ppb(16, 4, 2, 16, "float32") == 32
+    assert ppb(40, 64, 2, 16, "float32") == 40
+    # forced and automatic split-K
+    assert ppb(64, 16, 16, 64, "bfloat16", pages_per_split=4) == 1
+    assert not pa.fits_single_softmax(2048, 16, 64, "bfloat16", None, 16)
+    assert ppb(2048, 16, 16, 64, "bfloat16") == 1
+
+
 # --------------------------------------------- refcounted allocator edges
 def test_allocator_share_free_refcounts():
     a = BlockAllocator(num_blocks=8, block_size=16)
