@@ -261,6 +261,77 @@ def kernel_parity(size: dict) -> dict:
                 raise AssertionError(
                     f"paged {name} kernel ({case}) off the dense "
                     f"reference by {gap}")
+    err.update(gqa_parity(size))
+    err.update(gmm_parity(size))
+    return err
+
+
+def gqa_parity(size: dict) -> dict:
+    """The paged body with fewer key/value heads than query heads, at
+    the LFM2-MoE cell's decode shape where the size allows: 64 rows x
+    256 pages, 32 query heads over 8 key/value heads of 64, ragged
+    contexts (1, partly filled pages, a compute block's edge, the whole
+    table), on a shuffled pool."""
+    import jax.numpy as jnp
+    from paddle2_tpu.serving.paged_attention import (
+        paged_attention_decode, paged_attention_reference)
+    H, Hkv, D, bs = 32, 8, 64, 16
+    n_pages = min(256, size["seq"] // bs * 4)
+    seq = n_pages * bs
+    rng = np.random.default_rng(1)
+    rows = 64 if size["seq"] >= 256 else 8
+    ctx = rng.integers(1, seq + 1, rows)
+    ctx[:6] = [1, 17, bs * 64 - 1, bs * 64, min(bs * 64 + 1, seq), seq]
+    ctx = np.minimum(ctx, seq)
+    n_blocks = rows * n_pages + 1
+    tables = (rng.permutation(np.arange(1, n_blocks))
+              .reshape(rows, n_pages).astype(np.int32))
+    kp, vp = (jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv * D)),
+                          jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(rows, 1, H, D)), jnp.bfloat16)
+    ref = np.concatenate([np.asarray(paged_attention_reference(
+        q[r:r + 8], kp, vp, tables[r:r + 8], ctx[r:r + 8]), np.float32)
+        for r in range(0, rows, 8)])
+    out = np.asarray(paged_attention_decode(q, kp[None], vp[None], tables,
+                                            ctx), np.float32)
+    gap = float(np.abs(out - ref).max())
+    if not np.isfinite(out).all() or gap > 2e-2:
+        raise AssertionError(f"paged GQA kernel off the dense reference "
+                             f"by {gap}")
+    return {"gqa.single": gap}
+
+
+def gmm_parity(size: dict) -> dict:
+    """The grouped matmul of the dropless expert layer against a plain
+    loop over the experts: even loads, one expert taking everything,
+    and a skewed routing that leaves experts empty; a decode step's 256
+    rows and a prefill's thousands, LFM2-24B-A2B's widths where the
+    size allows."""
+    import jax.numpy as jnp
+    from paddle2_tpu.kernels.moe_gmm import gmm_reference, moe_gmm
+    big = size["hidden"] >= 1024
+    E, K, N = (64, 2048, 1536) if big else (8, 128, 256)
+    rng = np.random.default_rng(2)
+    rhs = jnp.asarray(rng.normal(size=(E, K, N)) * 0.05, jnp.bfloat16)
+    err = {}
+    for rows in (256, 4096 if big else 512):
+        skew = np.bincount(np.minimum(rng.geometric(0.2, rows) - 1, E - 1),
+                           minlength=E)
+        loads = {"even": np.full(E, rows // E), "skewed": skew,
+                 "one_expert": np.eye(E, dtype=np.int64)[E // 2] * rows}
+        lhs = jnp.asarray(rng.normal(size=(rows, K)), jnp.bfloat16)
+        for name, sizes in loads.items():
+            sizes = jnp.asarray(sizes, jnp.int32)
+            out = np.asarray(moe_gmm(lhs, rhs, sizes), np.float32)
+            ref = np.asarray(gmm_reference(lhs, rhs, sizes), np.float32)
+            gap = err[f"gmm.{name}.{rows}"] = float(np.abs(out - ref).max())
+            # both round one f32 accumulation to bf16: equal but for the
+            # order of the sum (a bf16 step of the largest output)
+            if not np.isfinite(out).all() \
+                    or gap > 2.0 ** -7 * max(1.0, float(np.abs(ref).max())):
+                raise AssertionError(
+                    f"moe_gmm ({name}, {rows} rows) off the plain loop "
+                    f"by {gap}")
     return err
 
 

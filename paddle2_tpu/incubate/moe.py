@@ -26,7 +26,8 @@ from .. import nn
 from ..ops.dispatch import apply_op
 
 __all__ = ["TopKGate", "SwitchGate", "MoELayer", "dispatch_stats",
-           "token_ledger_closes", "router_reference_f64"]
+           "token_ledger_closes", "router_reference_f64",
+           "DroplessExperts", "sigmoid_topk_route"]
 
 
 def _one_hot(idx, n):
@@ -412,3 +413,113 @@ class MoELayer(nn.Layer):
 
         return apply_op("moe_gather_combine", combine_fn,
                         (expert_out, dest, gates), {})
+
+
+# ---------------------------------------------------------------- dropless
+def sigmoid_topk_route(a, gate_w, bias, k: int, norm_topk: bool = True,
+                       scale: float = 1.0):
+    """Sigmoid routing with a selection bias, in float32 whatever the
+    layer's dtype: ``s = sigmoid(a W_g)``; the ``k`` experts are the
+    largest ``s + bias`` (the bias selects only); the weights are the
+    unbiased scores, ``s_e / (sum_S s + 1e-6)`` when ``norm_topk``,
+    times ``scale``. Returns (ids ``[T, k]`` int32, weights ``[T, k]``
+    f32)."""
+    s = jax.nn.sigmoid(jnp.dot(a.astype(jnp.float32),
+                               gate_w.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    pick = s if bias is None else s + bias.astype(jnp.float32)
+    _, ids = jax.lax.top_k(pick, k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-6)
+    return ids.astype(jnp.int32), w * scale
+
+
+class DroplessExperts(nn.Layer):
+    """Top-k routed SwiGLU experts with NO capacity: every assignment
+    is computed. Rows are sorted by expert and all experts held run as
+    one grouped matmul (``kernels.moe_gmm``) per projection, for
+    thousands of prefill rows and a decode step's few hundred alike.
+
+    ``held = (first, count)`` is the contiguous share of the
+    ``num_experts`` this layer holds weights for: routing is always over
+    all experts, the output is the part its own experts contribute
+    (everything when it holds all), and nothing stands in for the rest.
+
+    Array-level (serving) API: :meth:`route_and_run` on ``[T, H]``
+    arrays; it also returns the layer's routing record, one int32
+    array: the counts ``[assignments computed, distinct experts hit,
+    largest load on one expert]`` and behind them the ``k`` experts
+    chosen for each row (what a router replay or a teacher-forced
+    comparison needs: top-k is discontinuous, so which experts ran is
+    part of the result)."""
+
+    def __init__(self, hidden: int, width: int, num_experts: int, k: int,
+                 use_bias: bool = True, norm_topk: bool = True,
+                 scale: float = 1.0, held=None, std: float = 0.02,
+                 dtype=None):
+        super().__init__()
+        self.num_experts, self.k = int(num_experts), int(k)
+        self.norm_topk, self.scale = bool(norm_topk), float(scale)
+        self.first, self.count = held or (0, self.num_experts)
+        init = nn.initializer.Normal(0.0, std)
+        n = self.count
+
+        def param(shape):
+            return self.create_parameter(shape, dtype=dtype,
+                                         default_initializer=init)
+
+        self.gate_weight = param([hidden, self.num_experts])
+        # published as a trained buffer; a parameter here so that a
+        # checkpoint (and the benchmark's seeded weights) reach it
+        self.expert_bias = param([self.num_experts]) if use_bias else None
+        self.w1 = param([n, hidden, width])
+        self.w3 = param([n, hidden, width])
+        self.w2 = param([n, width, hidden])
+
+    def route_and_run(self, a, valid=None, interpret=None):
+        """a ``[T, H]``; ``valid`` bool ``[T]`` marks the rows worth
+        computing (padding is skipped and not counted). Returns (out
+        ``[T, H]`` in a's dtype, record int32 ``[3 + T * k]``: the three
+        counts, then the chosen expert ids row by row)."""
+        from ..kernels.moe_gmm import gmm_plan, moe_gmm
+        T, H = a.shape
+        E, k = self.num_experts, self.k
+        with jax.named_scope("router"):
+            ids, w = sigmoid_topk_route(
+                a, self.gate_weight._data,
+                None if self.expert_bias is None else self.expert_bias._data,
+                k, self.norm_topk, self.scale)
+        with jax.named_scope("dispatch"):
+            flat = ids.reshape(-1)
+            held = (flat >= self.first) & (flat < self.first + self.count)
+            if valid is not None:
+                held &= jnp.repeat(valid, k)
+            # rows nobody here computes are parked behind the last expert
+            flat = jnp.where(held, flat, E)
+            order = jnp.argsort(flat, stable=True)
+            sizes = jnp.bincount(flat, length=E + 1).astype(jnp.int32)
+            rows = a[order // k]
+            counts = jnp.stack([jnp.sum(sizes[:E]),
+                                jnp.sum(sizes[:E] > 0),
+                                jnp.max(sizes[:E])]).astype(jnp.int32)
+        with jax.named_scope("experts"):
+            # one visit list for the layer's three products
+            plan = gmm_plan(sizes, T * k, self.first, self.count)
+            up = moe_gmm(rows, self.w1._data, interpret=interpret, plan=plan)
+            gate = moe_gmm(rows, self.w3._data, interpret=interpret,
+                           plan=plan)
+            h = (jax.nn.silu(up.astype(jnp.float32))
+                 * gate.astype(jnp.float32)).astype(a.dtype)
+            y = moe_gmm(h, self.w2._data, interpret=interpret, plan=plan)
+        with jax.named_scope("combine"):
+            inv = jnp.argsort(order)
+            y = y[inv].reshape(T, k, H).astype(jnp.float32)
+            out = jnp.sum(y * w[..., None], axis=1).astype(a.dtype)
+        return out, jnp.concatenate([counts, ids.reshape(-1)])
+
+    def forward(self, x):
+        x = x if isinstance(x, Tensor) else Tensor(x)
+        shape = x._data.shape
+        out, _ = self.route_and_run(x._data.reshape(-1, shape[-1]))
+        return Tensor(out.reshape(shape))

@@ -63,15 +63,21 @@ class Config:
         one-request-at-a-time Predictor. ``engine_kwargs`` are
         :class:`~paddle2_tpu.serving.EngineConfig` fields (block_size,
         num_blocks, max_batch, weight_only_int8, ...). Build the
-        engine with :meth:`create_serving_engine` — it needs the GPT
-        architecture config, which the serialized artifact does not
-        carry."""
+        engine with :meth:`create_serving_engine` — it needs the model's
+        architecture config (a ``GPTConfig`` or an ``Lfm2MoeConfig``),
+        which the serialized artifact does not carry."""
         self._serving = dict(engine_kwargs)
 
     def continuous_batching_enabled(self) -> bool:
         return self._serving is not None
 
     def create_serving_engine(self, gpt_config):
+        """The continuous-batching engine over this artifact.
+        ``gpt_config`` is the architecture config object of a family the
+        engine serves — ``GPTConfig`` or ``Lfm2MoeConfig`` (the keyword
+        is older than the second family); the engine rebuilds the model
+        from it and refuses at construction the features that family
+        does not have yet."""
         from .serving import EngineConfig, ServingEngine
         if self._serving is None:
             raise ValueError("call enable_continuous_batching() first")

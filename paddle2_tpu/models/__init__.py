@@ -5,6 +5,10 @@ from .gpt import (GPTConfig, GPTModel, GPTForCausalLM, gpt3_1p3b, gpt_small,
 from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
                     ernie3_base, ernie_tiny)
 
-__all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM", "gpt3_1p3b",
+from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_moe_tiny
+
+
+__all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
+           "GPTConfig", "GPTModel", "GPTForCausalLM", "gpt3_1p3b",
            "gpt_small", "gpt_tiny", "ErnieConfig", "ErnieModel",
            "ErnieForSequenceClassification", "ernie3_base", "ernie_tiny"]

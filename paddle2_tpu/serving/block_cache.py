@@ -101,20 +101,30 @@ class BlockAllocator:
     ``high_water * block_bytes`` against the contiguous
     max-seq-len cache a non-paged engine would have to reserve."""
 
-    def __init__(self, num_blocks: int, block_size: int):
+    def __init__(self, num_blocks: int, block_size: int,
+                 state_slots: int = 0):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is reserved)")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
+        # the second kind of state (a model whose layers keep a
+        # fixed-size recurrent state per sequence, not keys and values):
+        # ``state_slots`` slots 1..n of a device pool, one per running
+        # sequence, handed out and taken back HERE with the blocks, so
+        # that one object answers "can this sequence be admitted". Slot
+        # 0 is the garbage slot of padded batch rows, as block 0 is.
+        self.state_slots = int(state_slots)
+        self._free_slots: List[int] = list(range(self.state_slots, 0, -1))
         # LIFO free list: recently-freed blocks are re-used first (their
         # pool slots are warm in cache on real hardware)
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self.high_water = 0
-        # CoW plane: per-block reference count (absent/0 = free).
+        # CoW plane: per-block reference count (0 = free), an array so
+        # that the engine's per-tick table validation reads it whole.
         # total_allocated counts allocate() handouts MONOTONICALLY and
         # NOT share() bumps — it is the "KV bytes actually materialized"
         # numerator the prefix-cache bench gate divides by requests.
-        self._rc: Dict[int, int] = {}
+        self._rc = np.zeros(self.num_blocks, np.int64)
         self.total_allocated = 0
         # optional reclaimer (the PrefixCache): consulted when the free
         # list alone cannot cover a request — must expose
@@ -131,7 +141,13 @@ class BlockAllocator:
 
     def refcount(self, block: int) -> int:
         """Current owner count of ``block`` (0 = on the free list)."""
-        return self._rc.get(int(block), 0)
+        block = int(block)
+        return int(self._rc[block]) if 0 <= block < self.num_blocks else 0
+
+    def refcounts(self) -> np.ndarray:
+        """Owner count of every block, ``[num_blocks]`` (0 = free); the
+        allocator's own array, not to be written."""
+        return self._rc
 
     def set_reclaimer(self, reclaimer) -> None:
         """Install the cache that can give blocks back on demand
@@ -144,6 +160,28 @@ class BlockAllocator:
     def can_allocate(self, n: int) -> bool:
         return n <= len(self._free) + self._reclaimable()
 
+    # -- state slots -----------------------------------------------------
+    @property
+    def state_slots_used(self) -> int:
+        return self.state_slots - len(self._free_slots)
+
+    def can_admit(self, n_blocks: int) -> bool:
+        """Blocks AND (where the model keeps one) a state slot."""
+        return self.can_allocate(n_blocks) and (
+            not self.state_slots or bool(self._free_slots))
+
+    def take_state_slot(self) -> int:
+        if not self._free_slots:
+            raise OutOfBlocksError(
+                f"all {self.state_slots} state slots are in use")
+        return self._free_slots.pop()
+
+    def free_state_slot(self, slot: int) -> None:
+        slot = int(slot)
+        if not (0 < slot <= self.state_slots) or slot in self._free_slots:
+            raise BlockFreeError(f"bad or double free of state slot {slot}")
+        self._free_slots.append(slot)
+
     def allocate(self, n: int = 1) -> List[int]:
         if n > len(self._free) and self._reclaimer is not None:
             # cached prefix blocks nobody references are headroom, not
@@ -154,8 +192,7 @@ class BlockAllocator:
                 f"need {n} blocks, {len(self._free)} free "
                 f"(of {self.num_blocks - 1} usable)")
         out = [self._free.pop() for _ in range(n)]
-        for b in out:
-            self._rc[b] = 1
+        self._rc[out] = 1
         self.total_allocated += n
         self.high_water = max(self.high_water, self.used_count)
         return out
@@ -173,7 +210,7 @@ class BlockAllocator:
             if not (0 < b < self.num_blocks):
                 raise BlockFreeError(f"bad block id {b} (usable range "
                                      f"1..{self.num_blocks - 1})")
-            if self._rc.get(b, 0) < 1:
+            if self._rc[b] < 1:
                 raise BlockFreeError(
                     f"share of unallocated block {b}")
         for b in blocks:
@@ -199,7 +236,7 @@ class BlockAllocator:
             if not (0 < b < self.num_blocks):
                 raise BlockFreeError(f"bad block id {b} (usable range "
                                      f"1..{self.num_blocks - 1})")
-            if self._rc.get(b, 0) < 1:
+            if self._rc[b] < 1:
                 raise BlockFreeError(f"double free of block {b}")
             if b in seen:
                 raise BlockFreeError(
@@ -208,10 +245,10 @@ class BlockAllocator:
         for b in blocks:
             self._rc[b] -= 1
             if self._rc[b] == 0:
-                del self._rc[b]
                 self._free.append(b)
 
-    def rebuild_free_list(self, live_block_lists) -> None:
+    def rebuild_free_list(self, live_block_lists,
+                          live_state_slots=()) -> None:
         """Recovery path: recompute the free list — and the refcounts
         — from the surviving claims. Used after a block-table
         corruption, when one table's ids can no longer be trusted
@@ -234,10 +271,15 @@ class BlockAllocator:
             raise BlockFreeError(
                 f"rebuild_free_list given out-of-range ids {bad} — "
                 f"survivors must be validated tables")
-        self._rc = dict(claims)
+        self._rc[:] = 0
+        for b, n in claims.items():
+            self._rc[b] = n
         self._free = [b for b in range(self.num_blocks - 1, 0, -1)
                       if b not in claims]
         self.high_water = max(self.high_water, len(claims))
+        held = {int(s) for s in live_state_slots}
+        self._free_slots = [s for s in range(self.state_slots, 0, -1)
+                            if s not in held]
 
 
 class BlockTable:
@@ -250,6 +292,10 @@ class BlockTable:
         self._alloc = allocator
         self.blocks: List[int] = []
         self.num_tokens = 0
+        # the sequence's slot of the state pool (None: not admitted, or
+        # a model without such state); taken with the first blocks,
+        # given back by release()
+        self.state_slot: Optional[int] = None
 
     @property
     def capacity(self) -> int:
@@ -261,6 +307,10 @@ class BlockTable:
         list cannot cover the growth — the table is left unchanged."""
         need = blocks_for_tokens(n_tokens, self._alloc.block_size) \
             - len(self.blocks)
+        if self._alloc.state_slots and self.state_slot is None:
+            if need > 0 and not self._alloc.can_allocate(need):
+                raise OutOfBlocksError(f"need {need} blocks")
+            self.state_slot = self._alloc.take_state_slot()
         if need > 0:
             self.blocks.extend(self._alloc.allocate(need))
 
@@ -339,6 +389,10 @@ class BlockTable:
             self._alloc.free(self.blocks)
         self.blocks = []
         self.num_tokens = 0
+        if self.state_slot is not None:
+            # no clear: the next owner's prefill overwrites the slot
+            self._alloc.free_state_slot(self.state_slot)
+            self.state_slot = None
 
     def padded(self, n_pages: int) -> np.ndarray:
         """int32 table row padded to ``n_pages`` with the garbage
@@ -351,31 +405,67 @@ class BlockTable:
 
 class PagedKVCache:
     """Device pools for a whole model: K and V, each
-    ``[num_layers, num_blocks, block_size, num_heads * head_dim]`` —
-    token-major, a token's heads merged into one lane-dense row: the
-    chip pads the minor dimension to 128 lanes, so a separate
-    ``head_dim`` 64 axis would double the pool in HBM, and the paged
-    kernel's DMA addresses ``[block_size, 128]``-lane pages of exactly
-    this shape. A new token is one contiguous row, so the decode append
-    is a plain row scatter the compiler updates in place.
+    ``[attention layers, num_blocks, block_size, num_kv_heads *
+    head_dim]`` — token-major, a token's key/value heads merged into one
+    lane-dense row: the chip pads the minor dimension to 128 lanes, so a
+    separate ``head_dim`` 64 axis would double the pool in HBM, and the
+    paged kernel's DMA addresses ``[block_size, H_kv*D]`` pages of
+    exactly this shape. ``num_layers`` counts the layers that HAVE keys
+    and values and ``num_kv_heads`` the heads they keep (a grouped-query
+    model keeps fewer than it has query heads; ``num_heads`` is the
+    same number under its older name). A new token is one contiguous
+    row, so the decode append is a plain row scatter the compiler
+    updates in place.
 
-    Pools start zeroed; stale data in freed blocks is harmless — the
-    paged-attention kernel masks every slot past a sequence's context
-    length, and masked probabilities are exactly 0.0 in fp32."""
+    ``state_shape`` (with ``state_slots``) adds the second kind of
+    state: one pool ``[state layers, slots + 1, *per-layer shape]`` in
+    the cache's dtype for layers that keep a fixed-size recurrent state
+    per sequence (``state_shape = (layers, *per-layer shape)``; slot 0
+    is the padded rows' garbage slot). Slots are handed out by the
+    :class:`BlockAllocator`.
+
+    Pools start zeroed; stale data in freed blocks and slots is
+    harmless — the paged-attention kernel masks every slot past a
+    sequence's context length (masked probabilities are exactly 0.0 in
+    fp32), and a state slot is overwritten whole by its next owner's
+    prefill."""
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
-                 num_heads: int, head_dim: int, dtype="float32"):
+                 num_kv_heads: int, head_dim: int, dtype="float32",
+                 state_shape=None, state_slots: int = 0):
         import jax.numpy as jnp
         self.num_layers = int(num_layers)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        self.num_heads = int(num_heads)
+        self.num_kv_heads = self.num_heads = int(num_kv_heads)
         self.head_dim = int(head_dim)
         self.dtype = jnp.dtype(dtype)
         shape = (num_layers, num_blocks, block_size,
-                 num_heads * head_dim)
+                 num_kv_heads * head_dim)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
+        self.state = None
+        if state_shape is not None:
+            self.state = jnp.zeros(
+                (state_shape[0], int(state_slots) + 1)
+                + tuple(state_shape[1:]), self.dtype)
+
+    def write_state(self, slot: int, states) -> None:
+        """``state[:, slot] = states`` (one sequence's prefilled state,
+        ``[state layers, *per-layer shape]``): one jitted program with
+        the pool donated and the slot a runtime scalar."""
+        import jax
+        import jax.numpy as jnp
+        key = ("state", tuple(self.state.shape), str(self.state.dtype))
+        fn = _PREFILL_SCATTER_CACHE.get(key)
+        if fn is None:
+            def p2t_state_write(pool, st, sl):
+                with jax.named_scope("state_write"):
+                    return pool.at[:, sl].set(st.astype(pool.dtype))
+            fn = _PREFILL_SCATTER_CACHE[key] = jax.jit(
+                p2t_state_write, donate_argnums=(0,))
+        self.state = fn(self.state, states, jnp.asarray(int(slot),
+                                                        jnp.int32))
 
     @property
     def block_bytes(self) -> int:
@@ -398,8 +488,8 @@ class PagedKVCache:
     def scatter_decode(pool, layer, phys, slot, new_kv):
         """Write one new token per sequence into ONE layer's lane:
         ``pool[layer, phys[b], slot[b]] = new_kv[b]``.
-        pool: [L, N, bs, H*D]; phys/slot: int32 [B]; new_kv:
-        [B, H, D]. Traced inside the compiled decode program (which
+        pool: [L, N, bs, H_kv*D]; phys/slot: int32 [B]; new_kv:
+        [B, H_kv, D]. Traced inside the compiled decode program (which
         donates the pool), per layer — the decode loop appends each
         layer's K/V right where it is produced."""
         return pool.at[layer, phys, slot].set(
@@ -579,8 +669,8 @@ class HostKVTier:
 
 def audit_kv_ledger(allocator: BlockAllocator, live_block_lists,
                     prefix_cache: Optional["PrefixCache"] = None,
-                    in_migration=(), host_tier: Optional[HostKVTier] = None
-                    ) -> Dict[str, int]:
+                    in_migration=(), host_tier: Optional[HostKVTier] = None,
+                    live_state_slots=()) -> Dict[str, int]:
     """Cross-tier ownership audit (ISSUE 16): every usable block is
     owned EXACTLY once — on the free list, or referenced with a
     refcount equal to its claim multiplicity across the live tables,
@@ -588,8 +678,10 @@ def audit_kv_ledger(allocator: BlockAllocator, live_block_lists,
     and ``free + claimed == usable``. Host-tier entries are byte
     payloads, never allocator ids, so they cannot alias device blocks
     by construction; the audit reports their count so the property
-    test can close the whole ladder. Raises :class:`BlockFreeError`
-    on any violation; returns the tier census when clean."""
+    test can close the whole ladder. The state slots close the same
+    way: every slot 1..n is free or claimed by exactly one entry of
+    ``live_state_slots``. Raises :class:`BlockFreeError` on any
+    violation; returns the tier census when clean."""
     claims: Dict[int, int] = {}
     lists = [list(l) for l in live_block_lists]
     if prefix_cache is not None:
@@ -618,18 +710,27 @@ def audit_kv_ledger(allocator: BlockAllocator, live_block_lists,
             raise BlockFreeError(
                 f"block {b}: refcount {allocator.refcount(b)} != claim "
                 f"multiplicity {c}")
-    for b in allocator._rc:
+    for b in np.flatnonzero(allocator.refcounts()).tolist():
         if b not in claims:
             raise BlockFreeError(
-                f"block {b} allocated (rc={allocator._rc[b]}) but "
+                f"block {b} allocated (rc={allocator.refcount(b)}) but "
                 f"claimed by no table, cache, or migration")
     if len(free) + len(claims) != usable:
         raise BlockFreeError(
             f"ledger does not close: {len(free)} free + {len(claims)} "
             f"claimed != {usable} usable")
+    slots = [int(x) for x in live_state_slots]
+    free_slots = list(allocator._free_slots)
+    if sorted(slots + free_slots) != list(range(1, allocator.state_slots
+                                                + 1)):
+        raise BlockFreeError(
+            f"state slots do not close: claimed {sorted(slots)}, free "
+            f"{sorted(free_slots)}, of {allocator.state_slots}")
     return {"free": len(free), "claimed": len(claims),
             "host_tier": len(host_tier) if host_tier is not None else 0,
-            "in_migration": len(list(in_migration))}
+            "in_migration": len(list(in_migration)),
+            "state_slots_free": len(free_slots),
+            "state_slots_claimed": len(slots)}
 
 
 class PrefixCache:

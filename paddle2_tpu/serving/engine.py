@@ -1,11 +1,13 @@
-"""ServingEngine: continuous batching + paged KV over a GPT model.
+"""ServingEngine: continuous batching + paged KV over a causal LM of a
+family the runner knows (``model_runner.served_classes``: GPT, LFM2-MoE).
 
 The production serving loop (ROADMAP item 2): requests come in via
 ``submit()``, the engine prefills them into paged KV blocks, and every
 ``decode_once()`` runs ONE bucketed compiled decode step over the
 whole running batch — admissions and evictions happen between steps
-(iteration-level scheduling). Construct it from a live
-``GPTForCausalLM`` or from a ``jit.save``'d artifact (the artifact's
+(iteration-level scheduling). Construct it from a live model
+(``GPTForCausalLM``, ``Lfm2MoeForCausalLM``) or from a ``jit.save``'d
+artifact (the artifact's
 weights are loaded into a rebuilt architecture — the exported forward
 program itself has no KV surface to page).
 
@@ -25,6 +27,7 @@ clocks in any gate).
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence as Seq, Tuple
 
@@ -33,7 +36,7 @@ import numpy as np
 from ..profiler import span as _span
 from .block_cache import (BlockAllocator, HostKVTier, PagedKVCache,
                           PrefixCache, blocks_for_tokens, GARBAGE_BLOCK)
-from .model_runner import PagedGPTRunner
+from .model_runner import PagedRunner, served_classes
 from .reliability import (EngineFailedError, PromptTooLongError,
                           ReliabilityConfig, RequestRejected,
                           flight_record as _flight_record)
@@ -103,7 +106,10 @@ class EngineConfig:
 
 
 class ServingEngine:
-    """Continuous-batching serving engine over one GPT model."""
+    """Continuous-batching serving engine over one causal LM.
+    ``gpt_config`` (the keyword predates the second family) is the
+    model's config object — ``GPTConfig`` or ``Lfm2MoeConfig`` — from
+    which an artifact's architecture is rebuilt."""
 
     def __init__(self, model=None, *, artifact_path: Optional[str] = None,
                  artifact_params_path: Optional[str] = None,
@@ -114,39 +120,50 @@ class ServingEngine:
                 raise ValueError("pass model= or artifact_path=")
             model = self._load_artifact(artifact_path, gpt_config,
                                         artifact_params_path)
-        cfg = model.cfg
-        if getattr(cfg, "stacked_blocks", False):
+        self.runner = PagedRunner(model, interpret=self.config.interpret,
+                                  split_pages=self.config.split_pages)
+        family = self.runner.family
+        refused = [f for f in family.unsupported
+                   if getattr(self.config, f)]
+        if refused:
             raise ValueError(
-                "serving requires addressable blocks; rebuild with "
-                "stacked_blocks=False (the decode program wires the "
-                "paged append between qkv and attention per block)")
+                f"{type(model).__name__} is not served with "
+                f"{', '.join(refused)} yet")
         self.model = model
         model.eval()
         self.max_model_len = int(self.config.max_model_len
-                                 or cfg.max_position_embeddings)
-        if self.max_model_len > cfg.max_position_embeddings:
+                                 or family.max_positions)
+        if self.max_model_len > family.max_positions:
             # jnp gathers CLAMP out-of-range indices, so positions past
             # the wpe table would silently decode with the wrong
             # embedding instead of raising
             raise ValueError(
                 f"max_model_len {self.max_model_len} exceeds the "
                 f"model's max_position_embeddings "
-                f"{cfg.max_position_embeddings}")
+                f"{family.max_positions}")
         if self.config.weight_only_int8:
             from ..quantization import weight_only_quantize
             # projection matmuls only: qkv/out_proj/up/down inside the
             # blocks — embeddings and the (tied) head stay fp unless
             # weight_only_lm_head opts the logits matmul in below
+            # (the GPT family's blocks: the others refused it above)
             for block in model.gpt.h:
                 weight_only_quantize(block)
         if self.config.weight_only_lm_head:
             from ..quantization import quantize_lm_head
             quantize_lm_head(model)
+        # two kinds of state, one manager: paged blocks for the layers
+        # that keep keys and values, and (where the family has layers
+        # with a fixed-size state) one slot per running sequence
+        slots = self.config.max_batch if family.state_shape else 0
         self.cache = PagedKVCache(
-            cfg.num_layers, self.config.num_blocks, self.config.block_size,
-            cfg.num_heads, cfg.head_dim, dtype=self.config.kv_dtype)
+            family.attn_layers, self.config.num_blocks,
+            self.config.block_size, family.num_kv_heads, family.head_dim,
+            dtype=self.config.kv_dtype, state_shape=family.state_shape,
+            state_slots=slots)
         self.allocator = BlockAllocator(self.config.num_blocks,
-                                        self.config.block_size)
+                                        self.config.block_size,
+                                        state_slots=slots)
         max_pages = blocks_for_tokens(self.max_model_len,
                                       self.config.block_size)
         # a speculative verify round rides k extra rows per sequence
@@ -193,9 +210,6 @@ class ServingEngine:
         # events fire deep inside the allocator's reclaim hook, so the
         # engine emits deltas rather than instrumenting the cache)
         self._kv_counts: Dict[str, int] = {}
-        self.runner = PagedGPTRunner(model, cfg.num_heads, cfg.head_dim,
-                                     interpret=self.config.interpret,
-                                     split_pages=self.config.split_pages)
         self.spec_accepted = 0
         self.spec_rejected = 0
         self._next_req_id = 0
@@ -235,9 +249,8 @@ class ServingEngine:
                 "artifact_path needs gpt_config= (the architecture is "
                 "rebuilt; the serialized program has no pageable KV)")
         from ..jit.api import load as jit_load
-        from ..models.gpt import GPTForCausalLM
         loaded = jit_load(artifact_path, params_path=params_path)
-        model = GPTForCausalLM(gpt_config)
+        model = served_classes(gpt_config)[0](gpt_config)
         state = loaded.state_dict()
         # the artifact's dtypes are the serving dtypes: a bf16
         # (amp O2) artifact is served in bf16, not widened back to the
@@ -337,6 +350,20 @@ class ServingEngine:
 
     def sequence(self, req_id: int) -> Sequence:
         return self._seqs[req_id]
+
+    def routed_experts(self, req_id: int) -> Optional[np.ndarray]:
+        """The experts the served path chose for each token it was fed
+        (prompt and generated, so one row fewer than the token log
+        while the last token has not been fed): int32 ``[positions,
+        expert layers, k]``; None for a family that routes nothing.
+        Top-k routing is discontinuous, so a replay or a comparison
+        against another implementation needs WHICH experts ran."""
+        if self.runner.family.routed is None:
+            return None
+        pieces = self._seqs[req_id].routed
+        layers, k = self.runner.family.routed
+        return (np.concatenate(pieces) if pieces
+                else np.zeros((0, layers, k), np.int32))
 
     # -- failure plane ---------------------------------------------------
     def _check_alive(self) -> None:
@@ -497,12 +524,21 @@ class ServingEngine:
         from ..observability import metrics
         n = len(seq.tokens)
         padded = self.runner.prefill_padded_len(n)
-        with _span("prefill", req=seq.req_id, tokens=n, padded=padded):
+        with _span("prefill", req=seq.req_id, tokens=n,
+                   padded=padded) as prefill_span:
             with _span("prefill.dispatch"):
-                tok, k_stack, v_stack = self.runner.prefill_dispatch(
-                    seq.tokens)
+                tok, k_stack, v_stack, *state = \
+                    self.runner.prefill_dispatch(seq.tokens)
             with _span("prefill.readback"):
-                tok = int(tok[0])       # the host waits for the device
+                # the host waits for the device; a family's counts
+                # come in the same array as the token
+                tok, counts, chosen = self.runner.split_counts(tok, 1)
+                tok = int(tok[0])
+            if counts:
+                prefill_span.set_metadata(**self._count_stats(counts))
+                # the whole token log was routed anew (a re-prefill
+                # after an eviction too): its record replaces the old
+                seq.routed = [chosen[:n]]
             row = np.asarray(seq.table.blocks, np.int64)
             # prefix-cache hit: the leading cached positions' KV is
             # ALREADY in the pool (and shared — rewriting it would
@@ -518,6 +554,10 @@ class ServingEngine:
                 self.cache.v = PagedKVCache.scatter_prefill(
                     self.cache.v, v_stack, row, n, self.cache.block_size,
                     start=start)
+                if state:
+                    # the whole prompt was computed (a prefix hit too),
+                    # so this is the state at its real last positions
+                    self.cache.write_state(seq.table.state_slot, state[0])
         seq.table.num_tokens = n
         seq.tokens.append(tok)
         cost = self.runner.prefill_cost(padded)
@@ -580,6 +620,13 @@ class ServingEngine:
             self.scheduler.finish(seq, seq.ready_at)
         return info
 
+    @staticmethod
+    def _count_stats(counts: Dict[str, list]) -> Dict[str, int]:
+        """A program's per-layer counts as span stats: summed over the
+        layers, but a ``*_max`` is the worst layer's."""
+        return {k: int(max(v) if k.endswith("_max") else sum(v))
+                for k, v in counts.items()}
+
     # -- block-table integrity --------------------------------------------
     def _validate_tables(self, active: List[Sequence],
                          now: Optional[float] = None) -> List[Sequence]:
@@ -599,37 +646,35 @@ class ServingEngine:
         one more survivor claim list. Returns the still-running subset
         of ``active``."""
         from ..observability import metrics
-        claimants: Dict[int, List[Sequence]] = {}
-        bad: List[Sequence] = []
-        for s in self.scheduler.running():
-            ok = len(s.table.blocks) >= blocks_for_tokens(
-                max(s.table.num_tokens, 1), self.config.block_size)
-            seen = set()
-            for b in s.table.blocks:
-                if not (0 < b < self.config.num_blocks):
-                    ok = False
-                    break
-                if b in seen:
-                    # a self-dup aliases two of this sequence's own
-                    # token pages onto one block — never legitimate
-                    ok = False
-                    break
-                seen.add(b)
-                claimants.setdefault(b, []).append(s)
-            if not ok:
-                bad.append(s)
-        held = (set(self.prefix_cache.held_blocks())
-                if self.prefix_cache is not None else ())
-        for b, owners in claimants.items():
-            hold = 1 if b in held else 0
-            if len(owners) + hold > self.allocator.refcount(b):
-                # over-claimed: sharing must be covered by references.
-                # A cross-table alias cannot say WHICH table was
-                # scribbled, so every claimant is rebuilt — re-prefill
-                # is exact either way.
-                for s in owners:
-                    if s not in bad:
-                        bad.append(s)
+        running = self.scheduler.running()
+        n_blocks = self.config.num_blocks
+        # every claim of every table in ONE pass of array operations (a
+        # Python step per block id was thousands of steps a tick at long
+        # contexts): the flat ids beside the index of their owner
+        lens = [len(s.table.blocks) for s in running]
+        flat = np.fromiter(itertools.chain.from_iterable(
+            s.table.blocks for s in running), np.int64, sum(lens))
+        owner = np.repeat(np.arange(len(running)), lens)
+        in_range = (flat > 0) & (flat < n_blocks)
+        ids, own = flat[in_range], owner[in_range]
+        # a repeat WITHIN one table aliases two of the sequence's own
+        # token pages onto one block: never legitimate
+        pair, times = np.unique(own * n_blocks + ids, return_counts=True)
+        # over-claimed: sharing must be covered by references (the
+        # prefix cache's hold is one more claim). A cross-table alias
+        # cannot say WHICH table was scribbled, so every claimant is
+        # rebuilt: re-prefill is exact either way
+        claims = np.bincount(ids, minlength=n_blocks)
+        if self.prefix_cache is not None:
+            claims[list(self.prefix_cache.held_blocks())] += 1
+        over = claims > self.allocator.refcounts()
+        wrong = np.zeros(len(running), bool)
+        wrong[owner[~in_range]] = True
+        wrong[pair[times > 1] // n_blocks] = True
+        wrong[own[over[ids]]] = True
+        bad = [s for s, w, n in zip(running, wrong, lens)
+               if w or n < blocks_for_tokens(max(s.table.num_tokens, 1),
+                                             self.config.block_size)]
         if not bad:
             return active
         for s in bad:
@@ -641,7 +686,10 @@ class ServingEngine:
         survivors = [s.table.blocks for s in self.scheduler.running()]
         if self.prefix_cache is not None:
             survivors.append(self.prefix_cache.held_blocks())
-        self.allocator.rebuild_free_list(survivors)
+        self.allocator.rebuild_free_list(
+            survivors, [s.table.state_slot
+                        for s in self.scheduler.running()
+                        if s.table.state_slot is not None])
         return [s for s in active if s.state is SeqState.RUNNING]
 
     # -- one decode step -------------------------------------------------
@@ -761,10 +809,20 @@ class ServingEngine:
             ids = np.zeros((b_bucket, 1), np.int32)
             positions = np.zeros((b_bucket,), np.int32)
             tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
+            # state slots of the rows (0: the padded rows' garbage slot)
+            slots = None if self.cache.state is None \
+                else np.zeros((b_bucket,), np.int32)
+            slot_counts = {}
             for i, (s, tok_in, pos) in enumerate(rows):
                 ids[i, 0] = tok_in
                 positions[i] = pos
                 tables[i] = s.table.padded(p_bucket)
+                if slots is not None:
+                    slots[i] = s.table.state_slot
+            if slots is not None:
+                slot_counts = dict(
+                    state_slots_in_use=self.allocator.state_slots_used,
+                    state_slots_total=self.allocator.state_slots)
         # runner.decode is the one call (H2D, the program, and inside
         # it the decode.readback span in which the host waits)
         with metrics.phase("compute"), \
@@ -774,18 +832,25 @@ class ServingEngine:
                       kernel_pages_per_block=kernel_ppb,
                       blocks_in_use=self.allocator.used_count,
                       blocks_total=self.config.num_blocks,
-                      evicted=len(victims)):
-            toks = self.runner.decode(self.cache, ids, positions, tables)
+                      evicted=len(victims), **slot_counts) as sp:
+            state_args = () if slots is None else (slots,)
+            toks, counts, chosen = self.runner.split_counts(
+                self.runner.decode(self.cache, ids, positions, tables,
+                                   *state_args), b_bucket)
+            if counts:
+                sp.set_metadata(**self._count_stats(counts))
         with _span("decode.emit"):
             return self._emit_decoded(now, active, drafts, victims,
                                       len(rows), (b_bucket, p_bucket),
-                                      toks)
+                                      toks, chosen)
 
     def _emit_decoded(self, now: float, active: List[Sequence],
                       drafts: Dict[int, List[int]], victims: list,
                       n_rows_total: int, bucket: Tuple[int, int],
-                      toks) -> dict:
-        """Append the step's tokens, finish what is done, count."""
+                      toks, chosen=None) -> dict:
+        """Append the step's tokens (and, for a routed family, the
+        experts ``chosen`` for each row's input token), finish what is
+        done, count."""
         from ..distributed.fault_tolerance import chaos
         from ..observability import metrics
         cfg = self.scheduler.config
@@ -839,6 +904,8 @@ class ServingEngine:
         for s in active:
             n_rows = 1 + len(drafts.get(id(s), ()))
             outs = [int(toks[ri + j]) for j in range(n_rows)]
+            if chosen is not None:
+                s.routed.append(chosen[ri:ri + 1])
             ri += n_rows
             if n_rows == 1:
                 emitted = [outs[0]]

@@ -1,30 +1,40 @@
-"""Compiled prefill/decode programs for GPT-family models over the
-paged KV cache.
+"""Compiled prefill/decode programs over the paged KV cache: ONE copy
+of the plumbing (:class:`PagedRunner`) and, per model family, the few
+functions that say what the model computes (:class:`ModelFamily`).
 
-The decode step cannot reuse ``GPTModel.decode_step`` (whose KV cache
-is a growing per-layer concat — exactly the contiguous layout paging
-replaces), so this runner re-wires one block step from the model's OWN
-sublayers (ln_1 -> fused qkv -> paged append -> paged attention ->
-out_proj -> mlp), mirroring ``GPTBlock.forward``'s head-major qkv
-split. Prefill DOES go through ``decode_step`` (empty caches): it
-computes every prompt position's K/V in one causal pass, and the
-runner scatters them into the sequence's blocks.
-
-Both paths are pure functions compiled with ``jax.jit``:
+The plumbing, the same for every family — both paths are pure
+functions compiled with ``jax.jit``:
 
 * weights ride as ARGUMENTS (the ``TracedProgram``/``_export_program``
   param-swap pattern) — never baked in as constants;
 * the decode program is keyed by the scheduler's (batch, pages)
   bucket, so the program count is bounded by the bucket grid (the
-  bench gate), and DONATES the KV pools for in-place append;
+  bench gate), and DONATES the KV pools (and the state pool, where the
+  family keeps one) for in-place append;
 * prefill is keyed by the padded prompt length (rounded up to
   :data:`PREFILL_PAD`); causal masking makes the padded tail invisible
   to real rows, so padding is exact, and the real last position is a
-  runtime index.
+  runtime index;
+* the family's step functions return LOGITS; sampling (greedy) is the
+  runner's thin wrapper around them, so a test can call the family's
+  step under :meth:`PagedRunner.bound` and compare logits;
+* builds run inside ``build`` spans and leave cost records.
+
+A family supplies its cache geometry (how many layers keep keys and
+values, how many key/value heads of what size, and the shape of any
+fixed-size per-sequence state) and ``prefill`` / ``decode``.
+:class:`GPTFamily` re-wires one GPT block step from the model's OWN
+sublayers (ln_1 -> fused qkv -> paged append -> paged attention ->
+out_proj -> mlp), mirroring ``GPTBlock.forward``'s head-major qkv
+split, because ``GPTModel.decode_step``'s cache is a growing per-layer
+concat — exactly the contiguous layout paging replaces. Its prefill
+DOES go through ``decode_step`` (empty caches). The LFM2-MoE family is
+in ``lfm2_family.py``.
 """
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -32,27 +42,176 @@ import numpy as np
 from ..profiler import build as _build_span, span as _span
 from .paged_attention import paged_attention_decode
 
-__all__ = ["PagedGPTRunner", "PREFILL_PAD"]
+__all__ = ["ModelFamily", "GPTFamily", "PagedRunner", "PagedGPTRunner",
+           "served_classes", "PREFILL_PAD"]
 
 # prefill programs are compiled per padded length; 16-token rounding
 # bounds their count at max_model_len/16 without wasting much compute
 PREFILL_PAD = 16
 
 
-class PagedGPTRunner:
-    """Owns the compiled programs + the state plumbing for one
-    ``GPTForCausalLM``. Greedy (argmax) decoding — sampling belongs to
-    a later PR; greedy is what the eviction-exactness guarantee is
-    stated for."""
+class ModelFamily:
+    """What a model family tells the runner and the engine.
 
-    def __init__(self, model, num_heads: int, head_dim: int,
-                 interpret: Optional[bool] = None,
+    Geometry: ``attn_layers`` (layers that keep keys and values — the
+    pools' leading axis), ``num_heads`` / ``num_kv_heads`` /
+    ``head_dim``, ``max_positions``, and ``state_shape``: ``None``, or
+    ``(state layers, *per-layer shape)`` of a fixed-size state every
+    running sequence keeps beside its blocks (one slot of the cache's
+    state pool). ``unsupported`` names the :class:`EngineConfig`
+    features the family cannot serve yet (the engine refuses them at
+    construction).
+
+    Steps, traced inside the runner's programs with the weights bound
+    (``interpret`` / ``split_pages`` are the runner's kernel options):
+
+    ``prefill(ids [1, P], last_idx, interpret)`` -> ``(logits [1, V] of
+    the real last position, k_stack, v_stack [attn_layers, P, H_kv, D],
+    state [state layers, ...] or None, counts or None)``;
+
+    ``decode(k_pool, v_pool, state_pool, ids [B, 1], positions [B],
+    block_tables [B, pages], slots [B] or None, block_size, interpret,
+    split_pages)`` -> ``(logits [B, V], k_pool, v_pool, state_pool,
+    counts)``.
+
+    ``counts`` is the int32 array the program hands back with the
+    tokens, or None: per expert layer of a routed model (``routed`` =
+    (expert layers, experts a token)) the routing counts that
+    ``count_names`` names and, behind them, the experts chosen for each
+    row (``DroplessExperts.route_and_run``'s record)."""
+
+    state_shape = None
+    unsupported: Tuple[str, ...] = ()
+    count_names: Tuple[str, ...] = ()
+    routed: Optional[Tuple[int, int]] = None
+
+    def __init__(self, model):
+        self.model = model
+
+    def prefill(self, ids, last_idx, interpret):
+        raise NotImplementedError
+
+    def decode(self, k_pool, v_pool, state_pool, ids, positions,
+               block_tables, slots, block_size, interpret, split_pages):
+        raise NotImplementedError
+
+
+class GPTFamily(ModelFamily):
+    """``GPTForCausalLM``: every layer keeps keys and values, as many
+    key/value heads as query heads, learned positions, no other
+    state."""
+
+    def __init__(self, model):
+        super().__init__(model)
+        cfg = model.cfg
+        if getattr(cfg, "stacked_blocks", False):
+            raise ValueError(
+                "serving requires addressable blocks; rebuild with "
+                "stacked_blocks=False (the decode program wires the "
+                "paged append between qkv and attention per block)")
+        self.attn_layers = cfg.num_layers
+        self.num_heads = self.num_kv_heads = cfg.num_heads
+        self.head_dim = cfg.head_dim
+        self.max_positions = cfg.max_position_embeddings
+
+    def prefill(self, ids, last_idx, interpret):
+        import jax
+        import jax.numpy as jnp
+        from ..framework.tensor import Tensor
+        model = self.model
+        # the named scopes are the model's own plus head_ce / kv_write
+        n_layers = model.cfg.num_layers
+        hidden, caches = model.gpt.decode_step(
+            Tensor(ids), [() for _ in range(n_layers)], 0)
+        with jax.named_scope("head_ce"):
+            h_last = jnp.take_along_axis(
+                hidden._data, last_idx.reshape(1, 1, 1), axis=1)
+            logits = model._head(Tensor(h_last))._data[:, -1]
+        with jax.named_scope("kv_write"):
+            k_stack = jnp.stack([c[0]._data[0] for c in caches])
+            v_stack = jnp.stack([c[1]._data[0] for c in caches])
+        return logits, k_stack, v_stack, None, None
+
+    def decode(self, k_pool, v_pool, state_pool, ids, positions,
+               block_tables, slots, block_size, interpret, split_pages):
+        import jax
+        import jax.numpy as jnp
+        from ..framework.tensor import Tensor
+        from .block_cache import PagedKVCache as _C
+        model = self.model
+        nh, hd = self.num_heads, self.head_dim
+        B = ids.shape[0]
+        phys = jnp.take_along_axis(
+            block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
+        slot = positions % block_size
+        ctx = positions + 1
+        scope = jax.named_scope     # GPTBlock.forward's names
+        with scope("embed"):
+            pos_t = Tensor(positions[:, None].astype(jnp.int32))
+            x = model.gpt.wte(Tensor(ids)) + model.gpt.wpe(pos_t)
+        for li, block in enumerate(model.gpt.h):
+            with scope("attn"):
+                with scope("norm"):
+                    ln1 = block.ln_1(x)
+                qkv = block.attn.qkv(ln1)
+                # head-major fused split, as GPTAttention.forward
+                qkv = qkv.reshape([B, 1, nh, 3, hd])
+                q, k, v = qkv.unbind(axis=3)
+            with scope("kv_write"):
+                k_pool = _C.scatter_decode(k_pool, li, phys, slot,
+                                           k._data[:, 0])
+                v_pool = _C.scatter_decode(v_pool, li, phys, slot,
+                                           v._data[:, 0])
+            with scope("attn"):
+                # the whole pool rides in; the layer is an index
+                # the kernel's copies take, never a sliced-out copy
+                attn = paged_attention_decode(
+                    q._data, k_pool, v_pool, block_tables,
+                    ctx, interpret=interpret,
+                    pages_per_split=split_pages, layer=li)
+                a = block.attn.out_proj(
+                    Tensor(attn.reshape(B, 1, nh * hd)))
+                x = x + block.dropout(a)
+            with scope("mlp"):
+                with scope("norm"):
+                    ln2 = block.ln_2(x)
+                x = x + block.dropout(block.mlp(ln2))
+        with scope("norm"):
+            x = model.gpt.ln_f(x)
+        with scope("head_ce"):
+            logits = model._head(x)._data[:, -1]
+        return logits, k_pool, v_pool, state_pool, None
+
+
+def served_classes(config) -> tuple:
+    """(causal-LM class, serving family class) of a config object: the
+    ONE place that says which models the engine serves. The engine
+    rebuilds an artifact's architecture with the first and the runner
+    reads the model through the second."""
+    from ..models.gpt import GPTConfig, GPTForCausalLM
+    from ..models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
+    from .lfm2_family import Lfm2MoeFamily
+    for config_class, classes in (
+            (GPTConfig, (GPTForCausalLM, GPTFamily)),
+            (Lfm2MoeConfig, (Lfm2MoeForCausalLM, Lfm2MoeFamily))):
+        if isinstance(config, config_class):
+            return classes
+    raise TypeError(
+        f"no serving family for config {type(config).__name__}")
+
+
+class PagedRunner:
+    """Owns the compiled programs + the weight plumbing for one model,
+    read through its :class:`ModelFamily` (:func:`served_classes`).
+    Greedy (argmax) decoding — sampling belongs to a later PR; greedy
+    is what the eviction-exactness guarantee is stated for."""
+
+    def __init__(self, model, interpret: Optional[bool] = None,
                  split_pages: Optional[int] = None):
         from ..jit.functional import _collect_state
+        self.family = served_classes(model.cfg)[1](model)
         self.model = model
         model.eval()
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
         self.interpret = interpret
         # split-K width for the paged-attention kernel (None = the
         # kernel's VMEM-fit auto dispatch); rides into every compiled
@@ -126,6 +285,48 @@ class PagedGPTRunner:
 
         return _Swap()
 
+    @contextlib.contextmanager
+    def bound(self, weight_arrays=None):
+        """Bind the model's tensors to ``weight_arrays`` (default: the
+        current weights), without gradients: inside it the family's
+        step functions can be traced, or called eagerly."""
+        import jax
+        from ..framework import core
+        from ..framework import random as fr
+        if weight_arrays is None:
+            weight_arrays = self._weights()
+        with self._swapped(weight_arrays), core.no_grad(), \
+                fr.scoped_rng(jax.random.PRNGKey(0)):
+            yield
+
+    @staticmethod
+    def _sample(logits, counts):
+        """Greedy tokens ``[B]``; a family's counts ride behind them in
+        the SAME int32 array, so the one read-back brings both."""
+        import jax
+        import jax.numpy as jnp
+        with jax.named_scope("sample"):
+            tok = jax.lax.argmax(logits, logits.ndim - 1, jnp.int32)
+        if counts is None:
+            return tok
+        return jnp.concatenate([tok, counts.reshape(-1).astype(jnp.int32)])
+
+    def split_counts(self, out, n_rows: int):
+        """(tokens ``[n_rows]``, {count name: per-layer list}, experts
+        chosen ``[rows routed, expert layers, k]``) of a program's
+        read-back int32 array; (tokens, None, None) for a family that
+        routes nothing."""
+        out = np.asarray(out)
+        if out.shape[0] == n_rows:
+            return out, None, None
+        layers, k = self.family.routed
+        names = self.family.count_names
+        rec = out[n_rows:].reshape(layers, -1)
+        chosen = rec[:, len(names):].reshape(layers, -1, k)
+        return (out[:n_rows],
+                {name: rec[:, i].tolist() for i, name in enumerate(names)},
+                chosen.transpose(1, 0, 2))
+
     @property
     def num_decode_programs(self) -> int:
         return len(self._decode_programs)
@@ -140,39 +341,22 @@ class PagedGPTRunner:
         """The padded length ``prefill`` will key its program/cost by —
         the ONE authoritative key (callers must not re-derive it with a
         different ceiling, or cost lookups silently miss)."""
-        return self.pad_len(n, self.model.cfg.max_position_embeddings)
+        return self.pad_len(n, self.family.max_positions)
 
     def _build_prefill(self, padded_len: int):
         import jax
-        import jax.numpy as jnp
-        from ..framework import core
-        from ..framework import random as fr
-        from ..framework.tensor import Tensor
-        model = self.model
+        family = self.family
 
-        # the function's name is the HLO module's (jit_p2t_prefill);
-        # the named scopes are the model's own plus head_ce / sample /
-        # kv_write, as in the decode program below
+        # the function's name is the HLO module's (jit_p2t_prefill)
         def p2t_prefill(weight_arrays, ids, last_idx):
             # ids: [1, padded_len] int32; last_idx: int32 scalar index
             # of the real last token (causal masking makes the padded
             # tail invisible to every real row)
-            with self._swapped(weight_arrays), core.no_grad(), \
-                    fr.scoped_rng(jax.random.PRNGKey(0)):
-                n_layers = model.cfg.num_layers
-                hidden, caches = model.gpt.decode_step(
-                    Tensor(ids), [() for _ in range(n_layers)], 0)
-                with jax.named_scope("head_ce"):
-                    h_last = jnp.take_along_axis(
-                        hidden._data, last_idx.reshape(1, 1, 1), axis=1)
-                    logits = model._head(Tensor(h_last))
-            with jax.named_scope("sample"):
-                tok = jnp.argmax(logits._data[:, -1],
-                                 axis=-1).astype(jnp.int32)
-            with jax.named_scope("kv_write"):
-                k_stack = jnp.stack([c[0]._data[0] for c in caches])
-                v_stack = jnp.stack([c[1]._data[0] for c in caches])
-            return tok, k_stack, v_stack        # [L, padded_len, H, D]
+            with self.bound(weight_arrays):
+                logits, k_stack, v_stack, state, counts = family.prefill(
+                    ids, last_idx, self.interpret)
+            out = (self._sample(logits, counts), k_stack, v_stack)
+            return out if state is None else out + (state,)
 
         return jax.jit(p2t_prefill)
 
@@ -180,9 +364,11 @@ class PagedGPTRunner:
         """Pad one sequence's prompt, move it to the device and call
         its prefill program (built, inside a ``build`` span, on first
         use of the padded length). Returns (first token ``[1]`` still
-        on the device, k_stack, v_stack) with stacks ``[L, padded_len,
-        H, D]`` — the caller scatters rows ``[:len(token_ids)]`` into
-        blocks and reads the token back."""
+        on the device — with the family's counts behind it, see
+        :meth:`split_counts` —, k_stack, v_stack, and the family's
+        state where it keeps one) with stacks ``[attn layers,
+        padded_len, H_kv, D]`` — the caller scatters rows
+        ``[:len(token_ids)]`` into blocks and reads the token back."""
         import jax.numpy as jnp
         n = len(token_ids)
         padded = self.prefill_padded_len(n)
@@ -202,110 +388,81 @@ class PagedGPTRunner:
 
     def prefill(self, token_ids: List[int]):
         """:meth:`prefill_dispatch` with the first token read back:
-        (first_token:int, k_stack, v_stack)."""
-        tok, k_stack, v_stack = self.prefill_dispatch(token_ids)
-        return int(tok[0]), k_stack, v_stack
+        (first_token:int, k_stack, v_stack[, state])."""
+        out = self.prefill_dispatch(token_ids)
+        return (int(out[0][0]),) + tuple(out[1:])
 
     # -- decode ----------------------------------------------------------
     def _build_decode(self, batch: int, n_pages: int, block_size: int):
         import jax
-        import jax.numpy as jnp
-        from ..framework import core
-        from ..framework import random as fr
-        from ..framework.tensor import Tensor
-        model = self.model
-        nh, hd = self.num_heads, self.head_dim
+        family = self.family
 
         def p2t_decode(weight_arrays, k_pool, v_pool, ids, positions,
-                       block_tables):
+                       block_tables, *state_args):
             # ids [B,1] int32; positions [B] int32 (0-based slot of the
             # NEW token); block_tables [B,P] int32. Pools
-            # [L, N, bs, H*D], donated.
-            B = batch
-            phys = jnp.take_along_axis(
-                block_tables, (positions // block_size)[:, None],
-                axis=1)[:, 0]
-            slot = positions % block_size
-            ctx = positions + 1
-            scope = jax.named_scope     # GPTBlock.forward's names
-            with self._swapped(weight_arrays), core.no_grad(), \
-                    fr.scoped_rng(jax.random.PRNGKey(0)):
-                with scope("embed"):
-                    pos_t = Tensor(positions[:, None].astype(jnp.int32))
-                    x = model.gpt.wte(Tensor(ids)) + model.gpt.wpe(pos_t)
-                for li, block in enumerate(model.gpt.h):
-                    with scope("attn"):
-                        with scope("norm"):
-                            ln1 = block.ln_1(x)
-                        qkv = block.attn.qkv(ln1)
-                        # head-major fused split, as GPTAttention.forward
-                        qkv = qkv.reshape([B, 1, nh, 3, hd])
-                        q, k, v = qkv.unbind(axis=3)
-                    from .block_cache import PagedKVCache as _C
-                    with scope("kv_write"):
-                        k_pool = _C.scatter_decode(k_pool, li, phys, slot,
-                                                   k._data[:, 0])
-                        v_pool = _C.scatter_decode(v_pool, li, phys, slot,
-                                                   v._data[:, 0])
-                    with scope("attn"):
-                        # the whole pool rides in; the layer is an index
-                        # the kernel's copies take, never a sliced-out copy
-                        attn = paged_attention_decode(
-                            q._data, k_pool, v_pool, block_tables,
-                            ctx, interpret=self.interpret,
-                            pages_per_split=self.split_pages, layer=li)
-                        a = block.attn.out_proj(
-                            Tensor(attn.reshape(B, 1, nh * hd)))
-                        x = x + block.dropout(a)
-                    with scope("mlp"):
-                        with scope("norm"):
-                            ln2 = block.ln_2(x)
-                        x = x + block.dropout(block.mlp(ln2))
-                with scope("norm"):
-                    x = model.gpt.ln_f(x)
-                with scope("head_ce"):
-                    logits = model._head(x)
-            with scope("sample"):
-                tok = jnp.argmax(logits._data[:, -1],
-                                 axis=-1).astype(jnp.int32)
-            return tok, k_pool, v_pool
+            # [L, N, bs, H_kv*D], donated. A family with per-sequence
+            # state adds (state pool [Ls, slots+1, ...] donated, slots
+            # [B] int32).
+            state_pool, slots = state_args or (None, None)
+            with self.bound(weight_arrays):
+                logits, k_pool, v_pool, state_pool, counts = family.decode(
+                    k_pool, v_pool, state_pool, ids, positions,
+                    block_tables, slots, block_size, self.interpret,
+                    self.split_pages)
+            out = (self._sample(logits, counts), k_pool, v_pool)
+            return out if state_pool is None else out + (state_pool,)
 
-        return jax.jit(p2t_decode, donate_argnums=(1, 2))
+        donate = (1, 2) if family.state_shape is None else (1, 2, 6)
+        return jax.jit(p2t_decode, donate_argnums=donate)
 
     def kernel_pages_per_block(self, cache, n_pages: int) -> int:
         """Pages the paged kernel gathers per step in the decode
         program of this page bucket (count on ``decode.dispatch``)."""
         from .paged_attention import kernel_pages_per_block
+        family = self.family
         return kernel_pages_per_block(
-            n_pages, cache.k.shape[2], self.num_heads, self.head_dim,
-            cache.k.dtype, self.split_pages)
+            n_pages, cache.k.shape[2], family.num_heads, family.head_dim,
+            cache.k.dtype, self.split_pages, family.num_kv_heads)
 
-    def decode(self, cache, ids, positions, block_tables):
+    def _decode_args(self, cache, ids, positions, block_tables, slots):
+        import jax.numpy as jnp
+        args = (self._weights(), cache.k, cache.v,
+                jnp.asarray(ids, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
+                jnp.asarray(block_tables, jnp.int32))
+        if cache.state is not None:
+            args += (cache.state, jnp.asarray(slots, jnp.int32))
+        return args
+
+    def decode(self, cache, ids, positions, block_tables, slots=None):
         """One decode step over a bucketed batch: move it to the
         device, call its decode program (built, inside a ``build``
         span, on first use of the bucket), read the tokens back (span
         ``decode.readback``: the host waits out the step). ``cache`` is
         the :class:`~.block_cache.PagedKVCache` whose pools are donated
-        and replaced. Returns int32 next tokens ``[B]``."""
-        import jax.numpy as jnp
+        and replaced; ``slots`` are the rows' state slots where the
+        family keeps per-sequence state. Returns the program's int32
+        array: next tokens ``[B]``, then the family's counts
+        (:meth:`split_counts`)."""
         B, n_pages = block_tables.shape
         key = (B, n_pages)
-        args = (self._weights(), cache.k, cache.v,
-                jnp.asarray(ids, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(block_tables, jnp.int32))
+        args = self._decode_args(cache, ids, positions, block_tables, slots)
         fn = self._decode_programs.get(key)
         if fn is not None:
-            tok, cache.k, cache.v = fn(*args)
+            out = fn(*args)
         else:
             from ..observability.cost_model import abstractify, program_cost
             fn = self._decode_programs[key] = self._build_decode(
                 B, n_pages, cache.block_size)
             shapes = abstractify(args)      # the call donates the pools
             with _build_span("decode", f"{B}x{n_pages}") as b:
-                tok, cache.k, cache.v = fn(*args)
+                out = fn(*args)
                 with b.cost():
                     self._decode_costs[key] = program_cost(fn, shapes)
+        tok, cache.k, cache.v = out[:3]
+        if cache.state is not None:
+            cache.state = out[3]
         with _span("decode.readback"):
             return np.asarray(tok)
 
@@ -320,3 +477,8 @@ class PagedGPTRunner:
 
     def prefill_cost(self, padded_len: int) -> Optional[dict]:
         return self._prefill_costs.get(int(padded_len))
+
+
+# the name older callers know the runner by (the benchmark's
+# broken-path test patches ``PagedGPTRunner.decode``)
+PagedGPTRunner = PagedRunner

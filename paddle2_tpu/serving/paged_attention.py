@@ -269,18 +269,21 @@ def _tile_pad(n: int, tile: int) -> int:
     return -(-int(n) // tile) * tile
 
 
-def _lane_group(num_heads: int, head_dim: int):
-    """``(heads per group, query rows R, lane width W)`` of one page:
-    a 128-lane block carries ``128 // D`` heads; a ``D`` that is a
-    multiple of 128 is its own block; anything else takes the whole
-    ``H*D`` row (small test models)."""
+def _lane_group(num_heads: int, head_dim: int, num_q_heads: int = None):
+    """``(key/value heads per group, query rows R, lane width W)`` of
+    one page of ``num_heads`` key/value heads: a 128-lane block carries
+    ``128 // D`` heads; a ``D`` that is a multiple of 128 is its own
+    block; anything else takes the whole ``H*D`` row (small test
+    models). The rows are the group's query heads (``num_q_heads /
+    num_heads`` to a key/value head), padded to the 8-row tile."""
     if head_dim % 128 == 0:
         hg = 1
     elif 128 % head_dim == 0 and num_heads % (128 // head_dim) == 0:
         hg = 128 // head_dim
     else:
         hg = num_heads
-    return hg, _tile_pad(hg, 8), hg * head_dim
+    kv_group = (num_q_heads or num_heads) // num_heads
+    return hg, _tile_pad(hg * kv_group, 8), hg * head_dim
 
 
 def _page_vmem_bytes(block_size, width, dtype) -> int:
@@ -323,13 +326,18 @@ def _pages_per_block(n_pages: int, block_size: int, width: int,
 
 def decode_scratch_vmem_bytes(n_pages: int, block_size: int,
                               head_dim: int, dtype="float32",
-                              num_heads: int = None) -> int:
+                              num_heads: int = None,
+                              num_kv_heads: int = None) -> int:
     """VMEM scratch bytes that scale with the context, as the chip
     lays them out. With ``num_heads``: the single-softmax body, all
     heads at once — the lane-dense ``[R, tokens]`` f32 scores plus
     the context-resident V buffer ``[P, bs, H*D]`` (the K double
     buffer and V's spare block are one compute block each whatever
-    the context, and live in the other half of the VMEM).
+    the context, and live in the other half of the VMEM). With
+    ``num_kv_heads`` fewer than ``num_heads`` (grouped-query) the score
+    rows are the query heads' and the V rows ``H_kv*D`` wide: 256 pages
+    of 16 slots at 32 query over 8 key/value heads of 64 in bf16 are
+    256 x (32 x 16 x 4 + 16 x 512 x 2) = 4.5 MiB of the 8 MiB budget.
     Without ``num_heads``: the split body's scratch for one standard
     128-lane group (``R`` 8, ``W`` ``max(D, 128)``), which is what the
     planners price."""
@@ -338,24 +346,27 @@ def decode_scratch_vmem_bytes(n_pages: int, block_size: int,
                                     max(int(head_dim), 128), dtype)
     scores = _tile_pad(num_heads, 8) * int(block_size) * 4
     return int(n_pages) * (scores + _page_vmem_bytes(
-        block_size, num_heads * int(head_dim), dtype))
+        block_size, (num_kv_heads or num_heads) * int(head_dim), dtype))
 
 
 def fits_single_softmax(n_pages: int, block_size: int, head_dim: int,
                         dtype="float32", budget: int = None,
-                        num_heads: int = None) -> bool:
+                        num_heads: int = None,
+                        num_kv_heads: int = None) -> bool:
     """Can the global-softmax body serve this context at all? False
     at 32k: its whole-context scratch blows the VMEM budget — the
     feasibility half of the bench's 32k gate."""
     if budget is None:
         budget = VMEM_FIT_BUDGET
     return decode_scratch_vmem_bytes(n_pages, block_size, head_dim,
-                                     dtype, num_heads) <= budget
+                                     dtype, num_heads,
+                                     num_kv_heads) <= budget
 
 
 def auto_pages_per_split(n_pages: int, block_size: int, head_dim: int,
                          dtype="float32", budget: int = None,
-                         num_heads: int = None) -> int:
+                         num_heads: int = None,
+                         num_kv_heads: int = None) -> int:
     """Largest halving of ``n_pages`` whose per-split scratch — the
     split body's, for one lane group of ``num_heads`` x ``head_dim``
     (the standard group without ``num_heads``) — fits the VMEM budget
@@ -363,7 +374,8 @@ def auto_pages_per_split(n_pages: int, block_size: int, head_dim: int,
     if budget is None:
         budget = VMEM_FIT_BUDGET
     rows, width = ((8, max(int(head_dim), 128)) if num_heads is None
-                   else _lane_group(num_heads, head_dim)[1:])
+                   else _lane_group(num_kv_heads or num_heads, head_dim,
+                                    num_heads)[1:])
     pps = max(int(n_pages), 1)
     while pps > 1 and _group_scratch_bytes(
             pps, block_size, rows, width, dtype) > budget:
@@ -466,32 +478,43 @@ def _merge_splits(o_parts, m, l, out_dtype):
     return (o / l_safe[..., None]).astype(out_dtype)
 
 
-def _spread_query(q, hg: int, rows: int, dtype):
-    """``[B, H, D] -> [B, G, R, hg*D]``: row ``r`` of group ``g`` holds
-    head ``g*hg + r`` in lanes ``[r*D, (r+1)*D)`` and zeros elsewhere;
-    rows past ``hg`` are zero padding up to the 8-row tile."""
+def _spread_query(q, hg: int, rows: int, dtype, kv_group: int = 1):
+    """``[B, H, D] -> [B, G, R, hg*D]``: one lane group holds ``hg``
+    key/value heads and the ``hg * kv_group`` query heads that read
+    them; row ``r`` of group ``g`` holds query head ``g*hg*kv_group +
+    r`` in the lanes of ITS key/value head, ``[(r // kv_group)*D, ...
+    + D)``, and zeros elsewhere; rows past ``hg * kv_group`` are zero
+    padding up to the 8-row tile. ``kv_group`` 1 is multi-head
+    attention: row ``r`` in lanes ``[r*D, (r+1)*D)``."""
     B, H, D = q.shape
-    eye = jnp.eye(hg, dtype=q.dtype)[None, None, :, :, None]
-    spread = (q.reshape(B, H // hg, hg, 1, D) * eye).reshape(
-        B, H // hg, hg, hg * D)
-    return jnp.pad(spread, ((0, 0), (0, 0), (0, rows - hg),
+    hq = hg * kv_group
+    own = (jnp.arange(hq)[:, None] // kv_group
+           == jnp.arange(hg)[None, :]).astype(q.dtype)
+    spread = (q.reshape(B, H // hq, hq, 1, D)
+              * own[None, None, :, :, None]).reshape(
+        B, H // hq, hq, hg * D)
+    return jnp.pad(spread, ((0, 0), (0, 0), (0, rows - hq),
                             (0, 0))).astype(dtype)
 
 
-def _own_lanes(x, hg: int, head_dim: int):
+def _own_lanes(x, hg: int, head_dim: int, kv_group: int = 1):
     """Inverse of :func:`_spread_query` on a kernel output
-    ``[..., R, hg*D]``: keep row ``r``'s own lanes -> ``[..., hg, D]``."""
+    ``[..., R, hg*D]``: keep each row's own key/value head's lanes ->
+    ``[..., hg * kv_group, D]``."""
     lead = x.shape[:-2]
-    x = x[..., :hg, :].reshape(lead + (hg, hg, head_dim))
-    return jnp.diagonal(x, axis1=-3, axis2=-2).swapaxes(-1, -2)
+    x = x[..., :hg * kv_group, :].reshape(
+        lead + (hg, kv_group, hg, head_dim))
+    # [..., kv_group, D, hg] -> [..., hg, kv_group, D]
+    x = jnp.moveaxis(jnp.diagonal(x, axis1=-4, axis2=-2), -1, -3)
+    return x.reshape(lead + (hg * kv_group, head_dim))
 
 
 def _split_width(n_pages, block_size, num_heads, head_dim, dtype,
-                 pages_per_split):
+                 pages_per_split, num_kv_heads=None):
     """Pages per split; ``n_pages`` means the single-softmax body."""
     if pages_per_split is not None:
         return max(1, min(int(pages_per_split), n_pages))
-    fit = (block_size, head_dim, dtype, None, num_heads)
+    fit = (block_size, head_dim, dtype, None, num_heads, num_kv_heads)
     if fits_single_softmax(n_pages, *fit):
         return n_pages
     return auto_pages_per_split(n_pages, *fit)
@@ -499,15 +522,16 @@ def _split_width(n_pages, block_size, num_heads, head_dim, dtype,
 
 def kernel_pages_per_block(n_pages: int, block_size: int, num_heads: int,
                            head_dim: int, dtype,
-                           pages_per_split=None) -> int:
+                           pages_per_split=None,
+                           num_kv_heads: int = None) -> int:
     """Pages :func:`paged_attention_decode` gathers per step at these
     shapes: the compute block of the single-softmax body, 1 where it
     dispatches to split-K (one page per grid step)."""
     if _split_width(n_pages, block_size, num_heads, head_dim, dtype,
-                    pages_per_split) < n_pages:
+                    pages_per_split, num_kv_heads) < n_pages:
         return 1
-    return _pages_per_block(n_pages, block_size, num_heads * head_dim,
-                            dtype)
+    return _pages_per_block(n_pages, block_size,
+                            (num_kv_heads or num_heads) * head_dim, dtype)
 
 
 def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
@@ -517,9 +541,13 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
 
     q: ``[B, 1, H, D]`` (paddle layout) — one new token per sequence.
     k_pool/v_pool: the whole model's shared pools
-    ``[L, num_blocks, block_size, H*D]`` (a token's heads merged into
-    one row); ``layer`` names the (static) layer to read. A caller
-    holding one layer's pool passes ``pool[None]``.
+    ``[L, num_blocks, block_size, H_kv*D]`` (a token's key/value heads
+    merged into one row); ``layer`` names the (static) layer to read. A
+    caller holding one layer's pool passes ``pool[None]``. The pool's
+    width says how many key/value heads there are: with ``H_kv < H``
+    (grouped-query) query head ``h`` reads key/value head ``h // (H /
+    H_kv)`` — the same kernel bodies, whose query tile puts head ``h``
+    in that head's lanes.
     block_tables: int32 ``[B, n_pages]`` physical block ids per
     sequence (pad rows with the garbage block).
     ctx_lens: int32 ``[B]`` valid keys per sequence (including the
@@ -539,20 +567,23 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
         scale = 1.0 / math.sqrt(D)
     if interpret is None:
         interpret = interpret_default()
-    pps = _split_width(n_pages, bs, H, D, k_pool.dtype, pages_per_split)
+    Hkv = k_pool.shape[3] // D
+    pps = _split_width(n_pages, bs, H, D, k_pool.dtype, pages_per_split,
+                       Hkv)
     bt = jnp.asarray(block_tables, jnp.int32)
     ln = jnp.asarray(ctx_lens, jnp.int32)
     if pps < n_pages:
-        hg, rows, _ = _lane_group(H, D)
-        qr = _spread_query(q[:, 0], hg, rows, k_pool.dtype)
+        hg, rows, _ = _lane_group(Hkv, D, H)
+        qr = _spread_query(q[:, 0], hg, rows, k_pool.dtype, H // Hkv)
         out = _paged_decode_split(qr, k_pool, v_pool, bt, ln, layer,
-                                  float(scale), pps, hg, D, interpret)
+                                  float(scale), pps, hg, D, interpret,
+                                  H // Hkv)
         return out.astype(q.dtype)[:, None]
 
     return _decode_single(
         q, k_pool, v_pool, bt, ln, jnp.asarray(layer, jnp.int32),
         scale=float(scale), interpret=interpret,
-        ppb=_pages_per_block(n_pages, bs, H * D, k_pool.dtype))
+        ppb=_pages_per_block(n_pages, bs, Hkv * D, k_pool.dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb"))
@@ -565,10 +596,11 @@ def _decode_single(q, k_pool, v_pool, bt, ln, layer, *, scale, interpret,
     B, _, H, D = q.shape
     bs = k_pool.shape[2]
     n_pages = bt.shape[1]
-    # all heads ride ONE query tile: row h holds head h in its own
-    # lanes of the merged H*D row
-    rows, width = _tile_pad(H, 8), H * D
-    qr = _spread_query(q[:, 0], H, rows, k_pool.dtype)[:, 0]
+    # all heads ride ONE query tile: row h holds query head h in the
+    # lanes of its key/value head of the merged H_kv*D row
+    Hkv = k_pool.shape[3] // D
+    rows, width = _tile_pad(H, 8), Hkv * D
+    qr = _spread_query(q[:, 0], Hkv, rows, k_pool.dtype, H // Hkv)[:, 0]
     n_blocks = -(-n_pages // ppb)
 
     def tile():
@@ -601,12 +633,12 @@ def _decode_single(q, k_pool, v_pool, bt, ln, layer, *, scale, interpret,
         interpret=interpret,
         name="paged_decode",
     )(bt, ln, layer.reshape(1), qr, k_pool, v_pool)
-    # [B, R, H*D] -> own lanes [B, H, D] -> [B, 1, H, D]
-    return _own_lanes(out, H, D).reshape(B, 1, H, D)
+    # [B, R, H_kv*D] -> own lanes [B, H, D] -> [B, 1, H, D]
+    return _own_lanes(out, Hkv, D, H // Hkv).reshape(B, 1, H, D)
 
 
 def _paged_decode_split(qr, k_pool, v_pool, bt, ln, layer, scale, pps,
-                        hg, head_dim, interpret):
+                        hg, head_dim, interpret, kv_group=1):
     """Split-K driver: pad the table out to whole splits, run the
     flash-decode body per (batch, lane group, split), merge the
     partials in one tiny jitted XLA reduction. Returns ``[B, H, D]``
@@ -660,10 +692,11 @@ def _paged_decode_split(qr, k_pool, v_pool, bt, ln, layer, scale, pps,
         name="paged_decode_split",
     )(bt, ln, qr, k_pool, v_pool)
     # per-head partials: [B, G, S, R, *] -> [B, H, S, *]
-    heads = B, groups * hg, n_splits
-    o_parts = jnp.swapaxes(_own_lanes(o_parts, hg, head_dim), 2, 3) \
-        .reshape(heads + (head_dim,))
-    m, l = (jnp.swapaxes(x[:, :, :, :hg, 0], 2, 3).reshape(heads)
+    hq = hg * kv_group
+    heads = B, groups * hq, n_splits
+    o_parts = jnp.swapaxes(_own_lanes(o_parts, hg, head_dim, kv_group),
+                           2, 3).reshape(heads + (head_dim,))
+    m, l = (jnp.swapaxes(x[:, :, :, :hq, 0], 2, 3).reshape(heads)
             for x in (m, l))
     return _merge_split_jit("float32")(o_parts, m, l)
 
@@ -675,9 +708,10 @@ def _merge_split_jit(out_dtype: str):
 
 
 def gathered_dense_kv(pool, block_tables, num_heads: int):
-    """Dense ``[B, n_pages*block_size, H, D]`` view of every
+    """Dense ``[B, n_pages*block_size, H_kv, D]`` view of every
     sequence's K or V through its block table (one vectorized gather
-    over one layer's ``[N, bs, H*D]`` pool)."""
+    over one layer's ``[N, bs, H_kv*D]`` pool; ``num_heads`` is the
+    pool's key/value-head count)."""
     g = pool[jnp.asarray(block_tables, jnp.int32)]   # [B, P, bs, H*D]
     return g.reshape(g.shape[0], -1, num_heads,
                      g.shape[-1] // num_heads)
@@ -692,7 +726,9 @@ _REF_CACHE: dict = {}
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
                               scale=None):
-    """Dense reference over ONE layer's ``[N, bs, H*D]`` pool: gather
+    """Dense reference over ONE layer's ``[N, bs, H_kv*D]`` pool (its
+    width says how many key/value heads; query head ``h`` reads
+    key/value head ``h // (H / H_kv)``): gather
     K/V through the block table, then the single-softmax body's op
     sequence — per (sequence, head) single-row 2-D dots, ``finfo.min``
     pad mask, one ``jax.nn.softmax(f32)`` — compiled as ONE jitted
@@ -774,13 +810,15 @@ def _split_partials_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
     """Dense mirror of the split kernel's per-(batch, head, split)
     partial computation: returns ``(o_parts [B,H,S,D] f32,
     m [B,H,S] f32, l [B,H,S] f32)``."""
-    kd = gathered_dense_kv(k_pool, block_tables, H)  # [B, S_pad, H, D]
-    vd = gathered_dense_kv(v_pool, block_tables, H)
+    D = q.shape[-1]
+    Hkv = k_pool.shape[-1] // D
+    g = H // Hkv
+    kd = gathered_dense_kv(k_pool, block_tables, Hkv)  # [B,S_pad,Hkv,D]
+    vd = gathered_dense_kv(v_pool, block_tables, Hkv)
     prec = _precision(q.dtype)
     bs = k_pool.shape[1]
     n_pages = block_tables.shape[1]
     n_splits = -(-n_pages // pps)
-    D = q.shape[-1]
     all_o, all_m, all_l = [], [], []
     for b in range(B):
         heads_o, heads_m, heads_l = [], [], []
@@ -800,7 +838,7 @@ def _split_partials_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
                         continue
                     lo = jg * bs
                     s = jax.lax.dot_general(
-                        q[b, :, h], kd[b, lo:lo + bs, h],
+                        q[b, :, h], kd[b, lo:lo + bs, h // g],
                         (((1,), (1,)), ((), ())),
                         precision=prec) * scale       # (1, bs)
                     valid = (jnp.arange(bs) + lo) < ctx_lens[b]
@@ -811,8 +849,8 @@ def _split_partials_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
                     dead = jnp.asarray(lo, jnp.int32) >= ctx_lens[b]
                     cols.append(jnp.where(dead, NEG_INF, s))
                     vals.append(jnp.where(
-                        dead, jnp.zeros_like(vd[b, lo:lo + bs, h]),
-                        vd[b, lo:lo + bs, h]))
+                        dead, jnp.zeros_like(vd[b, lo:lo + bs, h // g]),
+                        vd[b, lo:lo + bs, h // g]))
                 s = jnp.concatenate(cols, axis=1)     # (1, S_split) f32
                 v = jnp.concatenate(vals, axis=0)     # (S_split, D)
                 m = jnp.max(s, axis=1, keepdims=True)
@@ -837,8 +875,10 @@ def _split_partials_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
 
 def _reference_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
                     scale, B, H):
-    kd = gathered_dense_kv(k_pool, block_tables, H)  # [B, S_pad, H, D]
-    vd = gathered_dense_kv(v_pool, block_tables, H)
+    Hkv = k_pool.shape[-1] // q.shape[-1]
+    g = H // Hkv
+    kd = gathered_dense_kv(k_pool, block_tables, Hkv)  # [B,S_pad,Hkv,D]
+    vd = gathered_dense_kv(v_pool, block_tables, Hkv)
     prec = _precision(q.dtype)
     s_pad = kd.shape[1]
     out = []
@@ -847,13 +887,13 @@ def _reference_impl(q, k_pool, v_pool, block_tables, ctx_lens, *,
         heads = []
         for h in range(H):
             s = jax.lax.dot_general(
-                q[b, :, h], kd[b, :, h], (((1,), (1,)), ((), ())),
+                q[b, :, h], kd[b, :, h // g], (((1,), (1,)), ((), ())),
                 precision=prec) * scale              # (1, S_pad)
             s = jnp.where(valid[None, :], s, jnp.finfo(s.dtype).min)
             p = jax.nn.softmax(s.astype(jnp.float32),
                                axis=-1).astype(q.dtype)
             heads.append(jax.lax.dot_general(
-                p, vd[b, :, h], (((1,), (0,)), ((), ())),
+                p, vd[b, :, h // g], (((1,), (0,)), ((), ())),
                 precision=prec))                     # (1, D)
         out.append(jnp.stack(heads, axis=1))         # (1, H, D)
     return jnp.stack(out)                            # (B, 1, H, D)

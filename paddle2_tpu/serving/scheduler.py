@@ -112,6 +112,11 @@ class Sequence:
         # earliest stamp migrated KV is on-device (a failover
         # migration's DCN transfer completes here; admission waits)
         self.kv_ready_t = 0.0
+        # a routed family's record: the experts chosen for each token
+        # the model was fed, [positions, expert layers, k] in pieces (a
+        # prefill's, then one a decode step); ``ServingEngine.
+        # routed_experts`` joins them
+        self.routed: List = []
 
     def check(self) -> "Sequence":
         """Raise the typed error a post-submission failure recorded
@@ -374,8 +379,8 @@ class ContinuousBatchingScheduler:
                 need_tokens + 1, self.allocator.block_size) - len(cached)
             if spent and spent + need_tokens > budget:
                 break                      # budget spent: next round
-            if not self.allocator.can_allocate(need_blocks):
-                break                      # head-of-line until blocks free
+            if not self.allocator.can_admit(need_blocks):
+                break       # head-of-line until blocks (or a slot) free
             self.waiting.pop(0)
             seq.prefix_cached_tokens = 0
             seq.kv_fetched_host = 0

@@ -214,6 +214,77 @@ def test_paged_decode_single_softmax_at_vmem_budget(one_chip):
         _compile(one_chip, _paged(over), *_paged_avals(2, 8192, over))
 
 
+def test_paged_decode_grouped_query_cell_shape(one_chip):
+    """What `lfm2moe-serve-doc3k-backlog` runs: 32 query heads over 8
+    key/value heads of 64 (512-lane pages, a [32, 512] query tile), ONE
+    decode program of 64 rows x 256 pages (4096 positions) over a
+    16,384-block pool of the model's one attention layer. The
+    accounting — 256 x (32 rows x 16 slots x 4 B scores + 16 x 512 x 2 B
+    of V) = 4.5 MiB of the 8 MiB budget — keeps it on the single-softmax
+    body, and the chip's compiler takes it inside the scoped limit."""
+    assert pa.decode_scratch_vmem_bytes(256, 16, 64, BF16, 32, 8) == \
+        256 * (32 * 16 * 4 + 16 * 512 * 2)
+    assert pa.fits_single_softmax(256, 16, 64, BF16, None, 32, 8)
+    assert pa.kernel_pages_per_block(256, 16, 32, 64, BF16,
+                                     num_kv_heads=8) == 64
+    pool = ((1, 16384, 16, 8 * 64), BF16)
+    avals = (((64, 1, 32, 64), BF16), pool, pool,
+             ((64, 256), jnp.int32), ((64,), jnp.int32))
+    fn = functools.partial(pa.paged_attention_decode, interpret=False,
+                           layer=0)
+    text = _compile(one_chip, fn, *avals, kernels=["paged_decode"]).as_text()
+    assert "paged_decode_split" not in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[1,16384,16,512]" in ln]
+
+
+# ------------------------------------------------------- grouped matmul
+@pytest.mark.parametrize("rows,k,n", [
+    (256, 2048, 1536), (256, 1536, 2048),       # a 64-row decode step
+    (12288, 2048, 1536), (12288, 1536, 2048),   # a 3072-token prefill
+    (4096, 2048, 1536)])
+def test_moe_gmm_cell_shapes(one_chip, rows, k, n):
+    """The dropless expert layer's grouped matmul at LFM2-24B-A2B's
+    widths: all 64 experts held (and a 65th group where skipped rows
+    are parked), bf16, both projections, decode and prefill rows."""
+    from paddle2_tpu.kernels import moe_gmm
+
+    def fn(lhs, rhs, sizes, first):
+        return moe_gmm.moe_gmm(lhs, rhs, sizes, first, interpret=False)
+
+    _compile(one_chip, fn, ((rows, k), BF16), ((64, k, n), BF16),
+             ((65,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
+
+
+def test_moe_gmm_held_share(one_chip):
+    """Eight of the 64 experts held: the chip's share of a deployment
+    that divides a layer's experts over eight chips."""
+    from paddle2_tpu.kernels import moe_gmm
+
+    def fn(lhs, rhs, sizes, first):
+        return moe_gmm.moe_gmm(lhs, rhs, sizes, first, interpret=False)
+
+    _compile(one_chip, fn, ((2048, 2048), BF16), ((8, 2048, 1536), BF16),
+             ((65,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
+
+
+@pytest.mark.parametrize("rows", [64, 3072])
+def test_rmsnorm_and_rope_at_lfm2_shapes(one_chip, rows):
+    """The norm and rotary kernels as the LFM2-MoE programs call them:
+    a decode step's 64 rows and a 3072-token prefill, hidden 2048 in
+    bf16 with a float32 gain; q of 32 heads and k of 8 heads of 64."""
+    def norm(x, w):
+        return pallas_fused._rmsnorm(x, w, 1e-5, 512, False)
+
+    _compile(one_chip, norm, ((rows, 2048), BF16), ((2048,), F32),
+             kernels=["rmsnorm_fwd"])
+    for heads in (32, 8):
+        def rope(x, c, s):
+            return pallas_fused.fused_rope(x, c, s, interpret=False)
+        _compile(one_chip, rope, ((1, rows, heads, 64), BF16),
+                 ((rows, 64), F32), ((rows, 64), F32), kernels=["rope"])
+
+
 # ---------------------------------------------------------------- fused
 def test_fused_adamw_step(one_chip):
     """One [1024, 4096] f32 leaf (an MLP weight's master copy)."""

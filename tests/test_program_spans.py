@@ -301,8 +301,10 @@ def test_scopes_and_spans_change_no_arithmetic(traced, monkeypatch):
 def scope_in(text: str, scope: str) -> bool:
     """A scope of the program somewhere in an op's name-stack path, as
     it is or wrapped by a transformation: ``/attn/``, ``jvp(attn)``,
-    ``transpose(jvp(attn))``."""
-    return re.search(r"[/(\"]" + scope + r"[/)]", text) is not None
+    ``transpose(jvp(attn))``; or at the path's end, where the scope
+    holds one op that lowers to a call (``.../sample"``), which the
+    trace reader's ``_SCOPE_TOKEN`` takes too."""
+    return re.search(r"[/(\"]" + scope + r"[/)\"]", text) is not None
 
 
 @pytest.fixture(scope="module")

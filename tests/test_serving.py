@@ -295,7 +295,7 @@ def test_block_table_append_and_padding():
 
 def test_paged_cache_scatter_gather_roundtrip():
     cache = PagedKVCache(num_layers=2, num_blocks=8, block_size=4,
-                         num_heads=2, head_dim=4)
+                         num_kv_heads=2, head_dim=4)
     rng = np.random.default_rng(0)
     kv = jnp.asarray(rng.normal(size=(2, 7, 2, 4)), jnp.float32)
     row = np.asarray([3, 5], np.int64)
