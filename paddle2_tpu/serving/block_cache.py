@@ -424,6 +424,11 @@ class PagedKVCache:
     is the padded rows' garbage slot). Slots are handed out by the
     :class:`BlockAllocator`.
 
+    ``tokens`` (``token_rows`` wide, the widest decode batch) is the
+    last decode step's output tokens, kept on the device: the next step
+    takes a row's input token from it where the host has not read that
+    step back yet (``PagedRunner.decode``).
+
     Pools start zeroed; stale data in freed blocks and slots is
     harmless — the paged-attention kernel masks every slot past a
     sequence's context length (masked probabilities are exactly 0.0 in
@@ -432,7 +437,9 @@ class PagedKVCache:
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype="float32",
-                 state_shape=None, state_slots: int = 0):
+                 state_shape=None, state_slots: int = 0,
+                 token_rows: int = 1):
+        import jax
         import jax.numpy as jnp
         self.num_layers = int(num_layers)
         self.num_blocks = int(num_blocks)
@@ -444,6 +451,12 @@ class PagedKVCache:
                  num_kv_heads * head_dim)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
+        # committed to its device, as every later step's output is: an
+        # uncommitted first value would give the first decode call a
+        # signature of its own, and the second a compilation
+        self.tokens = jax.device_put(
+            jnp.zeros((int(token_rows),), jnp.int32),
+            next(iter(self.k.devices())))
         self.state = None
         if state_shape is not None:
             self.state = jnp.zeros(

@@ -3,9 +3,11 @@ family the runner knows (``model_runner.served_classes``: GPT, LFM2-MoE).
 
 The production serving loop (ROADMAP item 2): requests come in via
 ``submit()``, the engine prefills them into paged KV blocks, and every
-``decode_once()`` runs ONE bucketed compiled decode step over the
-whole running batch — admissions and evictions happen between steps
-(iteration-level scheduling). Construct it from a live model
+``decode_once()`` enqueues ONE bucketed compiled decode step over the
+whole running batch, one call ahead of the host: it reads back the
+step of the call before it AFTER enqueueing its own — admissions and
+evictions happen between steps (iteration-level scheduling).
+Construct it from a live model
 (``GPTForCausalLM``, ``Lfm2MoeForCausalLM``) or from a ``jit.save``'d
 artifact (the artifact's
 weights are loaded into a rebuilt architecture — the exported forward
@@ -54,6 +56,47 @@ def _pow2_ladder(lo: int, hi: int) -> Tuple[int, ...]:
         v *= 2
     out.append(hi)
     return tuple(sorted(set(out)))
+
+
+class _Step:
+    """One decode step from its enqueueing to its delivery: who rode in
+    it (``active``, in row order), what was sent (``arrays``, ``counts``
+    for the span), and ``out``, the program's int32 array, on the
+    device until the step is read back. ``kept[i]`` falls when sequence
+    i is evicted or requeued while the step is in flight: its token is
+    then dropped, not delivered."""
+
+    __slots__ = ("now", "active", "drafts", "bucket", "arrays", "counts",
+                 "out", "kept", "_rows", "_epochs")
+
+    def __init__(self, now, active, drafts, bucket, arrays, counts):
+        self.now, self.active, self.drafts = now, active, drafts
+        self.bucket, self.arrays, self.counts = bucket, arrays, counts
+        self.out = None
+        self._rows, row = [], 0
+        for s in active:
+            self._rows.append(row)
+            row += 1 + len(drafts.get(id(s), ()))
+        self.kept = [True] * len(active)
+        self._epochs = [(s.evictions, s.recoveries) for s in active]
+
+    def drop_moved(self) -> int:
+        """Mark the rows whose sequence is no longer where the step
+        left it (running, never evicted or rebuilt since); how many
+        fell now."""
+        fell = 0
+        for i, s in enumerate(self.active):
+            if self.kept[i] and not (
+                    s.state is SeqState.RUNNING
+                    and (s.evictions, s.recoveries) == self._epochs[i]):
+                self.kept[i] = False
+                fell += 1
+        return fell
+
+    def flying(self) -> Dict[int, int]:
+        """{id(sequence): its row} of the rows still kept."""
+        return {id(s): r for s, r, k in
+                zip(self.active, self._rows, self.kept) if k}
 
 
 @dataclass
@@ -152,18 +195,6 @@ class ServingEngine:
         if self.config.weight_only_lm_head:
             from ..quantization import quantize_lm_head
             quantize_lm_head(model)
-        # two kinds of state, one manager: paged blocks for the layers
-        # that keep keys and values, and (where the family has layers
-        # with a fixed-size state) one slot per running sequence
-        slots = self.config.max_batch if family.state_shape else 0
-        self.cache = PagedKVCache(
-            family.attn_layers, self.config.num_blocks,
-            self.config.block_size, family.num_kv_heads, family.head_dim,
-            dtype=self.config.kv_dtype, state_shape=family.state_shape,
-            state_slots=slots)
-        self.allocator = BlockAllocator(self.config.num_blocks,
-                                        self.config.block_size,
-                                        state_slots=slots)
         max_pages = blocks_for_tokens(self.max_model_len,
                                       self.config.block_size)
         # a speculative verify round rides k extra rows per sequence
@@ -192,6 +223,20 @@ class ServingEngine:
                           or _pow2_ladder(1, max_pages)),
             prefill_budget_tokens=self.config.prefill_budget_tokens,
             reliability=self.config.reliability)
+        # two kinds of state, one manager: paged blocks for the layers
+        # that keep keys and values, and (where the family has layers
+        # with a fixed-size state) one slot per running sequence; the
+        # last step's tokens stay on the device as wide as the widest
+        # decode batch
+        slots = self.config.max_batch if family.state_shape else 0
+        self.cache = PagedKVCache(
+            family.attn_layers, self.config.num_blocks,
+            self.config.block_size, family.num_kv_heads, family.head_dim,
+            dtype=self.config.kv_dtype, state_shape=family.state_shape,
+            state_slots=slots, token_rows=sched_cfg.batch_buckets[-1])
+        self.allocator = BlockAllocator(self.config.num_blocks,
+                                        self.config.block_size,
+                                        state_slots=slots)
         self.scheduler = ContinuousBatchingScheduler(sched_cfg,
                                                      self.allocator)
         self.prefix_cache: Optional[PrefixCache] = None
@@ -215,6 +260,14 @@ class ServingEngine:
         self._next_req_id = 0
         self._seqs: Dict[int, Sequence] = {}
         self.decode_steps = 0
+        # the decode step enqueued and not read back yet (decode_once),
+        # and its tokens dropped since the last dispatch span
+        self._ahead: Optional[_Step] = None
+        self._dropped_ahead = 0
+        # steps enqueued with the one before un-read, and tokens in
+        # flight thrown away by an eviction, a requeue or a failure
+        self.ahead_steps = 0
+        self.ahead_dropped = 0
         # failure plane: set by fail() (chaos kill_engine, an operator
         # kill, a poisoned device) — a failed engine refuses all work
         # and its in-flight sequences are harvested for failover
@@ -379,6 +432,7 @@ class ServingEngine:
         from ..observability import metrics
         if self.failed:
             return
+        self._drop_ahead()          # the device's state is lost
         self.failed = True
         self.fail_reason = reason
         self.failed_t = now
@@ -405,6 +459,7 @@ class ServingEngine:
             raise EngineFailedError(
                 "recover_inflight is only valid on a failed engine "
                 "(a healthy engine's sequences are still being served)")
+        self._drop_ahead()
         running = list(self.scheduler._running)
         waiting = [s for s in self.scheduler.waiting
                    if s.state is SeqState.WAITING]
@@ -475,6 +530,9 @@ class ServingEngine:
         affected request's trace."""
         from ..observability import metrics
         self._check_alive()
+        # the step in flight ran with the old weights: its tokens are
+        # delivered before the swap is stamped
+        self._flush_ahead()
         arrays = weights
         if hasattr(weights, "state_dict"):       # a live model
             from ..jit.functional import _collect_state
@@ -694,31 +752,89 @@ class ServingEngine:
 
     # -- one decode step -------------------------------------------------
     def decode_once(self, now: float = 0.0) -> Optional[dict]:
-        """Run ONE compiled decode step over every running sequence
-        whose prefill has completed (``ready_at <= now``). Returns a
-        step info dict, or None when nothing is ready. Raises
-        :class:`~.reliability.EngineFailedError` when the engine is
-        (or chaos makes it) dead."""
+        """Enqueue ONE compiled decode step over every running sequence
+        whose prefill has completed (``ready_at <= now``), THEN read
+        back and deliver the step the call before this one enqueued:
+        the device works on step n+1 while the host emits step n and
+        selects step n+2. A row's input token is the one thing the host
+        lacks for the next step (a sequence ends by length alone), and
+        that is taken on the device from the step in flight. So a call
+        delivers the tokens of the step before it, the first call after
+        an empty engine delivers none, and :meth:`idle` counts the step
+        in flight.
+
+        Where the next step's inputs DO need the host to have seen the
+        tokens (:meth:`_reads_back_first`) the step is read back in the
+        call that enqueued it. Returns a step info dict (``bucket``,
+        ``n_active``, ``cost`` of the step ``dispatched`` by this call,
+        else of the one delivered; ``tokens`` delivered; ``evictions``),
+        or None when nothing was enqueued or delivered. Raises
+        :class:`~.reliability.EngineFailedError` when the engine is (or
+        chaos makes it) dead."""
         self._check_alive()
         # host spans of one tick (profiler.span; PERF.md lists them):
-        # decode holds select -> build_batch -> dispatch (holding
-        # readback) -> emit; a tick with nothing ready ends inside
-        # select
+        # decode holds select -> build_batch -> dispatch (holding the
+        # readback of the step BEFORE) -> emit; a tick with nothing
+        # ready and nothing in flight ends inside select
         with _span("decode"):
-            with _span("decode.select"):
-                picked = self._select_decode_rows(now)
-            if picked is None:
+            ahead = self._ahead
+            sync = self._reads_back_first()
+            picked = None
+            if ahead is None or not sync:
+                with _span("decode.select"):
+                    picked = self._select_decode_rows(now, ahead)
+            if picked is None and ahead is None:
                 return None
-            return self._decode_rows(now, *picked)
+            return self._decode_rows(now, picked, ahead, sync)
 
-    def _select_decode_rows(self, now: float):
-        """Who decodes this tick: the ready running sequences whose
-        tables validate and whose next slots (drafts included) could
-        be reserved. Returns (active, drafts, victims) or None."""
+    def _reads_back_first(self) -> bool:
+        """Must a step be read back before the next is selected? Yes
+        where the next step's inputs are a function of its tokens: a
+        draft is drawn from the token log, and an armed chaos hook on
+        the step discards it and repeats it (``drop_decode_step``) or
+        kills the engine on a count of delivered steps."""
+        from ..distributed.fault_tolerance import chaos
+        if self.config.spec is not None:
+            return True
+        armed = chaos.active()
+        return armed is not None and (armed.armed("drop_decode_step")
+                                      or armed.armed("kill_engine"))
+
+    def _drop_ahead(self) -> None:
+        """Throw the step in flight away (the engine's device state is
+        lost, or its sequences leave): no token of it reaches a log."""
+        step, self._ahead = self._ahead, None
+        if step is not None:
+            self._count_dropped(sum(step.kept))
+
+    def _count_dropped(self, n: int) -> None:
+        self._dropped_ahead += n        # for the next dispatch span
+        self.ahead_dropped += n
+
+    def _flush_ahead(self) -> None:
+        """Deliver the step in flight now, for a caller whose next act
+        must not overtake it."""
+        if self._ahead is not None:
+            with _span("decode"):
+                self._decode_rows(self._ahead.now, None, self._ahead, True)
+
+    def _select_decode_rows(self, now: float, ahead: "Optional[_Step]"):
+        """Who decodes next: the ready running sequences whose tables
+        validate and whose next slots (drafts included) could be
+        reserved. A row of the step in flight (``ahead``) stands one
+        token further than its log says; one whose token in flight is
+        its last is not selected. Returns (active, drafts, victims,
+        {id(sequence): its row in ``ahead``}) or None."""
         from ..distributed.fault_tolerance import chaos
         from ..observability import metrics
+        flying = {}
+        if ahead is not None:
+            self._count_dropped(ahead.drop_moved())
+            flying = ahead.flying()
         active = [s for s in self.scheduler.running()
-                  if getattr(s, "ready_at", 0.0) <= now]
+                  if getattr(s, "ready_at", 0.0) <= now
+                  and not (id(s) in flying and len(s.generated) + 1
+                           >= s.request.max_new_tokens)]
         if not active:
             return None
         # chaos scribbles land BEFORE validation — the validator must
@@ -732,7 +848,8 @@ class ServingEngine:
         active = self._validate_tables(active, now=now)
         if not active:
             return None
-        victims = self.scheduler.reserve_decode_slots(active, now=now)
+        victims = self.scheduler.reserve_decode_slots(
+            active, now=now, slots=[1 + (id(s) in flying) for s in active])
         if victims:
             # counted HERE, not after the step: evicting every ready
             # sequence aborts the step below, and those evictions must
@@ -780,82 +897,121 @@ class ServingEngine:
                           if k in {id(s) for s in active}}
             if not active:
                 return None
-        return active, drafts, victims
+        return active, drafts, victims, flying
 
-    def _decode_rows(self, now: float, active: List[Sequence],
-                     drafts: Dict[int, List[int]],
-                     victims: list) -> dict:
-        from ..observability import metrics
+    def _build_step(self, now: float, active: List[Sequence],
+                    drafts: Dict[int, List[int]], victims: list,
+                    flying: Dict[int, int]) -> "_Step":
+        """The next step's arrays. A row of the step in flight
+        (``flying``: its row there) takes its input token on the device
+        (id ``-1 - row`` of that step) and stands one position past its
+        table's count."""
         cfg = self.scheduler.config
-        with _span("decode.build_batch"):
-            rows = []                      # (seq, token, position)
-            ctx_tokens = 0
-            for s in active:
-                p0 = s.num_cached
-                ctx_tokens += p0
-                rows.append((s, s.tokens[p0], p0))
-                for i, d in enumerate(drafts.get(id(s), ())):
-                    rows.append((s, d, p0 + 1 + i))
-            # pages that hold a key of some row (a row at position p
-            # attends over p + 1 keys): what the kernel moves, against
-            # the rows x page_bucket table it is handed
-            live_pages = sum(blocks_for_tokens(pos + 1,
-                                               self.config.block_size)
-                             for _, _, pos in rows)
-            b_bucket = cfg.batch_bucket(len(rows))
-            p_bucket = self.scheduler.decode_bucket(active)[1]
-            kernel_ppb = self.runner.kernel_pages_per_block(self.cache,
-                                                            p_bucket)
-            ids = np.zeros((b_bucket, 1), np.int32)
-            positions = np.zeros((b_bucket,), np.int32)
-            tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
-            # state slots of the rows (0: the padded rows' garbage slot)
-            slots = None if self.cache.state is None \
-                else np.zeros((b_bucket,), np.int32)
-            slot_counts = {}
-            for i, (s, tok_in, pos) in enumerate(rows):
-                ids[i, 0] = tok_in
-                positions[i] = pos
-                tables[i] = s.table.padded(p_bucket)
-                if slots is not None:
-                    slots[i] = s.table.state_slot
+        rows = []                      # (seq, token or -1 - row, position)
+        ctx_tokens = 0
+        for s in active:
+            row = flying.get(id(s))
+            p0 = s.num_cached + (row is not None)
+            ctx_tokens += p0
+            rows.append((s, s.tokens[p0] if row is None else -1 - row, p0))
+            for i, d in enumerate(drafts.get(id(s), ())):
+                rows.append((s, d, p0 + 1 + i))
+        # pages that hold a key of some row (a row at position p
+        # attends over p + 1 keys): what the kernel moves, against
+        # the rows x page_bucket table it is handed
+        live_pages = sum(blocks_for_tokens(pos + 1, self.config.block_size)
+                         for _, _, pos in rows)
+        b_bucket = cfg.batch_bucket(len(rows))
+        p_bucket = self.scheduler.decode_bucket(active)[1]
+        ids = np.zeros((b_bucket, 1), np.int32)
+        positions = np.zeros((b_bucket,), np.int32)
+        tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
+        # state slots of the rows (0: the padded rows' garbage slot)
+        slots = None if self.cache.state is None \
+            else np.zeros((b_bucket,), np.int32)
+        for i, (s, tok_in, pos) in enumerate(rows):
+            ids[i, 0] = tok_in
+            positions[i] = pos
+            tables[i] = s.table.padded(p_bucket)
             if slots is not None:
-                slot_counts = dict(
-                    state_slots_in_use=self.allocator.state_slots_used,
-                    state_slots_total=self.allocator.state_slots)
-        # runner.decode is the one call (H2D, the program, and inside
-        # it the decode.readback span in which the host waits)
-        with metrics.phase("compute"), \
-                _span("decode.dispatch", rows=len(rows),
-                      row_bucket=b_bucket, page_bucket=p_bucket,
-                      ctx_tokens=ctx_tokens, live_pages=live_pages,
-                      kernel_pages_per_block=kernel_ppb,
-                      blocks_in_use=self.allocator.used_count,
-                      blocks_total=self.config.num_blocks,
-                      evicted=len(victims), **slot_counts) as sp:
-            state_args = () if slots is None else (slots,)
-            toks, counts, chosen = self.runner.split_counts(
-                self.runner.decode(self.cache, ids, positions, tables,
-                                   *state_args), b_bucket)
-            if counts:
-                sp.set_metadata(**self._count_stats(counts))
-        with _span("decode.emit"):
-            return self._emit_decoded(now, active, drafts, victims,
-                                      len(rows), (b_bucket, p_bucket),
-                                      toks, chosen)
+                slots[i] = s.table.state_slot
+        counts = dict(
+            rows=len(rows), row_bucket=b_bucket, page_bucket=p_bucket,
+            ctx_tokens=ctx_tokens, live_pages=live_pages,
+            kernel_pages_per_block=self.runner.kernel_pages_per_block(
+                self.cache, p_bucket),
+            blocks_in_use=self.allocator.used_count,
+            blocks_total=self.config.num_blocks, evicted=len(victims))
+        if slots is not None:
+            counts.update(
+                state_slots_in_use=self.allocator.state_slots_used,
+                state_slots_total=self.allocator.state_slots)
+        return _Step(now, active, drafts, (b_bucket, p_bucket),
+                     (ids, positions, tables)
+                     + (() if slots is None else (slots,)), counts)
 
-    def _emit_decoded(self, now: float, active: List[Sequence],
-                      drafts: Dict[int, List[int]], victims: list,
-                      n_rows_total: int, bucket: Tuple[int, int],
-                      toks, chosen=None) -> dict:
+    def _decode_rows(self, now: float, picked, ahead: "Optional[_Step]",
+                     sync: bool) -> Optional[dict]:
+        """Enqueue the step ``picked`` (if any), then read back and
+        emit the step to deliver: the one in flight, else — where the
+        next may not run ahead of it (``sync``) — the one just
+        enqueued."""
+        from ..observability import metrics
+        if ahead is not None:
+            # evicted or requeued since it was enqueued (this call's
+            # selection included): the token is dropped, the re-prefill
+            # from the token log computes it again, exactly
+            self._count_dropped(ahead.drop_moved())
+        step = None
+        victims = []
+        if picked is not None:
+            victims = picked[2]
+            with _span("decode.build_batch"):
+                step = self._build_step(now, *picked)
+        due = ahead if ahead is not None else (step if sync else None)
+        dropped, self._dropped_ahead = self._dropped_ahead, 0
+        # runner.decode is the one call that enqueues (H2D and the
+        # program); decode.readback, in which the host waits for the
+        # step BEFORE it, nests here; the routing counts that arrive
+        # with it describe the step read
+        with metrics.phase("compute"), \
+                _span("decode.dispatch", **(step.counts if step else {}),
+                      ahead=int(step is not None and ahead is not None),
+                      dropped_ahead=dropped) as sp:
+            if step is not None:
+                step.out = self.runner.decode(self.cache, *step.arrays)
+                if ahead is not None:
+                    self.ahead_steps += 1
+                    metrics.inc("serving_decode_ahead_total")
+            self._ahead = None if step is due else step
+            if due is not None:
+                with _span("decode.readback"):
+                    toks, counts, chosen = self.runner.split_counts(
+                        due.out, due.bucket[0])
+                if counts:
+                    sp.set_metadata(**self._count_stats(counts))
+        about = step or due
+        info = {"bucket": about.bucket, "n_active": len(about.active),
+                "tokens": 0, "evictions": len(victims),
+                "spec_accepted": 0, "spec_rejected": 0,
+                "dispatched": step is not None,
+                "cost": self.runner.decode_cost(about.bucket)}
+        if due is not None:
+            with _span("decode.emit"):
+                info.update(self._emit_decoded(due, toks, chosen))
+        return info
+
+    def _emit_decoded(self, step: "_Step", toks, chosen=None) -> dict:
         """Append the step's tokens (and, for a routed family, the
-        experts ``chosen`` for each row's input token), finish what is
-        done, count."""
+        experts ``chosen`` for each row's input token) to the logs of
+        the rows that kept their place, finish what is done, count.
+        Stamps are the step's own: its tokens exist at ITS end."""
         from ..distributed.fault_tolerance import chaos
         from ..observability import metrics
         cfg = self.scheduler.config
-        b_bucket, p_bucket = bucket
-        cost = self.runner.decode_cost((b_bucket, p_bucket))
+        now, active, drafts = step.now, step.active, step.drafts
+        b_bucket, p_bucket = step.bucket
+        cost = self.runner.decode_cost(step.bucket)
         modeled_s = None
         if cost and "flops" in cost:
             from ..observability.cost_model import StepCost
@@ -882,10 +1038,7 @@ class ServingEngine:
                            chaos="drop_decode_step",
                            step=self.decode_steps + 1)
             self.decode_steps += 1
-            return {"bucket": (b_bucket, p_bucket),
-                    "n_active": len(active), "tokens": 0,
-                    "evictions": len(victims), "dropped": True,
-                    "cost": cost}
+            return {"tokens": 0, "dropped": True}
         # tokens exist at the step's END: finishing at `now` would cut
         # the final step's cost out of the virtual-clock makespan and
         # overstate the benched tokens/s
@@ -895,14 +1048,17 @@ class ServingEngine:
                        t=now, dur=modeled_s or 0.0,
                        tids=step_tids or None,
                        step=self.decode_steps, batch=len(active),
-                       rows=n_rows_total if drafts else None,
+                       rows=step.counts["rows"] if drafts else None,
                        bucket=[b_bucket, p_bucket])
         emitted_total = 0
         accepted_total = 0
         rejected_total = 0
         ri = 0
-        for s in active:
+        for i, s in enumerate(active):
             n_rows = 1 + len(drafts.get(id(s), ()))
+            if not step.kept[i]:
+                ri += n_rows            # evicted or requeued meanwhile
+                continue
             outs = [int(toks[ri + j]) for j in range(n_rows)]
             if chosen is not None:
                 s.routed.append(chosen[ri:ri + 1])
@@ -933,11 +1089,6 @@ class ServingEngine:
         if rejected_total:
             metrics.inc("serving_spec_rejected_total", rejected_total)
             self.spec_rejected += rejected_total
-        info = {"bucket": (b_bucket, p_bucket), "n_active": len(active),
-                "tokens": emitted_total, "evictions": len(victims),
-                "spec_accepted": accepted_total,
-                "spec_rejected": rejected_total,
-                "cost": cost}
         metrics.inc("serving_decode_tokens_total", emitted_total)
         self._gauge()
         extra = {"serving": 1,
@@ -945,7 +1096,8 @@ class ServingEngine:
         if modeled_s is not None:
             extra["modeled_step_s"] = modeled_s
         metrics.step_end(tokens=emitted_total, **extra)
-        return info
+        return {"tokens": emitted_total, "spec_accepted": accepted_total,
+                "spec_rejected": rejected_total}
 
     def tick(self, now: float = 0.0) -> Optional[dict]:
         """Convenience round for live serving: admissions then one
@@ -1016,4 +1168,6 @@ class ServingEngine:
                                            self.max_model_len)
 
     def idle(self) -> bool:
-        return not self.scheduler.waiting and not self.scheduler.running()
+        """Nothing queued, nothing running, no step in flight."""
+        return not self.scheduler.waiting \
+            and not self.scheduler.running() and self._ahead is None

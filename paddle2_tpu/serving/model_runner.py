@@ -18,6 +18,9 @@ functions compiled with ``jax.jit``:
 * the family's step functions return LOGITS; sampling (greedy) is the
   runner's thin wrapper around them, so a test can call the family's
   step under :meth:`PagedRunner.bound` and compare logits;
+* a decode step's tokens also stay ON THE DEVICE (``cache.tokens``),
+  where the next step can take a row's input from them: the engine
+  enqueues step n+1 before it has read step n back;
 * builds run inside ``build`` spans and leave cost records.
 
 A family supplies its cache geometry (how many layers keep keys and
@@ -314,7 +317,8 @@ class PagedRunner:
     def split_counts(self, out, n_rows: int):
         """(tokens ``[n_rows]``, {count name: per-layer list}, experts
         chosen ``[rows routed, expert layers, k]``) of a program's
-        read-back int32 array; (tokens, None, None) for a family that
+        int32 array, which is READ BACK here (the host waits for the
+        step that made it); (tokens, None, None) for a family that
         routes nothing."""
         out = np.asarray(out)
         if out.shape[0] == n_rows:
@@ -395,25 +399,36 @@ class PagedRunner:
     # -- decode ----------------------------------------------------------
     def _build_decode(self, batch: int, n_pages: int, block_size: int):
         import jax
+        import jax.numpy as jnp
         family = self.family
 
-        def p2t_decode(weight_arrays, k_pool, v_pool, ids, positions,
+        def p2t_decode(weight_arrays, k_pool, v_pool, fed, ids, positions,
                        block_tables, *state_args):
             # ids [B,1] int32; positions [B] int32 (0-based slot of the
             # NEW token); block_tables [B,P] int32. Pools
             # [L, N, bs, H_kv*D], donated. A family with per-sequence
             # state adds (state pool [Ls, slots+1, ...] donated, slots
-            # [B] int32).
+            # [B] int32). fed [R] int32: the tokens of the step before,
+            # never read by the host so far; an id below zero is a row
+            # of them (-1 - row) and not a token.
             state_pool, slots = state_args or (None, None)
+            with jax.named_scope("embed"):
+                taken = fed[jnp.clip(-1 - ids, 0, fed.shape[0] - 1)]
+                ids = jnp.where(ids < 0, taken, ids)
             with self.bound(weight_arrays):
                 logits, k_pool, v_pool, state_pool, counts = family.decode(
                     k_pool, v_pool, state_pool, ids, positions,
                     block_tables, slots, block_size, self.interpret,
                     self.split_pages)
-            out = (self._sample(logits, counts), k_pool, v_pool)
+            tok = self._sample(logits, counts)
+            with jax.named_scope("sample"):
+                # one width whatever the bucket, so that any program
+                # can follow any other without a new signature
+                fed = jnp.pad(tok[:batch], (0, fed.shape[0] - batch))
+            out = (tok, fed, k_pool, v_pool)
             return out if state_pool is None else out + (state_pool,)
 
-        donate = (1, 2) if family.state_shape is None else (1, 2, 6)
+        donate = (1, 2) if family.state_shape is None else (1, 2, 7)
         return jax.jit(p2t_decode, donate_argnums=donate)
 
     def kernel_pages_per_block(self, cache, n_pages: int) -> int:
@@ -427,7 +442,7 @@ class PagedRunner:
 
     def _decode_args(self, cache, ids, positions, block_tables, slots):
         import jax.numpy as jnp
-        args = (self._weights(), cache.k, cache.v,
+        args = (self._weights(), cache.k, cache.v, cache.tokens,
                 jnp.asarray(ids, jnp.int32),
                 jnp.asarray(positions, jnp.int32),
                 jnp.asarray(block_tables, jnp.int32))
@@ -437,14 +452,17 @@ class PagedRunner:
 
     def decode(self, cache, ids, positions, block_tables, slots=None):
         """One decode step over a bucketed batch: move it to the
-        device, call its decode program (built, inside a ``build``
-        span, on first use of the bucket), read the tokens back (span
-        ``decode.readback``: the host waits out the step). ``cache`` is
-        the :class:`~.block_cache.PagedKVCache` whose pools are donated
-        and replaced; ``slots`` are the rows' state slots where the
-        family keeps per-sequence state. Returns the program's int32
-        array: next tokens ``[B]``, then the family's counts
-        (:meth:`split_counts`)."""
+        device and call its decode program (built, inside a ``build``
+        span, on first use of the bucket). NOTHING is read back: the
+        step is enqueued and the call returns. ``cache`` is the
+        :class:`~.block_cache.PagedKVCache` whose pools are donated and
+        replaced, and whose ``tokens`` (the step's tokens, kept on the
+        device) feed the next step: an entry of ``ids`` below zero is
+        ``-1 - row`` of the step BEFORE this one and not a token id.
+        ``slots`` are the rows' state slots where the family keeps
+        per-sequence state. Returns the program's int32 array, still on
+        the device: next tokens ``[B]``, then the family's counts
+        (:meth:`split_counts` reads it back)."""
         B, n_pages = block_tables.shape
         key = (B, n_pages)
         args = self._decode_args(cache, ids, positions, block_tables, slots)
@@ -460,11 +478,10 @@ class PagedRunner:
                 out = fn(*args)
                 with b.cost():
                     self._decode_costs[key] = program_cost(fn, shapes)
-        tok, cache.k, cache.v = out[:3]
+        tok, cache.tokens, cache.k, cache.v = out[:4]
         if cache.state is not None:
-            cache.state = out[3]
-        with _span("decode.readback"):
-            return np.asarray(tok)
+            cache.state = out[4]
+        return tok
 
     # -- deterministic cost accounting (PR 7 cost model) -----------------
     @staticmethod

@@ -217,10 +217,13 @@ def simulate_serving(engine, trace: List[dict],
 
         step = engine.decode_once(decode_clock)
         if step is not None:
-            decode_clock += cost_seconds(step["cost"])
-            rep.modeled_flops += (step["cost"] or {}).get("flops", 0.0)
-            occupancy.append(step["n_active"]
-                             / engine.scheduler.config.max_batch)
+            # a call that only delivered the step in flight put nothing
+            # on the device: its step was charged when it was enqueued
+            if step["dispatched"]:
+                decode_clock += cost_seconds(step["cost"])
+                rep.modeled_flops += (step["cost"] or {}).get("flops", 0.0)
+                occupancy.append(step["n_active"]
+                                 / engine.scheduler.config.max_batch)
         else:
             # nothing ready: jump to the next event (arrival or a
             # prefill completing on its lane)
@@ -779,6 +782,7 @@ def simulate_router(router: EngineFailoverRouter, trace: List[dict],
         if on_round is not None:
             on_round(router, clock, round_idx)
         costs = []
+        delivered = False
         for idx in router.alive():
             eng = router.engines[idx]
             try:
@@ -789,10 +793,15 @@ def simulate_router(router: EngineFailoverRouter, trace: List[dict],
                 step = eng.decode_once(clock)
             except EngineFailedError:
                 continue            # died this round; next probe sees it
-            if step is not None:
+            if step is not None and step["dispatched"]:
                 costs.append(cost_seconds(step["cost"]))
                 rep.modeled_flops += (step["cost"] or {}).get(
                     "flops", 0.0)
+            elif step is not None:
+                # only delivered the step in flight (charged when it
+                # was enqueued): what that freed is admitted in a
+                # round at this same instant
+                delivered = True
         router.note_recovery(clock)
         if not router.alive():
             # total fleet death: nothing can ever serve the remainder
@@ -806,7 +815,7 @@ def simulate_router(router: EngineFailoverRouter, trace: List[dict],
             break
         if costs:
             clock += max(costs)
-        else:
+        elif not delivered:
             # legible stall diagnosis (simulate_serving's twin): an
             # idle engine whose head-of-line prompt needs more blocks
             # than its whole pool holds can never make progress
@@ -907,6 +916,7 @@ def simulate_predictor_baseline(engine, trace: List[dict]
     b1_cost = runner._cost_of(b1, (
         [aval(tuple(t.shape), t._data.dtype) for t in runner._state],
         aval(shape, engine.cache.dtype), aval(shape, engine.cache.dtype),
+        aval(engine.cache.tokens.shape, "int32"),
         aval((1, 1), "int32"), aval((1,), "int32"),
         aval((1, max_pages), "int32")))
     decode_s = cost_seconds(b1_cost)

@@ -82,6 +82,9 @@ _RELIABILITY_COUNTERS = (
     "serving_prefix_hits_total", "serving_prefix_misses_total",
     "serving_prefix_hit_blocks_total",
     "serving_spec_accepted_total", "serving_spec_rejected_total",
+    # decode steps enqueued before the step before them was read back
+    # (PR 28): against the step count, how often the host ran ahead
+    "serving_decode_ahead_total",
     # fleet-global KV ladder (ISSUE 16): tier traffic — a spill surge
     # is HBM cache pressure, a host/peer-fetch surge is the pressure
     # being absorbed (fetch, not recompute), migrated blocks are

@@ -77,7 +77,13 @@ def ref_logits(bench, params, seq):
 @pytest.fixture
 def logit_tap(monkeypatch):
     """Every logits array the runner's sampling wrapper is handed, in
-    call order, without a new engine flag."""
+    call order, without a new engine flag. ``serve`` pairs a call of
+    ``decode_once`` with the logits of the step it ran, so the engine
+    is held to reading every step back in the call that enqueued it —
+    by its own rule: an armed drop hook (which never fires here)."""
+    from paddle2_tpu.distributed.fault_tolerance import chaos
+    monkeypatch.setattr(chaos, "_ACTIVE",
+                        chaos.ChaosInjector("drop_decode_step:1000000000"))
     store = []
     sample = PagedRunner._sample
 

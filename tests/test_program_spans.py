@@ -158,7 +158,8 @@ def test_counts_equal_the_engines_own_info(traced):
     assert 0 < first["blocks_in_use"] <= 32
     assert set(first) == {"rows", "row_bucket", "page_bucket", "ctx_tokens",
                           "live_pages", "kernel_pages_per_block",
-                          "blocks_in_use", "blocks_total", "evicted"}
+                          "blocks_in_use", "blocks_total", "evicted",
+                          "ahead", "dropped_ahead"}
     steps = [s[3]["built"] for s in traced["spans"] if s[0] == "train.step"]
     assert steps == [1, 0]
 
@@ -335,9 +336,9 @@ def lowered_serving_texts():
     engine = tiny_engine()
     runner, cache = engine.runner, engine.cache
     decode = runner._build_decode(2, 2, cache.block_size)
-    dec = decode.lower(
-        runner._weights(), cache.k, cache.v, jnp.zeros((2, 1), jnp.int32),
-        jnp.zeros((2,), jnp.int32), jnp.zeros((2, 2), jnp.int32))
+    dec = decode.lower(*runner._decode_args(
+        cache, jnp.zeros((2, 1), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jnp.zeros((2, 2), jnp.int32), None))
     prefill = runner._build_prefill(16)
     pre = prefill.lower(runner._weights(), jnp.zeros((1, 16), jnp.int32),
                         jnp.asarray(4, jnp.int32))
