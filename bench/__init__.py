@@ -1,19 +1,14 @@
-"""Shared bench-lane machinery (ISSUE 17).
+"""The drills behind ``bench.py`` (the registry's command line).
 
-``bench.py`` at the repo root stays the CLI entry point; this package
-holds what the lanes share so each lane stops re-implementing it:
-
-- :mod:`bench.artifact` — stderr logging, the session scratch dir, the
-  byte-identical artifact writer every lane's tail used to copy-paste,
-  and the run-twice determinism check.
-- :mod:`bench.scenarios` — the declarative scenario registry (the
-  proof-of-concept slice of ROADMAP item 2): a scenario declares model
-  + parallelism + trace + gates, the runner supplies artifact emission
-  and gate evaluation.
+- :mod:`bench.artifact` — stderr logging, the session scratch dir and
+  the one artifact writer.
+- :mod:`bench.scenarios` — the scenario registry: a scenario declares
+  model + parallelism + trace + gates, the runner supplies artifact
+  emission and gate evaluation.
+- ``bench/artifacts/`` — the artifacts the drills write; ``README.md``
+  here says what the committed copies are for.
 """
 
-from .artifact import (artifact_bytes, bench_scratch, emit_result, log,
-                       runs_identical, write_artifact)
+from .artifact import artifact_path, bench_scratch, log, write_artifact
 
-__all__ = ["artifact_bytes", "bench_scratch", "emit_result", "log",
-           "runs_identical", "write_artifact"]
+__all__ = ["artifact_path", "bench_scratch", "log", "write_artifact"]

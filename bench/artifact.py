@@ -1,16 +1,21 @@
-"""Byte-identical artifact emission shared by every bench lane.
-
-Each lane used to end with the same hand-copied tail: print the result
-as one JSON line, write the ``*_r01.json`` artifact with ``indent=2``,
-log the failing gate subset, return 0/1. Ten copies drifted in small
-ways (one printed the whole gates dict on failure, one checked a
-pre-computed ``ok``); this module is the single implementation, plus
-the run-twice determinism check CI's ``cmp`` performs across processes.
+"""What every drill shares: stderr logging, the session scratch dir for
+metric/trace streams, and the writer of the ``*_r01.json`` artifacts
+under ``bench/artifacts/``.
 """
 
 import json
 import os
 import sys
+
+ARTIFACT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "artifacts")
+
+
+def artifact_path(filename):
+    """Where a drill's artifact is written: the one directory that also
+    holds the committed copies (``bench/README.md`` says what those are
+    for)."""
+    return os.path.join(ARTIFACT_DIR, filename)
 
 
 def log(*a):
@@ -43,55 +48,17 @@ def bench_scratch(name, env_var=None):
     return os.path.join(_SCRATCH_ROOT, name)
 
 
-def artifact_bytes(result, indent=2, sort_keys=False):
-    """The exact bytes :func:`write_artifact` puts on disk — the unit
-    CI's ``cmp`` compares, so determinism checks must hash THIS, not a
-    re-serialization with different options."""
-    return json.dumps(result, indent=indent,
-                      sort_keys=sort_keys).encode()
-
-
 def write_artifact(path, result, indent=2, sort_keys=False,
                    trailing_newline=False):
-    """Write the lane artifact; unwritable cwd (read-only CI mount) is
-    tolerated because the stdout JSON line already carries the result."""
+    """Write the lane artifact; an unwritable directory (read-only CI
+    mount) is tolerated because the stdout JSON line already carries
+    the result."""
     try:
         with open(path, "w") as f:
-            f.write(artifact_bytes(result, indent=indent,
-                                   sort_keys=sort_keys).decode())
+            f.write(json.dumps(result, indent=indent,
+                               sort_keys=sort_keys))
             if trailing_newline:
                 f.write("\n")
     except OSError:
         return False
     return True
-
-
-def emit_result(lane, artifact, result, gates=None):
-    """The shared lane tail: stdout JSON line, artifact file, gate
-    verdict. ``gates`` defaults to ``result["gates"]``. Returns the
-    process exit code (0 all gates passed / 1 any failed)."""
-    if gates is None:
-        gates = result.get("gates", {})
-    print(json.dumps(result))
-    write_artifact(artifact, result)
-    if not gates and "ok" in result:
-        # legacy lanes gate on one precomputed verdict, not a dict
-        gates = {"ok": bool(result["ok"])}
-    if gates and not all(gates.values()):
-        log(f"{lane}: GATE FAILURE "
-            f"{ {k: v for k, v in gates.items() if not v} }")
-        return 1
-    log(f"{lane}: all gates passed")
-    return 0
-
-
-def runs_identical(build, n=2, **artifact_opts):
-    """Run ``build()`` ``n`` times and require every run's artifact
-    bytes identical — the in-process twin of CI's run-twice-and-cmp.
-    Returns (identical, first_result)."""
-    first = build()
-    ref = artifact_bytes(first, **artifact_opts)
-    for _ in range(n - 1):
-        if artifact_bytes(build(), **artifact_opts) != ref:
-            return False, first
-    return True, first

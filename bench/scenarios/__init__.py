@@ -1,7 +1,5 @@
-"""Declarative bench-scenario registry (ROADMAP item 2, seed slice).
-
-Importing this package registers every scenario module; ``bench.py``
-dispatches CLI flags through :func:`run_scenario`.
+"""The drill registry. Importing this package registers every scenario
+module; ``bench.py`` resolves ``--<name>`` through :func:`run`.
 """
 
 from .registry import REGISTRY, Scenario, get, register, run
@@ -19,8 +17,11 @@ from . import sdc                   # noqa: F401
 from . import elastic               # noqa: F401
 from . import reliable_step         # noqa: F401
 from . import single_chip_speed     # noqa: F401
+from . import serving               # noqa: F401
+from . import serving_throughput    # noqa: F401
+from . import multichip_scaling     # noqa: F401
+from . import inject_fault          # noqa: F401
+from . import guardrails            # noqa: F401
+from . import flight_recorder       # noqa: F401
 
-run_scenario = run
-
-__all__ = ["REGISTRY", "Scenario", "get", "register", "run",
-           "run_scenario"]
+__all__ = ["REGISTRY", "Scenario", "get", "register", "run"]

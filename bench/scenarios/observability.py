@@ -1,11 +1,6 @@
-"""Scenario: the ``--observability`` metrics/cost-model triage lane.
-
-Ported byte-for-byte from ``bench.py::bench_observability`` onto the
-scenario registry (ISSUE 20 satellite): the body below is the original
-lane — only the tail changed from print-and-return to returning the
-result dict, which :func:`bench.artifact.emit_result` prints as the
-SAME stdout JSON line (and now also writes ``OBSERVABILITY_r01.json``).
-The verdict rides the legacy precomputed ``ok`` key (``gates=()``).
+"""Scenario: the ``--observability`` metrics/cost-model triage lane
+(artifact ``OBSERVABILITY_r01.json``). The verdict is the result's
+top-level ``ok`` key.
 """
 
 import os
@@ -177,6 +172,7 @@ SCENARIO = registry.register(registry.Scenario(
            "optimizer": "AdamW"},
     parallelism={},
     trace={"chaos": "stall_collective:6:2.0"},
-    gates=(),          # legacy lane: verdict is the precomputed "ok"
+    gates=("breakdown_sums_exact", "host_residual_nonnegative",
+           "cost_model_flops_exact", "ok"),
     streams={},
 ))

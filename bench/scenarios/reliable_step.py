@@ -1,15 +1,7 @@
-"""Scenario: the ``--reliable-step`` instrumented-train-step lane.
-
-Ported byte-for-byte from ``bench.py::bench_reliable_step`` onto the
-scenario registry (ISSUE 19 satellite, continuing the ROADMAP item 2
-lane migration): the body below is the original lane — only two things
-changed. The tail went from print-and-return to returning the result
-dict, which :func:`bench.artifact.emit_result` prints as the SAME
-stdout JSON line (and now also writes ``RELIABLE_STEP_r01.json``); and
-the warm-cache restart subprocess's ``PYTHONPATH`` is computed three
-directories up (this module lives in ``bench/scenarios/``, the
-original lived at the repo root). The verdict rides the legacy
-precomputed ``ok`` key (``gates=()``).
+"""Scenario: the ``--reliable-step`` instrumented-train-step lane
+(artifact ``RELIABLE_STEP_r01.json``). The verdict is the result's
+top-level ``ok`` key; the warm-cache drill records real compile
+seconds, so two runs' artifacts differ by design.
 """
 
 import json
@@ -208,6 +200,8 @@ SCENARIO = registry.register(registry.Scenario(
            "optimizer": "AdamW"},
     parallelism={"replicas": 1},
     trace={"chaos": "poison_loss:5", "steps": 16},
-    gates=(),          # legacy lane: verdict is the precomputed "ok"
+    gates=("clean_path_bitwise_transparent", "nan_recovery_bitwise",
+           "ok"),
     streams={},
+    deterministic=False,
 ))

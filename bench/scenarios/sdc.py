@@ -1,11 +1,6 @@
-"""Scenario: the ``--sdc`` silent-data-corruption defense lane.
-
-Ported byte-for-byte from ``bench.py::bench_sdc`` onto the scenario
-registry (ISSUE 18 satellite): the body below is the original lane —
-only the tail changed from print-and-return to returning the result
-dict, which :func:`bench.artifact.emit_result` prints as the SAME
-stdout JSON line (and now also writes ``SDC_r01.json``). The verdict
-rides the legacy precomputed ``ok`` key (``gates=()``).
+"""Scenario: the ``--sdc`` silent-data-corruption defense lane
+(artifact ``SDC_r01.json``). The verdict is the result's top-level
+``ok`` key.
 """
 
 import os
@@ -251,6 +246,8 @@ SCENARIO = registry.register(registry.Scenario(
            "optimizer": "AdamW"},
     parallelism={"replicas": 3},
     trace={"chaos": "flip_bits:grads:2:1"},
-    gates=(),          # legacy lane: verdict is the precomputed "ok"
+    gates=("detected_within_1_step", "replay_clean",
+           "replicas_bitwise_equal_after_recovery", "ok"),
     streams={},
+    deterministic=False,
 ))

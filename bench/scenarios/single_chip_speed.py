@@ -1,13 +1,5 @@
-"""Scenario: the ``--single-chip-speed`` raw-speed lane.
-
-Ported byte-for-byte from ``bench.py::bench_single_chip_speed`` onto
-the scenario registry (ISSUE 19 satellite, continuing the ROADMAP
-item 2 lane migration): the body below is the original lane — only the
-tail changed from calling ``emit_result`` directly to returning the
-result dict, which :func:`bench.scenarios.registry.run` feeds through
-the SAME ``emit_result`` (same stdout JSON line, same byte-identical
-``SPEED_r01.json``), now with the ten gate names DECLARED so a drifted
-implementation fails loudly.
+"""Scenario: the ``--single-chip-speed`` raw-speed lane (artifact
+``SPEED_r01.json``), modeled under pinned v5e rates.
 """
 
 import json
@@ -98,7 +90,7 @@ def build(scenario):
 
     def step_phases(remat_policy, int8_head, fused_opt):
         """The symmetric three-phase model. Accounting:
-        * matmul — the repo's own FLOPs convention (bench_gpt):
+        * matmul — the repo's own FLOPs convention:
           tokens x (6 n_params + 12 L T H); HBM = 3 weight passes
           (fwd/dgrad/wgrad) + the activation census written forward and
           re-read backward. int8_head runs the lm_head logits matmul

@@ -1,12 +1,6 @@
-"""Scenario: the ``--tracing`` request-lifecycle attribution lane.
-
-Ported byte-for-byte from ``bench.py::bench_tracing`` onto the
-scenario registry (ISSUE 20 satellite): the drills, gates, streams,
-stdout JSON line and ``TRACING_r01.json`` artifact bytes are all
-unchanged — only the tail changed from ``emit_result(...)`` to
-returning the result dict (the registry runner emits it through the
-SAME ``emit_result``), and the two stream scratch dirs now come
-through ``scenario.streams`` (same env vars, same CI pins).
+"""Scenario: the ``--tracing`` request-lifecycle attribution lane
+(artifact ``TRACING_r01.json``); its two stream scratch dirs come
+through ``scenario.streams``.
 """
 
 import os

@@ -5,8 +5,8 @@
 and through the public API only:
 
 * the trainer — GPT-2-medium widths (hidden 1024, 16 heads x 64, FFN
-  4096, 24 layers, seq 1024, batch 8, vocab 32768) built exactly as
-  ``bench.bench_gpt`` builds it (bf16 ``amp.decorate(level="O2")``,
+  4096, 24 layers, seq 1024, batch 8, vocab 32768) in bf16
+  (``amp.decorate(level="O2")``,
   ``AdamW(multi_precision=True)``, ``recompute_granularity="dots"``,
   stacked blocks, fused head+CE) and stepped through
   ``paddle.jit.train_step`` on distinct seeded batches;
@@ -114,7 +114,7 @@ def skewed_batch(rs, batch: int, seq: int, vocab: int):
 
 
 def build_trainer(size: dict, tensor_parallel: bool = False):
-    """The model/optimizer/step of ``bench.bench_gpt``."""
+    """The GPT trainer's model, optimizer and compiled step."""
     import paddle2_tpu as paddle
     import paddle2_tpu.optimizer as opt
     from paddle2_tpu.models import GPTConfig, GPTForCausalLM

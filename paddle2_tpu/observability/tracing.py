@@ -27,9 +27,9 @@ gates this on every finished request of the PR 11 chaos drills.
 Clock discipline (the PR 9/11 posture): time enters ONLY through the
 caller-supplied ``t=`` stamps. The discrete-event simulators pass
 their virtual cost-model clock — traces, decompositions, and the
-``TRACING_r01.json`` artifact are bit-stable across runs — while a
-live engine passes wall clock and gets the same span tree with real
-timestamps.
+``bench/artifacts/TRACING_r01.json`` artifact are bit-stable across
+runs — while a live engine passes wall clock and gets the same span
+tree with real timestamps.
 
 Overhead contract (the metrics/flight_recorder discipline): when the
 plane is off, every module-level hook is ONE module-attribute load

@@ -1,7 +1,7 @@
 """Deterministic discrete-event serving simulation (cost x rate).
 
-Wall clocks are banned from every perf gate in this repo (gVisor/CI
-sandboxes make them noise), so the serving bench drives the REAL
+This module's figures are modeled and never a device metric (the chip
+is measured by ``benchmark/``). The serving drills drive the REAL
 engine — real scheduler, real paged blocks, real compiled decode
 programs producing real tokens — under a VIRTUAL clock: each decode
 step advances time by the step's modeled cost (XLA ``cost_analysis``

@@ -2,6 +2,8 @@
 hierarchical + adaptive + transducer losses, beam-search decode,
 flashmask/sparse attention. Reference files cited per test."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -10,14 +12,18 @@ import paddle2_tpu.nn as nn
 import paddle2_tpu.nn.functional as F
 
 
+REF = "/root/reference/python/paddle"
+
+
 def test_nn_namespace_parity_is_complete():
     """Every name in the reference's nn / nn.functional __all__ exists."""
     import re
     for mod_name, path in [
-            ("paddle2_tpu.nn",
-             "/root/reference/python/paddle/nn/__init__.py"),
+            ("paddle2_tpu.nn", f"{REF}/nn/__init__.py"),
             ("paddle2_tpu.nn.functional",
-             "/root/reference/python/paddle/nn/functional/__init__.py")]:
+             f"{REF}/nn/functional/__init__.py")]:
+        if not os.path.isfile(path):
+            pytest.skip(f"the reference's {path} is not on this host")
         ref = open(path).read()
         m = re.search(r"__all__ = \[(.*?)\]", ref, re.S)
         names = set(re.findall(r"['\"](\w+)['\"]", m.group(1)))

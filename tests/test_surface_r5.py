@@ -5,6 +5,7 @@ detection ops (roi_pool/prior_box/yolo_box/matrix_nms/yolo_loss),
 ASGD/Rprop, saved_tensors_hooks. Namespace parity pinned against the
 reference __all__ lists."""
 
+import os
 import re
 
 import numpy as np
@@ -29,6 +30,8 @@ REF = "/root/reference/python/paddle"
 ])
 def test_namespace_parity(mod, path):
     import importlib
+    if not os.path.isfile(path):
+        pytest.skip(f"the reference's {path} is not on this host")
     ref = open(path).read()
     m = re.search(r"__all__\s*=\s*\[(.*?)\]", ref, re.S)
     names = set(re.findall(r"['\"](\w+)['\"]", m.group(1)))
