@@ -427,7 +427,9 @@ class PagedKVCache:
     ``tokens`` (``token_rows`` wide, the widest decode batch) is the
     last decode step's output tokens, kept on the device: the next step
     takes a row's input token from it where the host has not read that
-    step back yet (``PagedRunner.decode``).
+    step back yet (``PagedRunner.decode``). ``firsts``, as wide, holds
+    the first tokens of prefills the host has not read back yet
+    (:meth:`keep_first`): the same step takes those there too.
 
     Pools start zeroed; stale data in freed blocks and slots is
     harmless — the paged-attention kernel masks every slot past a
@@ -454,9 +456,10 @@ class PagedKVCache:
         # committed to its device, as every later step's output is: an
         # uncommitted first value would give the first decode call a
         # signature of its own, and the second a compilation
+        device = next(iter(self.k.devices()))
         self.tokens = jax.device_put(
-            jnp.zeros((int(token_rows),), jnp.int32),
-            next(iter(self.k.devices())))
+            jnp.zeros((int(token_rows),), jnp.int32), device)
+        self.firsts = jax.device_put(jnp.zeros_like(self.tokens), device)
         self.state = None
         if state_shape is not None:
             self.state = jnp.zeros(
@@ -479,6 +482,20 @@ class PagedKVCache:
                 p2t_state_write, donate_argnums=(0,))
         self.state = fn(self.state, states, jnp.asarray(int(slot),
                                                         jnp.int32))
+
+    def keep_first(self, row: int, out) -> None:
+        """``firsts[row] = out[0]``: the first token of a prefill (the
+        head of its program's int32 array ``out``) is put where the next
+        decode step can take it, without the host having seen it. One
+        jitted program, the row a runtime scalar."""
+        import jax
+        fn = _PREFILL_SCATTER_CACHE.get("first_token")
+        if fn is None:
+            def p2t_first_token(firsts, out, row):
+                return firsts.at[row].set(out[0])
+            fn = _PREFILL_SCATTER_CACHE["first_token"] = jax.jit(
+                p2t_first_token)
+        self.firsts = fn(self.firsts, out, np.int32(row))
 
     @property
     def block_bytes(self) -> int:

@@ -5,7 +5,8 @@ The production serving loop (ROADMAP item 2): requests come in via
 ``submit()``, the engine prefills them into paged KV blocks, and every
 ``decode_once()`` enqueues ONE bucketed compiled decode step over the
 whole running batch, one call ahead of the host: it reads back the
-step of the call before it AFTER enqueueing its own — admissions and
+step of the call before it AFTER enqueueing its own, and a prefill's
+first token after the step that consumes it — admissions and
 evictions happen between steps (iteration-level scheduling).
 Construct it from a live model
 (``GPTForCausalLM``, ``Lfm2MoeForCausalLM``) or from a ``jit.save``'d
@@ -58,6 +59,29 @@ def _pow2_ladder(lo: int, hi: int) -> Tuple[int, ...]:
     return tuple(sorted(set(out)))
 
 
+def _place(seq: Sequence) -> tuple:
+    """Where a running sequence stands: it moves when the sequence is
+    evicted, requeued or rebuilt, and a token in flight is then dropped
+    (the re-prefill from the token log computes it again)."""
+    return (seq.state is SeqState.RUNNING, seq.evictions, seq.recoveries)
+
+
+class _First:
+    """A prefill's first token from its enqueueing to its delivery:
+    ``out``, the prefill program's int32 array, on the device until it
+    is read back, of the ``n`` tokens prefilled; the token also waits in
+    ``row`` of ``cache.firsts`` for the decode step that consumes it."""
+
+    __slots__ = ("seq", "n", "out", "row", "_at")
+
+    def __init__(self, seq, n, out, row):
+        self.seq, self.n, self.out, self.row = seq, n, out, row
+        self._at = _place(seq)
+
+    def moved(self) -> bool:
+        return _place(self.seq) != self._at
+
+
 class _Step:
     """One decode step from its enqueueing to its delivery: who rode in
     it (``active``, in row order), what was sent (``arrays``, ``counts``
@@ -67,7 +91,7 @@ class _Step:
     then dropped, not delivered."""
 
     __slots__ = ("now", "active", "drafts", "bucket", "arrays", "counts",
-                 "out", "kept", "_rows", "_epochs")
+                 "out", "kept", "_rows", "_places")
 
     def __init__(self, now, active, drafts, bucket, arrays, counts):
         self.now, self.active, self.drafts = now, active, drafts
@@ -78,7 +102,7 @@ class _Step:
             self._rows.append(row)
             row += 1 + len(drafts.get(id(s), ()))
         self.kept = [True] * len(active)
-        self._epochs = [(s.evictions, s.recoveries) for s in active]
+        self._places = [_place(s) for s in active]
 
     def drop_moved(self) -> int:
         """Mark the rows whose sequence is no longer where the step
@@ -86,9 +110,7 @@ class _Step:
         fell now."""
         fell = 0
         for i, s in enumerate(self.active):
-            if self.kept[i] and not (
-                    s.state is SeqState.RUNNING
-                    and (s.evictions, s.recoveries) == self._epochs[i]):
+            if self.kept[i] and _place(s) != self._places[i]:
                 self.kept[i] = False
                 fell += 1
         return fell
@@ -261,12 +283,17 @@ class ServingEngine:
         self._seqs: Dict[int, Sequence] = {}
         self.decode_steps = 0
         # the decode step enqueued and not read back yet (decode_once),
-        # and its tokens dropped since the last dispatch span
+        # the prefills' first tokens likewise (in the order of their
+        # rows in ``cache.firsts``; the next decode_once delivers them
+        # all), and tokens in flight dropped since the last dispatch span
         self._ahead: Optional[_Step] = None
+        self._firsts: List[_First] = []
         self._dropped_ahead = 0
-        # steps enqueued with the one before un-read, and tokens in
-        # flight thrown away by an eviction, a requeue or a failure
+        # steps enqueued with the one before un-read, prefills left with
+        # their first token un-read, and tokens in flight thrown away by
+        # an eviction, a requeue or a failure
         self.ahead_steps = 0
+        self.prefill_ahead = 0
         self.ahead_dropped = 0
         # failure plane: set by fail() (chaos kill_engine, an operator
         # kill, a poisoned device) — a failed engine refuses all work
@@ -530,7 +557,7 @@ class ServingEngine:
         affected request's trace."""
         from ..observability import metrics
         self._check_alive()
-        # the step in flight ran with the old weights: its tokens are
+        # what is in flight ran with the old weights: its tokens are
         # delivered before the swap is stamped
         self._flush_ahead()
         arrays = weights
@@ -578,25 +605,25 @@ class ServingEngine:
     def _prefill_admitted(self, seq: Sequence, now: float,
                           ready_at_fn) -> dict:
         """Prefill one admitted sequence, scatter its K/V into its
-        blocks, sample its next token and mark it running."""
+        blocks and mark it running. Its first token stays on the device
+        (``cache.firsts``) for the decode step that consumes it, and the
+        next :meth:`decode_once` delivers it AFTER that step is
+        enqueued: everything done here follows from lengths the host
+        has. Where a step must be read back before the next is selected
+        (:meth:`_reads_back_first`) the token is delivered here."""
         from ..observability import metrics
         n = len(seq.tokens)
         padded = self.runner.prefill_padded_len(n)
-        with _span("prefill", req=seq.req_id, tokens=n,
-                   padded=padded) as prefill_span:
+        first_row = len(self._firsts)
+        ahead = not self._reads_back_first() \
+            and first_row < self.cache.firsts.shape[0]
+        with _span("prefill", req=seq.req_id, tokens=n, padded=padded,
+                   ahead=int(ahead)):
             with _span("prefill.dispatch"):
-                tok, k_stack, v_stack, *state = \
+                out, k_stack, v_stack, *state = \
                     self.runner.prefill_dispatch(seq.tokens)
-            with _span("prefill.readback"):
-                # the host waits for the device; a family's counts
-                # come in the same array as the token
-                tok, counts, chosen = self.runner.split_counts(tok, 1)
-                tok = int(tok[0])
-            if counts:
-                prefill_span.set_metadata(**self._count_stats(counts))
-                # the whole token log was routed anew (a re-prefill
-                # after an eviction too): its record replaces the old
-                seq.routed = [chosen[:n]]
+                if ahead:
+                    self.cache.keep_first(first_row, out)
             row = np.asarray(seq.table.blocks, np.int64)
             # prefix-cache hit: the leading cached positions' KV is
             # ALREADY in the pool (and shared — rewriting it would
@@ -617,7 +644,6 @@ class ServingEngine:
                     # so this is the state at its real last positions
                     self.cache.write_state(seq.table.state_slot, state[0])
         seq.table.num_tokens = n
-        seq.tokens.append(tok)
         cost = self.runner.prefill_cost(padded)
         info = {"seq": seq, "prompt_tokens": n, "padded_len": padded,
                 "cost": cost}
@@ -671,12 +697,40 @@ class ServingEngine:
                            host_blocks=host_blocks or None,
                            peer_blocks=peer_blocks or None)
         metrics.inc("serving_prefill_tokens_total", n)
-        if seq.done:
-            # its only token materializes when the prefill LANE
-            # finishes — finishing at the admission instant would
-            # stamp finish_t before first_token_t
-            self.scheduler.finish(seq, seq.ready_at)
+        self._firsts.append(_First(seq, n, out, first_row))
+        if ahead:
+            self.prefill_ahead += 1
+            metrics.inc("serving_prefill_ahead_total")
+        else:
+            self._deliver_firsts()
         return info
+
+    def _deliver_firsts(self) -> None:
+        """Read back every first token in flight (``prefill.readback``:
+        the host waits for the device) into its sequence's log, each
+        inside a span named ``prefill`` like the admission's, with
+        ``req`` and the family's counts, which come in the token's
+        array. One whose sequence moved meanwhile is dropped."""
+        firsts, self._firsts = self._firsts, []
+        for f in firsts:
+            if f.moved():
+                self._count_dropped(1)
+                continue
+            seq = f.seq
+            with _span("prefill", req=seq.req_id) as sp:
+                with _span("prefill.readback"):
+                    tok, counts, chosen = self.runner.split_counts(f.out, 1)
+                if counts:
+                    sp.set_metadata(**self._count_stats(counts))
+                    # the whole token log was routed anew (a re-prefill
+                    # after an eviction too): its record replaces the old
+                    seq.routed = [chosen[:f.n]]
+            seq.tokens.append(int(tok[0]))
+            if seq.done:
+                # its only token materializes when the prefill LANE
+                # finishes — finishing at the admission instant would
+                # stamp finish_t before first_token_t
+                self.scheduler.finish(seq, seq.ready_at)
 
     @staticmethod
     def _count_stats(counts: Dict[str, list]) -> Dict[str, int]:
@@ -758,17 +812,20 @@ class ServingEngine:
         the device works on step n+1 while the host emits step n and
         selects step n+2. A row's input token is the one thing the host
         lacks for the next step (a sequence ends by length alone), and
-        that is taken on the device from the step in flight. So a call
-        delivers the tokens of the step before it, the first call after
-        an empty engine delivers none, and :meth:`idle` counts the step
-        in flight.
+        that is taken on the device from the step in flight — or, for a
+        sequence just prefilled, from its first token, which the host
+        has not read either. So a call delivers the tokens of the step
+        before it and, after them, the first tokens of the prefills
+        since the call before; the first call after an empty engine
+        delivers no step, and :meth:`idle` counts what is in flight.
 
         Where the next step's inputs DO need the host to have seen the
         tokens (:meth:`_reads_back_first`) the step is read back in the
         call that enqueued it. Returns a step info dict (``bucket``,
         ``n_active``, ``cost`` of the step ``dispatched`` by this call,
-        else of the one delivered; ``tokens`` delivered; ``evictions``),
-        or None when nothing was enqueued or delivered. Raises
+        else of the one delivered; ``tokens`` delivered by steps;
+        ``evictions``), or None when nothing was enqueued or delivered.
+        Raises
         :class:`~.reliability.EngineFailedError` when the engine is (or
         chaos makes it) dead."""
         self._check_alive()
@@ -779,11 +836,15 @@ class ServingEngine:
         with _span("decode"):
             ahead = self._ahead
             sync = self._reads_back_first()
+            if sync:
+                # armed since the prefill: the next step's inputs are
+                # to be in the logs
+                self._deliver_firsts()
             picked = None
             if ahead is None or not sync:
                 with _span("decode.select"):
                     picked = self._select_decode_rows(now, ahead)
-            if picked is None and ahead is None:
+            if picked is None and ahead is None and not self._firsts:
                 return None
             return self._decode_rows(now, picked, ahead, sync)
 
@@ -801,39 +862,47 @@ class ServingEngine:
                                       or armed.armed("kill_engine"))
 
     def _drop_ahead(self) -> None:
-        """Throw the step in flight away (the engine's device state is
-        lost, or its sequences leave): no token of it reaches a log."""
+        """Throw the step and the first tokens in flight away (the
+        engine's device state is lost, or its sequences leave): no
+        token of them reaches a log."""
         step, self._ahead = self._ahead, None
-        if step is not None:
-            self._count_dropped(sum(step.kept))
+        firsts, self._firsts = self._firsts, []
+        self._count_dropped(sum(step.kept if step is not None else ())
+                            + len(firsts))
 
     def _count_dropped(self, n: int) -> None:
         self._dropped_ahead += n        # for the next dispatch span
         self.ahead_dropped += n
 
     def _flush_ahead(self) -> None:
-        """Deliver the step in flight now, for a caller whose next act
+        """Deliver what is in flight now, for a caller whose next act
         must not overtake it."""
-        if self._ahead is not None:
+        if self._ahead is not None or self._firsts:
             with _span("decode"):
-                self._decode_rows(self._ahead.now, None, self._ahead, True)
+                self._decode_rows(0.0, None, self._ahead, True)
 
     def _select_decode_rows(self, now: float, ahead: "Optional[_Step]"):
         """Who decodes next: the ready running sequences whose tables
         validate and whose next slots (drafts included) could be
-        reserved. A row of the step in flight (``ahead``) stands one
-        token further than its log says; one whose token in flight is
-        its last is not selected. Returns (active, drafts, victims,
-        {id(sequence): its row in ``ahead``}) or None."""
+        reserved. A row of the step in flight (``ahead``) and a
+        sequence whose first token is in flight stand one token further
+        than their logs say; one whose token in flight is its last is
+        not selected. Returns (active, drafts, victims, {id(sequence):
+        (where its input token waits on the device — a row of
+        ``cache.tokens`` and ``cache.firsts`` end to end —, positions it
+        stands past its table's count)}) or None."""
         from ..distributed.fault_tolerance import chaos
         from ..observability import metrics
-        flying = {}
+        fed = {}
         if ahead is not None:
             self._count_dropped(ahead.drop_moved())
-            flying = ahead.flying()
+            fed = {sid: (row, 1) for sid, row in ahead.flying().items()}
+        # the prefill wrote the table's count: a first token is AT it
+        width = self.cache.tokens.shape[0]
+        fed.update((id(f.seq), (width + f.row, 0)) for f in self._firsts)
         active = [s for s in self.scheduler.running()
                   if getattr(s, "ready_at", 0.0) <= now
-                  and not (id(s) in flying and len(s.generated) + 1
+                  and not (id(s) in fed and len(s.generated) + 1
                            >= s.request.max_new_tokens)]
         if not active:
             return None
@@ -849,7 +918,8 @@ class ServingEngine:
         if not active:
             return None
         victims = self.scheduler.reserve_decode_slots(
-            active, now=now, slots=[1 + (id(s) in flying) for s in active])
+            active, now=now,
+            slots=[1 + fed.get(id(s), (0, 0))[1] for s in active])
         if victims:
             # counted HERE, not after the step: evicting every ready
             # sequence aborts the step below, and those evictions must
@@ -897,21 +967,21 @@ class ServingEngine:
                           if k in {id(s) for s in active}}
             if not active:
                 return None
-        return active, drafts, victims, flying
+        return active, drafts, victims, fed
 
     def _build_step(self, now: float, active: List[Sequence],
                     drafts: Dict[int, List[int]], victims: list,
-                    flying: Dict[int, int]) -> "_Step":
-        """The next step's arrays. A row of the step in flight
-        (``flying``: its row there) takes its input token on the device
-        (id ``-1 - row`` of that step) and stands one position past its
-        table's count."""
+                    fed: Dict[int, Tuple[int, int]]) -> "_Step":
+        """The next step's arrays. A sequence in ``fed`` (a row of the
+        step in flight, a first token in flight) takes its input token
+        on the device (id ``-1 - row`` of the tokens held there) and
+        stands ``past`` positions beyond its table's count."""
         cfg = self.scheduler.config
         rows = []                      # (seq, token or -1 - row, position)
         ctx_tokens = 0
         for s in active:
-            row = flying.get(id(s))
-            p0 = s.num_cached + (row is not None)
+            row, past = fed.get(id(s), (None, 0))
+            p0 = s.num_cached + past
             ctx_tokens += p0
             rows.append((s, s.tokens[p0] if row is None else -1 - row, p0))
             for i, d in enumerate(drafts.get(id(s), ())):
@@ -955,7 +1025,8 @@ class ServingEngine:
         """Enqueue the step ``picked`` (if any), then read back and
         emit the step to deliver: the one in flight, else — where the
         next may not run ahead of it (``sync``) — the one just
-        enqueued."""
+        enqueued. Then the first tokens in flight: the step that
+        consumes them is on the device by now."""
         from ..observability import metrics
         if ahead is not None:
             # evicted or requeued since it was enqueued (this call's
@@ -969,6 +1040,13 @@ class ServingEngine:
             with _span("decode.build_batch"):
                 step = self._build_step(now, *picked)
         due = ahead if ahead is not None else (step if sync else None)
+        if step is None and due is None:
+            # no step goes out and none comes back (nothing ready, a
+            # request of one token): only first tokens are delivered
+            self._deliver_firsts()
+            return {"bucket": None, "n_active": 0, "tokens": 0,
+                    "evictions": 0, "spec_accepted": 0, "spec_rejected": 0,
+                    "dispatched": False, "cost": None}
         dropped, self._dropped_ahead = self._dropped_ahead, 0
         # runner.decode is the one call that enqueues (H2D and the
         # program); decode.readback, in which the host waits for the
@@ -999,6 +1077,7 @@ class ServingEngine:
         if due is not None:
             with _span("decode.emit"):
                 info.update(self._emit_decoded(due, toks, chosen))
+        self._deliver_firsts()
         return info
 
     def _emit_decoded(self, step: "_Step", toks, chosen=None) -> dict:
@@ -1168,6 +1247,7 @@ class ServingEngine:
                                            self.max_model_len)
 
     def idle(self) -> bool:
-        """Nothing queued, nothing running, no step in flight."""
+        """Nothing queued, nothing running, nothing in flight."""
         return not self.scheduler.waiting \
-            and not self.scheduler.running() and self._ahead is None
+            and not self.scheduler.running() and self._ahead is None \
+            and not self._firsts

@@ -20,7 +20,9 @@ functions compiled with ``jax.jit``:
   step under :meth:`PagedRunner.bound` and compare logits;
 * a decode step's tokens also stay ON THE DEVICE (``cache.tokens``),
   where the next step can take a row's input from them: the engine
-  enqueues step n+1 before it has read step n back;
+  enqueues step n+1 before it has read step n back — and a prefill's
+  first token likewise (``cache.firsts``): the step that consumes it is
+  enqueued before the host reads it;
 * builds run inside ``build`` spans and leave cost records.
 
 A family supplies its cache geometry (how many layers keep keys and
@@ -402,18 +404,20 @@ class PagedRunner:
         import jax.numpy as jnp
         family = self.family
 
-        def p2t_decode(weight_arrays, k_pool, v_pool, fed, ids, positions,
-                       block_tables, *state_args):
+        def p2t_decode(weight_arrays, k_pool, v_pool, fed, firsts, ids,
+                       positions, block_tables, *state_args):
             # ids [B,1] int32; positions [B] int32 (0-based slot of the
             # NEW token); block_tables [B,P] int32. Pools
             # [L, N, bs, H_kv*D], donated. A family with per-sequence
             # state adds (state pool [Ls, slots+1, ...] donated, slots
             # [B] int32). fed [R] int32: the tokens of the step before,
-            # never read by the host so far; an id below zero is a row
-            # of them (-1 - row) and not a token.
+            # firsts [R] int32: first tokens of prefills, neither read
+            # by the host so far; an id below zero is a row of the two
+            # end to end (-1 - row) and not a token.
             state_pool, slots = state_args or (None, None)
             with jax.named_scope("embed"):
-                taken = fed[jnp.clip(-1 - ids, 0, fed.shape[0] - 1)]
+                held = jnp.concatenate([fed, firsts])
+                taken = held[jnp.clip(-1 - ids, 0, held.shape[0] - 1)]
                 ids = jnp.where(ids < 0, taken, ids)
             with self.bound(weight_arrays):
                 logits, k_pool, v_pool, state_pool, counts = family.decode(
@@ -428,7 +432,7 @@ class PagedRunner:
             out = (tok, fed, k_pool, v_pool)
             return out if state_pool is None else out + (state_pool,)
 
-        donate = (1, 2) if family.state_shape is None else (1, 2, 7)
+        donate = (1, 2) if family.state_shape is None else (1, 2, 8)
         return jax.jit(p2t_decode, donate_argnums=donate)
 
     def kernel_pages_per_block(self, cache, n_pages: int) -> int:
@@ -443,7 +447,7 @@ class PagedRunner:
     def _decode_args(self, cache, ids, positions, block_tables, slots):
         import jax.numpy as jnp
         args = (self._weights(), cache.k, cache.v, cache.tokens,
-                jnp.asarray(ids, jnp.int32),
+                cache.firsts, jnp.asarray(ids, jnp.int32),
                 jnp.asarray(positions, jnp.int32),
                 jnp.asarray(block_tables, jnp.int32))
         if cache.state is not None:
@@ -458,7 +462,9 @@ class PagedRunner:
         :class:`~.block_cache.PagedKVCache` whose pools are donated and
         replaced, and whose ``tokens`` (the step's tokens, kept on the
         device) feed the next step: an entry of ``ids`` below zero is
-        ``-1 - row`` of the step BEFORE this one and not a token id.
+        ``-1 - row`` of the step BEFORE this one and not a token id — or,
+        from ``len(cache.tokens)`` on, a row of ``cache.firsts``, where
+        the prefills' first tokens wait that the host has not read.
         ``slots`` are the rows' state slots where the family keeps
         per-sequence state. Returns the program's int32 array, still on
         the device: next tokens ``[B]``, then the family's counts

@@ -917,6 +917,7 @@ def simulate_predictor_baseline(engine, trace: List[dict]
         [aval(tuple(t.shape), t._data.dtype) for t in runner._state],
         aval(shape, engine.cache.dtype), aval(shape, engine.cache.dtype),
         aval(engine.cache.tokens.shape, "int32"),
+        aval(engine.cache.firsts.shape, "int32"),
         aval((1, 1), "int32"), aval((1,), "int32"),
         aval((1, max_pages), "int32")))
     decode_s = cost_seconds(b1_cost)

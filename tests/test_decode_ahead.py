@@ -120,8 +120,9 @@ def both(model, arrivals, also="", **over):
 # -- the same tokens and experts, request by request ------------------------
 @pytest.mark.parametrize("family", FAMILIES)
 def test_admitted_while_a_step_is_in_flight(models, family):
-    """A sequence prefilled between two calls joins the next step with
-    its host token beside rows fed on the device."""
+    """A sequence prefilled between two calls joins the next step, its
+    first token fed on the device (``cache.firsts``) beside the rows fed
+    by the step in flight (``test_prefill_ahead.py``)."""
     model = models[family]
     p = prompts_of(model, (11, 7, 13))
     ahead, got, _, want = both(model, [(0, p[0], 9), (2, p[1], 6),
