@@ -27,7 +27,7 @@ from ..ops.dispatch import apply_op
 
 __all__ = ["TopKGate", "SwitchGate", "MoELayer", "dispatch_stats",
            "token_ledger_closes", "router_reference_f64",
-           "DroplessExperts", "sigmoid_topk_route"]
+           "DroplessExperts", "sigmoid_topk_route", "softmax_topk_route"]
 
 
 def _one_hot(idx, n):
@@ -435,11 +435,33 @@ def sigmoid_topk_route(a, gate_w, bias, k: int, norm_topk: bool = True,
     return ids.astype(jnp.int32), w * scale
 
 
+def softmax_topk_route(a, gate_w, k: int, norm_topk: bool = True,
+                       scale: float = 1.0):
+    """Softmax routing, in float32 whatever the layer's dtype: ``p =
+    softmax(a W_g)`` over ALL experts; the ``k`` largest; the weights
+    are ``p_e``, over their sum when ``norm_topk`` (no epsilon: a
+    softmax's top k never sum to zero), times ``scale``. Returns (ids
+    ``[T, k]`` int32, weights ``[T, k]`` f32)."""
+    p = jax.nn.softmax(jnp.dot(a.astype(jnp.float32),
+                               gate_w.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST), -1)
+    w, ids = jax.lax.top_k(p, k)
+    if norm_topk:
+        w = w / jnp.sum(w, -1, keepdims=True)
+    return ids.astype(jnp.int32), w * scale
+
+
 class DroplessExperts(nn.Layer):
     """Top-k routed SwiGLU experts with NO capacity: every assignment
     is computed. Rows are sorted by expert and all experts held run as
     one grouped matmul (``kernels.moe_gmm``) per projection, for
     thousands of prefill rows and a decode step's few hundred alike.
+
+    ``router`` names how a row's experts and weights are found:
+    ``"sigmoid"`` (:func:`sigmoid_topk_route`, with the selection bias
+    where ``use_bias``) or ``"softmax"`` (:func:`softmax_topk_route`, no
+    bias); what follows — the sort, ONE grouped matmul a projection, the
+    record — is the same.
 
     ``held = (first, count)`` is the contiguous share of the
     ``num_experts`` this layer holds weights for: routing is always over
@@ -457,8 +479,12 @@ class DroplessExperts(nn.Layer):
     def __init__(self, hidden: int, width: int, num_experts: int, k: int,
                  use_bias: bool = True, norm_topk: bool = True,
                  scale: float = 1.0, held=None, std: float = 0.02,
-                 dtype=None):
+                 dtype=None, router: str = "sigmoid"):
         super().__init__()
+        if router not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown router {router!r}")
+        self.router = router
+        use_bias = use_bias and router == "sigmoid"
         self.num_experts, self.k = int(num_experts), int(k)
         self.norm_topk, self.scale = bool(norm_topk), float(scale)
         self.first, self.count = held or (0, self.num_experts)
@@ -486,10 +512,15 @@ class DroplessExperts(nn.Layer):
         T, H = a.shape
         E, k = self.num_experts, self.k
         with jax.named_scope("router"):
-            ids, w = sigmoid_topk_route(
-                a, self.gate_weight._data,
-                None if self.expert_bias is None else self.expert_bias._data,
-                k, self.norm_topk, self.scale)
+            if self.router == "softmax":
+                ids, w = softmax_topk_route(a, self.gate_weight._data, k,
+                                            self.norm_topk, self.scale)
+            else:
+                ids, w = sigmoid_topk_route(
+                    a, self.gate_weight._data,
+                    None if self.expert_bias is None
+                    else self.expert_bias._data,
+                    k, self.norm_topk, self.scale)
         with jax.named_scope("dispatch"):
             flat = ids.reshape(-1)
             held = (flat >= self.first) & (flat < self.first + self.count)

@@ -21,8 +21,9 @@ from ._platform import on_tpu
 
 
 def _sdpa_xla(q, k, v, bias=None, causal=False, scale=None, dropout_p=0.0,
-              dropout_key=None):
-    """q,k,v: (B, S, H, D) paddle layout."""
+              dropout_key=None, causal_block=1):
+    """q,k,v: (B, S, H, D) paddle layout. ``causal_block`` B > 1 widens
+    the causal mask to blocks of B: a row sees its whole block."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     # (B, H, S, D)
@@ -36,7 +37,10 @@ def _sdpa_xla(q, k, v, bias=None, causal=False, scale=None, dropout_p=0.0,
         logits = logits + bias
     if causal:
         s, t = logits.shape[-2], logits.shape[-1]
-        mask = jnp.tril(jnp.ones((s, t), bool), k=t - s)
+        rows = jnp.arange(s)[:, None] + (t - s)
+        if causal_block > 1:
+            rows = rows // causal_block * causal_block + causal_block - 1
+        mask = rows >= jnp.arange(t)[None, :]
         logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     if dropout_p > 0.0 and dropout_key is not None:
@@ -149,11 +153,13 @@ def _per_shard(fn, q_shape):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, causal=None,
-                                 training=True, name=None):
+                                 training=True, name=None, causal_block=1):
     """paddle.nn.functional.scaled_dot_product_attention parity.
 
     Inputs are (batch, seq, num_heads, head_dim) like the reference flash-attn
     API (paddle/phi/kernels/gpu/flash_attn_kernel.cu qkv layout).
+    ``causal_block`` B > 1 (with ``is_causal``) masks by blocks of B
+    positions: a query sees its own block whole and every earlier one.
     """
     causal = causal if causal is not None else is_causal
     query, key, value = (ensure_tensor(query), ensure_tensor(key),
@@ -178,7 +184,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
         def fn(q, k, v):
             return flash_attention_bshd(q, k, v, causal=causal,
-                                        block_q=bq, block_k=bk)
+                                        block_q=bq, block_k=bk,
+                                        causal_block=causal_block)
         return apply_op("flash_attention",
                         _per_shard(fn, tuple(query.shape)),
                         tuple(tensors), {})
@@ -187,5 +194,5 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         bias = mask[0] if mask else None
         return _sdpa_xla(q, k, v, bias=bias, causal=causal,
                          dropout_p=dropout_p if drop_key is not None else 0.0,
-                         dropout_key=drop_key)
+                         dropout_key=drop_key, causal_block=causal_block)
     return apply_op("sdpa", fn, tuple(tensors), {})

@@ -13,6 +13,12 @@ TPU-native tiled online-softmax kernel:
 - causal masking skips fully-masked tiles via pl.when (no wasted MXU work
   on the upper triangle); with Sq != Sk the diagonal is bottom-right
   aligned, matching the XLA fallback and flash-attn v2.1 semantics.
+- the causal mask is BLOCK-causal by a static block length: inside the
+  kernels ``causal`` is that length (0: no mask, 1: plain causal, a
+  power of two B: a row sees the columns of its own block of B positions
+  whole, later ones included, and of every earlier block — the mask a
+  block-diffusion decoder prefills under). Forward and backward both;
+  tiles wholly above the staircase are still skipped.
 - lse/delta ride in (…, Sq, 128)-lane f32 buffers — the TPU lane-tiling
   minimum, the same layout the official jax flash kernel uses for l/m/di.
 
@@ -65,6 +71,16 @@ def _fit_block(s: int, want: int):
         b //= 2
     return None
 
+
+def _last_col(row, block):
+    """The last column a (bottom-right aligned) row sees under the
+    causal mask of block length ``block``: itself, or the end of its
+    block (``block`` a power of two). Scalars and iotas alike."""
+    return row if block == 1 else row | (block - 1)
+
+
+def _causal_keep(rows, cols, block):
+    return _last_col(rows, block) >= cols
 
 
 def _online_softmax_step(s, v, acc, m_sc, l_sc):
@@ -143,7 +159,7 @@ def _fwd_kernel_1blk(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
         bq, bk = s.shape
         rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + offset
         cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
     m = jnp.max(s, axis=1, keepdims=True)
     p = jnp.exp(s - m)                    # masked: exp(-inf - finite) = 0
     l = jnp.sum(p, axis=1, keepdims=True)
@@ -169,8 +185,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
     k_start = ik * block_k
     run = True
     if causal:
-        # skip tiles entirely above the (bottom-right aligned) diagonal
-        run = k_start <= q_start + offset + block_q - 1
+        # skip tiles entirely above the (bottom-right aligned) staircase
+        run = k_start <= _last_col(q_start + offset + block_q - 1, causal)
 
     @pl.when(run)
     def _compute():
@@ -185,7 +201,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
                 jnp.int32, (block_q, block_k), 0) + q_start + offset
             cols = jax.lax.broadcasted_iota(jnp.int32,
                                             (block_q, block_k), 1) + k_start
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
         _online_softmax_step(s, v, acc, m_sc, l_sc)
 
     @pl.when(ik == nk - 1)
@@ -291,7 +307,7 @@ def _bwd_fused_1blk_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         bq, bk = s.shape
         rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + offset
         cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
     p, ds_f = _bwd_p_ds(s, lse, delta, do, v, guarded=False)
     ds = ds_f.astype(q.dtype)
     dv_ref[0, 0] = jax.lax.dot_general(
@@ -323,7 +339,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k_start = ik * block_k
     run = True
     if causal:
-        run = q_start + offset + block_q - 1 >= k_start
+        run = _last_col(q_start + offset + block_q - 1, causal) >= k_start
 
     @pl.when(run)
     def _compute():
@@ -339,7 +355,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 0) + q_start + offset
             cols = jax.lax.broadcasted_iota(jnp.int32,
                                             (block_q, block_k), 1) + k_start
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
         p, ds = _bwd_p_ds(s, lse, delta, do, v)
         # dV += P^T dO ; dK += dS^T Q * scale
         dv_acc[:] += jax.lax.dot_general(
@@ -371,7 +387,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k_start = ik * block_k
     run = True
     if causal:
-        run = k_start <= q_start + offset + block_q - 1
+        run = k_start <= _last_col(q_start + offset + block_q - 1, causal)
 
     @pl.when(run)
     def _compute():
@@ -387,7 +403,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 0) + q_start + offset
             cols = jax.lax.broadcasted_iota(jnp.int32,
                                             (block_q, block_k), 1) + k_start
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
         _p, ds = _bwd_p_ds(s, lse, delta, do, v)
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
@@ -875,19 +891,29 @@ def flash_attention_varlen_packed(q, k, v, seg_q, off_q, seg_k, off_k,
 
 def flash_attention_bshd(q, k, v, causal=False, scale=None,
                          block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                         interpret=None):
+                         interpret=None, causal_block=1):
     """Flash attention on (batch, seq, heads, dim) arrays (reference
     flash_attn qkv layout). Differentiable via the Pallas backward kernels;
-    falls back to the XLA path when shapes are unsupported."""
+    falls back to the XLA path when shapes are unsupported.
+    ``causal_block`` (with ``causal``): the block length of the causal
+    mask — 1 is plain causal; B > 1, a power of two, lets a position see
+    its whole block of B, forward and backward alike."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    causal_block = int(causal_block)
     if not supported(q.shape, k.shape, block_q, block_k):
         from .attention import _sdpa_xla
-        return _sdpa_xla(q, k, v, causal=causal, scale=scale)
+        return _sdpa_xla(q, k, v, causal=causal, scale=scale,
+                         causal_block=causal_block)
+    if causal_block < 1 or causal_block & (causal_block - 1):
+        raise ValueError(
+            f"flash attention masks by blocks of a power of two, not "
+            f"{causal_block}")
     if interpret is None:
         interpret = interpret_default()
-    cfg = (float(scale), bool(causal), int(block_q), int(block_k),
-           bool(interpret))
+    # inside the kernels `causal` is the mask's block length, 0 for none
+    cfg = (float(scale), causal_block if causal else 0, int(block_q),
+           int(block_k), bool(interpret))
     def builder():
         def flash_bshd(q, k, v):
             return jnp.swapaxes(

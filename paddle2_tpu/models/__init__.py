@@ -6,9 +6,11 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
                     ernie3_base, ernie_tiny)
 
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_moe_tiny
+from .sdar import SdarMoeConfig, SdarMoeForCausalLM, sdar_moe_tiny
 
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
+           "SdarMoeConfig", "SdarMoeForCausalLM", "sdar_moe_tiny",
            "GPTConfig", "GPTModel", "GPTForCausalLM", "gpt3_1p3b",
            "gpt_small", "gpt_tiny", "ErnieConfig", "ErnieModel",
            "ErnieForSequenceClassification", "ernie3_base", "ernie_tiny"]

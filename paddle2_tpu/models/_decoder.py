@@ -1,0 +1,111 @@
+"""What the RMSNorm / rotary / grouped-query decoder families share
+(``models/lfm2.py``, ``models/sdar.py``): bias-free projections created
+in the model's dtype, the pre-norm through the repo's kernel, rotary
+tables in the rotate-half layout, and grouped-query attention with a
+per-head RMS norm of q and k. Written once, on arrays; inference only
+(no autograd tape)."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+from ..framework.tensor import Tensor
+from ..kernels.pallas_fused import fused_rms_norm, fused_rope
+from ..ops.linalg import _mxu_precision
+
+__all__ = ["GroupedQueryAttention", "created_in", "linear", "mm",
+           "pre_norm", "rms_head", "rope_tables"]
+
+
+def rms_head(x, weight, eps):
+    """Per-head RMS norm of q / k (a head's lanes a row: plain XLA, the
+    kernel's rows are whole hidden rows)."""
+    h = x.astype(jnp.float32)
+    h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True) + eps)
+    return (h * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_tables(positions, head_dim: int, theta: float):
+    """cos, sin ``[..., head_dim]`` f32 for integer ``positions``: the
+    half tables repeated, the layout ``fused_rope`` (rotate-half)
+    takes."""
+    inv = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                    / head_dim)
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    ang = jnp.concatenate([ang, ang], -1)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def mm(x, linear_layer):
+    w = linear_layer.weight._data
+    return jnp.dot(x, w, precision=_mxu_precision(x, w))
+
+
+def created_in(param, dtype):
+    """A parameter a stock layer made in the default dtype, in ``dtype``."""
+    if dtype is not None and str(param._data.dtype) != dtype:
+        param._replace_data(param._data.astype(dtype))
+
+
+def linear(d_in, d_out, std, dtype):
+    attr = nn.ParamAttr(initializer=nn.initializer.Normal(0.0, std))
+    layer = nn.Linear(d_in, d_out, weight_attr=attr, bias_attr=False)
+    created_in(layer.weight, dtype)
+    return layer
+
+
+def pre_norm(norm, x, eps):
+    with jax.named_scope("norm"):
+        # the repo's Pallas kernel: f32 inside, x's dtype out
+        return fused_rms_norm(x, norm.weight._data, eps)
+
+
+class GroupedQueryAttention(nn.Layer):
+    """``num_heads`` query heads over ``num_kv_heads`` key/value heads of
+    ``head_dim``; q and k normed per head and rotated (rotate-half,
+    base ``theta``)."""
+
+    def __init__(self, hidden: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, eps: float, theta: float, std: float,
+                 dtype=None):
+        super().__init__()
+        self.head_dim, self.eps, self.theta = head_dim, eps, float(theta)
+        self.q_proj = linear(hidden, num_heads * head_dim, std, dtype)
+        self.k_proj = linear(hidden, num_kv_heads * head_dim, std, dtype)
+        self.v_proj = linear(hidden, num_kv_heads * head_dim, std, dtype)
+        self.out_proj = linear(num_heads * head_dim, hidden, std, dtype)
+        self.q_norm = nn.RMSNorm(head_dim, epsilon=eps)
+        self.k_norm = nn.RMSNorm(head_dim, epsilon=eps)
+
+    def qkv(self, u, positions):
+        """u ``[B, S, H]``, positions int ``[B, S]`` -> q ``[B, S, nh,
+        hd]``, k, v ``[B, S, nkv, hd]``; q and k normed and rotated."""
+        B, S, _ = u.shape
+        hd = self.head_dim
+        q = mm(u, self.q_proj).reshape(B, S, -1, hd)
+        k = mm(u, self.k_proj).reshape(B, S, -1, hd)
+        v = mm(u, self.v_proj).reshape(B, S, -1, hd)
+        q = rms_head(q, self.q_norm.weight._data, self.eps)
+        k = rms_head(k, self.k_norm.weight._data, self.eps)
+        cos, sin = rope_tables(positions.reshape(-1), hd, self.theta)
+        return fused_rope(q, cos, sin), fused_rope(k, cos, sin), v
+
+    def full(self, u, causal_block: int = 1):
+        """Causal attention over a whole sequence -> (Op, k, v); with
+        ``causal_block`` B > 1 a position sees its whole block of B."""
+        from ..kernels.attention import scaled_dot_product_attention
+        B, S, _ = u.shape
+        pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+        q, k, v = self.qkv(u, pos)
+        g = q.shape[2] // k.shape[2]
+        a = scaled_dot_product_attention(
+            Tensor(q), Tensor(jnp.repeat(k, g, axis=2)),
+            Tensor(jnp.repeat(v, g, axis=2)), is_causal=True,
+            causal_block=causal_block)._data
+        return self.project(a.reshape(B, S, -1)), k, v
+
+    def project(self, a):
+        """The heads' outputs ``[..., nh * hd]`` through ``W_o``."""
+        return mm(a.astype(self.out_proj.weight._data.dtype), self.out_proj)

@@ -34,8 +34,10 @@ import jax.numpy as jnp
 from .. import nn
 from ..framework.tensor import Tensor
 from ..incubate.moe import DroplessExperts
-from ..kernels.pallas_fused import fused_rms_norm, fused_rope
+from ..kernels.pallas_fused import fused_rms_norm
 from ..ops.linalg import _mxu_precision
+from ._decoder import (GroupedQueryAttention, created_in as _created_in,
+                       linear, mm as _mm, pre_norm)
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny"]
 
@@ -99,41 +101,16 @@ class Lfm2MoeConfig:
 
 
 # --------------------------------------------------------------- the maths
-def _rms_head(x, weight, eps):
-    """Per-head RMS norm of q / k (64 lanes a row: plain XLA, the
-    kernel's rows are whole 128-lane tiles)."""
-    h = x.astype(jnp.float32)
-    h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True) + eps)
-    return (h * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def rope_tables(positions, head_dim: int, theta: float):
-    """cos, sin ``[..., head_dim]`` f32 for integer ``positions``: the
-    half tables repeated, the layout ``fused_rope`` (rotate-half)
-    takes."""
-    inv = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                    / head_dim)
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    ang = jnp.concatenate([ang, ang], -1)
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def _mm(x, linear):
-    w = linear.weight._data
-    return jnp.dot(x, w, precision=_mxu_precision(x, w))
-
-
 class Lfm2ShortConv(nn.Layer):
     def __init__(self, cfg: Lfm2MoeConfig):
         super().__init__()
         H, std = cfg.hidden_size, cfg.initializer_range
-        attr = nn.ParamAttr(initializer=nn.initializer.Normal(0.0, std))
         self.taps = cfg.conv_L_cache
-        self.in_proj = _linear(H, 3 * H, attr, cfg)
+        self.in_proj = _linear(H, 3 * H, cfg)
         self.conv_weight = self.create_parameter(
             [self.taps, H], dtype=cfg.dtype,
             default_initializer=nn.initializer.Normal(0.0, std))
-        self.out_proj = _linear(H, H, attr, cfg)
+        self.out_proj = _linear(H, H, cfg)
 
     def full(self, u):
         """u ``[B, S, H]`` -> (Op, z ``[B, S, H]``)."""
@@ -155,62 +132,13 @@ class Lfm2ShortConv(nn.Layer):
         return _mm(c * conv, self.out_proj), window[:, 1:]
 
 
-class Lfm2Attention(nn.Layer):
-    def __init__(self, cfg: Lfm2MoeConfig):
-        super().__init__()
-        self.cfg = cfg
-        H, hd = cfg.hidden_size, cfg.head_dim
-        nh, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
-        attr = nn.ParamAttr(initializer=nn.initializer.Normal(
-            0.0, cfg.initializer_range))
-        self.q_proj = _linear(H, nh * hd, attr, cfg)
-        self.k_proj = _linear(H, nkv * hd, attr, cfg)
-        self.v_proj = _linear(H, nkv * hd, attr, cfg)
-        self.out_proj = _linear(nh * hd, H, attr, cfg)
-        self.q_norm = nn.RMSNorm(hd, epsilon=cfg.norm_eps)
-        self.k_norm = nn.RMSNorm(hd, epsilon=cfg.norm_eps)
-
-    def qkv(self, u, positions):
-        """u ``[B, S, H]``, positions int ``[B, S]`` -> q ``[B, S, nh,
-        hd]``, k, v ``[B, S, nkv, hd]``; q and k normed and rotated."""
-        cfg = self.cfg
-        B, S, _ = u.shape
-        hd, eps = cfg.head_dim, cfg.norm_eps
-        q = _mm(u, self.q_proj).reshape(B, S, -1, hd)
-        k = _mm(u, self.k_proj).reshape(B, S, -1, hd)
-        v = _mm(u, self.v_proj).reshape(B, S, -1, hd)
-        q = _rms_head(q, self.q_norm.weight._data, eps)
-        k = _rms_head(k, self.k_norm.weight._data, eps)
-        cos, sin = rope_tables(positions.reshape(-1), hd, cfg.rope_theta)
-        return fused_rope(q, cos, sin), fused_rope(k, cos, sin), v
-
-    def full(self, u):
-        """Causal attention over a whole sequence -> (Op, k, v)."""
-        from ..kernels.attention import scaled_dot_product_attention
-        B, S, _ = u.shape
-        pos = jnp.broadcast_to(jnp.arange(S), (B, S))
-        q, k, v = self.qkv(u, pos)
-        g = q.shape[2] // k.shape[2]
-        a = scaled_dot_product_attention(
-            Tensor(q), Tensor(jnp.repeat(k, g, axis=2)),
-            Tensor(jnp.repeat(v, g, axis=2)), is_causal=True)._data
-        return self.project(a.reshape(B, S, -1)), k, v
-
-    def project(self, a):
-        """The heads' outputs ``[..., nh * hd]`` through ``W_o``."""
-        return _mm(a.astype(self.out_proj.weight._data.dtype),
-                   self.out_proj)
-
-
 class Lfm2MLP(nn.Layer):
     def __init__(self, cfg: Lfm2MoeConfig):
         super().__init__()
         H, F = cfg.hidden_size, cfg.intermediate_size
-        attr = nn.ParamAttr(initializer=nn.initializer.Normal(
-            0.0, cfg.initializer_range))
-        self.w1 = _linear(H, F, attr, cfg)
-        self.w3 = _linear(H, F, attr, cfg)
-        self.w2 = _linear(F, H, attr, cfg)
+        self.w1 = _linear(H, F, cfg)
+        self.w3 = _linear(H, F, cfg)
+        self.w2 = _linear(F, H, cfg)
 
     def run(self, a):
         up = jax.nn.silu(_mm(a, self.w1).astype(jnp.float32))
@@ -218,16 +146,8 @@ class Lfm2MLP(nn.Layer):
                    .astype(a.dtype), self.w2)
 
 
-def _created_in(param, dtype):
-    """A parameter a stock layer made in the default dtype, in ``dtype``."""
-    if dtype is not None and str(param._data.dtype) != dtype:
-        param._replace_data(param._data.astype(dtype))
-
-
-def _linear(d_in, d_out, attr, cfg):
-    layer = nn.Linear(d_in, d_out, weight_attr=attr, bias_attr=False)
-    _created_in(layer.weight, cfg.dtype)
-    return layer
+def _linear(d_in, d_out, cfg):
+    return linear(d_in, d_out, cfg.initializer_range, cfg.dtype)
 
 
 class Lfm2DecoderLayer(nn.Layer):
@@ -241,7 +161,10 @@ class Lfm2DecoderLayer(nn.Layer):
         if self.is_conv:
             self.conv = Lfm2ShortConv(cfg)
         else:
-            self.self_attn = Lfm2Attention(cfg)
+            self.self_attn = GroupedQueryAttention(
+                cfg.hidden_size, cfg.num_attention_heads,
+                cfg.num_key_value_heads, cfg.head_dim, cfg.norm_eps,
+                cfg.rope_theta, cfg.initializer_range, cfg.dtype)
         if self.is_dense:
             self.feed_forward = Lfm2MLP(cfg)
         else:
@@ -257,9 +180,7 @@ class Lfm2DecoderLayer(nn.Layer):
         return "conv" if self.is_conv else "attn"
 
     def pre_norm(self, norm, x):
-        with jax.named_scope("norm"):
-            # the repo's Pallas kernel: f32 inside, x's dtype out
-            return fused_rms_norm(x, norm.weight._data, self.cfg.norm_eps)
+        return pre_norm(norm, x, self.cfg.norm_eps)
 
     def feed(self, h, valid=None, interpret=None):
         """``h + FF(RMS(h))`` on ``[..., H]`` -> (y, routing counts or

@@ -429,7 +429,12 @@ class PagedKVCache:
     takes a row's input token from it where the host has not read that
     step back yet (``PagedRunner.decode``). ``firsts``, as wide, holds
     the first tokens of prefills the host has not read back yet
-    (:meth:`keep_first`): the same step takes those there too.
+    (:meth:`keep_first`): the same step takes those there too. For a
+    block-diffusion family (``block_length`` B: a step carries a block
+    of B positions a sequence, ``serving/blockdiff.py``) what stays on
+    the device instead is the BLOCK in flight of every row of the last
+    step: ``block_ids`` ``[token_rows, B]`` and ``block_masked`` (which
+    of them are not fixed yet), which the next pass takes where they lie.
 
     Pools start zeroed; stale data in freed blocks and slots is
     harmless — the paged-attention kernel masks every slot past a
@@ -440,7 +445,7 @@ class PagedKVCache:
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype="float32",
                  state_shape=None, state_slots: int = 0,
-                 token_rows: int = 1):
+                 token_rows: int = 1, block_length: Optional[int] = None):
         import jax
         import jax.numpy as jnp
         self.num_layers = int(num_layers)
@@ -460,6 +465,13 @@ class PagedKVCache:
         self.tokens = jax.device_put(
             jnp.zeros((int(token_rows),), jnp.int32), device)
         self.firsts = jax.device_put(jnp.zeros_like(self.tokens), device)
+        self.block_ids = self.block_masked = None
+        if block_length is not None:
+            shape = (int(token_rows), int(block_length))
+            self.block_ids = jax.device_put(jnp.zeros(shape, jnp.int32),
+                                            device)
+            self.block_masked = jax.device_put(jnp.zeros(shape, bool),
+                                               device)
         self.state = None
         if state_shape is not None:
             self.state = jnp.zeros(

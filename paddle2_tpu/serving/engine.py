@@ -7,7 +7,11 @@ The production serving loop (ROADMAP item 2): requests come in via
 whole running batch, one call ahead of the host: it reads back the
 step of the call before it AFTER enqueueing its own, and a prefill's
 first token after the step that consumes it — admissions and
-evictions happen between steps (iteration-level scheduling).
+evictions happen between steps (iteration-level scheduling). A family
+that generates by diffusion over blocks (``serving/blockdiff.py``) rides
+the same loop: its step is a pass over a block of positions a sequence,
+a sequence advances when its block is committed, and the block in
+flight stays on the device as a token's does.
 Construct it from a live model
 (``GPTForCausalLM``, ``Lfm2MoeForCausalLM``) or from a ``jit.save``'d
 artifact (the artifact's
@@ -39,6 +43,7 @@ import numpy as np
 from ..profiler import span as _span
 from .block_cache import (BlockAllocator, HostKVTier, PagedKVCache,
                           PrefixCache, blocks_for_tokens, GARBAGE_BLOCK)
+from .blockdiff import STRATEGIES, BlockInFlight
 from .model_runner import PagedRunner, served_classes
 from .reliability import (EngineFailedError, PromptTooLongError,
                           ReliabilityConfig, RequestRejected,
@@ -70,7 +75,9 @@ class _First:
     """A prefill's first token from its enqueueing to its delivery:
     ``out``, the prefill program's int32 array, on the device until it
     is read back, of the ``n`` tokens prefilled; the token also waits in
-    ``row`` of ``cache.firsts`` for the decode step that consumes it."""
+    ``row`` of ``cache.firsts`` for the decode step that consumes it. A
+    block-diffusion family's prefill yields no token (``row`` None):
+    what waits in ``out`` is its routing record alone."""
 
     __slots__ = ("seq", "n", "out", "row", "_at")
 
@@ -88,7 +95,8 @@ class _Step:
     for the span), and ``out``, the program's int32 array, on the
     device until the step is read back. ``kept[i]`` falls when sequence
     i is evicted or requeued while the step is in flight: its token is
-    then dropped, not delivered."""
+    then dropped, not delivered. A row carries ``family.row_positions``
+    positions: one, or a block-diffusion family's block."""
 
     __slots__ = ("now", "active", "drafts", "bucket", "arrays", "counts",
                  "out", "kept", "_rows", "_places")
@@ -168,6 +176,12 @@ class EngineConfig:
     host_tier_blocks: Optional[int] = None
     # host-link override in GB/s (None = env / shared default)
     host_link_gbps: Optional[float] = None
+    # generation by diffusion over blocks (a family with a
+    # ``block_length``): denoise passes a block (None = the block
+    # length, a position a pass; must divide it) and how a pass chooses
+    # the positions it fixes (serving.blockdiff.STRATEGIES)
+    denoising_steps: Optional[int] = None
+    unmask_strategy: str = "low_confidence_static"
 
 
 class ServingEngine:
@@ -196,6 +210,10 @@ class ServingEngine:
                 f"{', '.join(refused)} yet")
         self.model = model
         model.eval()
+        # a block-diffusion family: B positions a row, S denoise passes
+        # and a commit a block (0: a token a step, as every other family)
+        self._block = family.block_length or 0
+        self._steps = self._block_steps(family)
         self.max_model_len = int(self.config.max_model_len
                                  or family.max_positions)
         if self.max_model_len > family.max_positions:
@@ -244,7 +262,8 @@ class ServingEngine:
             page_buckets=(self.config.page_buckets
                           or _pow2_ladder(1, max_pages)),
             prefill_budget_tokens=self.config.prefill_budget_tokens,
-            reliability=self.config.reliability)
+            reliability=self.config.reliability,
+            slots_per_step=family.row_positions)
         # two kinds of state, one manager: paged blocks for the layers
         # that keep keys and values, and (where the family has layers
         # with a fixed-size state) one slot per running sequence; the
@@ -255,7 +274,8 @@ class ServingEngine:
             family.attn_layers, self.config.num_blocks,
             self.config.block_size, family.num_kv_heads, family.head_dim,
             dtype=self.config.kv_dtype, state_shape=family.state_shape,
-            state_slots=slots, token_rows=sched_cfg.batch_buckets[-1])
+            state_slots=slots, token_rows=sched_cfg.batch_buckets[-1],
+            block_length=family.block_length)
         self.allocator = BlockAllocator(self.config.num_blocks,
                                         self.config.block_size,
                                         state_slots=slots)
@@ -302,6 +322,36 @@ class ServingEngine:
         self.failed = False
         self.fail_reason: Optional[str] = None
         self.failed_t: Optional[float] = None
+
+    def _block_steps(self, family) -> int:
+        """Denoise passes a block, checked against what the family and
+        the cache can do; 0 for a family that appends a token a step."""
+        cfg, B = self.config, family.block_length
+        if B is None:
+            if cfg.denoising_steps is not None:
+                raise ValueError(
+                    f"{type(self.model).__name__} generates a token a "
+                    f"step: denoising_steps does not apply")
+            return 0
+        steps = cfg.denoising_steps or B
+        if steps < 1 or B % steps:
+            raise ValueError(f"denoising_steps {steps} must divide the "
+                             f"block length {B}")
+        if cfg.unmask_strategy not in STRATEGIES:
+            # a schedule whose pass count depends on the tokens needs
+            # every pass read back before the next is selected
+            raise ValueError(
+                f"unmask_strategy {cfg.unmask_strategy!r} is not served "
+                f"(have {', '.join(STRATEGIES)})")
+        from .model_runner import PREFILL_PAD
+        if cfg.block_size % B or PREFILL_PAD % B:
+            # a block in flight lies in ONE page, and a shared (prefix)
+            # page holds whole blocks only: its keys and values depend
+            # on nothing behind it
+            raise ValueError(
+                f"block length {B} must divide the cache's block_size "
+                f"{cfg.block_size} and the prefill padding {PREFILL_PAD}")
+        return steps
 
     @property
     def engine_id(self) -> int:
@@ -403,13 +453,16 @@ class ServingEngine:
             raise RequestRejected(
                 "max_new_tokens must be >= 1 (prefill always produces "
                 "the first token)")
-        if len(prompt) + max_new_tokens > self.max_model_len:
+        rows = self.runner.family.row_positions
+        if -(-(len(prompt) + max_new_tokens) // rows) * rows \
+                > self.max_model_len:
             # typed + at submit time: letting this through would only
             # surface later as a block-coverage stall or a clamped
             # position — far less legible than refusing the request
             raise PromptTooLongError(
                 f"prompt({len(prompt)}) + max_new({max_new_tokens}) "
-                f"exceeds max_model_len {self.max_model_len}")
+                f"exceeds max_model_len {self.max_model_len}"
+                + (f" in whole blocks of {rows}" if rows > 1 else ""))
         rel = self.scheduler.reliability
         rid = self._next_req_id
         self._next_req_id += 1
@@ -444,6 +497,13 @@ class ServingEngine:
         layers, k = self.runner.family.routed
         return (np.concatenate(pieces) if pieces
                 else np.zeros((0, layers, k), np.int32))
+
+    def block_passes(self, req_id: int) -> list:
+        """A block-diffusion family's record of a request, pass by pass
+        as the served path ran them: (block start, the block's ids after
+        the pass with -1 where still masked, the experts chosen for its
+        B rows ``[B, expert layers, k]``, was it the commit)."""
+        return list(self._seqs[req_id].passes)
 
     # -- failure plane ---------------------------------------------------
     def _check_alive(self) -> None:
@@ -612,18 +672,28 @@ class ServingEngine:
         has. Where a step must be read back before the next is selected
         (:meth:`_reads_back_first`) the token is delivered here."""
         from ..observability import metrics
-        n = len(seq.tokens)
-        padded = self.runner.prefill_padded_len(n)
-        first_row = len(self._firsts)
-        ahead = not self._reads_back_first() \
-            and first_row < self.cache.firsts.shape[0]
+        family = self.runner.family
+        # a block family prefills the whole blocks; the tokens left
+        # over open the first block in flight, already fixed
+        n = family.prefill_keeps(len(seq.tokens))
+        left = len(seq.tokens) - n
+        seq.block = None
+        seq.passes = [p for p in seq.passes if p[0] < n]
+        padded = self.runner.prefill_padded_len(n) if n else 0
+        first_row = len(self._firsts) if not self._block else None
+        ahead = n > 0 and not self._reads_back_first() and (
+            first_row is None or first_row < self.cache.firsts.shape[0])
         with _span("prefill", req=seq.req_id, tokens=n, padded=padded,
-                   ahead=int(ahead)):
-            with _span("prefill.dispatch"):
-                out, k_stack, v_stack, *state = \
-                    self.runner.prefill_dispatch(seq.tokens)
-                if ahead:
-                    self.cache.keep_first(first_row, out)
+                   ahead=int(ahead), **({"block_tokens": left}
+                                        if self._block else {})):
+            out = None
+            if n:
+                with _span("prefill.dispatch"):
+                    out, k_stack, v_stack, *state = \
+                        self.runner.prefill_dispatch(
+                            seq.tokens[:n] if left else seq.tokens)
+                    if ahead and first_row is not None:
+                        self.cache.keep_first(first_row, out)
             row = np.asarray(seq.table.blocks, np.int64)
             # prefix-cache hit: the leading cached positions' KV is
             # ALREADY in the pool (and shared — rewriting it would
@@ -633,16 +703,19 @@ class ServingEngine:
             # the first generated token comes from the last position.
             start = min(seq.prefix_cached_tokens, n)
             with _span("prefill.scatter"):
-                self.cache.k = PagedKVCache.scatter_prefill(
-                    self.cache.k, k_stack, row, n, self.cache.block_size,
-                    start=start)
-                self.cache.v = PagedKVCache.scatter_prefill(
-                    self.cache.v, v_stack, row, n, self.cache.block_size,
-                    start=start)
-                if state:
-                    # the whole prompt was computed (a prefix hit too),
-                    # so this is the state at its real last positions
-                    self.cache.write_state(seq.table.state_slot, state[0])
+                if n:
+                    self.cache.k = PagedKVCache.scatter_prefill(
+                        self.cache.k, k_stack, row, n,
+                        self.cache.block_size, start=start)
+                    self.cache.v = PagedKVCache.scatter_prefill(
+                        self.cache.v, v_stack, row, n,
+                        self.cache.block_size, start=start)
+                    if state:
+                        # the whole prompt was computed (a prefix hit
+                        # too), so this is the state at its real last
+                        # positions
+                        self.cache.write_state(seq.table.state_slot,
+                                               state[0])
         seq.table.num_tokens = n
         cost = self.runner.prefill_cost(padded)
         info = {"seq": seq, "prompt_tokens": n, "padded_len": padded,
@@ -676,11 +749,9 @@ class ServingEngine:
                    / self.host_link_bps
                    + getattr(seq, "kv_peer_fetch_s", 0.0))
         seq.ready_at = ready + fetch_s
-        if seq.first_token_t is None:
-            seq.first_token_t = seq.ready_at
-            metrics.observe("serving_ttft_s",
-                            max(0.0, seq.first_token_t
-                                - seq.request.arrival_t))
+        if not self._block:
+            # (a block family's first tokens exist at its first commit)
+            self._stamp_first_token(seq, seq.ready_at)
         self.scheduler.mark_running(seq)
         # prefill span: admission -> first-token-ready on the
         # prefill lane (lane queueing included — the decode lane
@@ -697,13 +768,22 @@ class ServingEngine:
                            host_blocks=host_blocks or None,
                            peer_blocks=peer_blocks or None)
         metrics.inc("serving_prefill_tokens_total", n)
-        self._firsts.append(_First(seq, n, out, first_row))
+        if out is not None:
+            self._firsts.append(_First(seq, n, out, first_row))
         if ahead:
             self.prefill_ahead += 1
             metrics.inc("serving_prefill_ahead_total")
         else:
             self._deliver_firsts()
         return info
+
+    @staticmethod
+    def _stamp_first_token(seq: Sequence, t: float) -> None:
+        from ..observability import metrics
+        if seq.first_token_t is None:
+            seq.first_token_t = t
+            metrics.observe("serving_ttft_s",
+                            max(0.0, t - seq.request.arrival_t))
 
     def _deliver_firsts(self) -> None:
         """Read back every first token in flight (``prefill.readback``:
@@ -725,6 +805,8 @@ class ServingEngine:
                     # the whole token log was routed anew (a re-prefill
                     # after an eviction too): its record replaces the old
                     seq.routed = [chosen[:f.n]]
+            if f.row is None:
+                continue            # a block family's prefill: no token
             seq.tokens.append(int(tok[0]))
             if seq.done:
                 # its only token materializes when the prefill LANE
@@ -897,13 +979,22 @@ class ServingEngine:
         if ahead is not None:
             self._count_dropped(ahead.drop_moved())
             fed = {sid: (row, 1) for sid, row in ahead.flying().items()}
-        # the prefill wrote the table's count: a first token is AT it
-        width = self.cache.tokens.shape[0]
-        fed.update((id(f.seq), (width + f.row, 0)) for f in self._firsts)
+        if self._block:
+            # a pass in flight moves its sequence's table only when it
+            # is the block's commit: the next block then opens behind it
+            seqs = {id(s): s for s in ahead.active} if fed else {}
+            fed = {sid: (row, self._block if seqs[sid].block.done
+                         == len(seqs[sid].block.plan) else 0)
+                   for sid, (row, _) in fed.items()}
+        else:
+            # the prefill wrote the table's count: a first token is AT it
+            width = self.cache.tokens.shape[0]
+            fed.update((id(f.seq), (width + f.row, 0))
+                       for f in self._firsts)
         active = [s for s in self.scheduler.running()
                   if getattr(s, "ready_at", 0.0) <= now
-                  and not (id(s) in fed and len(s.generated) + 1
-                           >= s.request.max_new_tokens)]
+                  and not (id(s) in fed and self._ends_in_flight(
+                      s, fed[id(s)][1]))]
         if not active:
             return None
         # chaos scribbles land BEFORE validation — the validator must
@@ -917,9 +1008,10 @@ class ServingEngine:
         active = self._validate_tables(active, now=now)
         if not active:
             return None
+        rows = self.runner.family.row_positions
         victims = self.scheduler.reserve_decode_slots(
             active, now=now,
-            slots=[1 + fed.get(id(s), (0, 0))[1] for s in active])
+            slots=[rows + fed.get(id(s), (0, 0))[1] for s in active])
         if victims:
             # counted HERE, not after the step: evicting every ready
             # sequence aborts the step below, and those evictions must
@@ -969,6 +1061,15 @@ class ServingEngine:
                 return None
         return active, drafts, victims, fed
 
+    def _ends_in_flight(self, seq: Sequence, past: int) -> bool:
+        """Does what is in flight for ``seq`` (``past`` positions beyond
+        its table's count) bring its last token? A token a step: the
+        one in flight; a block family: only a commit brings any."""
+        room = seq.request.max_new_tokens - len(seq.generated)
+        if not self._block:
+            return room <= 1
+        return past > 0 and seq.num_cached + past - len(seq.tokens) >= room
+
     def _build_step(self, now: float, active: List[Sequence],
                     drafts: Dict[int, List[int]], victims: list,
                     fed: Dict[int, Tuple[int, int]]) -> "_Step":
@@ -977,6 +1078,8 @@ class ServingEngine:
         on the device (id ``-1 - row`` of the tokens held there) and
         stands ``past`` positions beyond its table's count."""
         cfg = self.scheduler.config
+        if self._block:
+            return self._build_block_step(now, active, victims, fed)
         rows = []                      # (seq, token or -1 - row, position)
         ctx_tokens = 0
         for s in active:
@@ -1005,20 +1108,79 @@ class ServingEngine:
             tables[i] = s.table.padded(p_bucket)
             if slots is not None:
                 slots[i] = s.table.state_slot
+        counts = self._step_counts(len(rows), b_bucket, p_bucket, ctx_tokens,
+                                   live_pages, victims)
+        return _Step(now, active, drafts, (b_bucket, p_bucket),
+                     (ids, positions, tables)
+                     + (() if slots is None else (slots,)), counts)
+
+    def _step_counts(self, rows, b_bucket, p_bucket, ctx_tokens, live_pages,
+                     victims) -> dict:
+        """What ``decode.dispatch`` says of the step it enqueues."""
         counts = dict(
-            rows=len(rows), row_bucket=b_bucket, page_bucket=p_bucket,
+            rows=rows, row_bucket=b_bucket, page_bucket=p_bucket,
             ctx_tokens=ctx_tokens, live_pages=live_pages,
             kernel_pages_per_block=self.runner.kernel_pages_per_block(
                 self.cache, p_bucket),
             blocks_in_use=self.allocator.used_count,
             blocks_total=self.config.num_blocks, evicted=len(victims))
-        if slots is not None:
+        if self.cache.state is not None:
             counts.update(
                 state_slots_in_use=self.allocator.state_slots_used,
                 state_slots_total=self.allocator.state_slots)
-        return _Step(now, active, drafts, (b_bucket, p_bucket),
-                     (ids, positions, tables)
-                     + (() if slots is None else (slots,)), counts)
+        return counts
+
+    def _build_block_step(self, now: float, active: List[Sequence],
+                          victims: list,
+                          fed: Dict[int, Tuple[int, int]]) -> "_Step":
+        """The next step of a block-diffusion family: one row a
+        sequence, its block's next pass. A sequence in ``fed`` has a
+        pass in flight: its block waits on the device in that step's
+        row — unless that pass is the commit, after which a fresh block,
+        all masked, opens ``past`` positions on. Every count here follows
+        from the plan (``blockdiff.fix_plan``): nothing is read back."""
+        cfg, B = self.scheduler.config, self._block
+        b_bucket = cfg.batch_bucket(len(active))
+        p_bucket = self.scheduler.decode_bucket(active)[1]
+        # per row: source row or -1, positions to fix, the block's first
+        # position, live, then the host's ids and masked bits
+        meta = np.zeros((b_bucket, 4 + 2 * B), np.int32)
+        meta[:, 0] = -1
+        tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
+        ctx_tokens = live_pages = denoise = fresh = 0
+        for i, s in enumerate(active):
+            st = s.block
+            if st is None:
+                # first pass since the prefill: the tokens it left over
+                # open the block, already fixed
+                st = s.block = BlockInFlight(
+                    s.num_cached, s.tokens[s.num_cached:], B, self._steps)
+            row, past = fed.get(id(s), (None, 0))
+            q = st.done if row is None else st.done + 1
+            if past:
+                start, q, n_fix = st.start + past, 0, B // self._steps
+                meta[i, 4 + B:] = 1
+            else:
+                start, n_fix = st.start, st.fixes(q)
+                if row is None:
+                    meta[i, 4:4 + B] = st.ids
+                    meta[i, 4 + B:] = st.masked
+                else:
+                    meta[i, 0] = row
+            meta[i, 1:4] = n_fix, start, 1
+            tables[i] = s.table.padded(p_bucket)
+            ctx_tokens += start + B
+            live_pages += blocks_for_tokens(start + B,
+                                            self.config.block_size)
+            denoise += n_fix > 0
+            fresh += q == 0
+        counts = self._step_counts(len(active), b_bucket, p_bucket,
+                                   ctx_tokens, live_pages, victims)
+        counts.update(seqs=len(active), block_length=B,
+                      denoise_rows=denoise,
+                      commit_rows=len(active) - denoise, fresh_blocks=fresh)
+        return _Step(now, active, {}, (b_bucket, p_bucket), (meta, tables),
+                     counts)
 
     def _decode_rows(self, now: float, picked, ahead: "Optional[_Step]",
                      sync: bool) -> Optional[dict]:
@@ -1065,9 +1227,12 @@ class ServingEngine:
             if due is not None:
                 with _span("decode.readback"):
                     toks, counts, chosen = self.runner.split_counts(
-                        due.out, due.bucket[0])
+                        due.out,
+                        due.bucket[0] * self.runner.family.row_positions)
                 if counts:
                     sp.set_metadata(**self._count_stats(counts))
+                if self._block:
+                    sp.set_metadata(**self._read_block_rows(due, toks))
         about = step or due
         info = {"bucket": about.bucket, "n_active": len(about.active),
                 "tokens": 0, "evictions": len(victims),
@@ -1133,10 +1298,19 @@ class ServingEngine:
         accepted_total = 0
         rejected_total = 0
         ri = 0
+        W = self.runner.family.row_positions    # positions a row yields
         for i, s in enumerate(active):
             n_rows = 1 + len(drafts.get(id(s), ()))
             if not step.kept[i]:
                 ri += n_rows            # evicted or requeued meanwhile
+                continue
+            if self._block:
+                # the row's block, B positions; tokens exist at a commit
+                emitted_total += self._emit_block_row(
+                    s, toks[ri * W:ri * W + W],
+                    None if chosen is None else chosen[ri * W:ri * W + W],
+                    done_at)
+                ri += 1
                 continue
             outs = [int(toks[ri + j]) for j in range(n_rows)]
             if chosen is not None:
@@ -1177,6 +1351,54 @@ class ServingEngine:
         metrics.step_end(tokens=emitted_total, **extra)
         return {"tokens": emitted_total, "spec_accepted": accepted_total,
                 "spec_rejected": rejected_total}
+
+    def _read_block_rows(self, step: "_Step", toks) -> Dict[str, int]:
+        """What ``decode.dispatch`` says of a block family's step as it
+        is read back: positions its denoise passes fixed and tokens its
+        commits bring (of the rows that kept their place)."""
+        from ..observability import metrics
+        B = self._block
+        fixed = committed = commits = passes = 0
+        for i, s in enumerate(step.active):
+            if not step.kept[i]:
+                continue
+            st = s.block
+            passes += 1
+            if st.done < len(st.plan):
+                fixed += st.plan[st.done]
+            else:
+                commits += 1
+                committed += min(
+                    st.start + B - len(s.tokens),
+                    s.request.max_new_tokens - len(s.generated))
+        metrics.inc("serving_block_passes_total", passes)
+        metrics.inc("serving_block_commits_total", commits)
+        return {"tokens_fixed": fixed, "tokens_committed": committed}
+
+    def _emit_block_row(self, seq: Sequence, row, chosen, done_at) -> int:
+        """One sequence's pass as read back: ``row`` is its block after
+        the pass (-1: still masked). A denoise pass updates what the
+        host knows of the block; the commit's keys and values stay, so
+        the table grows by the block, its tokens join the log (those
+        past ``max_new_tokens`` are computed and not delivered) and the
+        next block opens. Returns the tokens delivered."""
+        st, B = seq.block, self._block
+        commit = st.done == len(st.plan)
+        seq.passes.append((st.start, np.array(row), chosen, commit))
+        if not commit:
+            st.masked = row < 0
+            st.ids = np.where(st.masked, 0, row).astype(np.int32)
+            st.done += 1
+            return 0
+        limit = len(seq.request.prompt) + seq.request.max_new_tokens
+        new = st.ids[len(seq.tokens) - st.start:limit - st.start].tolist()
+        seq.tokens.extend(new)
+        seq.table.num_tokens = st.start + B
+        seq.block = BlockInFlight(st.start + B, (), B, self._steps)
+        self._stamp_first_token(seq, done_at)
+        if seq.done:
+            self.scheduler.finish(seq, done_at)
+        return len(new)
 
     def tick(self, now: float = 0.0) -> Optional[dict]:
         """Convenience round for live serving: admissions then one

@@ -23,6 +23,13 @@ functions compiled with ``jax.jit``:
   enqueues step n+1 before it has read step n back — and a prefill's
   first token likewise (``cache.firsts``): the step that consumes it is
   enqueued before the host reads it;
+* a family that generates by diffusion over BLOCKS (``block_length``;
+  ``serving/blockdiff.py``) has a decode program of another signature:
+  a row is a sequence's block of B positions, the program's sampling is
+  "argmax + confidence + choose + write back" (scope ``unmask``), and
+  what stays on the device for the next pass is the block in flight
+  (``cache.block_ids`` / ``cache.block_masked``); its prefill yields
+  keys and values and no token;
 * builds run inside ``build`` spans and leave cost records.
 
 A family supplies its cache geometry (how many layers keep keys and
@@ -34,7 +41,7 @@ out_proj -> mlp), mirroring ``GPTBlock.forward``'s head-major qkv
 split, because ``GPTModel.decode_step``'s cache is a growing per-layer
 concat — exactly the contiguous layout paging replaces. Its prefill
 DOES go through ``decode_step`` (empty caches). The LFM2-MoE family is
-in ``lfm2_family.py``.
+in ``lfm2_family.py``, the SDAR-MoE family in ``sdar_family.py``.
 """
 
 from __future__ import annotations
@@ -79,6 +86,18 @@ class ModelFamily:
     split_pages)`` -> ``(logits [B, V], k_pool, v_pool, state_pool,
     counts)``.
 
+    A family that generates by diffusion over blocks sets
+    ``block_length`` (B) and ``mask_token_id``; its prefill returns
+    ``None`` for the logits (it yields no token, and keeps the keys and
+    values of the prompt's WHOLE blocks only: :meth:`prefill_keeps`),
+    and in place of ``decode`` it has
+
+    ``decode_block(k_pool, v_pool, ids [R, B], starts [R], block_tables
+    [R, pages], live [R] bool, block_size, interpret, split_pages)`` ->
+    ``(logits [R, B, V], k_pool, v_pool, counts)``: the block's B
+    positions from ``starts`` on, seeing the cache before them and each
+    other in both directions, their keys and values written in place.
+
     ``counts`` is the int32 array the program hands back with the
     tokens, or None: per expert layer of a routed model (``routed`` =
     (expert layers, experts a token)) the routing counts that
@@ -89,9 +108,22 @@ class ModelFamily:
     unsupported: Tuple[str, ...] = ()
     count_names: Tuple[str, ...] = ()
     routed: Optional[Tuple[int, int]] = None
+    block_length: Optional[int] = None
+    mask_token_id: Optional[int] = None
 
     def __init__(self, model):
         self.model = model
+
+    @property
+    def row_positions(self) -> int:
+        """Positions a decode row carries, and tokens it can yield."""
+        return self.block_length or 1
+
+    def prefill_keeps(self, n: int) -> int:
+        """Of ``n`` tokens, how many leading ones a prefill computes and
+        keeps keys and values of: all, or a block family's whole blocks
+        (the rest opens its first block in flight)."""
+        return n - n % self.row_positions
 
     def prefill(self, ids, last_idx, interpret):
         raise NotImplementedError
@@ -195,10 +227,13 @@ def served_classes(config) -> tuple:
     reads the model through the second."""
     from ..models.gpt import GPTConfig, GPTForCausalLM
     from ..models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
+    from ..models.sdar import SdarMoeConfig, SdarMoeForCausalLM
     from .lfm2_family import Lfm2MoeFamily
+    from .sdar_family import SdarMoeFamily
     for config_class, classes in (
             (GPTConfig, (GPTForCausalLM, GPTFamily)),
-            (Lfm2MoeConfig, (Lfm2MoeForCausalLM, Lfm2MoeFamily))):
+            (Lfm2MoeConfig, (Lfm2MoeForCausalLM, Lfm2MoeFamily)),
+            (SdarMoeConfig, (SdarMoeForCausalLM, SdarMoeFamily))):
         if isinstance(config, config_class):
             return classes
     raise TypeError(
@@ -306,12 +341,19 @@ class PagedRunner:
 
     @staticmethod
     def _sample(logits, counts):
-        """Greedy tokens ``[B]``; a family's counts ride behind them in
-        the SAME int32 array, so the one read-back brings both."""
+        """Greedy tokens ``[B]`` (one zero where a family's prefill
+        yields no token: ``logits`` None); a family's counts ride behind
+        them in the SAME int32 array, so the one read-back brings both."""
         import jax
         import jax.numpy as jnp
         with jax.named_scope("sample"):
-            tok = jax.lax.argmax(logits, logits.ndim - 1, jnp.int32)
+            tok = jnp.zeros((1,), jnp.int32) if logits is None else \
+                jax.lax.argmax(logits, logits.ndim - 1, jnp.int32)
+        return PagedRunner._with_counts(tok, counts)
+
+    @staticmethod
+    def _with_counts(tok, counts):
+        import jax.numpy as jnp
         if counts is None:
             return tok
         return jnp.concatenate([tok, counts.reshape(-1).astype(jnp.int32)])
@@ -321,7 +363,8 @@ class PagedRunner:
         chosen ``[rows routed, expert layers, k]``) of a program's
         int32 array, which is READ BACK here (the host waits for the
         step that made it); (tokens, None, None) for a family that
-        routes nothing."""
+        routes nothing. A block family's rows are positions, B a
+        sequence, and a token below zero is a position still masked."""
         out = np.asarray(out)
         if out.shape[0] == n_rows:
             return out, None, None
@@ -399,10 +442,55 @@ class PagedRunner:
         return (int(out[0][0]),) + tuple(out[1:])
 
     # -- decode ----------------------------------------------------------
+    def _build_block_decode(self, block_size: int):
+        """The decode program of a block-diffusion family: ONE program
+        serves rows in different phases (a denoise pass, the commit)."""
+        import jax
+        import jax.numpy as jnp
+        from .blockdiff import unmask_low_confidence
+        family = self.family
+        B = family.block_length
+
+        def p2t_decode(weight_arrays, k_pool, v_pool, held_ids, held_masked,
+                       meta, block_tables):
+            # a row is a sequence's block of B positions. meta [R, 4 +
+            # 2B] int32, per row: where its block waits on the device (a
+            # row of held_ids / held_masked [rows, B], the step before's,
+            # which the host has not read) or -1: the block is the
+            # host's, in the row's last 2B columns (ids, then masked:
+            # a fresh block is all masked); how many positions this pass
+            # fixes (0: a commit); the block's first position; is the row
+            # a sequence at all. Pools [L, N, bs, H_kv*D], donated.
+            with jax.named_scope("embed"):
+                src, n_fix, starts = meta[:, 0], meta[:, 1], meta[:, 2]
+                live = meta[:, 3] > 0
+                own = (src < 0)[:, None]
+                at = jnp.clip(src, 0, held_ids.shape[0] - 1)
+                ids = jnp.where(own, meta[:, 4:4 + B], held_ids[at])
+                masked = jnp.where(own, meta[:, 4 + B:] > 0, held_masked[at])
+                fed = jnp.where(masked, family.mask_token_id, ids)
+            with self.bound(weight_arrays):
+                logits, k_pool, v_pool, counts = family.decode_block(
+                    k_pool, v_pool, fed, starts, block_tables, live,
+                    block_size, self.interpret, self.split_pages)
+            with jax.named_scope("unmask"):
+                ids, masked = unmask_low_confidence(logits, ids, masked,
+                                                    n_fix)
+                # one read-back: a position still masked reads -1
+                tok = self._with_counts(
+                    jnp.where(masked, -1, ids).reshape(-1), counts)
+                pad = ((0, held_ids.shape[0] - ids.shape[0]), (0, 0))
+                held = (jnp.pad(ids, pad), jnp.pad(masked, pad))
+            return (tok,) + held + (k_pool, v_pool)
+
+        return jax.jit(p2t_decode, donate_argnums=(1, 2))
+
     def _build_decode(self, batch: int, n_pages: int, block_size: int):
         import jax
         import jax.numpy as jnp
         family = self.family
+        if family.block_length is not None:
+            return self._build_block_decode(block_size)
 
         def p2t_decode(weight_arrays, k_pool, v_pool, fed, firsts, ids,
                        positions, block_tables, *state_args):
@@ -440,21 +528,29 @@ class PagedRunner:
         program of this page bucket (count on ``decode.dispatch``)."""
         from .paged_attention import kernel_pages_per_block
         family = self.family
+        # a block's positions ride the query tile as so many more heads
         return kernel_pages_per_block(
-            n_pages, cache.k.shape[2], family.num_heads, family.head_dim,
+            n_pages, cache.k.shape[2],
+            family.num_heads * family.row_positions, family.head_dim,
             cache.k.dtype, self.split_pages, family.num_kv_heads)
 
-    def _decode_args(self, cache, ids, positions, block_tables, slots):
+    def _decode_args(self, cache, *arrays):
         import jax.numpy as jnp
+        if self.family.block_length is not None:
+            meta, block_tables = arrays
+            return (self._weights(), cache.k, cache.v, cache.block_ids,
+                    cache.block_masked, jnp.asarray(meta, jnp.int32),
+                    jnp.asarray(block_tables, jnp.int32))
+        ids, positions, block_tables = arrays[:3]
         args = (self._weights(), cache.k, cache.v, cache.tokens,
                 cache.firsts, jnp.asarray(ids, jnp.int32),
                 jnp.asarray(positions, jnp.int32),
                 jnp.asarray(block_tables, jnp.int32))
         if cache.state is not None:
-            args += (cache.state, jnp.asarray(slots, jnp.int32))
+            args += (cache.state, jnp.asarray(arrays[3], jnp.int32))
         return args
 
-    def decode(self, cache, ids, positions, block_tables, slots=None):
+    def decode(self, cache, *arrays):
         """One decode step over a bucketed batch: move it to the
         device and call its decode program (built, inside a ``build``
         span, on first use of the bucket). NOTHING is read back: the
@@ -465,13 +561,19 @@ class PagedRunner:
         ``-1 - row`` of the step BEFORE this one and not a token id — or,
         from ``len(cache.tokens)`` on, a row of ``cache.firsts``, where
         the prefills' first tokens wait that the host has not read.
-        ``slots`` are the rows' state slots where the family keeps
-        per-sequence state. Returns the program's int32 array, still on
-        the device: next tokens ``[B]``, then the family's counts
-        (:meth:`split_counts` reads it back)."""
+        ``arrays`` are ``ids, positions, block_tables`` and, where the
+        family keeps per-sequence state, the rows' state ``slots``; for
+        a block-diffusion family ``meta, block_tables`` (the program's
+        comment says what a row of ``meta`` holds), and what stays on
+        the device is the block in flight of every row. Returns the
+        program's int32 array, still on the device: next tokens ``[B]``
+        (a block family's: the rows' blocks end to end), then the
+        family's counts (:meth:`split_counts` reads it back)."""
+        block_tables = arrays[1 if self.family.block_length is not None
+                              else 2]
         B, n_pages = block_tables.shape
         key = (B, n_pages)
-        args = self._decode_args(cache, ids, positions, block_tables, slots)
+        args = self._decode_args(cache, *arrays)
         fn = self._decode_programs.get(key)
         if fn is not None:
             out = fn(*args)
@@ -484,9 +586,12 @@ class PagedRunner:
                 out = fn(*args)
                 with b.cost():
                     self._decode_costs[key] = program_cost(fn, shapes)
-        tok, cache.tokens, cache.k, cache.v = out[:4]
-        if cache.state is not None:
-            cache.state = out[4]
+        if self.family.block_length is not None:
+            tok, cache.block_ids, cache.block_masked, cache.k, cache.v = out
+        else:
+            tok, cache.tokens, cache.k, cache.v = out[:4]
+            if cache.state is not None:
+                cache.state = out[4]
         return tok
 
     # -- deterministic cost accounting (PR 7 cost model) -----------------

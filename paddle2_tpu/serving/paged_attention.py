@@ -539,7 +539,14 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
                            pages_per_split=None, layer=0):
     """Paged decode attention.
 
-    q: ``[B, 1, H, D]`` (paddle layout) — one new token per sequence.
+    q: ``[B, Q, H, D]`` (paddle layout) — ``Q`` query positions per
+    sequence: 1 for a decoder that appends a token a step; ``Q`` > 1 for
+    one that carries a BLOCK of positions a step, all of which see the
+    same context (``ctx_lens`` ends at the block's end, so they see each
+    other in both directions). The ``Q`` positions ride as ``Q`` times as
+    many query heads of each key/value head: one query tile, ONE walk
+    of the sequence's live pages, and with ``Q`` 1 the program is what
+    it was.
     k_pool/v_pool: the whole model's shared pools
     ``[L, num_blocks, block_size, H_kv*D]`` (a token's key/value heads
     merged into one row); ``layer`` names the (static) layer to read. A
@@ -551,7 +558,7 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
     block_tables: int32 ``[B, n_pages]`` physical block ids per
     sequence (pad rows with the garbage block).
     ctx_lens: int32 ``[B]`` valid keys per sequence (including the
-    token just appended). Returns ``[B, 1, H, D]``.
+    token just appended). Returns ``[B, Q, H, D]``.
 
     ``pages_per_split``: split-K width for the flash-decode body.
     ``None`` auto-dispatches — the single-split global-softmax body
@@ -559,7 +566,7 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
     :func:`auto_pages_per_split`. An explicit value forces split-K
     whenever more than one split results.
     """
-    B, _, H, D = q.shape
+    B, Q, H, D = q.shape
     layer = int(layer)
     bs = k_pool.shape[2]
     n_pages = block_tables.shape[1]
@@ -568,6 +575,16 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
     if interpret is None:
         interpret = interpret_default()
     Hkv = k_pool.shape[3] // D
+    if Q > 1:
+        # [B, Q, H_kv, g, D] -> key/value head major: the Q x g query
+        # rows of a key/value head lie together, as a group's do
+        g = H // Hkv
+        heads = jnp.swapaxes(q.reshape(B, Q, Hkv, g, D), 1, 2)
+        out = paged_attention_decode(
+            heads.reshape(B, 1, H * Q, D), k_pool, v_pool, block_tables,
+            ctx_lens, scale, interpret, pages_per_split, layer)
+        return jnp.swapaxes(out.reshape(B, Hkv, Q, g, D), 1, 2).reshape(
+            B, Q, H, D)
     pps = _split_width(n_pages, bs, H, D, k_pool.dtype, pages_per_split,
                        Hkv)
     bt = jnp.asarray(block_tables, jnp.int32)

@@ -117,6 +117,14 @@ class Sequence:
         # prefill's, then one a decode step); ``ServingEngine.
         # routed_experts`` joins them
         self.routed: List = []
+        # a block-diffusion family's: the block in flight as the host
+        # last read it (``blockdiff.BlockInFlight``; None until the
+        # first pass after a prefill), and the record of every pass
+        # read back: (block start, the block's ids after the pass with
+        # -1 where still masked — a commit's are the final tokens —,
+        # the experts chosen for its rows, was it the commit)
+        self.block = None
+        self.passes: List = []
 
     def check(self) -> "Sequence":
         """Raise the typed error a post-submission failure recorded
@@ -181,6 +189,9 @@ class SchedulerConfig:
     # admission control / load shedding (None = PR 9 behavior:
     # unbounded queue, no deadlines)
     reliability: Optional[ReliabilityConfig] = None
+    # cache slots a sequence's step writes: 1 (a token a step), or the
+    # block length of a family whose step carries a block of positions
+    slots_per_step: int = 1
 
     def __post_init__(self):
         self.batch_buckets = tuple(sorted(set(self.batch_buckets)))
@@ -375,8 +386,9 @@ class ContinuousBatchingScheduler:
                 # request never leaks shared references
                 cached, _ = self.prefix_cache.lookup(seq.tokens,
                                                      share=False)
+            step = self.config.slots_per_step
             need_blocks = blocks_for_tokens(
-                need_tokens + 1, self.allocator.block_size) - len(cached)
+                need_tokens + step, self.allocator.block_size) - len(cached)
             if spent and spent + need_tokens > budget:
                 break                      # budget spent: next round
             if not self.allocator.can_admit(need_blocks):
@@ -409,7 +421,7 @@ class ContinuousBatchingScheduler:
                 else:
                     metrics.inc("serving_prefix_misses_total")
             try:
-                seq.table.ensure_capacity(need_tokens + 1)
+                seq.table.ensure_capacity(need_tokens + step)
             except OutOfBlocksError:
                 # the can_allocate check above counted reclaimable
                 # cached blocks as headroom — but THIS request's own
@@ -464,7 +476,8 @@ class ContinuousBatchingScheduler:
         """Make sure every sequence in ``seqs`` (default: all running)
         has block slots for the token(s) the next decode step appends
         — ``slots[i]`` per sequence (default 1; a speculative verify
-        round reserves ``1 + len(drafts)``) — evicting LIFO on
+        round reserves ``1 + len(drafts)``, a block-diffusion pass its
+        whole block) — evicting LIFO on
         exhaustion. Returns the evicted sequences (already requeued).
         ``now`` stamps the eviction spans."""
         victims: List[Sequence] = []

@@ -238,6 +238,47 @@ def test_paged_decode_grouped_query_cell_shape(one_chip):
                 if " copy(" in ln and "[1,16384,16,512]" in ln]
 
 
+def test_paged_decode_block_of_positions_cell_shape(one_chip):
+    """What `sdar-serve-gen512-backlog` runs: a pass carries 4 positions
+    of each of 64 sequences, 32 query heads over 4 key/value heads of
+    128 (512-lane pages): the positions ride the query tile as 4 x 32
+    rows ([128, 512]) over ONE walk of 192 pages (3072 positions) of a
+    12,288-block pool, layer 3 of 4. 192 x (128 rows x 16 slots x 4 B of
+    scores + 16 x 512 x 2 B of V) = 4.5 MiB of the 8 MiB budget keeps it
+    on the single-softmax body."""
+    assert pa.decode_scratch_vmem_bytes(192, 16, 128, BF16, 4 * 32, 4) == \
+        192 * (128 * 16 * 4 + 16 * 512 * 2)
+    assert pa.fits_single_softmax(192, 16, 128, BF16, None, 4 * 32, 4)
+    assert pa.kernel_pages_per_block(192, 16, 4 * 32, 128, BF16,
+                                     num_kv_heads=4) == 64
+    pool = ((4, 12288, 16, 4 * 128), BF16)
+    avals = (((64, 4, 32, 128), BF16), pool, pool,
+             ((64, 192), jnp.int32), ((64,), jnp.int32))
+    fn = functools.partial(pa.paged_attention_decode, interpret=False,
+                           layer=3)
+    text = _compile(one_chip, fn, *avals, kernels=["paged_decode"]).as_text()
+    assert "paged_decode_split" not in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[4,12288,16,512]" in ln]
+
+
+@pytest.mark.parametrize("seq", [1024, 2048])
+def test_flash_block_causal_prefill_shape(one_chip, seq):
+    """The block-diffusion prefill's attention: [1, S, 32, 128] bf16
+    under the causal mask of blocks of 4, forward and backward (S 2048
+    walks two k blocks per q block)."""
+    qkv = ((1, seq, 32, 128), BF16)
+
+    def loss(q, k, v):
+        o = pallas_flash.flash_attention_bshd(
+            q, k, v, causal=True, causal_block=4, interpret=False)
+        return o.astype(F32).sum()
+
+    _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv,
+             kernels=(["flash_fwd", "flash_bwd"] if seq == 1024 else
+                      ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"]))
+
+
 # ------------------------------------------------------- grouped matmul
 @pytest.mark.parametrize("rows,k,n", [
     (256, 2048, 1536), (256, 1536, 2048),       # a 64-row decode step
@@ -266,6 +307,20 @@ def test_moe_gmm_held_share(one_chip):
 
     _compile(one_chip, fn, ((2048, 2048), BF16), ((8, 2048, 1536), BF16),
              ((65,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    (2048, 2048, 768), (2048, 768, 2048),       # a pass: 64 x 4 rows x 8
+    (16384, 2048, 768), (16384, 768, 2048)])    # a 2048-token prefill
+def test_moe_gmm_128_experts_cell_shapes(one_chip, rows, k, n):
+    """`sdar-serve-gen512-backlog`: 128 experts of width 768, 8 a row."""
+    from paddle2_tpu.kernels import moe_gmm
+
+    def fn(lhs, rhs, sizes, first):
+        return moe_gmm.moe_gmm(lhs, rhs, sizes, first, interpret=False)
+
+    _compile(one_chip, fn, ((rows, k), BF16), ((128, k, n), BF16),
+             ((129,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
 
 
 @pytest.mark.parametrize("rows", [64, 3072])

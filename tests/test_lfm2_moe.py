@@ -450,37 +450,48 @@ def test_grouped_matmul_against_plain_loop(sizes, first, held):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_expert_shares_add_up_to_the_whole_layer(bench):
-    """Eight 8-expert shares of one 64-expert layer: each routes over
-    all 64 and computes its own experts' part; the parts add up to the
-    uncut reference's layer (and each part is the reference's share)."""
-    H, F, E, k = 64, 48, 64, 4
+@pytest.mark.parametrize("router,E,k", [("sigmoid", 64, 4),
+                                        ("softmax", 128, 8)])
+def test_expert_shares_add_up_to_the_whole_layer(bench, router, E, k):
+    """Eight shares of one expert layer (8 of 64 sigmoid-routed experts,
+    16 of 128 softmax-routed ones): each routes over all and computes its
+    own experts' part; the parts add up to the uncut reference's layer
+    (and each part is the reference's share)."""
+    H, F, n = 64, 48, E // 8
     paddle.seed(21)
-    whole = DroplessExperts(H, F, E, k, std=0.2)
-    ref, cfg = bench["ref"], dict(bench["cfg"], num_experts=E,
-                                  num_experts_per_tok=k)
-    p = {"moe0_gate": whole.gate_weight._data,
-         "moe0_bias": whole.expert_bias._data, "moe0_w1": whole.w1._data,
-         "moe0_w3": whole.w3._data, "moe0_w2": whole.w2._data}
+    whole = DroplessExperts(H, F, E, k, std=0.2, router=router)
+    from common import load_module
+    from reference.common import matmul_f32
+    if router == "sigmoid":
+        ref, pre = bench["ref"], "moe0_"
+        extra = {"moe0_bias": whole.expert_bias._data}
+        cfg = dict(bench["cfg"], num_experts=E, num_experts_per_tok=k)
+    else:
+        ref, pre, extra = load_module("reference", "sdar_moe"), "l0_", {}
+        cfg = {"num_experts": E, "num_experts_per_tok": k,
+               "norm_topk_prob": True}
+    p = dict({pre + "gate": whole.gate_weight._data,
+              pre + "w1": whole.w1._data, pre + "w3": whole.w3._data,
+              pre + "w2": whole.w2._data}, **extra)
     a = jnp.asarray(np.random.default_rng(21).standard_normal((40, H)),
                     jnp.float32)
-    from reference.common import matmul_f32
     want, used, _ = ref.experts_ff(a, p, 0, cfg, matmul_f32)
     total = jnp.zeros_like(a)
     assigned = 0
     for share in range(8):
-        lo = 8 * share
-        part = DroplessExperts(H, F, E, k, held=(lo, 8))
+        lo = n * share
+        part = DroplessExperts(H, F, E, k, held=(lo, n), router=router)
         part.gate_weight.set_value(whole.gate_weight)
-        part.expert_bias.set_value(whole.expert_bias)
+        if router == "sigmoid":
+            part.expert_bias.set_value(whole.expert_bias)
         for name in ("w1", "w3", "w2"):
             getattr(part, name).set_value(paddle.Tensor(
-                getattr(whole, name)._data[lo:lo + 8]))
+                getattr(whole, name)._data[lo:lo + n]))
         out, counts = part.route_and_run(a, interpret=True)
         np.testing.assert_allclose(
             np.asarray(out),
             np.asarray(ref.experts_ff(a, p, 0, cfg, matmul_f32,
-                                      held=(lo, 8))[0]), atol=2e-5)
+                                      held=(lo, n))[0]), atol=2e-5)
         total = total + out
         assigned += int(counts[0])
     assert assigned == 40 * k                  # every assignment, once
