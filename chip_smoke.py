@@ -263,6 +263,53 @@ def kernel_parity(size: dict) -> dict:
                     f"reference by {gap}")
     err.update(gqa_parity(size))
     err.update(gmm_parity(size))
+    err.update(flash_parity(size))
+    return err
+
+
+def flash_parity(size: dict) -> dict:
+    """The flash kernel's walk — forward and the three gradients —
+    against the XLA attention path on this device, where the size
+    allows at the training cell's shape (B 8, S 1024, 16 heads of 64,
+    causal: four q tiles, one fused backward call) and at the
+    block-diffusion prefill's (S 2048, 32 heads of 128, causal by
+    blocks of 4)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle2_tpu.kernels.attention import _sdpa_xla
+    from paddle2_tpu.kernels.pallas_flash import flash_attention_bshd
+    big = size["hidden"] >= 1024
+    cases = {"train": ((8, 1024, 16, 64) if big else (1, 256, 2, 64), 1),
+             "blockdiff": ((1, 2048, 32, 128) if big else (1, 256, 2, 128),
+                           4)}
+    rng = np.random.default_rng(3)
+    err = {}
+    for case, (shape, block) in cases.items():
+        q, k, v, w = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                      for _ in range(4))
+
+        def both(attend):
+            def run(q, k, v):
+                o = attend(q, k, v, causal=True, causal_block=block)
+                return (o.astype(jnp.float32)
+                        * w.astype(jnp.float32)).sum(), o
+            (_, o), grads = jax.jit(jax.value_and_grad(
+                run, (0, 1, 2), has_aux=True))(q, k, v)
+            return [np.asarray(x, np.float32) for x in (o,) + grads]
+
+        for name, out, ref in zip(("o", "dq", "dk", "dv"),
+                                  both(flash_attention_bshd),
+                                  both(_sdpa_xla)):
+            gap = err[f"flash.{case}.{name}"] = float(
+                np.abs(out - ref).max())
+            # both round f32 sums to bf16 (the XLA path its
+            # probabilities too): the other kernels' 2e-2, in units of
+            # the largest value where that passes 1
+            if not np.isfinite(out).all() \
+                    or gap > 2e-2 * max(1.0, float(np.abs(ref).max())):
+                raise AssertionError(
+                    f"flash kernel ({case}, {name}) off the XLA path "
+                    f"by {gap}")
     return err
 
 

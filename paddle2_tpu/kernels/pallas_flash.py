@@ -1,26 +1,40 @@
-"""Pallas TPU flash attention, forward + backward (FlashAttention-2).
+"""Pallas TPU flash attention, forward + backward.
 
 Replaces the reference's CUDA flash kernels
-(paddle/phi/kernels/gpu/flash_attn_kernel.cu, third_party/flashattn) with a
-TPU-native tiled online-softmax kernel:
+(paddle/phi/kernels/gpu/flash_attn_kernel.cu, third_party/flashattn) with
+TPU-native kernels that never write the score matrix to HBM:
 
-- forward: grid (B, H, nq, nk) with the k-axis innermost; a VMEM scratch
-  accumulator carries (o_acc, row-max m, row-sum l) across k steps, so HBM
-  traffic is O(S*D) not O(S^2). The log-sum-exp is saved for the backward.
-- backward: two kernels recompute attention tile-wise (flash-2 split):
-  dK/dV with the q-axis innermost, dQ with the k-axis innermost, both
-  seeded by delta = rowsum(dO * O).
-- causal masking skips fully-masked tiles via pl.when (no wasted MXU work
-  on the upper triangle); with Sq != Sk the diagonal is bottom-right
-  aligned, matching the XLA fallback and flash-attn v2.1 semantics.
+- the WALK (``_fwd_walk_kernel`` / ``_bwd_walk_kernel``), taken whenever
+  a head's Q, K, V (and dO, O, dQ, dK, dV) fit VMEM: grid (B, H), one
+  step a (batch row, head), the tiles of the score matrix walked INSIDE
+  the step by a statically unrolled loop (no grid step a tile). The
+  forward walks q tiles: a tile's rows meet the strip of columns their
+  mask lets them see in ONE QK^T, take a direct softmax over it (the
+  strip is resident, so no running maximum) and ONE PV. The backward is
+  ONE fused call that walks k tiles: a tile's columns meet the strip of
+  rows that see them, one QK^T, one dO V^T and one exp a tile, dK and dV
+  of the tile complete when it ends, dQ accumulated across tiles in
+  VMEM. Tiles wholly above the staircase are never touched, tiles wholly
+  under it take NO mask, only the tiles the staircase crosses build one
+  (``_row_strips`` / ``_col_strips``). lse crosses HBM as one f32 row a
+  head, (B, H, 1, S), turned between row and column in the kernel;
+  delta = rowsum(dO * O) is computed in the backward from the tiles it
+  holds.
+- the GRID kernels, for sequences whose heads do not fit VMEM (and for
+  Sq > Sk under a causal mask, where rows see nothing): forward grid
+  (B, H, nq, nk) with the k-axis innermost, a VMEM scratch accumulator
+  carrying (o_acc, row-max m, row-sum l) across k steps; backward in two
+  kernels (flash-2 split), dK/dV with the q-axis innermost, dQ with the
+  k-axis innermost, both seeded by delta. Tiles wholly above the
+  staircase are skipped via pl.when; lse/delta ride in (…, Sq, 128)-lane
+  f32 buffers there.
+- with Sq != Sk the diagonal is bottom-right aligned, matching the XLA
+  fallback and flash-attn v2.1 semantics.
 - the causal mask is BLOCK-causal by a static block length: inside the
   kernels ``causal`` is that length (0: no mask, 1: plain causal, a
   power of two B: a row sees the columns of its own block of B positions
   whole, later ones included, and of every earlier block — the mask a
-  block-diffusion decoder prefills under). Forward and backward both;
-  tiles wholly above the staircase are still skipped.
-- lse/delta ride in (…, Sq, 128)-lane f32 buffers — the TPU lane-tiling
-  minimum, the same layout the official jax flash kernel uses for l/m/di.
+  block-diffusion decoder prefills under). Forward and backward both.
 
 Layout contract matches the reference flash API: (batch, seq, heads, dim).
 Compute is f32 on the MXU regardless of input dtype (bf16 in, f32 softmax).
@@ -47,16 +61,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._platform import interpret_default
 
-# 1024-tiles measured best on v5e for the GPT bench (scores tile of
-# 1024x1024 f32 = 4MB sits comfortably in VMEM; fewer grid steps beats
-# finer tiling until S is long enough that autotune picks smaller blocks).
-# Env-overridable for per-chip tuning (incubate.autotune searches these).
+# Upper bounds on a tile's rows and columns, for both kinds of kernel
+# (the walk's own tile is *_WALK_TILE or this, whichever is smaller; the
+# grid's tile is this). Env-overridable for per-chip tuning
+# (incubate.autotune searches these).
 import os as _os
 DEFAULT_BLOCK_Q = int(_os.environ.get("FLAGS_flash_block_q", 1024))
 DEFAULT_BLOCK_K = int(_os.environ.get("FLAGS_flash_block_k", 1024))
 # backward kernels may prefer different tiles than forward
 BWD_BLOCK_Q = int(_os.environ.get("FLAGS_flash_bwd_block_q", 0)) or None
 BWD_BLOCK_K = int(_os.environ.get("FLAGS_flash_bwd_block_k", 0)) or None
+# The walk's tile (rows of a q tile, columns of a k tile), by direction.
+# Read on a v5e at B 8 / S 1024 / H 16 / D 64 (PERF.md section 6, PR 33;
+# the same order held at D 128 / S 2048 and at S 3072): a K or V tile
+# held in the MXU is paid for by the rows that stream past it, so the
+# forward, whose tiles stream a q tile's rows, does best at 512 (12 of
+# the square's 16 tiles of 256) and loses at 128 though that does 9 of
+# 16; the backward's tiles stream whole strips of rows and do best at
+# 256 (10 of 16).
+FWD_WALK_TILE = 512
+BWD_WALK_TILE = 256
+# What a walk's step may hold in VMEM by `_walk_bytes`' reckoning (a v5e
+# core has 128 MiB); beyond it the grid kernels run. The compiler is
+# given twice that as its limit: the reckoning leaves Mosaic's own
+# temporaries out.
+WALK_VMEM_BYTES = 32 << 20
 NEG_INF = float("-inf")
 
 
@@ -83,6 +112,17 @@ def _causal_keep(rows, cols, block):
     return _last_col(rows, block) >= cols
 
 
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.DEFAULT)
+
+
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
+_TN = ((0,), (0,))      # a.T @ b
+
+
 def _online_softmax_step(s, v, acc, m_sc, l_sc):
     """Shared flash-fwd tile update: online softmax recurrence over the
     masked score tile `s` (NEG_INF = masked). Mutates acc/m_sc/l_sc."""
@@ -94,10 +134,7 @@ def _online_softmax_step(s, v, acc, m_sc, l_sc):
     p = jnp.where(s == NEG_INF, 0.0, p)
     alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - safe_m))
     l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-    acc[:] = acc[:] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.DEFAULT)
+    acc[:] = acc[:] * alpha + _dot(p.astype(v.dtype), v, _NN)
     m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
     l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
 
@@ -113,61 +150,157 @@ def _flash_finalize(o_ref, lse_ref, acc, m_sc, l_sc):
 
 
 def _scores(q, k, scale):
-    return jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.DEFAULT) * scale
+    return _dot(q, k, _NT) * scale
 
 
-def _bwd_p_ds(s, lse, delta, do, v, guarded=True):
-    """Shared flash-bwd tile math: probabilities p and score cotangent ds
-    from the masked tile `s` and saved (lse, delta). `guarded=False`
-    skips the fully-masked-row selects (two VPU passes over the tile) —
-    valid whenever every row has at least one unmasked column, i.e.
-    causal with Sk >= Sq or no mask (the single-block fused path)."""
-    if guarded:
-        p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))
-        p = jnp.where((s == NEG_INF) | (lse == NEG_INF), 0.0, p)
-    else:
-        p = jnp.exp(s - lse)              # masked: exp(-inf - finite) = 0
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.DEFAULT)
+def _bwd_p_ds(s, lse, delta, do, v):
+    """Shared flash-bwd tile math of the grid kernels: probabilities p
+    and score cotangent ds from the masked tile `s` and saved (lse,
+    delta), rows that see nothing guarded."""
+    p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))
+    p = jnp.where((s == NEG_INF) | (lse == NEG_INF), 0.0, p)
+    dp = _dot(do, v, _NT)
     return p, p * (dp - delta)
+
+
+# ------------------------------------------------------------- the walk
+
+def _tile_kind(r0, r1, c0, c1, block, offset):
+    """What the (block-)causal mask does to the tile of rows r0..r1 and
+    columns c0..c1 (inclusive; the diagonal bottom-right aligned by
+    ``offset``): 0 every entry masked, 1 the staircase crosses it,
+    2 no entry masked. A row sees columns 0.._last_col(row), and that
+    grows with the row: the tile's corners decide."""
+    if not block or _last_col(r0 + offset, block) >= c1:
+        return 2
+    return 1 if _last_col(r1 + offset, block) >= c0 else 0
+
+
+def _row_strips(Sq, Sk, tq, tk, block, offset):
+    """The forward walk's plan. Per q tile (lo, hi): columns [0, lo) are
+    seen by every row of the tile, [lo, hi) by some — the tiles the
+    staircase crosses —, [hi, Sk) by none. Kinds only fall along a row
+    of tiles, so counting them places the two edges."""
+    out = []
+    for r0 in range(0, Sq, tq):
+        kinds = [_tile_kind(r0, r0 + tq - 1, c0, c0 + tk - 1, block, offset)
+                 for c0 in range(0, Sk, tk)]
+        out.append((kinds.count(2) * tk, (len(kinds) - kinds.count(0)) * tk))
+    return out
+
+
+def _col_strips(Sq, Sk, tq, tk, block, offset):
+    """The backward walk's plan. Per k tile (lo, hi): rows [0, lo) see
+    none of the tile's columns, [lo, hi) some, [hi, Sq) all. Kinds only
+    rise down a column of tiles."""
+    out = []
+    for c0 in range(0, Sk, tk):
+        kinds = [_tile_kind(r0, r0 + tq - 1, c0, c0 + tk - 1, block, offset)
+                 for r0 in range(0, Sq, tq)]
+        out.append((kinds.count(0) * tq, (len(kinds) - kinds.count(2)) * tq))
+    return out
+
+
+def _masked(s, rows0, cols0, block):
+    """The score tile ``s`` (its first row and column at rows0 — offset
+    included — and cols0) under the mask: NEG_INF where not seen."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + rows0
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + cols0
+    return jnp.where(_causal_keep(rows, cols, block), s, NEG_INF)
+
+
+def _fold_scale(scale):
+    """(factor for the q tile, factor for the f32 scores): a power of
+    two folds into the bf16 q tile exactly (an XLA-side pre-scale would
+    cost a full extra HBM pass on q); any other scale stays on the f32
+    scores, where it rounds nothing."""
+    return (scale, 1.0) if math.frexp(scale)[0] == 0.5 else (1.0, scale)
+
+
+def _times(x, factor):
+    return x if factor == 1.0 else x * jnp.asarray(factor, x.dtype)
+
+
+def _walk_bytes(Sq, Sk, D, itemsize, tq, tk, backward):
+    """VMEM a walk's step holds, roughly: the head's operands and
+    results double-buffered (D padded to 128 lanes), the backward's
+    three f32 scratches, and the f32 temporaries of the widest strip."""
+    lanes = -(-D // 128) * 128
+    if backward:
+        io = 2 * (4 * Sq + 4 * Sk) * lanes * itemsize
+        return io + 3 * Sq * 128 * 4 + Sq * tk * (16 + 2 * itemsize)
+    io = 2 * (2 * Sq + 2 * Sk) * lanes * itemsize
+    return io + tq * Sk * (8 + itemsize)
+
+
+def _walks(Sq, Sk, D, dtype, causal, block_q, block_k, backward):
+    """The walk's tiles (rows of a q tile, columns of a k tile: the
+    direction's tile, no more than the caller's bound, fitted to the
+    sequence) if this shape takes the walk, else None: every row sees a
+    column (causal with Sq > Sk leaves rows that see none: the grid
+    kernels guard those), tiles the chip can slice and turn
+    (lane-aligned, or the whole axis), and the head fits VMEM."""
+    tile = BWD_WALK_TILE if backward else FWD_WALK_TILE
+    tq = _fit_block(Sq, min(block_q, tile))
+    tk = _fit_block(Sk, min(block_k, tile))
+    fits = _walk_bytes(Sq, Sk, D, jnp.dtype(dtype).itemsize, tq, tk,
+                       backward) <= WALK_VMEM_BYTES
+    aligned = (tq % 128 == 0 or tq == Sq) and (tk % 128 == 0 or tk == Sk)
+    return (tq, tk) if fits and aligned and (
+        not causal or Sk >= Sq) else None
+
+
+def _walk_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=2 * WALK_VMEM_BYTES)
+
+
+def _lanes(x, n):
+    """(rows, 128) with every lane of a row alike -> (rows, n): whole
+    copies side by side where n allows it (a slice of one lane would be
+    spread again by a permute a vreg), else the one lane broadcast."""
+    if n % 128:
+        return x[:, :1]
+    return x if n == 128 else jnp.concatenate([x] * (n // 128), axis=1)
+
+
+def _col_to_row(x):
+    """(n, 1) f32 -> (1, n): through the transpose unit, 128 lanes wide."""
+    return jnp.broadcast_to(x, (x.shape[0], 128)).T[:1]
+
+
+def _row_to_cols(x):
+    """(1, n) f32 -> (n, 128), row i holding x[0, i] in every lane."""
+    return jnp.broadcast_to(x, (128, x.shape[1])).T
 
 
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel_1blk(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                     offset):
-    """Single-block specialization (nq == nk == 1): the whole row fits in
-    one tile, so the online-softmax recurrence, VMEM scratch, and init/
-    finalize predication all collapse into a direct softmax — measured
-    ~30% faster than the general kernel at the GPT bench shape
-    (B8 S1024 H16 D64 on v5e). scale folds into the q tile in VMEM (an
-    XLA-side pre-scale would cost a full extra HBM pass on q).
-    Requires offset >= 0 when causal (every row has a valid column, so
-    the row max is finite and no masked-row guards are needed)."""
-    q = q_ref[0, 0] * jnp.asarray(scale, q_ref.dtype)
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                            precision=jax.lax.Precision.DEFAULT)
-    if causal:
-        bq, bk = s.shape
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + offset
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
-    m = jnp.max(s, axis=1, keepdims=True)
-    p = jnp.exp(s - m)                    # masked: exp(-inf - finite) = 0
-    l = jnp.sum(p, axis=1, keepdims=True)
-    o = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                            precision=jax.lax.Precision.DEFAULT)
-    o_ref[0, 0] = (o / l).astype(o_ref.dtype)
-    lse_ref[0, 0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[2:])
+def _fwd_walk_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
+                     offset, tq, tk):
+    """One (batch row, head): q tiles walked in the step, each against
+    the strip of columns it sees — one QK^T, a direct softmax (the row
+    max is finite: every row sees a column), one PV. Only the strip's
+    tail [lo, hi), which the staircase crosses, is masked. A sequence of
+    one tile is one strip: the plain softmax over the masked square."""
+    Sq, Sk = q_ref.shape[2], k_ref.shape[2]
+    q_scale, s_scale = _fold_scale(scale)
+    strips = _row_strips(Sq, Sk, tq, tk, causal, offset)
+    for i, (lo, hi) in enumerate(strips):
+        rows = slice(i * tq, (i + 1) * tq)
+        q = _times(q_ref[0, 0, rows], q_scale)
+        s = _times(_dot(q, k_ref[0, 0, :hi], _NT), s_scale)
+        if hi > lo:
+            tail = _masked(s[:, lo:], i * tq + offset, lo, causal)
+            s = jnp.concatenate([s[:, :lo], tail], axis=1) if lo else tail
+        m = jnp.max(s, axis=1, keepdims=True)
+        p = jnp.exp(s - m)                # masked: exp(-inf - finite) = 0
+        l = jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, 0, :hi]
+        o = _dot(p.astype(v.dtype), v, _NN)
+        o_ref[0, 0, rows] = (o / l).astype(o_ref.dtype)
+        lse_ref[0, 0, :, rows] = _col_to_row(m + jnp.log(l))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
@@ -197,11 +330,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
         # operands to f32 would fall off the MXU fast path (~8x slower)
         s = _scores(q, k, scale)                      # (Bq, Bk) f32
         if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + q_start + offset
-            cols = jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1) + k_start
-            s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
+            s = _masked(s, q_start + offset, k_start, causal)
         _online_softmax_step(s, v, acc, m_sc, l_sc)
 
     @pl.when(ik == nk - 1)
@@ -223,31 +352,27 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     block_q, block_k = _clamp_blocks_for_dtype(q.dtype, block_q, block_k)
-    bq, bk = _fit_block(Sq, block_q), _fit_block(Sk, block_k)
-    nq, nk = Sq // bq, Sk // bk
-
-    if nq == 1 and nk == 1 and (not causal or Sk >= Sq):
+    walk = _walks(Sq, Sk, D, q.dtype, causal, block_q, block_k, False)
+    if walk:
+        spec_q = pl.BlockSpec((1, 1, Sq, D), lambda b, h: (b, h, 0, 0))
+        spec_k = pl.BlockSpec((1, 1, Sk, D), lambda b, h: (b, h, 0, 0))
         o, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_1blk, scale=scale, causal=causal,
-                              offset=Sk - Sq),
+            functools.partial(_fwd_walk_kernel, scale=scale, causal=causal,
+                              offset=Sk - Sq, tq=walk[0], tk=walk[1]),
             grid=(B, H),
-            in_specs=[pl.BlockSpec((1, 1, Sq, D),
-                                   lambda b, h: (b, h, 0, 0)),
-                      pl.BlockSpec((1, 1, Sk, D),
-                                   lambda b, h: (b, h, 0, 0)),
-                      pl.BlockSpec((1, 1, Sk, D),
-                                   lambda b, h: (b, h, 0, 0))],
-            out_specs=[pl.BlockSpec((1, 1, Sq, D),
-                                    lambda b, h: (b, h, 0, 0)),
-                       pl.BlockSpec((1, 1, Sq, 128),
-                                    lambda b, h: (b, h, 0, 0))],
+            in_specs=[spec_q, spec_k, spec_k],
+            out_specs=[spec_q, pl.BlockSpec((1, 1, 1, Sq),
+                                            lambda b, h: (b, h, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-                       jax.ShapeDtypeStruct((B, H, Sq, 128), jnp.float32)],
+                       jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32)],
+            compiler_params=_walk_params(),
             name="flash_fwd",
             interpret=interpret,
         )(q, k, v)
-        return o, lse[..., 0]
+        return o, lse[:, :, 0]
 
+    bq, bk = _fit_block(Sq, block_q), _fit_block(Sk, block_k)
+    nq, nk = Sq // bq, Sk // bk
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=bq, block_k=bk, nk=nk,
                                offset=Sk - Sq)
@@ -284,44 +409,48 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 
 # --------------------------------------------------------------- backward
 
-def _bwd_fused_1blk_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dq_ref, dk_ref, dv_ref, *, scale, causal,
-                           offset):
-    """Single-block fused backward (nq == nk == 1): dQ, dK, dV from ONE
-    score/probability computation — the two-kernel flash-2 split exists
-    only to order the tile accumulations, which a single tile does not
-    need. Saves one QK^T, one dO V^T, and one mask+exp pass vs the split
-    (measured 2.45 -> 1.70 ms/layer at the GPT bench shape on v5e).
-    Requires offset >= 0 when causal (no fully-masked rows, lse finite)."""
-    q = q_ref[0, 0]
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0][:, :1]
-    delta = delta_ref[0, 0][:, :1]
-    qs = q * jnp.asarray(scale, q.dtype)
-    s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                            precision=jax.lax.Precision.DEFAULT)
-    if causal:
-        bq, bk = s.shape
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + offset
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
-    p, ds_f = _bwd_p_ds(s, lse, delta, do, v, guarded=False)
-    ds = ds_f.astype(q.dtype)
-    dv_ref[0, 0] = jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.DEFAULT).astype(dv_ref.dtype)
-    dk_ref[0, 0] = jax.lax.dot_general(
-        ds, qs, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.DEFAULT).astype(dk_ref.dtype)
-    dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32,
-                             precision=jax.lax.Precision.DEFAULT)
-    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
+def _bwd_walk_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                     dq_ref, dk_ref, dv_ref, dq_acc, lse_sc, delta_sc,
+                     *, scale, causal, offset, tq, tk):
+    """One (batch row, head), dQ, dK and dV from ONE score/probability
+    computation a tile (the two-kernel flash-2 split recomputes scores
+    twice: measured 2.45 -> 1.70 ms/layer at the GPT bench shape on v5e
+    when the single-tile fused body replaced it). k tiles are walked in
+    the step, each against the strip of rows that see it: the strip's
+    head [lo, hi), which the staircase crosses, is masked, the rest is
+    not. dK and dV of a tile are whole when its strip is done; dQ adds
+    up across tiles in VMEM. Every row sees a column, so lse is finite
+    and no masked-row guards are needed."""
+    Sq, Sk = q_ref.shape[2], k_ref.shape[2]
+    q_scale, s_scale = _fold_scale(scale)
+    # the rows' two statistics as columns alike in every lane, once a head
+    lse_sc[:] = _row_to_cols(lse_ref[0, 0])
+    delta_sc[:] = jnp.broadcast_to(jnp.sum(
+        do_ref[0, 0].astype(jnp.float32) * o_ref[0, 0].astype(jnp.float32),
+        axis=1, keepdims=True), delta_sc.shape)
+    strips = _col_strips(Sq, Sk, tq, tk, causal, offset)
+    for j, (lo, hi) in enumerate(strips):
+        cols = slice(j * tk, (j + 1) * tk)
+        k = k_ref[0, 0, cols]
+        q = _times(q_ref[0, 0, lo:], q_scale)
+        do = do_ref[0, 0, lo:]
+        s = _times(_dot(q, k, _NT), s_scale)
+        if hi > lo:
+            head = _masked(s[:hi - lo], lo + offset, j * tk, causal)
+            s = jnp.concatenate([head, s[hi - lo:]], axis=0) \
+                if hi < Sq else head
+        p = jnp.exp(s - _lanes(lse_sc[lo:], tk))  # masked: exp(-inf) = 0
+        dp = _dot(do, v_ref[0, 0, cols], _NT)
+        ds = (p * (dp - _lanes(delta_sc[lo:], tk))).astype(q.dtype)
+        dv_ref[0, 0, cols] = _dot(p.astype(do.dtype), do, _TN).astype(
+            dv_ref.dtype)
+        dk_ref[0, 0, cols] = _times(_dot(ds, q, _TN), s_scale).astype(
+            dk_ref.dtype)
+        if j:
+            dq_acc[lo:] += _dot(ds, k, _NN)
+        else:               # every row sees the first tile: lo is 0
+            dq_acc[:] = _dot(ds, k, _NN)
+    dq_ref[0, 0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -351,21 +480,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0, 0][:, :1]                  # (Bq, 1)
         s = _scores(q, k, scale)                       # (Bq, Bk)
         if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + q_start + offset
-            cols = jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1) + k_start
-            s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
+            s = _masked(s, q_start + offset, k_start, causal)
         p, ds = _bwd_p_ds(s, lse, delta, do, v)
         # dV += P^T dO ; dK += dS^T Q * scale
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT)
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT) * scale
+        dv_acc[:] += _dot(p.astype(do.dtype), do, _TN)
+        dk_acc[:] += _dot(ds.astype(q.dtype), q, _TN) * scale
 
     @pl.when(iq == nq - 1)
     def _finalize():
@@ -399,16 +518,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0, 0][:, :1]
         s = _scores(q, k, scale)
         if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + q_start + offset
-            cols = jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1) + k_start
-            s = jnp.where(_causal_keep(rows, cols, causal), s, NEG_INF)
+            s = _masked(s, q_start + offset, k_start, causal)
         _p, ds = _bwd_p_ds(s, lse, delta, do, v)
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT) * scale
+        dq_acc[:] += _dot(ds.astype(k.dtype), k, _NN) * scale
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -422,31 +534,35 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     block_q = BWD_BLOCK_Q or block_q
     block_k = BWD_BLOCK_K or block_k
     block_q, block_k = _clamp_blocks_for_dtype(q.dtype, block_q, block_k)
-    bq, bk = _fit_block(Sq, block_q), _fit_block(Sk, block_k)
-    nq, nk = Sq // bq, Sk // bk
-
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                              # (B, H, Sq)
-    lse_b = jnp.broadcast_to(lse[..., None], (B, H, Sq, 128))
-    delta_b = jnp.broadcast_to(delta[..., None], (B, H, Sq, 128))
-
-    if nq == 1 and nk == 1 and (not causal or Sk >= Sq):
+    walk = _walks(Sq, Sk, D, q.dtype, causal, block_q, block_k, True)
+    if walk:
         spec_q = pl.BlockSpec((1, 1, Sq, D), lambda b, h: (b, h, 0, 0))
         spec_k = pl.BlockSpec((1, 1, Sk, D), lambda b, h: (b, h, 0, 0))
-        spec_r = pl.BlockSpec((1, 1, Sq, 128), lambda b, h: (b, h, 0, 0))
+        spec_r = pl.BlockSpec((1, 1, 1, Sq), lambda b, h: (b, h, 0, 0))
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_fused_1blk_kernel, scale=scale,
-                              causal=causal, offset=Sk - Sq),
+            functools.partial(_bwd_walk_kernel, scale=scale, causal=causal,
+                              offset=Sk - Sq, tq=walk[0], tk=walk[1]),
             grid=(B, H),
-            in_specs=[spec_q, spec_k, spec_k, spec_q, spec_r, spec_r],
+            in_specs=[spec_q, spec_k, spec_k, spec_q, spec_q, spec_r],
             out_specs=[spec_q, spec_k, spec_k],
             out_shape=[jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
                        jax.ShapeDtypeStruct((B, H, Sk, D), k.dtype),
                        jax.ShapeDtypeStruct((B, H, Sk, D), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32),
+                            pltpu.VMEM((Sq, 128), jnp.float32),
+                            pltpu.VMEM((Sq, 128), jnp.float32)],
+            compiler_params=_walk_params(),
             name="flash_bwd",
             interpret=interpret,
-        )(q, k, v, do, lse_b, delta_b)
+        )(q, k, v, o, do, lse[:, :, None])
         return dq, dk, dv
+
+    bq, bk = _fit_block(Sq, block_q), _fit_block(Sk, block_k)
+    nq, nk = Sq // bq, Sk // bk
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)                              # (B, H, Sq)
+    lse_b = jnp.broadcast_to(lse[..., None], (B, H, Sq, 128))
+    delta_b = jnp.broadcast_to(delta[..., None], (B, H, Sq, 128))
 
     q_spec_kmaj = pl.BlockSpec((1, 1, bq, D),
                                lambda b, h, ik, iq: (b, h, iq, 0))
@@ -627,14 +743,8 @@ def _bwd_dkv_kernel_varlen(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = _scores(q, k, scale)
         s = jnp.where(_mk_varlen_mask(sq, oq, sk, ok), s, NEG_INF)
         p, ds = _bwd_p_ds(s, lse, delta, do, v)
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT)
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT) * scale
+        dv_acc[:] += _dot(p.astype(do.dtype), do, _TN)
+        dk_acc[:] += _dot(ds.astype(q.dtype), q, _TN) * scale
 
     @pl.when(iq == nq - 1)
     def _finalize():
@@ -668,10 +778,7 @@ def _bwd_dq_kernel_varlen(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = _scores(q, k, scale)
         s = jnp.where(_mk_varlen_mask(sq, oq, sk, ok), s, NEG_INF)
         _p, ds = _bwd_p_ds(s, lse, delta, do, v)
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT) * scale
+        dq_acc[:] += _dot(ds.astype(k.dtype), k, _NN) * scale
 
     @pl.when(ik == nk - 1)
     def _finalize():

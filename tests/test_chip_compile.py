@@ -78,7 +78,8 @@ def _compile(one_chip, fn, *avals, kernels=()):
 @pytest.mark.parametrize("seq", [1024, 4096])
 def test_flash_fwd_bwd(one_chip, seq):
     """The trainer's attention: [8, S, 16, 64] bf16 causal, forward and
-    backward (S 4096 walks several k blocks per q block)."""
+    backward (at S 4096 the backward's eight resident tensors and its
+    strips pass the walk's VMEM reckoning: the grid kernels)."""
     qkv = ((8 if seq == 1024 else 2, seq, 16, 64), BF16)
 
     def loss(q, k, v):
@@ -89,6 +90,41 @@ def test_flash_fwd_bwd(one_chip, seq):
     _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv,
              kernels=(["flash_fwd", "flash_bwd"] if seq == 1024 else
                       ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"]))
+
+
+def test_flash_training_calls_are_what_the_benchmark_reads(one_chip):
+    """The trainer's attention at the training cell's shape, as the
+    benchmark's reader finds it (`flash_roofline_pct.train`, by the
+    signature patterns of `gpt2m-pretrain-1k.json`): ONE `flash_fwd`
+    custom call a layer with result (bf16 o, f32 lse), ONE `flash_bwd`
+    with three bf16 results, and neither moving a per-row statistic 128
+    lanes wide (`f32[8,16,1024,128]`: lse and delta at 67 MB a call)."""
+    import json
+    import pathlib
+    cell = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
+                       / "workloads" / "gpt2m-pretrain-1k.json").read_text())
+    qkv = ((8, 1024, 16, 64), BF16)
+
+    def loss(q, k, v):
+        o = pallas_flash.flash_attention_bshd(q, k, v, causal=True,
+                                              interpret=False)
+        return o.astype(F32).sum()
+
+    text = _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv,
+                    qkv, kernels=["flash_fwd", "flash_bwd"]).as_text()
+    lines = [re.sub(r"^ROOT ", "", ln.strip()) for ln in text.splitlines()]
+    calls = [ln for ln in lines if "tpu_custom_call" in ln
+             and "custom-call(" in ln]
+    assert len(calls) == 2, [ln[:80] for ln in calls]
+    for key in ("flash_fwd", "flash_bwd"):
+        hits = [ln for ln in calls
+                if re.search(cell["kernels"][key]["pattern"], ln)]
+        assert len(hits) == 1 and hits[0].startswith(f"%{key}"), (
+            key, [ln[:120] for ln in calls])
+    # a call's operands are named, not typed, on its line: look the
+    # whole program over for a per-row statistic 128 lanes wide
+    assert not re.search(r"f32\[8,16,1024,128\]", text)
+    assert re.search(r"f32\[8,16,1,1024\]", text)       # lse, one row a head
 
 
 def test_flash_varlen_packed_fwd_bwd(one_chip):
@@ -265,8 +301,8 @@ def test_paged_decode_block_of_positions_cell_shape(one_chip):
 @pytest.mark.parametrize("seq", [1024, 2048])
 def test_flash_block_causal_prefill_shape(one_chip, seq):
     """The block-diffusion prefill's attention: [1, S, 32, 128] bf16
-    under the causal mask of blocks of 4, forward and backward (S 2048
-    walks two k blocks per q block)."""
+    under the causal mask of blocks of 4, forward and backward (both
+    lengths fit VMEM a head: the walk, one fused backward call)."""
     qkv = ((1, seq, 32, 128), BF16)
 
     def loss(q, k, v):
@@ -275,8 +311,7 @@ def test_flash_block_causal_prefill_shape(one_chip, seq):
         return o.astype(F32).sum()
 
     _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv,
-             kernels=(["flash_fwd", "flash_bwd"] if seq == 1024 else
-                      ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"]))
+             kernels=["flash_fwd", "flash_bwd"])
 
 
 # ------------------------------------------------------- grouped matmul

@@ -319,3 +319,140 @@ class TestVarlenPacked:
         np.testing.assert_allclose(np.asarray(packed._data),
                                    np.asarray(dense._data),
                                    rtol=5e-3, atol=5e-3)
+
+
+# ------------------------------------------------------------ the walk
+# One grid step a (batch row, head), the tiles walked inside it: the
+# forward by q tiles against row strips, the backward by k tiles against
+# column strips (kernels/pallas_flash.py, `_row_strips` / `_col_strips`).
+
+def _staircase_entries(Sq, Sk, tq, tk, block):
+    """(entries of the tiles holding a seen entry, entries of the tiles
+    the staircase crosses, tiles of the square, of the first kind), by
+    brute force over the dense mask the XLA path builds."""
+    rows = np.arange(Sq)[:, None] + (Sk - Sq)
+    keep = (rows | (block - 1)) >= np.arange(Sk)[None, :] if block \
+        else np.ones((Sq, Sk), bool)
+    t = keep.reshape(Sq // tq, tq, Sk // tk, tk)
+    some, every = t.any((1, 3)), t.all((1, 3))
+    return (int(some.sum()) * tq * tk, int((some & ~every).sum()) * tq * tk,
+            some.size, int(some.sum()))
+
+
+WALK_CASES = {
+    # name: (B, Sq, Sk, H, D, causal, causal_block, tile)
+    "causal_s512_tile128": (1, 512, 512, 2, 64, True, 1, 128),
+    "cell_s1024_d64": (1, 1024, 1024, 2, 64, True, 1, 1024),
+    "block4_d128": (1, 512, 512, 2, 128, True, 4, 128),
+    "sq_lt_sk_bottom_right": (1, 256, 512, 2, 64, True, 1, 128),
+    "non_causal": (1, 256, 512, 2, 64, False, 1, 128),
+    "one_tile": (1, 128, 128, 2, 64, True, 1, 1024),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WALK_CASES))
+def test_walk_matches_xla_forward_and_gradients(name, monkeypatch):
+    from paddle2_tpu.kernels import pallas_flash as pf
+    B, Sq, Sk, H, D, causal, cb, tile = WALK_CASES[name]
+    block = cb if causal else 0
+    tiles = {back: pf._walks(Sq, Sk, D, jnp.float32, block, tile, tile, back)
+             for back in (False, True)}
+    assert all(tiles.values()), "not the walk's shape"
+    if name == "cell_s1024_d64":    # the shape rule's own tiles, several
+        assert tiles == {False: (pf.FWD_WALK_TILE,) * 2,
+                         True: (pf.BWD_WALK_TILE,) * 2}
+        assert Sq // pf.FWD_WALK_TILE > 1
+    # what the two bodies trace: the entries of every product of a score
+    # tile's shape (QK^T; in the backward dO V^T too) and of every mask
+    seen = {"scored": 0, "masked": 0}
+    dot, masked = pf._dot, pf._masked
+
+    def counting_dot(a, b, contract):
+        if contract == pf._NT and a.shape[1] == D and b.shape[1] == D:
+            seen["scored"] += a.shape[0] * b.shape[0]
+        return dot(a, b, contract)
+
+    def counting_masked(s, *a):
+        seen["masked"] += s.size
+        return masked(s, *a)
+
+    monkeypatch.setattr(pf, "_dot", counting_dot)
+    monkeypatch.setattr(pf, "_masked", counting_masked)
+    pf._JIT_CACHE.clear()
+    q = _rand((B, Sq, H, D), seed=0)
+    k, v = _rand((B, Sk, H, D), seed=1), _rand((B, Sk, H, D), seed=2)
+    w = _rand((B, Sq, H, D), seed=3)
+
+    def flash(q, k, v):
+        return flash_attention_bshd(q, k, v, causal=causal, block_q=tile,
+                                    block_k=tile, causal_block=cb,
+                                    interpret=True)
+
+    def dense(q, k, v):
+        return _sdpa_xla(q, k, v, causal=causal, causal_block=cb)
+
+    try:
+        got = flash(q, k, v)
+        fwd_seen = dict(seen)
+        g1 = jax.grad(lambda *a: (flash(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    finally:
+        pf._JIT_CACHE.clear()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense(q, k, v)),
+                               atol=2e-5)
+    g2 = jax.grad(lambda *a: (dense(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+    # the mechanism's counter: the forward's strips cover the tiles on
+    # or under the staircase and no other, and only the crossed ones
+    # build a mask. The gradient ran the forward once more (for the
+    # residuals) and the backward's own walk over ITS tiles, with two
+    # products of a score tile's shape each and one mask a crossed tile
+    f_scored, f_masked, f_square, f_run = _staircase_entries(
+        Sq, Sk, *tiles[False], block)
+    b_scored, b_masked, _, _ = _staircase_entries(Sq, Sk, *tiles[True], block)
+    assert fwd_seen == {"scored": f_scored, "masked": f_masked}
+    assert seen["scored"] - 2 * f_scored == 2 * b_scored
+    assert seen["masked"] - 2 * f_masked == b_masked
+    if causal and f_square > 1:
+        assert f_masked < f_scored < Sq * Sk
+    if name == "causal_s512_tile128":
+        assert (f_run, f_masked // 128 ** 2, f_square) == (10, 4, 16)
+
+
+def test_walk_gives_way_to_the_grid_when_a_head_does_not_fit(monkeypatch):
+    """The shape rule: the walk while a head fits VMEM by its own
+    reckoning, the grid kernels beyond — and wherever rows see nothing
+    (causal, Sq > Sk) or a forced tile is not lane-aligned."""
+    from paddle2_tpu.kernels import pallas_flash as pf
+    bf16 = jnp.bfloat16
+    # the four cells' shapes, forward (and the trainer's backward)
+    assert pf._walks(1024, 1024, 64, bf16, 1, 1024, 1024, True)
+    assert pf._walks(3072, 3072, 64, bf16, 1, 1024, 1024, False)
+    assert pf._walks(2048, 2048, 128, bf16, 4, 1024, 1024, False)
+    assert pf._walks(2048, 2048, 128, bf16, 4, 1024, 1024, True)
+    assert pf._walks(8192, 8192, 64, bf16, 1, 1024, 1024, False) is None
+    assert pf._walks(4096, 4096, 64, bf16, 1, 1024, 1024, True) is None
+    assert pf._walks(512, 256, 64, bf16, 1, 1024, 1024, False) is None
+    assert pf._walks(512, 256, 64, bf16, 0, 1024, 1024, False)
+    assert pf._walks(256, 256, 64, bf16, 1, 64, 64, False) is None
+    # the forward's tile streams its own rows past each K/V tile and is
+    # the larger; the backward's streams whole strips
+    assert pf._walks(1024, 1024, 64, bf16, 1, 1024, 1024, False) == (512, 512)
+    assert pf._walks(1024, 1024, 64, bf16, 1, 1024, 1024, True) == (256, 256)
+    # and the grid path still answers: same numbers, other kernels
+    monkeypatch.setattr(pf, "WALK_VMEM_BYTES", 0)
+    pf._JIT_CACHE.clear()
+    q, k, v = (_rand((1, 256, 2, 64), seed=i) for i in range(3))
+    try:
+        o = flash_attention_bshd(q, k, v, causal=True, block_q=128,
+                                 block_k=128, interpret=True)
+        g = jax.grad(lambda q: flash_attention_bshd(
+            q, k, v, causal=True, block_q=128, block_k=128,
+            interpret=True).sum())(q)
+    finally:
+        pf._JIT_CACHE.clear()
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(_sdpa_xla(q, k, v, causal=True)),
+        atol=2e-5)
+    gref = jax.grad(lambda q: _sdpa_xla(q, k, v, causal=True).sum())(q)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(gref), atol=5e-5)
