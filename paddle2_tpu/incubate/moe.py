@@ -451,6 +451,31 @@ def softmax_topk_route(a, gate_w, k: int, norm_topk: bool = True,
     return ids.astype(jnp.int32), w * scale
 
 
+def softmax_group_limited_route(a, gate_w, k: int, n_group: int,
+                                topk_group: int, norm_topk: bool = False,
+                                scale: float = 1.0):
+    """Softmax routing under a limit on the GROUPS a row may reach
+    (``group_limited_greedy``), in float32 whatever the layer's dtype:
+    ``p = softmax(a W_g)`` over ALL experts; the experts are ``n_group``
+    contiguous groups, a group's score is its largest ``p``; the
+    ``topk_group`` best groups are kept and the ``k`` largest ``p`` among
+    THEIR experts chosen; the weights are those ``p_e`` (over their sum
+    when ``norm_topk``), times ``scale``. Returns (ids ``[T, k]`` int32,
+    weights ``[T, k]`` f32)."""
+    p = jax.nn.softmax(jnp.dot(a.astype(jnp.float32),
+                               gate_w.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST), -1)
+    T, E = p.shape
+    _, groups = jax.lax.top_k(p.reshape(T, n_group, -1).max(-1), topk_group)
+    kept = jnp.sum(jax.nn.one_hot(groups, n_group, dtype=jnp.int32), 1) > 0
+    # a softmax is positive: an expert of a dropped group never wins
+    w, ids = jax.lax.top_k(
+        jnp.where(jnp.repeat(kept, E // n_group, axis=1), p, 0.0), k)
+    if norm_topk:
+        w = w / jnp.sum(w, -1, keepdims=True)
+    return ids.astype(jnp.int32), w * scale
+
+
 class DroplessExperts(nn.Layer):
     """Top-k routed SwiGLU experts with NO capacity: every assignment
     is computed. Rows are sorted by expert and all experts held run as
@@ -459,9 +484,11 @@ class DroplessExperts(nn.Layer):
 
     ``router`` names how a row's experts and weights are found:
     ``"sigmoid"`` (:func:`sigmoid_topk_route`, with the selection bias
-    where ``use_bias``) or ``"softmax"`` (:func:`softmax_topk_route`, no
-    bias); what follows — the sort, ONE grouped matmul a projection, the
-    record — is the same.
+    where ``use_bias``), ``"softmax"`` (:func:`softmax_topk_route`, no
+    bias) or ``"softmax_group_limited"``
+    (:func:`softmax_group_limited_route`: ``n_group`` groups, a row
+    reaches ``topk_group`` of them); what follows — the sort, ONE
+    grouped matmul a projection, the record — is the same.
 
     ``held = (first, count)`` is the contiguous share of the
     ``num_experts`` this layer holds weights for: routing is always over
@@ -470,20 +497,30 @@ class DroplessExperts(nn.Layer):
 
     Array-level (serving) API: :meth:`route_and_run` on ``[T, H]``
     arrays; it also returns the layer's routing record, one int32
-    array: the counts ``[assignments computed, distinct experts hit,
-    largest load on one expert]`` and behind them the ``k`` experts
-    chosen for each row (what a router replay or a teacher-forced
-    comparison needs: top-k is discontinuous, so which experts ran is
-    part of the result)."""
+    array: the counts (:attr:`COUNT_NAMES`: assignments computed,
+    distinct experts hit, largest load on one expert, rows with at least
+    one of their ``k`` experts held here, rows routed at all) and behind
+    them the ``k`` experts chosen for each row (what a router replay or
+    a teacher-forced comparison needs: top-k is discontinuous, so which
+    experts ran is part of the result)."""
+
+    COUNT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_load_max",
+                   "moe_rows_routed_here", "moe_rows")
 
     def __init__(self, hidden: int, width: int, num_experts: int, k: int,
                  use_bias: bool = True, norm_topk: bool = True,
                  scale: float = 1.0, held=None, std: float = 0.02,
-                 dtype=None, router: str = "sigmoid"):
+                 dtype=None, router: str = "sigmoid", n_group: int = 1,
+                 topk_group: int = 1):
         super().__init__()
-        if router not in ("sigmoid", "softmax"):
+        if router not in ("sigmoid", "softmax", "softmax_group_limited"):
             raise ValueError(f"unknown router {router!r}")
+        if num_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(
+                f"{num_experts} experts in {n_group} groups, {topk_group} "
+                f"of them a row")
         self.router = router
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         use_bias = use_bias and router == "sigmoid"
         self.num_experts, self.k = int(num_experts), int(k)
         self.norm_topk, self.scale = bool(norm_topk), float(scale)
@@ -506,8 +543,8 @@ class DroplessExperts(nn.Layer):
     def route_and_run(self, a, valid=None, interpret=None):
         """a ``[T, H]``; ``valid`` bool ``[T]`` marks the rows worth
         computing (padding is skipped and not counted). Returns (out
-        ``[T, H]`` in a's dtype, record int32 ``[3 + T * k]``: the three
-        counts, then the chosen expert ids row by row)."""
+        ``[T, H]`` in a's dtype, record int32 ``[len(COUNT_NAMES) + T *
+        k]``: the counts, then the chosen expert ids row by row)."""
         from ..kernels.moe_gmm import gmm_plan, moe_gmm
         T, H = a.shape
         E, k = self.num_experts, self.k
@@ -515,6 +552,10 @@ class DroplessExperts(nn.Layer):
             if self.router == "softmax":
                 ids, w = softmax_topk_route(a, self.gate_weight._data, k,
                                             self.norm_topk, self.scale)
+            elif self.router == "softmax_group_limited":
+                ids, w = softmax_group_limited_route(
+                    a, self.gate_weight._data, k, self.n_group,
+                    self.topk_group, self.norm_topk, self.scale)
             else:
                 ids, w = sigmoid_topk_route(
                     a, self.gate_weight._data,
@@ -526,6 +567,8 @@ class DroplessExperts(nn.Layer):
             held = (flat >= self.first) & (flat < self.first + self.count)
             if valid is not None:
                 held &= jnp.repeat(valid, k)
+            rows_here = jnp.sum(jnp.any(held.reshape(T, k), -1))
+            n_rows = T if valid is None else jnp.sum(valid)
             # rows nobody here computes are parked behind the last expert
             flat = jnp.where(held, flat, E)
             order = jnp.argsort(flat, stable=True)
@@ -533,7 +576,8 @@ class DroplessExperts(nn.Layer):
             rows = a[order // k]
             counts = jnp.stack([jnp.sum(sizes[:E]),
                                 jnp.sum(sizes[:E] > 0),
-                                jnp.max(sizes[:E])]).astype(jnp.int32)
+                                jnp.max(sizes[:E]), rows_here,
+                                n_rows]).astype(jnp.int32)
         with jax.named_scope("experts"):
             # one visit list for the layer's three products
             plan = gmm_plan(sizes, T * k, self.first, self.count)

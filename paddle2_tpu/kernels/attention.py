@@ -153,13 +153,16 @@ def _per_shard(fn, q_shape):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, causal=None,
-                                 training=True, name=None, causal_block=1):
+                                 training=True, name=None, causal_block=1,
+                                 scale=None):
     """paddle.nn.functional.scaled_dot_product_attention parity.
 
     Inputs are (batch, seq, num_heads, head_dim) like the reference flash-attn
     API (paddle/phi/kernels/gpu/flash_attn_kernel.cu qkv layout).
     ``causal_block`` B > 1 (with ``is_causal``) masks by blocks of B
     positions: a query sees its own block whole and every earlier one.
+    ``scale`` replaces the ``head_dim ** -0.5`` on the scores; the value
+    heads may be narrower or wider than the query/key heads.
     """
     causal = causal if causal is not None else is_causal
     query, key, value = (ensure_tensor(query), ensure_tensor(key),
@@ -183,7 +186,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                 tuple(query.shape), tuple(key.shape), causal, (bq, bk))
 
         def fn(q, k, v):
-            return flash_attention_bshd(q, k, v, causal=causal,
+            return flash_attention_bshd(q, k, v, causal=causal, scale=scale,
                                         block_q=bq, block_k=bk,
                                         causal_block=causal_block)
         return apply_op("flash_attention",
@@ -192,7 +195,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
     def fn(q, k, v, *mask):
         bias = mask[0] if mask else None
-        return _sdpa_xla(q, k, v, bias=bias, causal=causal,
+        return _sdpa_xla(q, k, v, bias=bias, causal=causal, scale=scale,
                          dropout_p=dropout_p if drop_key is not None else 0.0,
                          dropout_key=drop_key, causal_block=causal_block)
     return apply_op("sdpa", fn, tuple(tensors), {})

@@ -7,7 +7,7 @@ used as it stands): the grid walks ``(n tile, visit)`` where a visit is
 one (row tile, group) pair that share rows — a row tile that several
 groups share is visited once by each, consecutively, and every visit
 stores only its own group's rows. The whole contraction rides one step
-(``K`` 2048 and 1536 here), so there is no accumulator and a group's
+(``K`` 768 to 5120 here), so there is no accumulator and a group's
 ``[K, tn]`` weight tile is streamed once per row tile it touches: at
 decode (a few rows a group) the kernel moves each hit expert's weights
 once and is bound by their bytes; a prefill of a few thousand tokens
@@ -63,11 +63,14 @@ def _row_tile(m: int) -> int:
     return tm
 
 
-def _col_tile(n: int) -> int:
-    """Columns of one weight tile: ``[K, 512]`` is about 2 MB, so two of
-    them and two row tiles stay well inside the scoped VMEM."""
+def _col_tile(n: int, k: int = 2048, itemsize: int = 2) -> int:
+    """Columns of one weight tile: ``[K, 512]`` is about 2 MB at the
+    ``K`` of 2048 and under, so two of them and two row tiles stay well
+    inside the scoped VMEM; a longer contraction (``K`` 5120: 5 MB a
+    tile, and 2.6 MB a row tile) halves the columns until the tile is
+    under 3 MB again."""
     tn = 512
-    while n % tn:
+    while n % tn or (k * tn * itemsize > 3 << 20 and tn > 128):
         tn //= 2
     return tn
 
@@ -93,7 +96,7 @@ def gmm_plan(group_sizes, m: int, first=0, held: int = None):
 def _gmm(lhs, rhs, plan, *, interpret):
     m, k = lhs.shape
     held, _, n = rhs.shape
-    tm, tn = _row_tile(m), _col_tile(n)
+    tm, tn = _row_tile(m), _col_tile(n, k, rhs.dtype.itemsize)
     offsets, gids, mtiles, visits, first = plan
     out = pl.pallas_call(
         functools.partial(_kernel, tm=tm),

@@ -28,8 +28,8 @@ TPU-native kernels that never write the score matrix to HBM:
   k-axis innermost, both seeded by delta. Tiles wholly above the
   staircase are skipped via pl.when; lse/delta ride in (…, Sq, 128)-lane
   f32 buffers there.
-- with Sq != Sk the diagonal is bottom-right aligned, matching the XLA
-  fallback and flash-attn v2.1 semantics.
+- with Sq != Sk the diagonal is bottom-right aligned (flash-attn v2.1,
+  the XLA fallback); the values' width may differ from q/k's (forward).
 - the causal mask is BLOCK-causal by a static block length: inside the
   kernels ``causal`` is that length (0: no mask, 1: plain causal, a
   power of two B: a row sees the columns of its own block of B positions
@@ -221,19 +221,19 @@ def _times(x, factor):
     return x if factor == 1.0 else x * jnp.asarray(factor, x.dtype)
 
 
-def _walk_bytes(Sq, Sk, D, itemsize, tq, tk, backward):
+def _walk_bytes(Sq, Sk, D, itemsize, tq, tk, backward, Dv=None):
     """VMEM a walk's step holds, roughly: the head's operands and
-    results double-buffered (D padded to 128 lanes), the backward's
-    three f32 scratches, and the f32 temporaries of the widest strip."""
-    lanes = -(-D // 128) * 128
+    results double-buffered (D and the values' Dv padded to 128 lanes),
+    the backward's three f32 scratches, the widest strip's temporaries."""
+    lanes, v_lanes = (-(-d // 128) * 128 for d in (D, Dv or D))
     if backward:
         io = 2 * (4 * Sq + 4 * Sk) * lanes * itemsize
         return io + 3 * Sq * 128 * 4 + Sq * tk * (16 + 2 * itemsize)
-    io = 2 * (2 * Sq + 2 * Sk) * lanes * itemsize
+    io = 2 * (Sq + Sk) * (lanes + v_lanes) * itemsize
     return io + tq * Sk * (8 + itemsize)
 
 
-def _walks(Sq, Sk, D, dtype, causal, block_q, block_k, backward):
+def _walks(Sq, Sk, D, dtype, causal, block_q, block_k, backward, Dv=None):
     """The walk's tiles (rows of a q tile, columns of a k tile: the
     direction's tile, no more than the caller's bound, fitted to the
     sequence) if this shape takes the walk, else None: every row sees a
@@ -244,7 +244,7 @@ def _walks(Sq, Sk, D, dtype, causal, block_q, block_k, backward):
     tq = _fit_block(Sq, min(block_q, tile))
     tk = _fit_block(Sk, min(block_k, tile))
     fits = _walk_bytes(Sq, Sk, D, jnp.dtype(dtype).itemsize, tq, tk,
-                       backward) <= WALK_VMEM_BYTES
+                       backward, Dv) <= WALK_VMEM_BYTES
     aligned = (tq % 128 == 0 or tq == Sq) and (tk % 128 == 0 or tk == Sk)
     return (tq, tk) if fits and aligned and (
         not causal or Sk >= Sq) else None
@@ -348,22 +348,23 @@ def _clamp_blocks_for_dtype(dtype, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    """q,k,v: (B, H, S, D) — returns (o, lse)."""
+    """q,k: (B, H, S, D), v: (B, H, Sk, Dv) — returns (o, lse)."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     block_q, block_k = _clamp_blocks_for_dtype(q.dtype, block_q, block_k)
-    walk = _walks(Sq, Sk, D, q.dtype, causal, block_q, block_k, False)
+    walk = _walks(Sq, Sk, D, q.dtype, causal, block_q, block_k, False, Dv)
     if walk:
-        spec_q = pl.BlockSpec((1, 1, Sq, D), lambda b, h: (b, h, 0, 0))
-        spec_k = pl.BlockSpec((1, 1, Sk, D), lambda b, h: (b, h, 0, 0))
+        spec_q, spec_k, spec_v, spec_o = (pl.BlockSpec(
+            (1, 1, s, d), lambda b, h: (b, h, 0, 0)) for s, d in (
+            (Sq, D), (Sk, D), (Sk, Dv), (Sq, Dv)))
         o, lse = pl.pallas_call(
             functools.partial(_fwd_walk_kernel, scale=scale, causal=causal,
                               offset=Sk - Sq, tq=walk[0], tk=walk[1]),
             grid=(B, H),
-            in_specs=[spec_q, spec_k, spec_k],
-            out_specs=[spec_q, pl.BlockSpec((1, 1, 1, Sq),
+            in_specs=[spec_q, spec_k, spec_v],
+            out_specs=[spec_o, pl.BlockSpec((1, 1, 1, Sq),
                                             lambda b, h: (b, h, 0, 0))],
-            out_shape=[jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+            out_shape=[jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
                        jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32)],
             compiler_params=_walk_params(),
             name="flash_fwd",
@@ -376,25 +377,24 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=bq, block_k=bk, nk=nk,
                                offset=Sk - Sq)
-    grid = (B, H, nq, nk)
     o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda b, h, iq, ik: (b, h, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, bq, 128), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
@@ -658,6 +658,10 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 
 def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, g):
     q, k, v, o, lse = res
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "flash attention with value heads of another width than the "
+            "query/key heads has a forward only")
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, g, scale, causal,
                             block_q, block_k, interpret)
     return dq, dk, dv

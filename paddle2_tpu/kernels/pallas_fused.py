@@ -349,11 +349,15 @@ _JIT_CACHE: dict = {}
 
 def fused_rms_norm(x, weight, epsilon=1e-6, block_rows=512, interpret=None):
     """RMSNorm over the last dim in one pallas pass (fwd + custom bwd);
-    any leading shape. Differentiable."""
+    any leading shape. Differentiable. A block holds ``block_rows`` rows
+    of up to 2048 lanes; wider rows take fewer of them (the block and
+    its f32 temporaries share the scoped VMEM)."""
     if interpret is None:
         interpret = interpret_default()
     shape = x.shape
     H = shape[-1]
+    while block_rows > 8 and block_rows * H > 512 * 2048:
+        block_rows //= 2
     key = ("rmsnorm", float(epsilon), int(block_rows), bool(interpret))
     fn = _JIT_CACHE.get(key)
     if fn is None:

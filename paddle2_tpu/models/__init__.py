@@ -7,10 +7,13 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
 
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_moe_tiny
 from .sdar import SdarMoeConfig, SdarMoeForCausalLM, sdar_moe_tiny
+from .deepseek import (DeepseekV2Config, DeepseekV2ForCausalLM,
+                       deepseek_v2_tiny)
 
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny",
            "SdarMoeConfig", "SdarMoeForCausalLM", "sdar_moe_tiny",
+           "DeepseekV2Config", "DeepseekV2ForCausalLM", "deepseek_v2_tiny",
            "GPTConfig", "GPTModel", "GPTForCausalLM", "gpt3_1p3b",
            "gpt_small", "gpt_tiny", "ErnieConfig", "ErnieModel",
            "ErnieForSequenceClassification", "ernie3_base", "ernie_tiny"]

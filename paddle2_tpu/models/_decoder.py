@@ -1,22 +1,27 @@
-"""What the RMSNorm / rotary / grouped-query decoder families share
-(``models/lfm2.py``, ``models/sdar.py``): bias-free projections created
-in the model's dtype, the pre-norm through the repo's kernel, rotary
-tables in the rotate-half layout, and grouped-query attention with a
-per-head RMS norm of q and k. Written once, on arrays; inference only
+"""What the RMSNorm / rotary decoder families share (``models/lfm2.py``,
+``models/sdar.py``, ``models/deepseek.py``): bias-free projections
+created in the model's dtype, the pre-norm through the repo's kernel,
+rotary tables in the rotate-half layout (plain or YaRN-scaled
+frequencies), the SwiGLU feed-forward, and grouped-query attention with
+a per-head RMS norm of q and k. Written once, on arrays; inference only
 (no autograd tape)."""
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import nn
 from ..framework.tensor import Tensor
 from ..kernels.pallas_fused import fused_rms_norm, fused_rope
 from ..ops.linalg import _mxu_precision
 
-__all__ = ["GroupedQueryAttention", "created_in", "linear", "mm",
-           "pre_norm", "rms_head", "rope_tables"]
+__all__ = ["GroupedQueryAttention", "SwiGLU", "created_in", "linear", "mm",
+           "pre_norm", "rms_head", "rope_tables", "rotate_half_rope",
+           "yarn_inv_freq", "yarn_mscale"]
 
 
 def rms_head(x, weight, eps):
@@ -27,15 +32,54 @@ def rms_head(x, weight, eps):
     return (h * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_tables(positions, head_dim: int, theta: float):
+def rope_tables(positions, head_dim: int, theta: float, inv_freq=None):
     """cos, sin ``[..., head_dim]`` f32 for integer ``positions``: the
     half tables repeated, the layout ``fused_rope`` (rotate-half)
-    takes."""
+    takes. ``inv_freq`` ``[head_dim / 2]`` replaces the plain
+    ``theta^(-2i/d)`` frequencies (:func:`yarn_inv_freq`)."""
     inv = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                    / head_dim)
+                    / head_dim) if inv_freq is None else inv_freq
     ang = positions.astype(jnp.float32)[..., None] * inv
     ang = jnp.concatenate([ang, ang], -1)
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def rotate_half_rope(x, cos, sin):
+    """``x cos + rotate_half(x) sin`` over the last axis, in float32, as
+    plain XLA (``fused_rope``'s mathematics for an ``x`` that is a lane
+    slice of a wider projection, which the compiler fuses into it)."""
+    h = x.astype(jnp.float32)
+    half = h.shape[-1] // 2
+    turned = jnp.concatenate([-h[..., half:], h[..., :half]], -1)
+    return (h * cos + turned * sin).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 m ln(factor) + 1`` (1 where
+    nothing is scaled)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32,
+                  beta_slow: float = 1):
+    """YaRN's ``[dim / 2]`` rotary frequencies (Peng et al. 2023, as
+    the DeepSeek-V2 publication applies them): the fast dimensions keep
+    ``f_i = theta^(-2i/dim)``, the slow ones are interpolated to ``f_i /
+    factor``, and a linear ramp blends the two between the dimensions
+    that turn ``beta_fast`` and ``beta_slow`` times over the original
+    context."""
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def turns_at(rotations):
+        return dim * math.log(original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return jnp.asarray(f / factor * ramp + f * (1 - ramp), jnp.float32)
 
 
 def mm(x, linear_layer):
@@ -60,6 +104,21 @@ def pre_norm(norm, x, eps):
     with jax.named_scope("norm"):
         # the repo's Pallas kernel: f32 inside, x's dtype out
         return fused_rms_norm(x, norm.weight._data, eps)
+
+
+class SwiGLU(nn.Layer):
+    """``(silu(a W1) * (a W3)) W2``, the product in float32."""
+
+    def __init__(self, hidden: int, width: int, std: float, dtype=None):
+        super().__init__()
+        self.w1 = linear(hidden, width, std, dtype)
+        self.w3 = linear(hidden, width, std, dtype)
+        self.w2 = linear(width, hidden, std, dtype)
+
+    def run(self, a):
+        up = jax.nn.silu(mm(a, self.w1).astype(jnp.float32))
+        return mm((up * mm(a, self.w3).astype(jnp.float32))
+                  .astype(a.dtype), self.w2)
 
 
 class GroupedQueryAttention(nn.Layer):

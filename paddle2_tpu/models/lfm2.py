@@ -36,8 +36,9 @@ from ..framework.tensor import Tensor
 from ..incubate.moe import DroplessExperts
 from ..kernels.pallas_fused import fused_rms_norm
 from ..ops.linalg import _mxu_precision
-from ._decoder import (GroupedQueryAttention, created_in as _created_in,
-                       linear, mm as _mm, pre_norm)
+from ._decoder import (GroupedQueryAttention, SwiGLU,
+                       created_in as _created_in, linear, mm as _mm,
+                       pre_norm)
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM", "lfm2_moe_tiny"]
 
@@ -132,20 +133,6 @@ class Lfm2ShortConv(nn.Layer):
         return _mm(c * conv, self.out_proj), window[:, 1:]
 
 
-class Lfm2MLP(nn.Layer):
-    def __init__(self, cfg: Lfm2MoeConfig):
-        super().__init__()
-        H, F = cfg.hidden_size, cfg.intermediate_size
-        self.w1 = _linear(H, F, cfg)
-        self.w3 = _linear(H, F, cfg)
-        self.w2 = _linear(F, H, cfg)
-
-    def run(self, a):
-        up = jax.nn.silu(_mm(a, self.w1).astype(jnp.float32))
-        return _mm((up * _mm(a, self.w3).astype(jnp.float32))
-                   .astype(a.dtype), self.w2)
-
-
 def _linear(d_in, d_out, cfg):
     return linear(d_in, d_out, cfg.initializer_range, cfg.dtype)
 
@@ -166,7 +153,8 @@ class Lfm2DecoderLayer(nn.Layer):
                 cfg.num_key_value_heads, cfg.head_dim, cfg.norm_eps,
                 cfg.rope_theta, cfg.initializer_range, cfg.dtype)
         if self.is_dense:
-            self.feed_forward = Lfm2MLP(cfg)
+            self.feed_forward = SwiGLU(cfg.hidden_size, cfg.intermediate_size,
+                                       cfg.initializer_range, cfg.dtype)
         else:
             self.feed_forward = DroplessExperts(
                 cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,

@@ -417,6 +417,15 @@ class PagedKVCache:
     row, so the decode append is a plain row scatter the compiler
     updates in place.
 
+    ``kv_widths = (K row width, V row width)`` replaces ``num_kv_heads *
+    head_dim`` twice for a cache that is not "heads x head_dim, twice".
+    A LATENT cache (multi-head latent attention) keeps ONE vector a
+    token and layer — the latent and the rotary key, read as keys and as
+    values — in the K pool and has no V pool at all: ``(row width, 0)``,
+    ``v`` None; per-head keys and values are never stored. The bytes a
+    block holds (:attr:`block_bytes`, what the allocator's ledger and
+    the pool's live share count) follow the row widths.
+
     ``state_shape`` (with ``state_slots``) adds the second kind of
     state: one pool ``[state layers, slots + 1, *per-layer shape]`` in
     the cache's dtype for layers that keep a fixed-size recurrent state
@@ -445,7 +454,8 @@ class PagedKVCache:
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype="float32",
                  state_shape=None, state_slots: int = 0,
-                 token_rows: int = 1, block_length: Optional[int] = None):
+                 token_rows: int = 1, block_length: Optional[int] = None,
+                 kv_widths: Optional[Tuple[int, int]] = None):
         import jax
         import jax.numpy as jnp
         self.num_layers = int(num_layers)
@@ -454,10 +464,11 @@ class PagedKVCache:
         self.num_kv_heads = self.num_heads = int(num_kv_heads)
         self.head_dim = int(head_dim)
         self.dtype = jnp.dtype(dtype)
-        shape = (num_layers, num_blocks, block_size,
-                 num_kv_heads * head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        self.kv_widths = tuple(int(w) for w in (
+            kv_widths or (num_kv_heads * head_dim,) * 2))
+        self.k, self.v = (
+            jnp.zeros((num_layers, num_blocks, block_size, w), self.dtype)
+            if w else None for w in self.kv_widths)
         # committed to its device, as every later step's output is: an
         # uncommitted first value would give the first decode call a
         # signature of its own, and the second a compilation
@@ -511,9 +522,10 @@ class PagedKVCache:
 
     @property
     def block_bytes(self) -> int:
-        """Bytes one block holds across K+V and all layers."""
-        return (2 * self.num_layers * self.block_size * self.num_heads
-                * self.head_dim * self.dtype.itemsize)
+        """Bytes one block holds across K+V (or the one latent pool)
+        and all layers."""
+        return (self.num_layers * self.block_size * sum(self.kv_widths)
+                * self.dtype.itemsize)
 
     def bytes_for_blocks(self, n_blocks: int) -> int:
         return n_blocks * self.block_bytes
@@ -522,8 +534,8 @@ class PagedKVCache:
         """What a contiguous per-request max-seq-len cache would
         reserve for ``batch`` sequences — the paged-vs-contiguous
         comparator the serving bench gates on."""
-        return (2 * self.num_layers * batch * max_seq_len
-                * self.num_heads * self.head_dim * self.dtype.itemsize)
+        return (self.num_layers * batch * max_seq_len
+                * sum(self.kv_widths) * self.dtype.itemsize)
 
     # -- device ops (traced inside the compiled programs) ---------------
     @staticmethod
@@ -539,7 +551,7 @@ class PagedKVCache:
 
     @staticmethod
     def scatter_prefill(pool, layer_kv, block_row, n_tokens, block_size,
-                        start: int = 0):
+                        start: int = 0, by_layer: bool = False):
         """Write a prefilled sequence's K/V into its blocks as ONE
         jitted scatter with the pool DONATED — the eager per-page
         ``.at[].set`` loop this replaces copied the ENTIRE pool once
@@ -551,7 +563,15 @@ class PagedKVCache:
         the shared blocks it reads (their bytes belong to every
         sharer), so only the private tail ``[start, n_tokens)`` is
         scattered. The tiny scatter program is cached per
-        (pool, T, start, n_tokens) signature."""
+        (pool, T, start, n_tokens) signature.
+
+        ``by_layer`` writes one layer at a time, the decode append's own
+        scatter (``pool[layer, phys, slot] = rows``), which the chip's
+        compiler updates in place: the all-layers form above makes two
+        whole-pool copies in temporaries 1.6 x the pool (8.1 GB for a
+        5 GB latent pool: it does not load beside the weights). The
+        latent cache takes it; the K/V pools keep the all-layers form
+        until the benchmark can show its repair (ROADMAP S1 / W1)."""
         import jax
         import jax.numpy as jnp
         start = int(start)
@@ -562,7 +582,7 @@ class PagedKVCache:
                            jnp.int32)
         slot = jnp.asarray(idx % block_size, jnp.int32)
         key = (tuple(pool.shape), str(pool.dtype),
-               tuple(layer_kv.shape), start, int(n_tokens))
+               tuple(layer_kv.shape), start, int(n_tokens), bool(by_layer))
         fn = _PREFILL_SCATTER_CACHE.get(key)
         if fn is not None:
             return fn(pool, layer_kv, phys, slot)
@@ -571,8 +591,12 @@ class PagedKVCache:
         # the function's name is the HLO module's in a device trace
         def p2t_kv_scatter_prefill(p, kv, ph, sl):
             with jax.named_scope("kv_write"):
-                return p.at[:, ph, sl].set(
-                    kv[:, start:n].reshape(kv.shape[0], n - start, -1))
+                rows = kv[:, start:n].reshape(kv.shape[0], n - start, -1)
+                if not by_layer:
+                    return p.at[:, ph, sl].set(rows)
+                for layer in range(rows.shape[0]):
+                    p = p.at[layer, ph, sl].set(rows[layer])
+                return p
 
         fn = jax.jit(p2t_kv_scatter_prefill, donate_argnums=(0,))
         if len(_PREFILL_SCATTER_CACHE) > 1024:

@@ -275,7 +275,7 @@ class ServingEngine:
             self.config.block_size, family.num_kv_heads, family.head_dim,
             dtype=self.config.kv_dtype, state_shape=family.state_shape,
             state_slots=slots, token_rows=sched_cfg.batch_buckets[-1],
-            block_length=family.block_length)
+            block_length=family.block_length, kv_widths=family.kv_widths)
         self.allocator = BlockAllocator(self.config.num_blocks,
                                         self.config.block_size,
                                         state_slots=slots)
@@ -704,12 +704,15 @@ class ServingEngine:
             start = min(seq.prefix_cached_tokens, n)
             with _span("prefill.scatter"):
                 if n:
+                    # (a latent cache: one pool, written a layer at a time)
                     self.cache.k = PagedKVCache.scatter_prefill(
                         self.cache.k, k_stack, row, n,
-                        self.cache.block_size, start=start)
-                    self.cache.v = PagedKVCache.scatter_prefill(
-                        self.cache.v, v_stack, row, n,
-                        self.cache.block_size, start=start)
+                        self.cache.block_size, start=start,
+                        by_layer=v_stack is None)
+                    if v_stack is not None:
+                        self.cache.v = PagedKVCache.scatter_prefill(
+                            self.cache.v, v_stack, row, n,
+                            self.cache.block_size, start=start)
                     if state:
                         # the whole prompt was computed (a prefix hit
                         # too), so this is the state at its real last
@@ -817,9 +820,16 @@ class ServingEngine:
     @staticmethod
     def _count_stats(counts: Dict[str, list]) -> Dict[str, int]:
         """A program's per-layer counts as span stats: summed over the
-        layers, but a ``*_max`` is the worst layer's."""
-        return {k: int(max(v) if k.endswith("_max") else sum(v))
-                for k, v in counts.items()}
+        layers, but a ``*_max`` is the worst layer's. The rows routed,
+        and those with an expert held here, also go to the metrics
+        plane."""
+        from ..observability import metrics
+        stats = {k: int(max(v) if k.endswith("_max") else sum(v))
+                 for k, v in counts.items()}
+        for name in ("moe_rows", "moe_rows_routed_here"):
+            if name in stats:
+                metrics.inc(f"serving_{name}_total", stats[name])
+        return stats
 
     # -- block-table integrity --------------------------------------------
     def _validate_tables(self, active: List[Sequence],

@@ -41,7 +41,9 @@ out_proj -> mlp), mirroring ``GPTBlock.forward``'s head-major qkv
 split, because ``GPTModel.decode_step``'s cache is a growing per-layer
 concat — exactly the contiguous layout paging replaces. Its prefill
 DOES go through ``decode_step`` (empty caches). The LFM2-MoE family is
-in ``lfm2_family.py``, the SDAR-MoE family in ``sdar_family.py``.
+in ``lfm2_family.py``, the SDAR-MoE family in ``sdar_family.py``, the
+DeepSeek-V2 family (latent attention: one pool) in
+``deepseek_family.py``.
 """
 
 from __future__ import annotations
@@ -70,7 +72,11 @@ class ModelFamily:
     ``head_dim``, ``max_positions``, and ``state_shape``: ``None``, or
     ``(state layers, *per-layer shape)`` of a fixed-size state every
     running sequence keeps beside its blocks (one slot of the cache's
-    state pool). ``unsupported`` names the :class:`EngineConfig`
+    state pool). ``kv_widths``: ``None``, or the pools' row widths where
+    they are not ``num_kv_heads * head_dim`` twice (``PagedKVCache``: a
+    latent cache has ONE pool, ``(row width, 0)``, and its ``v`` stack
+    and pool are ``None`` everywhere below). ``unsupported`` names
+    the :class:`EngineConfig`
     features the family cannot serve yet (the engine refuses them at
     construction).
 
@@ -105,6 +111,7 @@ class ModelFamily:
     row (``DroplessExperts.route_and_run``'s record)."""
 
     state_shape = None
+    kv_widths: Optional[Tuple[int, int]] = None
     unsupported: Tuple[str, ...] = ()
     count_names: Tuple[str, ...] = ()
     routed: Optional[Tuple[int, int]] = None
@@ -131,6 +138,16 @@ class ModelFamily:
     def decode(self, k_pool, v_pool, state_pool, ids, positions,
                block_tables, slots, block_size, interpret, split_pages):
         raise NotImplementedError
+
+    def kernel_pages_per_block(self, cache, n_pages: int,
+                               split_pages) -> int:
+        """Pages the family's paged kernel gathers per step in the
+        decode program of this page bucket."""
+        from .paged_attention import kernel_pages_per_block
+        # a block's positions ride the query tile as so many more heads
+        return kernel_pages_per_block(
+            n_pages, cache.block_size, self.num_heads * self.row_positions,
+            self.head_dim, cache.dtype, split_pages, self.num_kv_heads)
 
 
 class GPTFamily(ModelFamily):
@@ -225,15 +242,18 @@ def served_classes(config) -> tuple:
     ONE place that says which models the engine serves. The engine
     rebuilds an artifact's architecture with the first and the runner
     reads the model through the second."""
+    from ..models.deepseek import DeepseekV2Config, DeepseekV2ForCausalLM
     from ..models.gpt import GPTConfig, GPTForCausalLM
     from ..models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
     from ..models.sdar import SdarMoeConfig, SdarMoeForCausalLM
+    from .deepseek_family import DeepseekV2Family
     from .lfm2_family import Lfm2MoeFamily
     from .sdar_family import SdarMoeFamily
     for config_class, classes in (
             (GPTConfig, (GPTForCausalLM, GPTFamily)),
             (Lfm2MoeConfig, (Lfm2MoeForCausalLM, Lfm2MoeFamily)),
-            (SdarMoeConfig, (SdarMoeForCausalLM, SdarMoeFamily))):
+            (SdarMoeConfig, (SdarMoeForCausalLM, SdarMoeFamily)),
+            (DeepseekV2Config, (DeepseekV2ForCausalLM, DeepseekV2Family))):
         if isinstance(config, config_class):
             return classes
     raise TypeError(
@@ -526,13 +546,8 @@ class PagedRunner:
     def kernel_pages_per_block(self, cache, n_pages: int) -> int:
         """Pages the paged kernel gathers per step in the decode
         program of this page bucket (count on ``decode.dispatch``)."""
-        from .paged_attention import kernel_pages_per_block
-        family = self.family
-        # a block's positions ride the query tile as so many more heads
-        return kernel_pages_per_block(
-            n_pages, cache.k.shape[2],
-            family.num_heads * family.row_positions, family.head_dim,
-            cache.k.dtype, self.split_pages, family.num_kv_heads)
+        return self.family.kernel_pages_per_block(cache, n_pages,
+                                                  self.split_pages)
 
     def _decode_args(self, cache, *arrays):
         import jax.numpy as jnp

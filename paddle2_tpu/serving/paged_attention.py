@@ -88,6 +88,8 @@ from ..kernels._platform import interpret_default
 from ..kernels.pallas_flash import NEG_INF
 
 __all__ = ["paged_attention_decode", "paged_attention_reference",
+           "paged_mla_decode", "paged_mla_reference",
+           "mla_pages_per_block", "mla_row_width",
            "paged_attention_split_reference", "gathered_dense_kv",
            "decode_scratch_vmem_bytes", "fits_single_softmax",
            "auto_pages_per_split", "kernel_pages_per_block",
@@ -722,6 +724,209 @@ def _paged_decode_split(qr, k_pool, v_pool, bt, ln, layer, scale, pps,
 def _merge_split_jit(out_dtype: str):
     return jax.jit(functools.partial(_merge_splits,
                                      out_dtype=jnp.dtype(out_dtype)))
+
+
+# --------------------------------------------- latent attention (MLA)
+def mla_row_width(rank: int, rope_dim: int) -> int:
+    """Lanes of a token's row in a latent pool: the latent, then the
+    rotary key padded with zeros to whole 128-lane groups (the chip's
+    copies address a pool's minor axis in groups of 128)."""
+    return int(rank) + _tile_pad(rope_dim, 128)
+
+
+def mla_pages_per_block(n_pages: int, block_size: int, width: int,
+                        dtype) -> int:
+    """Pages per compute block of :func:`paged_mla_decode` (``width``:
+    a row of the pool): the single-softmax body's rule
+    (:func:`_pages_per_block`) — the block is what the body holds at a
+    time, whatever the context."""
+    return _pages_per_block(n_pages, block_size, width, dtype)
+
+
+def _mla_kernel(bt_ref, len_ref, layer_ref, q_ref, pool_hbm, o_ref, buf,
+                acc, m_sc, l_sc, slot_ref, sem, *, scale, block_size,
+                pages_per_block, n_pages, batch):
+    """One grid step = one sequence, all query heads against the ONE
+    shared latent head: the row's LIVE pages are walked in compute
+    blocks of ``pages_per_block`` pages, each page — a token's ``[c | r
+    | 0]`` rows — fetched ONCE into one half of a double buffer, driven
+    by the block table, one block ahead (a row's last block starts the
+    next row's first). Per block: ``s = q [c | r]^T * scale`` in f32 (one
+    dot: the query is ``[q_c | q_r | 0]``), the online-softmax
+    recurrence (running max, denominator, accumulator), ``acc += p c``
+    with ``c`` the page's leading lanes: the bytes that were the keys
+    are the values. Nothing in VMEM grows with the context, so a row of
+    any length takes this one body."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    ppb, bs = pages_per_block, block_size
+    tokens = ppb * bs
+    rank = o_ref.shape[-1]
+    fill = jnp.finfo(jnp.float32).min
+
+    def live_pages(row):
+        return jnp.minimum((len_ref[row] + bs - 1) // bs, n_pages)
+
+    def each_live_page(row, i, slot, act):
+        # dead pages (and the garbage block behind them) never move
+        n_live = jnp.clip(live_pages(row) - i * ppb, 0, ppb)
+
+        def one(j, carry):
+            act(pltpu.make_async_copy(
+                pool_hbm.at[layer, bt_ref[row, i * ppb + j]],
+                buf.at[slot, j], sem.at[slot]))
+            return carry
+        jax.lax.fori_loop(0, n_live, one, 0)
+
+    def start(row, i, slot):
+        each_live_page(row, i, slot, lambda cp: cp.start())
+
+    def wait(row, i, slot):
+        each_live_page(row, i, slot, lambda cp: cp.wait())
+
+    @pl.when(b == 0)
+    def _prime():
+        # a dead page of a live block meets an exactly-0 probability:
+        # whatever the buffer holds there must be finite
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    ctx = len_ref[b]
+    # a row without a key still takes one (empty) step: it is the step
+    # that starts the next row's copies; its output is 0
+    n_blocks = jnp.maximum((live_pages(b) + ppb - 1) // ppb, 1)
+    acc[...] = jnp.zeros_like(acc)
+    m_sc[...] = jnp.full_like(m_sc, fill)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    q = q_ref[...]                                # (R, W)
+
+    def block(i, slot):
+        nxt = 1 - slot
+
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start(b, i + 1, nxt)
+
+        @pl.when((i + 1 == n_blocks) & (b + 1 < batch))
+        def _next_row():
+            start(b + 1, 0, nxt)
+
+        wait(b, i, slot)
+        page = buf[slot].reshape(tokens, buf.shape[-1])       # (T, W)
+        s = jax.lax.dot_general(
+            q, page, (((1,), (1,)), ((), ())),
+            precision=_precision(page.dtype),
+            preferred_element_type=jnp.float32) * scale       # (R, T)
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * tokens
+        s = jnp.where(cols < ctx, s, fill)
+        m_prev = m_sc[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a masked column: exp(finfo.min - finite) is exactly 0
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_sc[...] = jnp.broadcast_to(
+            alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+            l_sc.shape)
+        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+            p.astype(page.dtype), page[:, :rank], (((1,), (0,)), ((), ())),
+            precision=_precision(page.dtype),
+            preferred_element_type=jnp.float32)               # (R, rank)
+        return nxt
+
+    slot_ref[0] = jax.lax.fori_loop(0, n_blocks, block, slot_ref[0])
+    o = acc[...] / jnp.where(ctx > 0, l_sc[:, :1], 1.0)
+    o_ref[...] = jnp.where(ctx > 0, o, 0.0).astype(o_ref.dtype)
+
+
+def paged_mla_decode(q_c, q_r, pool, block_tables, ctx_lens, scale,
+                     interpret=None, layer=0):
+    """Paged decode attention over a LATENT cache (multi-head latent
+    attention with the up-projections absorbed into the query and the
+    output): every one of the ``H`` query heads reads the one shared
+    head of a token, and the values are its latent itself.
+
+    q_c ``[B, H, rank]`` (the content query through ``W_UK``), q_r ``[B,
+    H, dr]`` (the rotary query); pool ``[L, N, bs, W]`` the whole
+    model's latent pool, a token's row ``[c (rank) | r (dr) | zeros]``
+    with ``W`` = :func:`mla_row_width` (``layer`` names the layer read:
+    an index the copies take); block_tables int32 ``[B, n_pages]``;
+    ctx_lens int32 ``[B]`` (the token just appended included).
+    ``softmax((q_c c^T + q_r r^T) * scale) c`` in float32 -> ``[B, H,
+    rank]`` in q_c's dtype. ONE body for every context: it streams the
+    row's live pages in compute blocks (:func:`mla_pages_per_block`),
+    each page read once for scores and values."""
+    if interpret is None:
+        interpret = interpret_default()
+    return _mla_decode(
+        q_c, q_r, pool, jnp.asarray(block_tables, jnp.int32),
+        jnp.asarray(ctx_lens, jnp.int32), jnp.asarray(int(layer), jnp.int32),
+        scale=float(scale), interpret=interpret,
+        ppb=mla_pages_per_block(block_tables.shape[1], pool.shape[2],
+                                pool.shape[3], pool.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb"))
+def _mla_decode(q_c, q_r, pool, bt, ln, layer, *, scale, interpret, ppb):
+    """The call, jitted with the layer a traced scalar: a decode
+    program's layers are ONE traced and lowered kernel."""
+    B, H, rank = q_c.shape
+    bs, width = pool.shape[2], pool.shape[3]
+    rows = _tile_pad(H, 8)
+    q = jnp.concatenate([q_c, q_r], -1).astype(pool.dtype)
+    q = jnp.pad(q, ((0, 0), (0, rows - H), (0, width - q.shape[-1])))
+
+    def tile(lanes):
+        return pl.BlockSpec((None, rows, lanes),
+                            lambda b, bt, ln, layer: (b, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_mla_kernel, scale=scale, block_size=bs,
+                          pages_per_block=ppb, n_pages=bt.shape[1], batch=B),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            # the pool stays where it is; the layer is an index the
+            # copies take, so no program slices a layer out
+            in_specs=[tile(width), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile(rank),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb, bs, width), pool.dtype),
+                pltpu.VMEM((rows, rank), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, rank), q_c.dtype),
+        # rows run in order: each starts the copies of the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_mla_decode",
+    )(bt, ln, layer.reshape(1), q, pool)
+    return out[:, :H]
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def paged_mla_reference(q_c, q_r, pool, block_tables, ctx_lens, scale):
+    """Dense twin of :func:`paged_mla_decode` over ONE layer's pool
+    ``[N, bs, W]``: gather the rows through the block table, one
+    float32 softmax over the whole context."""
+    bt = jnp.asarray(block_tables, jnp.int32)
+    rank, dr = q_c.shape[-1], q_r.shape[-1]
+    rows = pool[bt].reshape(bt.shape[0], -1, pool.shape[-1])  # [B, S, W]
+    c, r = rows[..., :rank], rows[..., rank:rank + dr]
+    prec = _precision(pool.dtype)
+    s = (jnp.einsum("bhr,bsr->bhs", q_c.astype(c.dtype), c, precision=prec,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhd,bsd->bhs", q_r.astype(r.dtype), r, precision=prec,
+                      preferred_element_type=jnp.float32)) * scale
+    seen = jnp.arange(c.shape[1])[None, None] < ctx_lens[:, None, None]
+    p = jax.nn.softmax(jnp.where(seen, s, jnp.finfo(jnp.float32).min), -1)
+    return jnp.einsum("bhs,bsr->bhr", p.astype(c.dtype), c, precision=prec,
+                      preferred_element_type=jnp.float32).astype(q_c.dtype)
 
 
 def gathered_dense_kv(pool, block_tables, num_heads: int):

@@ -21,6 +21,7 @@ chosen for every row), as the LFM2-MoE family does.
 
 from __future__ import annotations
 
+from ..incubate.moe import DroplessExperts
 from .model_runner import ModelFamily
 from .paged_attention import paged_attention_decode
 
@@ -31,7 +32,7 @@ class SdarMoeFamily(ModelFamily):
     # engine features this family does not have yet
     unsupported = ("weight_only_int8", "weight_only_lm_head", "spec",
                    "enable_kv_spill")
-    count_names = ("moe_assignments", "moe_experts_hit", "moe_load_max")
+    count_names = DroplessExperts.COUNT_NAMES
 
     def __init__(self, model):
         super().__init__(model)

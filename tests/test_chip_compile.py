@@ -298,6 +298,39 @@ def test_paged_decode_block_of_positions_cell_shape(one_chip):
                 if " copy(" in ln and "[4,12288,16,512]" in ln]
 
 
+def test_paged_mla_decode_cell_shape(one_chip):
+    """What `dsv2-serve-doc5k-backlog` runs: 128 rows, 128 query heads
+    against the one latent head of 512 + 64 lanes in rows of 640, 384
+    pages (6,144 positions) of a 49,152-block pool, layer 4 of 5: the
+    streaming body in compute blocks of 48 pages, no copy of the pool."""
+    assert pa.mla_row_width(512, 64) == 640
+    assert pa.mla_pages_per_block(384, 16, 640, BF16) == 48
+    avals = (((128, 128, 512), BF16), ((128, 128, 64), BF16),
+             ((5, 49152, 16, 640), BF16),
+             ((128, 384), jnp.int32), ((128,), jnp.int32))
+    fn = functools.partial(pa.paged_mla_decode, scale=0.1147,
+                           interpret=False, layer=4)
+    text = _compile(one_chip, fn, *avals,
+                    kernels=["paged_mla_decode"]).as_text()
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[5,49152,16," in ln]
+
+
+@pytest.mark.parametrize("seq,grid", [(2048, False), (3072, False),
+                                      (5120, True)])
+def test_flash_mla_prefill_shapes(one_chip, seq, grid):
+    """The latent-attention prefill's expanded attention: [1, S, 128,
+    192] queries and keys against [1, S, 128, 128] values, bf16, causal,
+    forward only; the two shorter prompt buckets take the walk, the
+    5,120-token one the grid kernel."""
+    assert (pallas_flash._walks(seq, seq, 192, BF16, 1, 1024, 1024, False,
+                                128) is None) == grid
+    qk, v = ((1, seq, 128, 192), BF16), ((1, seq, 128, 128), BF16)
+    fn = functools.partial(pallas_flash.flash_attention_bshd, causal=True,
+                           scale=0.1147, interpret=False)
+    _compile(one_chip, fn, qk, qk, v, kernels=["flash_fwd"])
+
+
 @pytest.mark.parametrize("seq", [1024, 2048])
 def test_flash_block_causal_prefill_shape(one_chip, seq):
     """The block-diffusion prefill's attention: [1, S, 32, 128] bf16
@@ -373,6 +406,19 @@ def test_rmsnorm_and_rope_at_lfm2_shapes(one_chip, rows):
             return pallas_fused.fused_rope(x, c, s, interpret=False)
         _compile(one_chip, rope, ((1, rows, heads, 64), BF16),
                  ((rows, 64), F32), ((rows, 64), F32), kernels=["rope"])
+
+
+@pytest.mark.parametrize("rows", [128, 5120])
+def test_rmsnorm_at_hidden_5120(one_chip, rows):
+    """The pre-norm as the latent-attention family's programs call it: a
+    decode step's 128 rows and a 5,120-token prefill of hidden 5120 in
+    bf16 (512 rows of that width overran the scoped VMEM: the public
+    entry takes fewer rows of a wider model)."""
+    def norm(x, w):
+        return pallas_fused.fused_rms_norm(x, w, 1e-6, interpret=False)
+
+    _compile(one_chip, norm, ((rows, 5120), BF16), ((5120,), F32),
+             kernels=["rmsnorm_fwd"])
 
 
 # ---------------------------------------------------------------- fused

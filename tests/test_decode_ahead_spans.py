@@ -10,12 +10,13 @@ import jax
 import numpy as np
 import pytest
 
+from paddle2_tpu.incubate.moe import DroplessExperts
 from paddle2_tpu.models import Lfm2MoeForCausalLM, lfm2_moe_tiny
 from paddle2_tpu.serving import EngineConfig, ServingEngine
 from paddle2_tpu.serving.spec import SpeculativeConfig
 from test_program_spans import PARENT, PROMPTS, read_spans, tiny_engine
 
-ROUTING = ("moe_assignments", "moe_experts_hit", "moe_load_max")
+ROUTING = DroplessExperts.COUNT_NAMES
 
 
 def serve_traced(tmp_path_factory, engine, requests):

@@ -1,0 +1,153 @@
+"""The span contract of the DeepSeek-V2 family (PERF.md section 3),
+beside ``test_prefill_ahead_spans.py``: ``decode.dispatch`` and the
+read-back ``prefill`` span carry, beside the routing counts every routed
+family writes, ``moe_rows_routed_here`` and ``moe_rows``; ``ctx_tokens``,
+``live_pages`` and ``kernel_pages_per_block`` are of LATENT pages — and
+the benchmark's new readers (``moe_rows_here_pct.serve``, and the counts
+``paged_mla_roofline_pct.serve`` / ``flash_mla_prefill_roofline_pct.serve``
+take) read them off the engine's own spans."""
+
+import importlib.util
+import os
+import sys
+import types
+
+import numpy as np
+import pytest
+
+import paddle2_tpu as paddle
+from paddle2_tpu.models import DeepseekV2ForCausalLM, deepseek_v2_tiny
+from paddle2_tpu.observability import metrics
+from paddle2_tpu.serving import EngineConfig, ServingEngine
+from paddle2_tpu.serving import paged_attention as pa
+from test_decode_ahead_spans import ROUTING, serve_traced
+
+BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+PROMPTS = (9, 12, 14)
+NEW = 4
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    paddle.seed(0)
+    model = DeepseekV2ForCausalLM(deepseek_v2_tiny(held_group=1))
+    model.eval()
+    engine = ServingEngine(model, config=EngineConfig(
+        block_size=8, num_blocks=64, max_batch=4, max_model_len=96,
+        batch_buckets=(4,), page_buckets=(4,), interpret=True))
+    rng = np.random.default_rng(0)
+    spans = serve_traced(
+        tmp_path_factory, engine,
+        [(rng.integers(1, 503, n).tolist(), NEW) for n in PROMPTS])
+    return engine, spans
+
+
+def test_rows_routed_and_rows_here_ride_with_the_routing_counts(traced):
+    engine, spans = traced
+    layers, k = engine.runner.family.routed
+    assert ROUTING[-2:] == ("moe_rows_routed_here", "moe_rows")
+    delivered = [s[3] for s in spans
+                 if s[0] == "prefill" and "tokens" not in s[3]]
+    assert [c["req"] for c in delivered] == [0, 1, 2]
+    for c, n in zip(delivered, PROMPTS):
+        assert set(c) == {"req", *ROUTING}
+        # every prompt token is routed in every expert layer; those
+        # with an expert of the held group are the rows computed for
+        assert c["moe_rows"] == n * layers
+        assert c["moe_assignments"] <= k * c["moe_rows_routed_here"]
+        assert c["moe_rows_routed_here"] <= c["moe_assignments"] \
+            <= c["moe_rows"] * k
+    steps = [s[3] for s in spans if s[0] == "decode.dispatch"]
+    read = [c for c in steps if "moe_rows" in c]
+    assert read and all(c["moe_rows"] == 3 * layers for c in read)
+    assert all(c["moe_rows_routed_here"] <= c["moe_rows"] for c in read)
+
+
+def test_the_page_counts_are_of_latent_pages(traced):
+    engine, spans = traced
+    width = engine.runner.family.kv_widths[0]
+    steps = [s[3] for s in spans
+             if s[0] == "decode.dispatch" and "rows" in s[3]]
+    assert steps
+    first = steps[0]
+    # the step after the prefills: each row at its prompt's length
+    assert first["ctx_tokens"] == sum(PROMPTS)
+    assert first["live_pages"] == sum(-(-(n + 1) // 8) for n in PROMPTS)
+    assert first["kernel_pages_per_block"] == pa.mla_pages_per_block(
+        4, 8, width, engine.cache.dtype)
+    assert first["blocks_total"] == 64
+
+
+@pytest.fixture()
+def readers(monkeypatch, traced):
+    monkeypatch.syspath_prepend(BENCHMARK)
+    for name in ("program_trace", "moe_trace", "trace_reduce", "common"):
+        monkeypatch.delitem(sys.modules, name, raising=False)
+    import program_trace
+
+    def reader(name):
+        spec = importlib.util.spec_from_file_location(
+            "reader_" + name.split(".")[0],
+            os.path.join(BENCHMARK, "layer_metrics", name + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    pt = program_trace.ProgramTrace()
+    pt.spans = list(traced[1])
+    ctx = {"cell": {"trace_dir": "spans-of-the-test"},
+           "trace": types.SimpleNamespace(window=None)}
+    monkeypatch.setattr(program_trace.trace_reduce, "find_xplane",
+                        lambda trace_dir: trace_dir)
+    monkeypatch.setitem(program_trace._LOADED, "spans-of-the-test", pt)
+    yield types.SimpleNamespace(program_trace=program_trace, pt=pt, ctx=ctx,
+                                reader=reader)
+    for name in ("program_trace", "moe_trace", "trace_reduce", "common"):
+        sys.modules.pop(name, None)
+
+
+def test_the_new_readers_read_the_engines_own_spans(readers, traced):
+    engine, spans = traced
+    counted = [s[3] for s in spans if s[0] in ("decode.dispatch", "prefill")
+               and "moe_rows" in s[3]]
+    want = 100.0 * sum(c["moe_rows_routed_here"] for c in counted) \
+        / sum(c["moe_rows"] for c in counted)
+    got = readers.reader("moe_rows_here_pct.serve").read(readers.ctx)
+    assert got == pytest.approx(want) and 0 < got < 100
+    # what the two roofline readers take off the spans is there: the
+    # steps' contexts and rows, the admissions' padded lengths
+    steps = [c for _, _, _, c in readers.program_trace.spans_named(
+        readers.pt, "decode.dispatch") if "ctx_tokens" in c and "rows" in c]
+    assert len(steps) == NEW - 1 and all(c["rows"] == 3 for c in steps)
+    assert [c["ctx_tokens"] for c in steps] == \
+        [sum(PROMPTS) + 3 * i for i in range(NEW - 1)]
+    padded = [c["padded"] for _, _, _, c in readers.program_trace.spans_named(
+        readers.pt, "prefill") if c.get("padded")]
+    assert padded == [16, 16, 16]
+    # and without a device trace or peaks they say nothing, not zero
+    cell = {"workload": {"kernels": {"paged_mla_decode": {}, "flash_fwd": {}}},
+            "config": {"kv_lora_rank": 32}, "peaks": None,
+            "trace_dir": "spans-of-the-test"}
+    ctx = dict(readers.ctx, cell=cell,
+               trace=types.SimpleNamespace(window=None, devices={}))
+    for name in ("paged_mla_roofline_pct.serve",
+                 "flash_mla_prefill_roofline_pct.serve"):
+        assert readers.reader(name).read(ctx) is None
+
+
+def test_the_metrics_plane_counts_the_rows(tmp_path):
+    plane = metrics.enable(str(tmp_path), rank=0)
+    try:
+        stats = ServingEngine._count_stats(
+            {"moe_assignments": [5, 4], "moe_experts_hit": [2, 2],
+             "moe_load_max": [3, 2], "moe_rows_routed_here": [4, 3],
+             "moe_rows": [9, 9]})
+        assert stats == {"moe_assignments": 9, "moe_experts_hit": 4,
+                         "moe_load_max": 3, "moe_rows_routed_here": 7,
+                         "moe_rows": 18}
+        assert plane.counter("serving_moe_rows_total").value() == 18
+        assert plane.counter(
+            "serving_moe_rows_routed_here_total").value() == 7
+    finally:
+        metrics.disable()

@@ -501,9 +501,11 @@ def test_expert_shares_add_up_to_the_whole_layer(bench, router, E, k):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5)
     assert int(counts[0]) == 40 * k
-    # behind the three counts, the experts chosen row by row
+    # every row is routed, and holding all experts, routed here
+    assert counts[3:5].tolist() == [40, 40]
+    # behind the counts, the experts chosen row by row
     np.testing.assert_array_equal(
-        np.sort(np.asarray(counts[3:]).reshape(40, k), -1),
+        np.sort(np.asarray(counts[5:]).reshape(40, k), -1),
         np.sort(np.asarray(used), -1))
 
 
