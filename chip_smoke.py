@@ -264,7 +264,102 @@ def kernel_parity(size: dict) -> dict:
     err.update(gqa_parity(size))
     err.update(gmm_parity(size))
     err.update(flash_parity(size))
+    err.update(mla_split(size))
     return err
+
+
+def mla_split(size: dict) -> dict:
+    """The latent paged body (``paged_mla_decode``) where the size
+    allows at the DeepSeek-V2 cell's decode shape — 128 rows, 128 query
+    heads over rows of 512 + 64 (+ 64) lanes, 384 pages of 16; contexts
+    like the cell's: a prompt of 2,048 / 3,072 / 5,120 whose pages are
+    one ascending run, then 0-1,024 decoded tokens on scattered pages —
+    against the dense reference, and its time SPLIT (PERF.md section 6,
+    PR 36, step 0): the whole body, its copies alone (the block's
+    arithmetic left out) and its arithmetic alone (no copy started or
+    waited for; what the buffer holds does not change the time), us a
+    row, best of 5 chains of twenty distinct calls. Where the two parts
+    add up to the whole they do not overlap. Times are taken on the chip
+    only."""
+    import functools
+    from unittest import mock
+    import jax
+    import jax.numpy as jnp
+    from paddle2_tpu.serving import paged_attention as pa
+    big = size["hidden"] >= 1024
+    B, H, rank, dr, bs, P = ((128, 128, 512, 64, 16, 384) if big
+                             else (4, 4, 32, 8, 8, 32))
+    rng = np.random.default_rng(4)
+    prompts = rng.choice([2048, 3072, 5120] if big else [64, 128], B)
+    ctx = (prompts + rng.integers(0, (P * bs - prompts.max()) + 1, B)
+           ).astype(np.int32)
+    tables = np.zeros((B, P), np.int32)
+    scattered = iter(rng.permutation(np.arange(B * P // 2, B * P)) + 1)
+    first = 1
+    for r in range(B):
+        run, live = prompts[r] // bs, -(-int(ctx[r]) // bs)
+        tables[r, :run] = np.arange(first, first + run)
+        tables[r, run:live] = [next(scattered) for _ in range(live - run)]
+        first += run
+    W = pa.mla_row_width(rank, dr)
+    pool = jax.random.normal(jax.random.PRNGKey(0), (1, B * P + 1, bs, W),
+                             jnp.bfloat16).at[..., rank + dr:].set(0)
+    q_c, q_r = (jax.random.normal(jax.random.PRNGKey(k), (B, H, n),
+                                  jnp.bfloat16)
+                for k, n in ((1, rank), (2, dr)))
+    got = np.asarray(pa.paged_mla_decode(q_c, q_r, pool, tables, ctx, 0.1147),
+                     np.float32)
+    ref = np.asarray(pa.paged_mla_reference(q_c[:8], q_r[:8], pool[0],
+                                            tables[:8], ctx[:8], 0.1147),
+                     np.float32)
+    out = {"mla.cell": float(np.abs(got[:8] - ref).max())}
+    if not np.isfinite(got).all() or out["mla.cell"] > 2e-2:
+        raise AssertionError(f"latent paged kernel off the dense "
+                             f"reference by {out['mla.cell']}")
+    if not big:
+        return out
+
+    class NoCopy:
+        start = wait = staticmethod(lambda: None)
+
+    ppb, ppc = pa._mla_plan(P, bs, W, pool.dtype)
+    parts = {
+        "whole": [],
+        "copies_only": [mock.patch.object(pa, "_mla_attend",
+                                          lambda *a, **k: None)],
+        "arith_only": [mock.patch.object(pa.pltpu, "make_async_copy",
+                                         lambda *a, **k: NoCopy)],
+    }
+    for name, patches in parts.items():
+        for p in patches:
+            p.start()
+        try:
+            # a trace of its own: the patched names are not in the key
+            # of the jitted call
+            call = functools.partial(
+                pa._mla_decode.__wrapped__, scale=0.1147, interpret=False,
+                ppb=ppb, ppc=ppc)
+
+            @jax.jit
+            def chain(q_c, q_r, pool, tables, ctx):
+                # distinct operands: identical calls would be merged
+                return sum(call(jnp.roll(q_c, k, 0), q_r, pool, tables, ctx,
+                                jnp.asarray(0, jnp.int32))[:, 0, 0]
+                           .astype(jnp.float32) for k in range(20))
+
+            args = (q_c, q_r, pool, jnp.asarray(tables), jnp.asarray(ctx))
+            chain(*args).block_until_ready()
+            best = math.inf
+            for _ in range(5):
+                t0 = time.perf_counter()
+                chain(*args).block_until_ready()
+                best = min(best, time.perf_counter() - t0)
+        finally:
+            for p in patches:
+                p.stop()
+        out[f"mla.us_a_row.{name}"] = best / 20 / B * 1e6
+    out["mla.live_pages_a_row"] = float(np.mean(-(-ctx // bs)))
+    return out
 
 
 def flash_parity(size: dict) -> dict:

@@ -242,7 +242,12 @@ class BlockAllocator:
                 raise BlockFreeError(
                     f"block {b} appears twice in one free() call")
             seen.add(b)
-        for b in blocks:
+        # last block first: the next allocation pops them in the order
+        # they were freed in — a table freed whole comes back ascending
+        # where it was ascending, runs of consecutive pages that ONE
+        # copy of a paged kernel fetches (paged_attention.
+        # mla_coalesced_pages); still LIFO by sequence
+        for b in reversed(blocks):
             self._rc[b] -= 1
             if self._rc[b] == 0:
                 self._free.append(b)

@@ -28,7 +28,8 @@ from __future__ import annotations
 from ..incubate.moe import DroplessExperts
 from .block_cache import GARBAGE_BLOCK
 from .model_runner import ModelFamily
-from .paged_attention import (mla_pages_per_block, mla_row_width,
+from .paged_attention import (mla_coalesced_pages, mla_pages_per_block,
+                              mla_pages_per_copy, mla_row_width,
                               paged_mla_decode)
 
 __all__ = ["DeepseekV2Family"]
@@ -54,9 +55,16 @@ class DeepseekV2Family(ModelFamily):
         self.routed = (cfg.num_hidden_layers - cfg.first_k_dense_replace,
                        cfg.num_experts_per_tok)
 
-    def kernel_pages_per_block(self, cache, n_pages, split_pages):
-        return mla_pages_per_block(n_pages, cache.block_size,
-                                   self.kv_widths[0], cache.dtype)
+    def kernel_page_counts(self, cache, tables, live_pages, split_pages):
+        shape = (tables.shape[1], cache.block_size, self.kv_widths[0],
+                 cache.dtype)
+        return dict(
+            kernel_pages_per_block=mla_pages_per_block(*shape),
+            # of the step's live pages, those the kernel fetches a run
+            # of consecutive pages at a time
+            coalesced_pages=mla_coalesced_pages(
+                tables[:len(live_pages)], live_pages,
+                mla_pages_per_copy(*shape)))
 
     def _rows(self, c, k_rope):
         """The pool's rows ``[..., W]`` of latents ``c`` and rotary keys

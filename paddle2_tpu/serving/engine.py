@@ -1102,8 +1102,8 @@ class ServingEngine:
         # pages that hold a key of some row (a row at position p
         # attends over p + 1 keys): what the kernel moves, against
         # the rows x page_bucket table it is handed
-        live_pages = sum(blocks_for_tokens(pos + 1, self.config.block_size)
-                         for _, _, pos in rows)
+        live_pages = [blocks_for_tokens(pos + 1, self.config.block_size)
+                      for _, _, pos in rows]
         b_bucket = cfg.batch_bucket(len(rows))
         p_bucket = self.scheduler.decode_bucket(active)[1]
         ids = np.zeros((b_bucket, 1), np.int32)
@@ -1118,20 +1118,22 @@ class ServingEngine:
             tables[i] = s.table.padded(p_bucket)
             if slots is not None:
                 slots[i] = s.table.state_slot
-        counts = self._step_counts(len(rows), b_bucket, p_bucket, ctx_tokens,
+        counts = self._step_counts(len(rows), tables, ctx_tokens,
                                    live_pages, victims)
         return _Step(now, active, drafts, (b_bucket, p_bucket),
                      (ids, positions, tables)
                      + (() if slots is None else (slots,)), counts)
 
-    def _step_counts(self, rows, b_bucket, p_bucket, ctx_tokens, live_pages,
+    def _step_counts(self, rows, tables, ctx_tokens, live_pages,
                      victims) -> dict:
-        """What ``decode.dispatch`` says of the step it enqueues."""
+        """What ``decode.dispatch`` says of the step it enqueues over
+        ``tables`` (``live_pages``: of each row)."""
         counts = dict(
-            rows=rows, row_bucket=b_bucket, page_bucket=p_bucket,
-            ctx_tokens=ctx_tokens, live_pages=live_pages,
-            kernel_pages_per_block=self.runner.kernel_pages_per_block(
-                self.cache, p_bucket),
+            rows=rows, row_bucket=tables.shape[0],
+            page_bucket=tables.shape[1], ctx_tokens=ctx_tokens,
+            live_pages=sum(live_pages),
+            **self.runner.kernel_page_counts(self.cache, tables,
+                                             live_pages),
             blocks_in_use=self.allocator.used_count,
             blocks_total=self.config.num_blocks, evicted=len(victims))
         if self.cache.state is not None:
@@ -1157,7 +1159,8 @@ class ServingEngine:
         meta = np.zeros((b_bucket, 4 + 2 * B), np.int32)
         meta[:, 0] = -1
         tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
-        ctx_tokens = live_pages = denoise = fresh = 0
+        ctx_tokens = denoise = fresh = 0
+        live_pages = []
         for i, s in enumerate(active):
             st = s.block
             if st is None:
@@ -1180,12 +1183,12 @@ class ServingEngine:
             meta[i, 1:4] = n_fix, start, 1
             tables[i] = s.table.padded(p_bucket)
             ctx_tokens += start + B
-            live_pages += blocks_for_tokens(start + B,
-                                            self.config.block_size)
+            live_pages.append(blocks_for_tokens(start + B,
+                                                self.config.block_size))
             denoise += n_fix > 0
             fresh += q == 0
-        counts = self._step_counts(len(active), b_bucket, p_bucket,
-                                   ctx_tokens, live_pages, victims)
+        counts = self._step_counts(len(active), tables, ctx_tokens,
+                                   live_pages, victims)
         counts.update(seqs=len(active), block_length=B,
                       denoise_rows=denoise,
                       commit_rows=len(active) - denoise, fresh_blocks=fresh)

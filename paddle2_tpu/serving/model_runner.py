@@ -139,15 +139,18 @@ class ModelFamily:
                block_tables, slots, block_size, interpret, split_pages):
         raise NotImplementedError
 
-    def kernel_pages_per_block(self, cache, n_pages: int,
-                               split_pages) -> int:
-        """Pages the family's paged kernel gathers per step in the
-        decode program of this page bucket."""
+    def kernel_page_counts(self, cache, tables, live_pages,
+                           split_pages) -> dict:
+        """What ``decode.dispatch`` says of the paged kernel's work on a
+        step's ``tables`` (``[rows, pages]``, ``live_pages`` a row):
+        ``kernel_pages_per_block``, the pages the family's kernel
+        gathers per compute block in this page bucket's program."""
         from .paged_attention import kernel_pages_per_block
         # a block's positions ride the query tile as so many more heads
-        return kernel_pages_per_block(
-            n_pages, cache.block_size, self.num_heads * self.row_positions,
-            self.head_dim, cache.dtype, split_pages, self.num_kv_heads)
+        return dict(kernel_pages_per_block=kernel_pages_per_block(
+            tables.shape[1], cache.block_size,
+            self.num_heads * self.row_positions, self.head_dim,
+            cache.dtype, split_pages, self.num_kv_heads))
 
 
 class GPTFamily(ModelFamily):
@@ -543,11 +546,12 @@ class PagedRunner:
         donate = (1, 2) if family.state_shape is None else (1, 2, 8)
         return jax.jit(p2t_decode, donate_argnums=donate)
 
-    def kernel_pages_per_block(self, cache, n_pages: int) -> int:
-        """Pages the paged kernel gathers per step in the decode
-        program of this page bucket (count on ``decode.dispatch``)."""
-        return self.family.kernel_pages_per_block(cache, n_pages,
-                                                  self.split_pages)
+    def kernel_page_counts(self, cache, tables, live_pages) -> dict:
+        """The paged kernel's counts on ``decode.dispatch`` for a step
+        over ``tables`` (the family's: pages per compute block; a latent
+        cache's also the pages that arrive a run at a time)."""
+        return self.family.kernel_page_counts(cache, tables, live_pages,
+                                              self.split_pages)
 
     def _decode_args(self, cache, *arrays):
         import jax.numpy as jnp

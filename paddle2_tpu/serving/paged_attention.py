@@ -88,8 +88,8 @@ from ..kernels._platform import interpret_default
 from ..kernels.pallas_flash import NEG_INF
 
 __all__ = ["paged_attention_decode", "paged_attention_reference",
-           "paged_mla_decode", "paged_mla_reference",
-           "mla_pages_per_block", "mla_row_width",
+           "paged_mla_decode", "paged_mla_reference", "mla_row_width",
+           "mla_pages_per_block", "mla_pages_per_copy", "mla_coalesced_pages",
            "paged_attention_split_reference", "gathered_dense_kv",
            "decode_scratch_vmem_bytes", "fits_single_softmax",
            "auto_pages_per_split", "kernel_pages_per_block",
@@ -734,61 +734,194 @@ def mla_row_width(rank: int, rope_dim: int) -> int:
     return int(rank) + _tile_pad(rope_dim, 128)
 
 
+# What the latent body holds at a time and moves at a time: one half of
+# its double buffer (a compute block's pages: 96 pages of 20 KB at the
+# DeepSeek-V2 cell's widths, a [128, 1536] score tile — the widest of
+# 48 / 96 / 144 pages on the chip, because the per-block cost of the
+# running softmax is paid half as often while the ragged last block
+# still wastes less than it saves), and the most ONE copy brings (a run
+# of consecutive pages: 16 pages — a descriptor a page cost the scalar
+# core 34 bundles in a loop nothing overlaps, more than the page's
+# transfer takes). Readings: PERF.md section 6, PR 36.
+_MLA_BLOCK_BYTES = 2 ** 21
+_MLA_COPY_BYTES = 5 * 2 ** 16
+
+
+def _mla_plan(n_pages: int, block_size: int, width: int, dtype) -> tuple:
+    """``(pages a compute block, pages a copy)`` of
+    :func:`paged_mla_decode`, from what the code can see (``width``: a
+    row of the pool). Both are whole 128-lane score rows of tokens; a
+    block weighs about :data:`_MLA_BLOCK_BYTES` and is no wider than
+    the table; a copy divides the block and weighs at most
+    :data:`_MLA_COPY_BYTES`."""
+    lane_dense = 128 // math.gcd(128, int(block_size))
+    page_bytes = int(block_size) * int(width) * jnp.dtype(dtype).itemsize
+    k = max(min(_MLA_BLOCK_BYTES // page_bytes,
+                _tile_pad(n_pages, lane_dense)) // lane_dense, 1)
+    fit = max(_MLA_COPY_BYTES // (lane_dense * page_bytes), 1)
+    d = max(d for d in range(1, k + 1) if k % d == 0 and d <= fit)
+    return k * lane_dense, d * lane_dense
+
+
 def mla_pages_per_block(n_pages: int, block_size: int, width: int,
                         dtype) -> int:
     """Pages per compute block of :func:`paged_mla_decode` (``width``:
-    a row of the pool): the single-softmax body's rule
-    (:func:`_pages_per_block`) — the block is what the body holds at a
-    time, whatever the context."""
-    return _pages_per_block(n_pages, block_size, width, dtype)
+    a row of the pool) — the block is what the body holds at a time,
+    whatever the context."""
+    return _mla_plan(n_pages, block_size, width, dtype)[0]
 
 
-def _mla_kernel(bt_ref, len_ref, layer_ref, q_ref, pool_hbm, o_ref, buf,
-                acc, m_sc, l_sc, slot_ref, sem, *, scale, block_size,
-                pages_per_block, n_pages, batch):
+def mla_pages_per_copy(n_pages: int, block_size: int, width: int,
+                       dtype) -> int:
+    """Pages ONE copy of :func:`paged_mla_decode` brings where a row's
+    table allows: the table is read in aligned groups of this many
+    entries, and a group that is all live and holds consecutive page
+    ids is one copy (:func:`mla_coalesced_pages`)."""
+    return _mla_plan(n_pages, block_size, width, dtype)[1]
+
+
+def _page_runs(tables, pages_per_copy: int):
+    """``[B, P]`` block tables (``P`` a multiple of ``pages_per_copy``;
+    numpy on the host, traced on the device) -> ``[B, P //
+    pages_per_copy]`` booleans: the group's entries are consecutive
+    ascending page ids — ONE contiguous stretch of the pool."""
+    g = tables.reshape(tables.shape[0], -1, pages_per_copy)
+    return (g[..., 1:] - g[..., :-1] == 1).all(-1)
+
+
+def mla_coalesced_pages(tables, live_pages, pages_per_copy: int) -> int:
+    """Of the live pages of a step's rows (``tables`` ``[B, P]``,
+    ``live_pages`` a row), how many :func:`paged_mla_decode` fetches in
+    copies of a whole run: the pages of the aligned groups that are all
+    live and one run. On the host, from the tables the step is built
+    from: the count ``decode.dispatch`` carries."""
+    tables = np.asarray(tables)
+    ppc = int(pages_per_copy)
+    pad = _tile_pad(tables.shape[1], ppc) - tables.shape[1]
+    runs = _page_runs(np.pad(tables, ((0, 0), (0, pad))), ppc)
+    whole = (np.arange(runs.shape[1])[None] + 1) * ppc \
+        <= np.asarray(live_pages)[:, None]
+    return int((runs & whole).sum()) * ppc
+
+
+def _mla_attend(q_ref, page_ref, acc, m_sc, l_sc, scale, live=None):
+    """One compute block ``page_ref`` ``[pages, bs, W]`` into the running
+    softmax of :func:`_mla_kernel` (``acc`` ``[R, rank]``, ``m_sc`` /
+    ``l_sc`` the running maximum and denominator over 128 lanes).
+    ``live``: the block's leading columns that are context, where the
+    context ends in it — scores behind them are masked with
+    ``finfo.min`` and their values with zeros; None: every column."""
+    rank = acc.shape[-1]
+    page = page_ref[...].reshape(-1, page_ref.shape[-1])      # (T, W)
+    s = jax.lax.dot_general(
+        q_ref[...], page, (((1,), (1,)), ((), ())),
+        precision=_precision(page.dtype),
+        preferred_element_type=jnp.float32) * scale           # (R, T)
+    c = page[:, :rank]
+    if live is not None:
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols < live, s, jnp.finfo(jnp.float32).min)
+        slots = jax.lax.broadcasted_iota(jnp.int32, c.shape, 0)
+        c = jnp.where(slots < live, c, jnp.zeros_like(c))
+    m_prev = m_sc[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # a masked column: exp(finfo.min - finite) is exactly 0
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_sc[...] = jnp.broadcast_to(
+        alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True), l_sc.shape)
+    m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+    acc[...] = acc[...] * alpha + jax.lax.dot_general(
+        p.astype(page.dtype), c, (((1,), (0,)), ((), ())),
+        precision=_precision(page.dtype),
+        preferred_element_type=jnp.float32)                   # (R, rank)
+
+
+def _mla_kernel(bt_ref, len_ref, run_ref, layer_ref, q_ref, pool_hbm, o_ref,
+                buf, acc, m_sc, l_sc, slot_ref, sem, *, scale, block_size,
+                pages_per_block, pages_per_copy, n_pages, batch):
     """One grid step = one sequence, all query heads against the ONE
     shared latent head: the row's LIVE pages are walked in compute
     blocks of ``pages_per_block`` pages, each page — a token's ``[c | r
-    | 0]`` rows — fetched ONCE into one half of a double buffer, driven
-    by the block table, one block ahead (a row's last block starts the
-    next row's first). Per block: ``s = q [c | r]^T * scale`` in f32 (one
-    dot: the query is ``[q_c | q_r | 0]``), the online-softmax
-    recurrence (running max, denominator, accumulator), ``acc += p c``
-    with ``c`` the page's leading lanes: the bytes that were the keys
-    are the values. Nothing in VMEM grows with the context, so a row of
-    any length takes this one body."""
+    | 0]`` rows — fetched ONCE into one half of a double buffer, one
+    block ahead (a row's last block starts the next row's first). The
+    table is read in aligned groups of ``pages_per_copy`` entries: a
+    group that is all live and that ``run_ref`` marks as consecutive
+    page ids arrives in ONE copy, any other a live page at a time; a
+    block's bytes are waited for a group at a time whatever brought
+    them (the semaphore counts bytes). Per block: ``s = q [c | r]^T *
+    scale`` in f32 (one dot: the query is ``[q_c | q_r | 0]``), the
+    online-softmax recurrence (running max, denominator, accumulator),
+    ``acc += p c`` with ``c`` the page's leading lanes: the bytes that
+    were the keys are the values. Only the block the context ENDS in
+    can hold a dead column: it alone is masked — its scores with
+    ``finfo.min``, its values with zeros, so that nothing a dead slot
+    or a page that never moved holds reaches the result. Nothing in
+    VMEM grows with the context, so a row of any length takes this one
+    body."""
     b = pl.program_id(0)
     layer = layer_ref[0]
-    ppb, bs = pages_per_block, block_size
+    ppb, ppc, bs = pages_per_block, pages_per_copy, block_size
     tokens = ppb * bs
-    rank = o_ref.shape[-1]
-    fill = jnp.finfo(jnp.float32).min
 
     def live_pages(row):
         return jnp.minimum((len_ref[row] + bs - 1) // bs, n_pages)
 
-    def each_live_page(row, i, slot, act):
+    def block_pages(row, i):
         # dead pages (and the garbage block behind them) never move
-        n_live = jnp.clip(live_pages(row) - i * ppb, 0, ppb)
-
-        def one(j, carry):
-            act(pltpu.make_async_copy(
-                pool_hbm.at[layer, bt_ref[row, i * ppb + j]],
-                buf.at[slot, j], sem.at[slot]))
-            return carry
-        jax.lax.fori_loop(0, n_live, one, 0)
+        return jnp.clip(live_pages(row) - i * ppb, 0, ppb)
 
     def start(row, i, slot):
-        each_live_page(row, i, slot, lambda cp: cp.start())
+        n_live = block_pages(row, i)
+
+        def group(g, carry):
+            first = i * ppb + g * ppc
+            left = n_live - g * ppc
+            run = (left >= ppc) & (run_ref[row, i * (ppb // ppc) + g] == 1)
+
+            def page(j, c=None):
+                pltpu.make_async_copy(
+                    pool_hbm.at[layer, bt_ref[row, first + j]],
+                    buf.at[slot, g * ppc + j], sem.at[slot]).start()
+                return c
+
+            @pl.when(run)
+            def _run():
+                pltpu.make_async_copy(
+                    pool_hbm.at[layer, pl.ds(bt_ref[row, first], ppc)],
+                    buf.at[slot, pl.ds(g * ppc, ppc)], sem.at[slot]).start()
+
+            @pl.when(jnp.logical_not(run) & (left >= ppc))
+            def _scattered():
+                # all live, no run: straight-line descriptors, whose
+                # address arithmetic the scheduler interleaves (a third
+                # of the bundles of a loop step a page)
+                for j in range(ppc):
+                    page(j)
+
+            @pl.when(left < ppc)
+            def _ragged():
+                jax.lax.fori_loop(0, left, page, 0)
+            return carry
+        jax.lax.fori_loop(0, (n_live + ppc - 1) // ppc, group, 0)
 
     def wait(row, i, slot):
-        each_live_page(row, i, slot, lambda cp: cp.wait())
+        n_live = block_pages(row, i)
+
+        def arrived(pages):
+            # the semaphore counts bytes: a descriptor of as many pages
+            # waits for them, whatever copies brought them
+            def wait_one(_, carry):
+                pltpu.make_async_copy(pool_hbm.at[layer, pl.ds(0, pages)],
+                                      buf.at[slot, pl.ds(0, pages)],
+                                      sem.at[slot]).wait()
+                return carry
+            return wait_one
+        jax.lax.fori_loop(0, n_live // ppc, arrived(ppc), 0)
+        jax.lax.fori_loop(0, n_live % ppc, arrived(1), 0)
 
     @pl.when(b == 0)
     def _prime():
-        # a dead page of a live block meets an exactly-0 probability:
-        # whatever the buffer holds there must be finite
-        buf[...] = jnp.zeros_like(buf)
         slot_ref[0] = 0
         start(0, 0, 0)
 
@@ -797,9 +930,8 @@ def _mla_kernel(bt_ref, len_ref, layer_ref, q_ref, pool_hbm, o_ref, buf,
     # that starts the next row's copies; its output is 0
     n_blocks = jnp.maximum((live_pages(b) + ppb - 1) // ppb, 1)
     acc[...] = jnp.zeros_like(acc)
-    m_sc[...] = jnp.full_like(m_sc, fill)
+    m_sc[...] = jnp.full_like(m_sc, jnp.finfo(jnp.float32).min)
     l_sc[...] = jnp.zeros_like(l_sc)
-    q = q_ref[...]                                # (R, W)
 
     def block(i, slot):
         nxt = 1 - slot
@@ -813,26 +945,16 @@ def _mla_kernel(bt_ref, len_ref, layer_ref, q_ref, pool_hbm, o_ref, buf,
             start(b + 1, 0, nxt)
 
         wait(b, i, slot)
-        page = buf[slot].reshape(tokens, buf.shape[-1])       # (T, W)
-        s = jax.lax.dot_general(
-            q, page, (((1,), (1,)), ((), ())),
-            precision=_precision(page.dtype),
-            preferred_element_type=jnp.float32) * scale       # (R, T)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * tokens
-        s = jnp.where(cols < ctx, s, fill)
-        m_prev = m_sc[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # a masked column: exp(finfo.min - finite) is exactly 0
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[...] = jnp.broadcast_to(
-            alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_sc.shape)
-        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
-        acc[...] = acc[...] * alpha + jax.lax.dot_general(
-            p.astype(page.dtype), page[:, :rank], (((1,), (0,)), ((), ())),
-            precision=_precision(page.dtype),
-            preferred_element_type=jnp.float32)               # (R, rank)
+        page = buf.at[slot]
+
+        @pl.when((i + 1) * tokens <= ctx)
+        def _whole():
+            _mla_attend(q_ref, page, acc, m_sc, l_sc, scale)
+
+        @pl.when((i * tokens < ctx) & (ctx < (i + 1) * tokens))
+        def _ragged():
+            _mla_attend(q_ref, page, acc, m_sc, l_sc, scale,
+                        live=ctx - i * tokens)
         return nxt
 
     slot_ref[0] = jax.lax.fori_loop(0, n_blocks, block, slot_ref[0])
@@ -854,38 +976,52 @@ def paged_mla_decode(q_c, q_r, pool, block_tables, ctx_lens, scale,
     an index the copies take); block_tables int32 ``[B, n_pages]``;
     ctx_lens int32 ``[B]`` (the token just appended included).
     ``softmax((q_c c^T + q_r r^T) * scale) c`` in float32 -> ``[B, H,
-    rank]`` in q_c's dtype. ONE body for every context: it streams the
-    row's live pages in compute blocks (:func:`mla_pages_per_block`),
-    each page read once for scores and values."""
+    rank]`` in q_c's dtype. ONE body for every context and every table:
+    it streams the row's live pages in compute blocks
+    (:func:`mla_pages_per_block`), each page read once for scores and
+    values, and fetches what the table holds as runs of consecutive
+    pages a run at a time (:func:`mla_pages_per_copy`)."""
     if interpret is None:
         interpret = interpret_default()
+    ppb, ppc = _mla_plan(block_tables.shape[1], pool.shape[2],
+                         pool.shape[3], pool.dtype)
+    if pool.shape[1] < ppc:
+        # a pool of fewer blocks than a copy group holds no run of one
+        # (an engine of a test's size): every page a copy of its own
+        ppc = 1
     return _mla_decode(
         q_c, q_r, pool, jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(ctx_lens, jnp.int32), jnp.asarray(int(layer), jnp.int32),
-        scale=float(scale), interpret=interpret,
-        ppb=mla_pages_per_block(block_tables.shape[1], pool.shape[2],
-                                pool.shape[3], pool.dtype))
+        scale=float(scale), interpret=interpret, ppb=ppb, ppc=ppc)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb"))
-def _mla_decode(q_c, q_r, pool, bt, ln, layer, *, scale, interpret, ppb):
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "ppb", "ppc"))
+def _mla_decode(q_c, q_r, pool, bt, ln, layer, *, scale, interpret, ppb,
+                ppc):
     """The call, jitted with the layer a traced scalar: a decode
     program's layers are ONE traced and lowered kernel."""
     B, H, rank = q_c.shape
     bs, width = pool.shape[2], pool.shape[3]
+    n_pages = bt.shape[1]
     rows = _tile_pad(H, 8)
     q = jnp.concatenate([q_c, q_r], -1).astype(pool.dtype)
     q = jnp.pad(q, ((0, 0), (0, rows - H), (0, width - q.shape[-1])))
+    # the table in whole compute blocks (the pad is never live), and
+    # which of its groups are runs: decided from the table alone
+    bt = jnp.pad(bt, ((0, 0), (0, _tile_pad(n_pages, ppb) - n_pages)))
+    runs = _page_runs(bt, ppc).astype(jnp.int32)
 
     def tile(lanes):
         return pl.BlockSpec((None, rows, lanes),
-                            lambda b, bt, ln, layer: (b, 0, 0))
+                            lambda b, bt, ln, runs, layer: (b, 0, 0))
 
     out = pl.pallas_call(
         functools.partial(_mla_kernel, scale=scale, block_size=bs,
-                          pages_per_block=ppb, n_pages=bt.shape[1], batch=B),
+                          pages_per_block=ppb, pages_per_copy=ppc,
+                          n_pages=n_pages, batch=B),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B,),
             # the pool stays where it is; the layer is an index the
             # copies take, so no program slices a layer out
@@ -905,7 +1041,7 @@ def _mla_decode(q_c, q_r, pool, bt, ln, layer, *, scale, interpret, ppb):
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_mla_decode",
-    )(bt, ln, layer.reshape(1), q, pool)
+    )(bt, ln, runs, layer.reshape(1), q, pool)
     return out[:, :H]
 
 

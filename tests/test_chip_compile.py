@@ -302,9 +302,21 @@ def test_paged_mla_decode_cell_shape(one_chip):
     """What `dsv2-serve-doc5k-backlog` runs: 128 rows, 128 query heads
     against the one latent head of 512 + 64 lanes in rows of 640, 384
     pages (6,144 positions) of a 49,152-block pool, layer 4 of 5: the
-    streaming body in compute blocks of 48 pages, no copy of the pool."""
+    streaming body in compute blocks of 96 pages (1.9 MiB a half of
+    the double buffer), a run of consecutive pages fetched 16 pages
+    (320 KB) a copy, no copy of the pool. What the body asks of the
+    scoped VMEM — the double buffer, the float32 accumulator and
+    statistics, the query and output tiles twice (the pipeline's two
+    buffers) — stays under a third of the compiler's limit, which
+    leaves the [128, 1536] score and probability tiles and the
+    compiler's own temporaries their room (PR 34 met that limit three
+    times on the chip, never in an interpreted test)."""
     assert pa.mla_row_width(512, 64) == 640
-    assert pa.mla_pages_per_block(384, 16, 640, BF16) == 48
+    assert pa.mla_pages_per_block(384, 16, 640, BF16) == 96
+    assert pa.mla_pages_per_copy(384, 16, 640, BF16) == 16
+    asked = (2 * 96 * 16 * 640 * 2 + 128 * 512 * 4 + 2 * 128 * 128 * 4
+             + 2 * 128 * (640 + 512) * 2)
+    assert asked <= pa.VMEM_BYTES // 3
     avals = (((128, 128, 512), BF16), ((128, 128, 64), BF16),
              ((5, 49152, 16, 640), BF16),
              ((128, 384), jnp.int32), ((128,), jnp.int32))

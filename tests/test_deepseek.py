@@ -544,11 +544,12 @@ def test_paged_mla_decode_against_dense_softmax(monkeypatch, ctx):
     """The streaming body (interpreted) against one dense softmax over
     the gathered latents, at contexts on both sides of a compute
     block's edge (blocks of 16 pages of 8: 128 tokens; 3 blocks)."""
-    monkeypatch.setattr(pa, "_BLOCK_TARGET_BYTES", 1)
+    monkeypatch.setattr(pa, "_MLA_BLOCK_BYTES", 1)
     rng = np.random.default_rng(0)
     L, N, bs, rank, dr, H, B, P = 2, 160, 8, 32, 8, 4, 3, 48
     W = pa.mla_row_width(rank, dr)
     assert pa.mla_pages_per_block(P, bs, W, jnp.float32) == 16
+    assert pa.mla_pages_per_copy(P, bs, W, jnp.float32) == 16
     pool = np.zeros((L, N, bs, W), np.float32)
     pool[..., :rank + dr] = rng.normal(size=(L, N, bs, rank + dr))
     pool = jnp.asarray(pool)

@@ -2,8 +2,10 @@
 beside ``test_prefill_ahead_spans.py``: ``decode.dispatch`` and the
 read-back ``prefill`` span carry, beside the routing counts every routed
 family writes, ``moe_rows_routed_here`` and ``moe_rows``; ``ctx_tokens``,
-``live_pages`` and ``kernel_pages_per_block`` are of LATENT pages — and
-the benchmark's new readers (``moe_rows_here_pct.serve``, and the counts
+``live_pages`` and ``kernel_pages_per_block`` are of LATENT pages,
+``coalesced_pages`` those of them the kernel fetches a run of consecutive
+pages at a time — and the benchmark's readers (``moe_rows_here_pct.serve``,
+``mla_pages_coalesced_pct.serve``, and the counts
 ``paged_mla_roofline_pct.serve`` / ``flash_mla_prefill_roofline_pct.serve``
 take) read them off the engine's own spans."""
 
@@ -77,6 +79,31 @@ def test_the_page_counts_are_of_latent_pages(traced):
     assert first["kernel_pages_per_block"] == pa.mla_pages_per_block(
         4, 8, width, engine.cache.dtype)
     assert first["blocks_total"] == 64
+    # a copy group is 16 pages of 8 slots here, no row holds 16: every
+    # page of these steps arrives a page a copy
+    assert pa.mla_pages_per_copy(4, 8, width, engine.cache.dtype) == 16
+    assert all(c["coalesced_pages"] == 0 for c in steps)
+
+
+def test_coalesced_pages_of_a_table_by_hand(traced):
+    """The count the engine asks of the family (``_step_counts`` ->
+    runner -> family) for a step's tables: the pages of the aligned
+    groups of entries — 48 at this table's shapes — that are all live
+    and hold consecutive ascending page ids."""
+    engine, _ = traced
+    shape = (96, 8, engine.runner.family.kv_widths[0], engine.cache.dtype)
+    assert pa.mla_pages_per_copy(*shape) == 48
+    tables = np.zeros((4, 96), np.int32)
+    tables[0] = np.arange(5, 101)            # one run, 60 pages live
+    tables[1] = np.arange(300, 204, -1)      # a run that descends
+    tables[2, :48] = np.arange(120, 168)     # a run, then scattered ids
+    tables[2, 48:] = np.arange(400, 496, 2)
+    live = [60, 96, 96]                      # row 3 is batch padding
+    counts = engine._step_counts(3, tables, 0, live, [])
+    assert counts["live_pages"] == 252
+    assert counts["coalesced_pages"] == 48 + 0 + 48
+    assert counts["kernel_pages_per_block"] == pa.mla_pages_per_block(*shape)
+    assert (counts["row_bucket"], counts["page_bucket"]) == (4, 96)
 
 
 @pytest.fixture()
@@ -115,6 +142,20 @@ def test_the_new_readers_read_the_engines_own_spans(readers, traced):
         / sum(c["moe_rows"] for c in counted)
     got = readers.reader("moe_rows_here_pct.serve").read(readers.ctx)
     assert got == pytest.approx(want) and 0 < got < 100
+    # the coalesced share: 0 of these steps' live pages (the test above),
+    # the steps' own ratio once a span says otherwise, nothing where no
+    # span carries the count (a program from before it)
+    coalesced = readers.reader("mla_pages_coalesced_pct.serve")
+    assert coalesced.read(readers.ctx) == 0.0
+    dispatch = [s for s in readers.pt.spans if s[0] == "decode.dispatch"
+                and "coalesced_pages" in s[3]]
+    dispatch[0][3]["coalesced_pages"] = dispatch[0][3]["live_pages"]
+    assert coalesced.read(readers.ctx) == pytest.approx(
+        100.0 * dispatch[0][3]["live_pages"]
+        / sum(s[3]["live_pages"] for s in dispatch))
+    for s in dispatch:
+        del s[3]["coalesced_pages"]
+    assert coalesced.read(readers.ctx) is None
     # what the two roofline readers take off the spans is there: the
     # steps' contexts and rows, the admissions' padded lengths
     steps = [c for _, _, _, c in readers.program_trace.spans_named(

@@ -10,6 +10,20 @@ import paddle2_tpu.optimizer as opt
 from paddle2_tpu.incubate import MoELayer, SwitchGate, TopKGate
 
 
+@pytest.fixture(autouse=True)
+def _no_mesh_left_by_another_file():
+    """A layer here is single-device unless its test installs a mesh:
+    one that an earlier file of the same worker left installed shards
+    the experts (``MoELayer._expert_axis``) and re-places their weights,
+    and the optimizer step then meets two device sets (seen once in six
+    workers' file order, PR 36)."""
+    from paddle2_tpu.distributed import mesh as mesh_mod
+    prev = mesh_mod.get_mesh(auto_init=False)
+    mesh_mod.set_mesh(None)
+    yield
+    mesh_mod.set_mesh(prev)
+
+
 def _experts(n, d, h):
     return [nn.Sequential(nn.Linear(d, h), nn.GELU(), nn.Linear(h, d))
             for _ in range(n)]
