@@ -41,11 +41,16 @@ import jax.numpy as jnp
 from ..framework import random as fr
 from ..framework.tensor import Tensor
 from ..observability import metrics as _metrics
-from ..profiler import build as _build_span, span as _span
+from ..profiler import (build as _build_span, launch as _launch,
+                        launched as _launched, span as _span)
 from .functional import (_collect_state, _guard_key, _rebound_call,
                          _split_tensors, _trace_lock)
 
 __all__ = ["train_step", "TrainStepProgram"]
+
+# the HLO module of the fused step ("jit_" + ``step_fn.__name__``): the
+# key of its launch ordinals (``profiler.launch``)
+STEP_MODULE = "jit_p2t_train_step"
 
 
 class TrainStepProgram:
@@ -154,7 +159,10 @@ class TrainStepProgram:
         step_span.set_metadata(built=int(built_now))
 
         pl = _metrics._ACTIVE
-        with _span("train.dispatch"):
+        # `launch`: the ordinal of the execution enqueued here, which
+        # joins this span to the device's module event of that name
+        with _span("train.dispatch", program=STEP_MODULE,
+                   launch=_launched(STEP_MODULE)):
             if pl is not None:
                 pl.phase_enter("compute")
             try:
@@ -162,6 +170,7 @@ class TrainStepProgram:
                     out = self._first_call(entry, call_args)
                 else:
                     out = entry(*call_args)
+                _launch(STEP_MODULE)
             finally:
                 if pl is not None:
                     pl.phase_exit()

@@ -15,7 +15,10 @@ name``. Whatever profiler session is active (``Profiler`` here, a bare
 device ops' clock; with none a span costs what an inactive TraceMe
 costs, so call sites are unconditional. :func:`build` is the span
 around the first use of a newly made ``jax.jit`` entry and also feeds
-the always-on :func:`builds` log.
+the always-on :func:`builds` log. :func:`launch` counts, per HLO module
+name, the executions the host has enqueued: the span that enqueues one
+carries its ordinal, which is what joins a host span to the device's
+"XLA Modules" events of that name (FIFO a program).
 """
 
 from __future__ import annotations
@@ -34,7 +37,7 @@ import jax
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
            "SortedKeys", "SummaryView", "benchmark", "merge_traces",
-           "span", "build", "builds"]
+           "span", "build", "builds", "launch", "launched"]
 
 SPAN_PREFIX = "p2t:"
 
@@ -48,6 +51,31 @@ def span(name: str, **counts):
     or per-token loop, never a device read to compute a count, never
     inside a jitted function."""
     return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **counts)
+
+
+# -- launch ordinals --------------------------------------------------------
+# Executions enqueued so far in this process, by the HLO module's name
+# (``jit_p2t_decode``). Advanced WHERE the jitted entry is called, after
+# the call returned (an entry that raised enqueued nothing); a plain int
+# in a dict under the GIL, always on.
+_launched: Dict[str, int] = {}
+
+
+def launch(program: str) -> int:
+    """One more execution of the jitted entry whose HLO module is named
+    ``program`` has been enqueued; returns its ordinal (0 for the
+    process's first). The device runs a program's executions in the
+    order of their ordinals."""
+    n = _launched.get(program, 0)
+    _launched[program] = n + 1
+    return n
+
+
+def launched(program: str) -> int:
+    """Executions of ``program`` enqueued so far: the ordinal the next
+    one will get. A span that enqueues reads it before (``launch``) and
+    after (``launches`` = the difference)."""
+    return _launched.get(program, 0)
 
 
 # -- the build log ---------------------------------------------------------

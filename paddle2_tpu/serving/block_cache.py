@@ -72,6 +72,9 @@ GARBAGE_BLOCK = 0
 
 # jitted prefill-scatter programs, keyed by array signature
 _PREFILL_SCATTER_CACHE: Dict = {}
+# the HLO module of ``scatter_prefill``'s jitted entry ("jit_" + the
+# function's name): the key of its launch ordinals (``profiler.launch``)
+SCATTER_MODULE = "jit_p2t_kv_scatter_prefill"
 
 
 def blocks_for_tokens(n_tokens: int, block_size: int) -> int:
@@ -588,9 +591,12 @@ class PagedKVCache:
         slot = jnp.asarray(idx % block_size, jnp.int32)
         key = (tuple(pool.shape), str(pool.dtype),
                tuple(layer_kv.shape), start, int(n_tokens), bool(by_layer))
+        from ..profiler import build, launch
         fn = _PREFILL_SCATTER_CACHE.get(key)
         if fn is not None:
-            return fn(pool, layer_kv, phys, slot)
+            out = fn(pool, layer_kv, phys, slot)
+            launch(SCATTER_MODULE)
+            return out
         n = int(n_tokens)
 
         # the function's name is the HLO module's in a device trace
@@ -607,10 +613,11 @@ class PagedKVCache:
         if len(_PREFILL_SCATTER_CACHE) > 1024:
             _PREFILL_SCATTER_CACHE.clear()
         _PREFILL_SCATTER_CACHE[key] = fn
-        from ..profiler import build
         with build("kv_scatter_prefill",
                    f"{layer_kv.shape[1]}:{start}:{n}"):
-            return fn(pool, layer_kv, phys, slot)
+            out = fn(pool, layer_kv, phys, slot)
+        launch(SCATTER_MODULE)
+        return out
 
     @staticmethod
     def copy_block(pool, src: int, dst: int):

@@ -40,11 +40,13 @@ from typing import Dict, List, Optional, Sequence as Seq, Tuple
 
 import numpy as np
 
-from ..profiler import span as _span
+from ..profiler import launched as _launched, span as _span
 from .block_cache import (BlockAllocator, HostKVTier, PagedKVCache,
-                          PrefixCache, blocks_for_tokens, GARBAGE_BLOCK)
+                          PrefixCache, blocks_for_tokens, GARBAGE_BLOCK,
+                          SCATTER_MODULE)
 from .blockdiff import STRATEGIES, BlockInFlight
-from .model_runner import PagedRunner, served_classes
+from .model_runner import (DECODE_MODULE, PREFILL_MODULE, PagedRunner,
+                           served_classes)
 from .reliability import (EngineFailedError, PromptTooLongError,
                           ReliabilityConfig, RequestRejected,
                           flight_record as _flight_record)
@@ -96,15 +98,17 @@ class _Step:
     device until the step is read back. ``kept[i]`` falls when sequence
     i is evicted or requeued while the step is in flight: its token is
     then dropped, not delivered. A row carries ``family.row_positions``
-    positions: one, or a block-diffusion family's block."""
+    positions: one, or a block-diffusion family's block. ``launch`` is
+    the ordinal of the execution that computes it (``profiler.launch``),
+    known once it is enqueued."""
 
     __slots__ = ("now", "active", "drafts", "bucket", "arrays", "counts",
-                 "out", "kept", "_rows", "_places")
+                 "out", "launch", "kept", "_rows", "_places")
 
     def __init__(self, now, active, drafts, bucket, arrays, counts):
         self.now, self.active, self.drafts = now, active, drafts
         self.bucket, self.arrays, self.counts = bucket, arrays, counts
-        self.out = None
+        self.out = self.launch = None
         self._rows, row = [], 0
         for s in active:
             self._rows.append(row)
@@ -688,10 +692,17 @@ class ServingEngine:
                                         if self._block else {})):
             out = None
             if n:
-                with _span("prefill.dispatch"):
+                # which execution this span enqueued: the ordinal the
+                # runner's call site is about to take, and how many it
+                # took (one), read off the counter on both sides
+                first = _launched(PREFILL_MODULE)
+                with _span("prefill.dispatch", program=PREFILL_MODULE,
+                           launch=first) as sp:
                     out, k_stack, v_stack, *state = \
                         self.runner.prefill_dispatch(
                             seq.tokens[:n] if left else seq.tokens)
+                    sp.set_metadata(
+                        launches=_launched(PREFILL_MODULE) - first)
                     if ahead and first_row is not None:
                         self.cache.keep_first(first_row, out)
             row = np.asarray(seq.table.blocks, np.int64)
@@ -702,7 +713,9 @@ class ServingEngine:
             # the tail's hidden states need the prefix context, and
             # the first generated token comes from the last position.
             start = min(seq.prefix_cached_tokens, n)
-            with _span("prefill.scatter"):
+            first = _launched(SCATTER_MODULE)
+            with _span("prefill.scatter", program=SCATTER_MODULE,
+                       launch=first) as sp:
                 if n:
                     # (a latent cache: one pool, written a layer at a time)
                     self.cache.k = PagedKVCache.scatter_prefill(
@@ -719,6 +732,9 @@ class ServingEngine:
                         # positions
                         self.cache.write_state(seq.table.state_slot,
                                                state[0])
+                # a pool each (the latent cache has one); none where a
+                # prefix hit covers the whole prompt
+                sp.set_metadata(launches=_launched(SCATTER_MODULE) - first)
         seq.table.num_tokens = n
         cost = self.runner.prefill_cost(padded)
         info = {"seq": seq, "prompt_tokens": n, "padded_len": padded,
@@ -775,7 +791,6 @@ class ServingEngine:
             self._firsts.append(_First(seq, n, out, first_row))
         if ahead:
             self.prefill_ahead += 1
-            metrics.inc("serving_prefill_ahead_total")
         else:
             self._deliver_firsts()
         return info
@@ -1136,10 +1151,6 @@ class ServingEngine:
                                              live_pages),
             blocks_in_use=self.allocator.used_count,
             blocks_total=self.config.num_blocks, evicted=len(victims))
-        if self.cache.state is not None:
-            counts.update(
-                state_slots_in_use=self.allocator.state_slots_used,
-                state_slots_total=self.allocator.state_slots)
         return counts
 
     def _build_block_step(self, now: float, active: List[Sequence],
@@ -1226,7 +1237,11 @@ class ServingEngine:
         # runner.decode is the one call that enqueues (H2D and the
         # program); decode.readback, in which the host waits for the
         # step BEFORE it, nests here; the routing counts that arrive
-        # with it describe the step read
+        # with it describe the step read: `read_launch` names that
+        # execution, `launch` the one enqueued here
+        if step is not None:
+            step.launch = _launched(DECODE_MODULE)
+            step.counts.update(program=DECODE_MODULE, launch=step.launch)
         with metrics.phase("compute"), \
                 _span("decode.dispatch", **(step.counts if step else {}),
                       ahead=int(step is not None and ahead is not None),
@@ -1242,6 +1257,7 @@ class ServingEngine:
                     toks, counts, chosen = self.runner.split_counts(
                         due.out,
                         due.bucket[0] * self.runner.family.row_positions)
+                sp.set_metadata(read_launch=due.launch)
                 if counts:
                     sp.set_metadata(**self._count_stats(counts))
                 if self._block:
@@ -1369,23 +1385,18 @@ class ServingEngine:
         """What ``decode.dispatch`` says of a block family's step as it
         is read back: positions its denoise passes fixed and tokens its
         commits bring (of the rows that kept their place)."""
-        from ..observability import metrics
         B = self._block
-        fixed = committed = commits = passes = 0
+        fixed = committed = 0
         for i, s in enumerate(step.active):
             if not step.kept[i]:
                 continue
             st = s.block
-            passes += 1
             if st.done < len(st.plan):
                 fixed += st.plan[st.done]
             else:
-                commits += 1
                 committed += min(
                     st.start + B - len(s.tokens),
                     s.request.max_new_tokens - len(s.generated))
-        metrics.inc("serving_block_passes_total", passes)
-        metrics.inc("serving_block_commits_total", commits)
         return {"tokens_fixed": fixed, "tokens_committed": committed}
 
     def _emit_block_row(self, seq: Sequence, row, chosen, done_at) -> int:

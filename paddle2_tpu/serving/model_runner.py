@@ -53,11 +53,17 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..profiler import build as _build_span, span as _span
+from ..profiler import build as _build_span, launch as _launch
 from .paged_attention import paged_attention_decode
 
 __all__ = ["ModelFamily", "GPTFamily", "PagedRunner", "PagedGPTRunner",
            "served_classes", "PREFILL_PAD"]
+
+# the HLO modules of the two jitted entries ("jit_" + the function's
+# name): what a device trace calls their executions, and the key their
+# launch ordinals are counted under (``profiler.launch``)
+PREFILL_MODULE = "jit_p2t_prefill"
+DECODE_MODULE = "jit_p2t_decode"
 
 # prefill programs are compiled per padded length; 16-token rounding
 # bounds their count at max_model_len/16 without wasting much compute
@@ -450,12 +456,15 @@ class PagedRunner:
                 jnp.asarray(n - 1, jnp.int32))
         fn = self._prefill_programs.get(padded)
         if fn is not None:
-            return fn(*args)
-        fn = self._prefill_programs[padded] = self._build_prefill(padded)
-        with _build_span("prefill", str(padded)) as b:
             out = fn(*args)
-            with b.cost():
-                self._prefill_costs[padded] = self._cost_of(fn, args)
+        else:
+            fn = self._prefill_programs[padded] = \
+                self._build_prefill(padded)
+            with _build_span("prefill", str(padded)) as b:
+                out = fn(*args)
+                with b.cost():
+                    self._prefill_costs[padded] = self._cost_of(fn, args)
+        _launch(PREFILL_MODULE)
         return out
 
     def prefill(self, token_ids: List[int]):
@@ -605,6 +614,7 @@ class PagedRunner:
                 out = fn(*args)
                 with b.cost():
                     self._decode_costs[key] = program_cost(fn, shapes)
+        _launch(DECODE_MODULE)
         if self.family.block_length is not None:
             tok, cache.block_ids, cache.block_masked, cache.k, cache.v = out
         else:

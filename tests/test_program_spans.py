@@ -16,6 +16,7 @@ import pytest
 import paddle2_tpu as paddle
 from paddle2_tpu import profiler
 from paddle2_tpu.models.gpt import GPTForCausalLM, gpt_tiny
+from paddle2_tpu.serving import block_cache
 from paddle2_tpu.serving.engine import EngineConfig, ServingEngine
 
 TRAIN_SPANS = ["train.step", "train.prepare", "train.dispatch",
@@ -99,6 +100,10 @@ def read_spans(trace_dir):
 def traced(tmp_path_factory):
     trace_dir = str(tmp_path_factory.mktemp("p2t_trace"))
     profiler._builds.clear()
+    # the scatter programs are cached per process: a test file that ran
+    # first in this worker with the same engine would leave nothing to
+    # build here, and the build log's test below counts builds
+    block_cache._PREFILL_SCATTER_CACHE.clear()
     jax.profiler.start_trace(trace_dir)
     try:
         losses, tokens, first = run_both()
@@ -165,7 +170,7 @@ def test_counts_equal_the_engines_own_info(traced):
     assert set(first) == {"rows", "row_bucket", "page_bucket", "ctx_tokens",
                           "live_pages", "kernel_pages_per_block",
                           "blocks_in_use", "blocks_total", "evicted",
-                          "ahead", "dropped_ahead"}
+                          "ahead", "dropped_ahead", "program", "launch"}
     steps = [s[3]["built"] for s in traced["spans"] if s[0] == "train.step"]
     assert steps == [1, 0]
 
