@@ -368,11 +368,12 @@ def flash_parity(size: dict) -> dict:
     allows at the training cell's shape (B 8, S 1024, 16 heads of 64,
     causal: four q tiles, one fused backward call) and at the
     block-diffusion prefill's (S 2048, 32 heads of 128, causal by
-    blocks of 4)."""
+    blocks of 4); then the head-major entry against the other one."""
     import jax
     import jax.numpy as jnp
     from paddle2_tpu.kernels.attention import _sdpa_xla
-    from paddle2_tpu.kernels.pallas_flash import flash_attention_bshd
+    from paddle2_tpu.kernels.pallas_flash import (flash_attention_bhsd,
+                                                  flash_attention_bshd)
     big = size["hidden"] >= 1024
     cases = {"train": ((8, 1024, 16, 64) if big else (1, 256, 2, 64), 1),
              "blockdiff": ((1, 2048, 32, 128) if big else (1, 256, 2, 128),
@@ -405,6 +406,26 @@ def flash_parity(size: dict) -> dict:
                 raise AssertionError(
                     f"flash kernel ({case}, {name}) off the XLA path "
                     f"by {gap}")
+    # the latent-attention prefill's HEAD-MAJOR entry at the serving
+    # cell's three prompt buckets (128 heads, 192 query / key lanes
+    # against 128 value lanes, forward only): the same kernel on the
+    # same operands as the (batch, seq, heads, dim) entry, so the same
+    # values to the bit
+    heads, seqs = (128, (2048, 3072, 5120)) if big else (2, (256,))
+    for seq in seqs:
+        q, k = (jnp.asarray(rng.normal(size=(1, heads, seq, 192)),
+                            jnp.bfloat16) for _ in range(2))
+        v = jnp.asarray(rng.normal(size=(1, heads, seq, 128)), jnp.bfloat16)
+        out = flash_attention_bhsd(q, k, v, causal=True, scale=0.1147)
+        ref = jnp.swapaxes(flash_attention_bshd(
+            *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=True,
+            scale=0.1147), 1, 2)
+        gap = err[f"flash.mla_head_major.{seq}"] = float(jnp.abs(
+            out.astype(jnp.float32) - ref.astype(jnp.float32)).max())
+        if out.shape != v.shape or not gap == 0.0:
+            raise AssertionError(
+                f"head-major flash entry (S {seq}) off the (batch, seq, "
+                f"heads, dim) entry by {gap}")
     return err
 
 

@@ -199,3 +199,30 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                          dropout_p=dropout_p if drop_key is not None else 0.0,
                          dropout_key=drop_key, causal_block=causal_block)
     return apply_op("sdpa", fn, tuple(tensors), {})
+
+
+def _sdpa_xla_bhsd(q, k, v, causal=False, scale=None, causal_block=1):
+    """:func:`_sdpa_xla` on (batch, heads, seq, dim) arrays (the swaps
+    cancel against its own in the compiled program)."""
+    return jnp.swapaxes(_sdpa_xla(
+        *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
+        scale=scale, causal_block=causal_block), 1, 2)
+
+
+def attention_bhsd(q, k, v, causal=False, scale=None, causal_block=1):
+    """Attention over raw arrays in the flash kernels' own layout,
+    (batch, heads, seq, dim): for a model whose projections write
+    head-major and whose output projection contracts over (heads, dim)
+    (``models/deepseek.py``), so that nothing is transposed around the
+    kernel. Takes the Pallas kernel where
+    :func:`scaled_dot_product_attention` would (``use_pallas``) and the
+    XLA path elsewhere. One device's arrays: no mask, dropout, tape or
+    mesh. The value heads may be of another width than the query/key
+    heads."""
+    B, H, S, D = q.shape
+    if use_pallas((B, S, H, D)):
+        from .pallas_flash import flash_attention_bhsd as attend
+    else:
+        attend = _sdpa_xla_bhsd
+    return attend(q, k, v, causal=causal, scale=scale,
+                  causal_block=causal_block)

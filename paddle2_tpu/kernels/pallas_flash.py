@@ -36,17 +36,17 @@ TPU-native kernels that never write the score matrix to HBM:
   whole, later ones included, and of every earlier block — the mask a
   block-diffusion decoder prefills under). Forward and backward both.
 
-Layout contract matches the reference flash API: (batch, seq, heads, dim).
-Compute is f32 on the MXU regardless of input dtype (bf16 in, f32 softmax).
+Two entries over the one ``_flash``: ``flash_attention_bshd`` takes the
+reference flash API's (batch, seq, heads, dim) and swaps axes around the
+kernels; ``flash_attention_bhsd`` takes the kernels' own (batch, heads,
+seq, dim) as it comes. Compute is f32 on the MXU (bf16 in, f32 softmax).
 
 Every ``pallas_call`` has a ``name=``: a device trace shows the kernel
-under it, and the benchmark's readers find it by it. The compile cache
-sees this file too: a kernel's Mosaic payload holds this file's path and
-the line numbers of the kernel's body, and the payload is in JAX's
-compile-cache key. So any edit that moves lines here makes every
-program that holds a flash kernel miss the cache once — while a
-``jax.named_scope`` added anywhere else is debug info, is stripped from
-the key, and is NOT seen (PERF.md section 6, the empty-cache rule).
+under it, and the benchmark's readers find it by it. A kernel's Mosaic
+payload holds the file paths and line numbers of its body AND of its call
+sites, and is in JAX's compile-cache key: an edit that moves lines above
+a kernel here, or above the call in ``kernels/attention.py``, makes each
+program holding it miss the cache once (a ``jax.named_scope`` does not).
 """
 
 from __future__ import annotations
@@ -1032,4 +1032,37 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None,
                        jnp.swapaxes(v, 1, 2), *cfg), 1, 2)
         return flash_bshd
     fn = _cached_jit(("bshd",) + cfg, builder)
+    return fn(q, k, v)
+
+
+def flash_attention_bhsd(q, k, v, causal=False, scale=None,
+                         block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                         interpret=None, causal_block=1):
+    """Flash attention on (batch, heads, seq, dim) arrays: the kernels'
+    own layout, for a caller whose projections write head-major and
+    whose output projection contracts over (heads, dim) — nothing is
+    transposed on the way in or out. Everything else is
+    :func:`flash_attention_bshd`'s: the same kernels, backward, block
+    arguments, and the XLA path for the shapes it sends there."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    causal_block = int(causal_block)
+    if not supported(*((x.shape[0], x.shape[2], x.shape[1], x.shape[3])
+                       for x in (q, k)), block_q, block_k):
+        from .attention import _sdpa_xla_bhsd
+        return _sdpa_xla_bhsd(q, k, v, causal=causal, scale=scale,
+                              causal_block=causal_block)
+    if causal_block < 1 or causal_block & (causal_block - 1):
+        raise ValueError(
+            f"flash attention masks by blocks of a power of two, not "
+            f"{causal_block}")
+    if interpret is None:
+        interpret = interpret_default()
+    cfg = (float(scale), causal_block if causal else 0, int(block_q),
+           int(block_k), bool(interpret))
+    def builder():
+        def flash_bhsd(q, k, v):
+            return _flash(q, k, v, *cfg)
+        return flash_bhsd
+    fn = _cached_jit(("bhsd",) + cfg, builder)
     return fn(q, k, v)

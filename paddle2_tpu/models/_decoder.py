@@ -21,7 +21,7 @@ from ..ops.linalg import _mxu_precision
 
 __all__ = ["GroupedQueryAttention", "SwiGLU", "created_in", "linear", "mm",
            "pre_norm", "rms_head", "rope_tables", "rotate_half_rope",
-           "yarn_inv_freq", "yarn_mscale"]
+           "rotate_half_rope_mxu", "yarn_inv_freq", "yarn_mscale"]
 
 
 def rms_head(x, weight, eps):
@@ -168,3 +168,19 @@ class GroupedQueryAttention(nn.Layer):
     def project(self, a):
         """The heads' outputs ``[..., nh * hd]`` through ``W_o``."""
         return mm(a.astype(self.out_proj.weight._data.dtype), self.out_proj)
+
+
+def rotate_half_rope_mxu(x, cos, sin):
+    """:func:`rotate_half_rope` with ``rotate_half(x)`` taken as the
+    product ``x P``, ``P = [[0, I], [-I, 0]]``: exact (every output is
+    plus or minus one input), the same float32 arithmetic after it. For
+    an ``x`` whose lanes are the minor axis of a large activation: there
+    the compiler turns the two half slices and their concat into padded
+    float32 arrays of their own (a quarter of a 128-lane tile each),
+    while this contraction over the lanes reads ``x`` where it lies."""
+    half = x.shape[-1] // 2
+    eye, zero = np.eye(half), np.zeros((half, half))
+    turn = jnp.asarray(np.block([[zero, eye], [-eye, zero]]), x.dtype)
+    turned = jnp.dot(x, turn, precision=_mxu_precision(x),
+                     preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + turned * sin).astype(x.dtype)

@@ -18,7 +18,13 @@ nothing per head. Two associations of the same product:
   model's forward and the serving prefill): ``[k_nope | v]`` per head
   ``= c W_kvb``; ``k_h = [k_nope | RoPE(k_rope)]`` (the rotary part is
   one vector shared by all heads); flash attention with query/key width
-  ``qk_nope + qk_rope`` and value width ``v_head_dim``.
+  ``qk_nope + qk_rope`` and value width ``v_head_dim``. HEAD-MAJOR: the
+  projections write q, k and v as (batch, heads, seq, dim), the flash
+  kernels' own layout, taken by ``kernels.attention.attention_bhsd`` ->
+  ``pallas_flash.flash_attention_bhsd`` (every other family holds the
+  reference's (batch, seq, heads, dim) and goes through
+  ``scaled_dot_product_attention`` -> ``flash_attention_bshd``), and
+  ``W_o`` contracts over (heads, dim) of the kernel's output.
 * ABSORBED (:meth:`LatentAttention.absorb` / :meth:`unabsorb`, one new
   token against the cache: the serving decode): ``W_kvb`` is split per
   head into ``W_UK`` and ``W_UV``; ``q'_h = [q_nope W_UK^T |
@@ -53,8 +59,8 @@ from ..framework.tensor import Tensor
 from ..incubate.moe import DroplessExperts
 from ..ops.linalg import _mxu_precision
 from ._decoder import (SwiGLU, created_in, linear, mm, pre_norm, rms_head,
-                       rope_tables, rotate_half_rope, yarn_inv_freq,
-                       yarn_mscale)
+                       rope_tables, rotate_half_rope, rotate_half_rope_mxu,
+                       yarn_inv_freq, yarn_mscale)
 
 __all__ = ["DeepseekV2Config", "DeepseekV2ForCausalLM", "LatentAttention",
            "deepseek_v2_tiny"]
@@ -164,20 +170,28 @@ class LatentAttention(nn.Layer):
                                  "implemented")
             self.scale *= yarn_mscale(ys["factor"], ys["mscale_all_dim"]) ** 2
 
+    def _tables(self, positions):
+        """cos, sin ``[T, dr]`` f32 at ``positions [T]``."""
+        return rope_tables(positions, self.dr, self.theta, self.inv_freq)
+
     def _rope(self, x, positions):
         """x ``[T, ..., dr]`` rotated at ``positions [T]``."""
-        cos, sin = rope_tables(positions, self.dr, self.theta, self.inv_freq)
+        cos, sin = self._tables(positions)
         lead = (slice(None),) + (None,) * (x.ndim - 2)
         return rotate_half_rope(x, cos[lead], sin[lead])
 
+    def _c_q(self, u):
+        """u ``[..., H]`` -> the queries' normed low-rank vector ``[...,
+        q_lora_rank]``."""
+        return rms_head(mm(u, self.q_a_proj),
+                        self.q_a_layernorm.weight._data, self.eps)
+
     def queries(self, u, positions):
         """u ``[T, H]`` -> (q_nope ``[T, nh, dn]``, RoPE(q_rope) ``[T,
-        nh, dr]``)."""
+        nh, dr]``), token-major: the decode step's rows."""
         with jax.named_scope("q_lora"):
-            c_q = rms_head(mm(u, self.q_a_proj),
-                           self.q_a_layernorm.weight._data, self.eps)
-            q = mm(c_q, self.q_b_proj).reshape(-1, self.nh,
-                                               self.dn + self.dr)
+            q = mm(self._c_q(u), self.q_b_proj).reshape(
+                -1, self.nh, self.dn + self.dr)
             return q[..., :self.dn], self._rope(q[..., self.dn:], positions)
 
     def latent(self, u, positions):
@@ -189,33 +203,58 @@ class LatentAttention(nn.Layer):
                          self.kv_a_layernorm.weight._data, self.eps)
             return c, self._rope(kv[:, self.rank:], positions)
 
+    def _w_qb(self):
+        return self.q_b_proj.weight._data.reshape(
+            -1, self.nh, self.dn + self.dr)
+
     def _w_kvb(self):
         return self.kv_b_proj.weight._data.reshape(
             self.rank, self.nh, self.dn + self.dv)
 
     def full(self, u):
         """Causal attention over a whole sequence, EXPANDED: u ``[B, S,
-        H]`` -> (Op, c ``[B, S, rank]``, RoPE(k_rope) ``[B, S, dr]``)."""
-        from ..kernels.attention import scaled_dot_product_attention
+        H]`` -> (Op, c ``[B, S, rank]``, RoPE(k_rope) ``[B, S, dr]``).
+        HEAD-MAJOR from the projections to ``W_o``: q, k and v are
+        written ``[B, nh, S, d]``, the layout the flash kernel reads, by
+        the matmuls that compute them (q whole from ``W_qb``; k from
+        ``W_UK`` with ``dr`` zero lanes behind it), the rope turns q's
+        ``dr`` rope lanes and the one shared ``RoPE(k_rope)`` fills k's
+        where they lie, and the kernel's output meets ``W_o`` viewed
+        ``[nh, dv, H]`` as the kernel wrote it: no activation is
+        transposed, and none of ``nh x (dn + dr)`` lanes a token is
+        sliced or joined."""
+        from ..kernels.attention import attention_bhsd
         B, S, H = u.shape
-        pos = jnp.tile(jnp.arange(S), B)
-        flat = u.reshape(B * S, H)
-        q_nope, q_rope = self.queries(flat, pos)
-        c, k_rope = self.latent(flat, pos)
+        dn, pos = self.dn, jnp.arange(S)
+        c, k_rope = self.latent(u.reshape(B * S, H), jnp.tile(pos, B))
+        c, k_rope = c.reshape(B, S, -1), k_rope.reshape(B, S, -1)
+
+        def heads(x, w):
+            return jnp.einsum("bsr,rhd->bhsd", x, w,
+                              precision=_mxu_precision(x, w))
+
+        def rope_lanes(x, lanes):
+            return jax.lax.dynamic_update_slice_in_dim(x, lanes, dn, -1)
+
+        with jax.named_scope("q_lora"):
+            q = heads(self._c_q(u), self._w_qb())
+        with jax.named_scope("rope"):
+            q = rope_lanes(q, rotate_half_rope_mxu(q[..., dn:],
+                                                   *self._tables(pos)))
         with jax.named_scope("expand"):
-            kv = mm(c, self.kv_b_proj).reshape(B, S, self.nh,
-                                               self.dn + self.dv)
-            k = jnp.concatenate([
-                kv[..., :self.dn],
-                jnp.broadcast_to(k_rope.reshape(B, S, 1, self.dr),
-                                 (B, S, self.nh, self.dr))], -1)
-            q = jnp.concatenate([q_nope, q_rope], -1).reshape(
-                B, S, self.nh, -1)
-        a = scaled_dot_product_attention(
-            Tensor(q), Tensor(k), Tensor(kv[..., self.dn:]), is_causal=True,
-            scale=self.scale)._data
-        return (self.project(a.reshape(B, S, -1)), c.reshape(B, S, -1),
-                k_rope.reshape(B, S, -1))
+            w = self._w_kvb()
+            k = heads(c, jnp.pad(w[..., :dn], ((0, 0), (0, 0), (0, self.dr))))
+            k = rope_lanes(k, jnp.broadcast_to(
+                k_rope[:, None], (B, self.nh, S, self.dr)))
+            v = heads(c, w[..., dn:])
+        a = attention_bhsd(q, k, v, causal=True, scale=self.scale)
+        with jax.named_scope("out"):
+            w = self.o_proj.weight._data
+            a = a.astype(w.dtype)
+            op = jnp.einsum("bhsv,hvo->bso", a,
+                            w.reshape(self.nh, self.dv, -1),
+                            precision=_mxu_precision(a, w))
+        return op, c, k_rope
 
     def absorb(self, q_nope):
         """``q_nope W_UK^T``: ``[T, nh, dn] -> [T, nh, rank]``, the query
@@ -235,8 +274,10 @@ class LatentAttention(nn.Layer):
             return a.reshape(o.shape[0], -1)
 
     def project(self, a):
-        """The heads' outputs ``[..., nh * dv]`` through ``W_o``."""
-        return mm(a.astype(self.o_proj.weight._data.dtype), self.o_proj)
+        """The heads' outputs ``[..., nh * dv]``, token-major, through
+        ``W_o``."""
+        with jax.named_scope("out"):
+            return mm(a.astype(self.o_proj.weight._data.dtype), self.o_proj)
 
 
 class DeepseekV2MoE(nn.Layer):

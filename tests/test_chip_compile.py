@@ -16,6 +16,7 @@ touched at import, in a ``skipif``/``parametrize`` argument or in
 """
 
 import functools
+import math
 import re
 
 import jax
@@ -328,19 +329,76 @@ def test_paged_mla_decode_cell_shape(one_chip):
                 if " copy(" in ln and "[5,49152,16," in ln]
 
 
+@pytest.mark.parametrize("entry", ["bshd", "bhsd"])
 @pytest.mark.parametrize("seq,grid", [(2048, False), (3072, False),
                                       (5120, True)])
-def test_flash_mla_prefill_shapes(one_chip, seq, grid):
-    """The latent-attention prefill's expanded attention: [1, S, 128,
-    192] queries and keys against [1, S, 128, 128] values, bf16, causal,
-    forward only; the two shorter prompt buckets take the walk, the
-    5,120-token one the grid kernel."""
+def test_flash_mla_prefill_shapes(one_chip, seq, grid, entry):
+    """The latent-attention prefill's expanded attention: 128 heads of
+    192 query / key lanes against 128 value lanes over S positions,
+    bf16, causal, forward only, through both entries — (batch, seq,
+    heads, dim) and the head-major one the model takes; the two shorter
+    prompt buckets take the walk, the 5,120-token one the grid kernel."""
     assert (pallas_flash._walks(seq, seq, 192, BF16, 1, 1024, 1024, False,
                                 128) is None) == grid
-    qk, v = ((1, seq, 128, 192), BF16), ((1, seq, 128, 128), BF16)
-    fn = functools.partial(pallas_flash.flash_attention_bshd, causal=True,
-                           scale=0.1147, interpret=False)
+    dims = (1, seq, 128) if entry == "bshd" else (1, 128, seq)
+    qk, v = (dims + (192,), BF16), (dims + (128,), BF16)
+    fn = functools.partial(getattr(pallas_flash, f"flash_attention_{entry}"),
+                           causal=True, scale=0.1147, interpret=False)
     _compile(one_chip, fn, qk, qk, v, kernels=["flash_fwd"])
+
+
+def test_mla_prefill_attention_moves_no_activation(one_chip, monkeypatch):
+    """One latent-attention layer of the published widths over a
+    3,072-token prompt, compiled as the prefill program holds it: of the
+    arrays of S x 128 heads x 128 lanes or more, each is written by a
+    matmul (q, k, v, the output projection), the flash kernel, the rope
+    on q's rope lanes or the fill of k's — no copy, no transpose, no
+    slice or join of a q-, k-, v- or o-sized array in between (the
+    token-major program this replaced fails here on four copies and a
+    slice a layer)."""
+    from paddle2_tpu.kernels import attention
+    from paddle2_tpu.models.deepseek import DeepseekV2Config, LatentAttention
+    monkeypatch.setattr(attention, "use_pallas", lambda shape: True)
+    monkeypatch.setattr(pallas_flash, "interpret_default", lambda: False)
+    monkeypatch.setattr(pallas_flash, "_JIT_CACHE", {})
+    S = 3072
+    layer = LatentAttention(DeepseekV2Config(dtype="bfloat16"))
+    params = list(layer.parameters())
+
+    def prefill_attention(weights, u):
+        kept = [p._data for p in params]
+        for p, w in zip(params, weights):
+            p._data = w
+        try:
+            with jax.named_scope("attn"):
+                return u + layer.full(u)[0]
+        finally:
+            for p, w in zip(params, kept):
+                p._data = w
+
+    avals = [jax.ShapeDtypeStruct(p._data.shape, p._data.dtype,
+                                  sharding=one_chip) for p in params]
+    u = jax.ShapeDtypeStruct((1, S, 5120), BF16, sharding=one_chip)
+    text = jax.jit(prefill_attention).lower(avals, u).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    big = []
+    for ln in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \(?bf16\[([\d,]+)\]\S* "
+                     r"([\w-]+)\(", ln)
+        if m and m.group(3) not in ("parameter", "bitcast",
+                                    "get-tuple-element"):
+            dims = [int(d) for d in m.group(2).split(",")]
+            if S in dims and math.prod(dims) >= S * 128 * 128:
+                path = re.search(r'op_name="([^"]*)"', ln)
+                big.append((m.group(1), m.group(3),
+                            path.group(1) if path else ""))
+    assert not [b for b in big if b[1] in ("copy", "transpose", "slice",
+                                           "concatenate")], big
+    assert all(op == "custom-call" and "flash_fwd" in path
+               or op == "fusion" and re.search(
+                   r"/(q_lora|rope|expand|out)/", path)
+               for _, op, path in big), big
+    assert len(big) <= 8, big
 
 
 @pytest.mark.parametrize("seq", [1024, 2048])

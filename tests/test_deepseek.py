@@ -13,7 +13,9 @@ online softmax per compute block against one row), so logits of scale
 magnitude of room and is two orders under what a bf16-for-f32
 substitution gives (``test_tolerance_rejects_bf16``)."""
 
+import functools
 import json
+import math
 import os
 import sys
 
@@ -26,7 +28,7 @@ import paddle2_tpu as paddle
 from paddle2_tpu import inference
 from paddle2_tpu.incubate.moe import (DroplessExperts,
                                       softmax_group_limited_route)
-from paddle2_tpu.kernels import pallas_flash
+from paddle2_tpu.kernels import attention, pallas_flash
 from paddle2_tpu.kernels.attention import _sdpa_xla
 from paddle2_tpu.models import (DeepseekV2Config, DeepseekV2ForCausalLM,
                                 deepseek_v2_tiny)
@@ -249,6 +251,137 @@ def test_absorbed_attention_equals_expanded():
     o = jnp.einsum("ths,sr->thr", jax.nn.softmax(s, -1), c[0])
     got = attn.project(attn.unabsorb(o))
     np.testing.assert_allclose(got[0], want[0, -1], atol=2e-6)
+
+
+def expanded_token_major(attn, u, attend=None):
+    """The expanded association in the reference's own layout, as the
+    program computed it until PR 38: ``[T, nh, dn + dr]`` queries sliced
+    at the rope lanes and joined again, keys joined from ``W_kvb``'s
+    product and the broadcast ``RoPE(k_rope)``, (batch, seq, heads, dim)
+    attention, a token-major ``W_o``. Plain XLA unless ``attend`` is
+    given."""
+    B, S, H = u.shape
+    pos = jnp.tile(jnp.arange(S), B)
+    flat = u.reshape(B * S, H)
+    q_nope, q_rope = attn.queries(flat, pos)
+    c, k_rope = attn.latent(flat, pos)
+    kv = (c @ attn.kv_b_proj.weight._data).reshape(B, S, attn.nh, -1)
+    k = jnp.concatenate([
+        kv[..., :attn.dn],
+        jnp.broadcast_to(k_rope.reshape(B, S, 1, -1),
+                         (B, S, attn.nh, attn.dr))], -1)
+    q = jnp.concatenate([q_nope, q_rope], -1).reshape(B, S, attn.nh, -1)
+    a = (attend or _sdpa_xla)(q, k, kv[..., attn.dn:], causal=True,
+                              scale=attn.scale)
+    return attn.project(a.reshape(B, S, -1))
+
+
+def force_flash(monkeypatch, body):
+    """``attention_bhsd`` / ``scaled_dot_product_attention`` take the
+    Pallas kernel (interpreted here) at any length; ``body``: the walk
+    or the grid forward."""
+    monkeypatch.setattr(attention, "use_pallas", lambda shape: True)
+    monkeypatch.setattr(pallas_flash, "_JIT_CACHE", {})
+    if body == "grid":
+        monkeypatch.setattr(pallas_flash, "WALK_VMEM_BYTES", 0)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+                                       ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("path", ["xla", "walk", "grid"])
+def test_head_major_expanded_attention(monkeypatch, path, dtype, tol):
+    """``LatentAttention.full`` (head-major from the projections to
+    ``W_o``, through ``attention_bhsd``) against the token-major form it
+    replaced and, for the last position, the ABSORBED association over
+    the latents it returns — on the XLA path and through both
+    interpreted flash forward bodies."""
+    S = 19 if path == "xla" else 1024
+    if path != "xla":
+        force_flash(monkeypatch, path)
+        ran = []
+        flash = pallas_flash._flash
+        monkeypatch.setattr(pallas_flash, "_flash", lambda *a: (
+            ran.append(pallas_flash._walks(
+                S, S, 24, a[0].dtype, 1, a[5], a[6], False, 16) is not None),
+            flash(*a))[1])
+    model = DeepseekV2ForCausalLM(deepseek_v2_tiny(dtype=dtype))
+    attn = model.model.layers[1].self_attn
+    u = jnp.asarray(np.random.default_rng(0).normal(size=(1, S, 64)), dtype)
+    got, c, k_rope = attn.full(u)
+    assert got.dtype == u.dtype and got.shape == u.shape
+    if path != "xla":
+        assert ran == [path == "walk"]
+    f32 = lambda x: np.asarray(x, np.float32)
+    np.testing.assert_allclose(f32(got), f32(expanded_token_major(attn, u)),
+                               atol=tol)
+    pos = jnp.asarray([S - 1])
+    q_nope, q_rope = attn.queries(u[0, -1:], pos)
+    s = (jnp.einsum("thr,sr->ths", attn.absorb(q_nope), c[0])
+         + jnp.einsum("thd,sd->ths", q_rope, k_rope[0])) * attn.scale
+    o = jnp.einsum("ths,sr->thr", jax.nn.softmax(s.astype(jnp.float32), -1),
+                   c[0].astype(jnp.float32)).astype(u.dtype)
+    np.testing.assert_allclose(f32(attn.project(attn.unabsorb(o))[0]),
+                               f32(got[0, -1]), atol=tol)
+
+
+def big_moves(fn, *args, elements, split):
+    """The mechanism's counter, on the jaxpr of ``fn``: (transposes of
+    arrays of at least ``elements`` elements — but for a ``dot_general``'s
+    own result, whose order is the matmul's to write —, slices / joins /
+    updates of an array whose last two axes are ``split`` = (heads, lanes a
+    head), the operand shapes of the flash kernel's calls)."""
+    turned, cut, calls = [], [], []
+
+    def visit(jaxpr):
+        made_by = {v: e.primitive.name for e in jaxpr.eqns
+                   for v in e.outvars}
+        for e in jaxpr.eqns:
+            name = e.primitive.name
+            shapes = [tuple(v.aval.shape) for v in (*e.invars, *e.outvars)
+                      if hasattr(v.aval, "shape")]
+            if name == "pallas_call":
+                calls.append(shapes[:3])
+                continue
+            if name == "transpose" and math.prod(shapes[0]) >= elements \
+                    and made_by.get(e.invars[0]) != "dot_general":
+                turned.append(shapes[0])
+            if name in ("slice", "dynamic_slice", "concatenate", "gather",
+                        "scatter", "dynamic_update_slice") \
+                    and any(s[-2:] == split for s in shapes):
+                cut.append((name, shapes))
+            for sub in jax.core.jaxprs_in_params(e.params):
+                visit(sub)
+
+    visit(jax.make_jaxpr(fn)(*args).jaxpr)
+    return turned, cut, calls
+
+
+def test_head_major_prefill_moves_no_activation(monkeypatch):
+    """With the flash kernel forced, the expanded attention's program
+    holds no transpose of an ``S x nh x dv`` array (the three head-major
+    products aside: ``tests/test_chip_compile.py`` holds the compiled
+    program to writing those in place) and no slice or join of a
+    ``[.., nh, dn + dr]`` activation, and the kernel is handed ``[B, nh,
+    S, d]`` operands; the token-major form trips both counts."""
+    force_flash(monkeypatch, "walk")
+    model = DeepseekV2ForCausalLM(deepseek_v2_tiny())
+    attn = model.model.layers[1].self_attn
+    S, nh, d = 1024, attn.nh, attn.dn + attn.dr
+    u = jnp.zeros((1, S, 64), jnp.float32)
+    count = functools.partial(big_moves, elements=S * nh * attn.dv,
+                              split=(nh, d))
+    turned, cut, calls = count(attn.full, u)
+    assert (turned, cut) == ([], [])
+    assert calls == [[(1, nh, S, d), (1, nh, S, d), (1, nh, S, attn.dv)]]
+
+    def attend(q, k, v, **kw):
+        return attention.scaled_dot_product_attention(
+            paddle.Tensor(q), paddle.Tensor(k), paddle.Tensor(v),
+            is_causal=kw["causal"], scale=kw["scale"])._data
+
+    turned, cut, calls = count(
+        lambda u: expanded_token_major(attn, u, attend), u)
+    assert len(turned) == 4 and len(cut) >= 4 and len(calls) == 1
 
 
 # ----------------------------------------------------- the serving plane
@@ -608,6 +741,83 @@ def test_flash_forward_value_width_differs(monkeypatch, walk):
         got, _sdpa_xla(q, k, v, causal=True, scale=0.3), atol=2e-6)
     with pytest.raises(NotImplementedError, match="forward only"):
         jax.grad(lambda q: flash(q, k, v).sum())(q)
+
+
+def swapped(x):
+    return jnp.swapaxes(x, 1, 2)
+
+
+@pytest.mark.parametrize("walk", [True, False])
+def test_head_major_flash_entry_is_the_bshd_one_on_swapped_operands(
+        monkeypatch, walk):
+    """``flash_attention_bhsd`` on (batch, heads, seq, dim) operands:
+    the very values ``flash_attention_bshd`` gives on the swapped ones,
+    value width != query width, both forward bodies; a mask block that
+    is no power of two is refused as there."""
+    if not walk:
+        monkeypatch.setattr(pallas_flash, "WALK_VMEM_BYTES", 0)
+    monkeypatch.setattr(pallas_flash, "_JIT_CACHE", {})
+    rng = np.random.default_rng(4)
+    q, k = (jnp.asarray(rng.normal(size=(2, 3, 512, 24)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(2, 3, 512, 16)), jnp.float32)
+    for block in (1, 4):
+        kw = dict(causal=True, scale=0.3, causal_block=block,
+                  interpret=True)
+        got = pallas_flash.flash_attention_bhsd(q, k, v, **kw)
+        assert got.shape == (2, 3, 512, 16)
+        np.testing.assert_array_equal(got, swapped(
+            pallas_flash.flash_attention_bshd(*map(swapped, (q, k, v)),
+                                              **kw)))
+    assert sorted(key[0] for key in pallas_flash._JIT_CACHE) == \
+        ["bhsd", "bhsd", "bshd", "bshd"]
+    with pytest.raises(ValueError, match="power of two"):
+        pallas_flash.flash_attention_bhsd(q, k, v, causal=True,
+                                          causal_block=3, interpret=True)
+
+
+@pytest.mark.parametrize("case", ["unsupported", "not_on_tpu", "on_tpu"])
+def test_head_major_entry_takes_the_xla_path_where_bshd_does(monkeypatch,
+                                                              case):
+    """The two places attention leaves the kernel: a length no 8-row
+    tile divides (``supported()`` false, inside the flash entry) and a
+    host that is no TPU (``use_pallas``, in ``attention_bhsd`` as in
+    ``scaled_dot_product_attention``); there the head-major entry is the
+    XLA path on swapped operands, and on a TPU it is the kernel."""
+    rng = np.random.default_rng(5)
+    S = 1001 if case == "unsupported" else 1024
+    q, k = (jnp.asarray(rng.normal(size=(1, 2, S, 24)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(1, 2, S, 16)), jnp.float32)
+    want = swapped(_sdpa_xla(*map(swapped, (q, k, v)), causal=True,
+                             scale=0.3))
+    monkeypatch.setattr(pallas_flash, "_JIT_CACHE", {})
+    calls = []
+    flash = pallas_flash._flash
+    monkeypatch.setattr(pallas_flash, "_flash",
+                        lambda *a: (calls.append(a[3:]), flash(*a))[1])
+    bshd_shape = (1, S, 2, 24)
+    if case == "unsupported":
+        assert not pallas_flash.supported(bshd_shape, bshd_shape)
+        got = pallas_flash.flash_attention_bhsd(q, k, v, causal=True,
+                                                scale=0.3, interpret=True)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(
+            swapped(got), pallas_flash.flash_attention_bshd(
+                *map(swapped, (q, k, v)), causal=True, scale=0.3,
+                interpret=True))
+    else:
+        assert not attention.use_pallas(bshd_shape)      # this host
+        if case == "on_tpu":
+            monkeypatch.setattr(attention, "on_tpu", lambda: True)
+            assert attention.use_pallas(bshd_shape)
+            assert not attention.use_pallas((1, 1023, 2, 24))
+        got = attention.attention_bhsd(q, k, v, causal=True, scale=0.3)
+        if case == "on_tpu":
+            np.testing.assert_allclose(got, want, atol=2e-6)
+        else:
+            np.testing.assert_array_equal(got, want)
+    assert len(calls) == (case == "on_tpu")
 
 
 def test_walk_bytes_count_the_value_width():
