@@ -1,13 +1,16 @@
 """What the RMSNorm / rotary decoder families share (``models/lfm2.py``,
-``models/sdar.py``, ``models/deepseek.py``): bias-free projections
-created in the model's dtype, the pre-norm through the repo's kernel,
-rotary tables in the rotate-half layout (plain or YaRN-scaled
-frequencies), the SwiGLU feed-forward, and grouped-query attention with
-a per-head RMS norm of q and k. Written once, on arrays; inference only
-(no autograd tape)."""
+``models/sdar.py``, ``models/deepseek.py``, ``models/falcon_h1.py``):
+bias-free projections created in the model's dtype, the pre-norm
+through the repo's kernel, rotary tables in the rotate-half layout
+(plain or YaRN-scaled frequencies), the SwiGLU feed-forward (with a
+model's gate and down multipliers, where it has them), and grouped-query
+attention with or without a per-head RMS norm of q and k and with a
+scale on the keys. Written once, on arrays; inference only (no autograd
+tape)."""
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -19,7 +22,8 @@ from ..framework.tensor import Tensor
 from ..kernels.pallas_fused import fused_rms_norm, fused_rope
 from ..ops.linalg import _mxu_precision
 
-__all__ = ["GroupedQueryAttention", "SwiGLU", "created_in", "linear", "mm",
+__all__ = ["GroupedQueryAttention", "NormalDraw", "SwiGLU", "created_in",
+           "linear", "mm",
            "pre_norm", "rms_head", "rope_tables", "rotate_half_rope",
            "rotate_half_rope_mxu", "yarn_inv_freq", "yarn_mscale"]
 
@@ -88,13 +92,43 @@ def mm(x, linear_layer):
 
 
 def created_in(param, dtype):
-    """A parameter a stock layer made in the default dtype, in ``dtype``."""
+    """A parameter a stock layer made in the default dtype, in ``dtype``.
+    The host waits for the cast: the float32 draft is freed only when it
+    has run, and a model's drafts enqueued ahead of the device stand
+    beside each other (3.5 GB of them after the last layer of an 8.79
+    GB model on a warm compile cache: my chip run, PR 39)."""
     if dtype is not None and str(param._data.dtype) != dtype:
         param._replace_data(param._data.astype(dtype))
+        param._data.block_until_ready()
 
 
-def linear(d_in, d_out, std, dtype):
-    attr = nn.ParamAttr(initializer=nn.initializer.Normal(0.0, std))
+class NormalDraw(nn.initializer.Initializer):
+    """``nn.initializer.Normal`` for a table that fills a chip: N(mean,
+    std) drawn in ONE compiled call. The stock initializer is three
+    eager operations (the draw, ``std *``, ``mean +``) whose results
+    stand beside each other when the host enqueues them ahead of the
+    device: three float32 copies of a 261,120 x 5,120 table are 16.05
+    GB, a 16 GB chip's whole memory (my chip runs, PR 39). The compiled
+    form rounds differently in float32, so the families that were there
+    keep the stock one."""
+
+    def __init__(self, mean: float = 0.0, std: float = 1.0):
+        self.mean, self.std = float(mean), float(std)
+
+    def __call__(self, shape, dtype=None):
+        from ..framework import core, random as fr
+        dtype = core.convert_dtype(dtype) or core.get_default_dtype()
+        return _normal_draw(fr.next_key(), tuple(shape), dtype, self.mean,
+                            self.std)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _normal_draw(key, shape, dtype, mean, std):
+    return mean + std * jax.random.normal(key, shape, dtype)
+
+
+def linear(d_in, d_out, std, dtype, initializer=nn.initializer.Normal):
+    attr = nn.ParamAttr(initializer=initializer(0.0, std))
     layer = nn.Linear(d_in, d_out, weight_attr=attr, bias_attr=False)
     created_in(layer.weight, dtype)
     return layer
@@ -107,36 +141,49 @@ def pre_norm(norm, x, eps):
 
 
 class SwiGLU(nn.Layer):
-    """``(silu(a W1) * (a W3)) W2``, the product in float32."""
+    """``(silu((a W1) g) * (a W3)) W2 d``, the product in float32;
+    ``g`` / ``d`` are the gate and down multipliers of a model that
+    scales its activations (both 1 elsewhere: nothing is multiplied)."""
 
-    def __init__(self, hidden: int, width: int, std: float, dtype=None):
+    def __init__(self, hidden: int, width: int, std: float, dtype=None,
+                 gate_multiplier: float = 1.0, down_multiplier: float = 1.0):
         super().__init__()
+        self.gate_multiplier = float(gate_multiplier)
+        self.down_multiplier = float(down_multiplier)
         self.w1 = linear(hidden, width, std, dtype)
         self.w3 = linear(hidden, width, std, dtype)
         self.w2 = linear(width, hidden, std, dtype)
 
     def run(self, a):
-        up = jax.nn.silu(mm(a, self.w1).astype(jnp.float32))
-        return mm((up * mm(a, self.w3).astype(jnp.float32))
-                  .astype(a.dtype), self.w2)
+        gate = mm(a, self.w1).astype(jnp.float32)
+        if self.gate_multiplier != 1.0:
+            gate = gate * self.gate_multiplier
+        up = jax.nn.silu(gate)
+        out = mm((up * mm(a, self.w3).astype(jnp.float32))
+                 .astype(a.dtype), self.w2)
+        return out if self.down_multiplier == 1.0 \
+            else out * jnp.asarray(self.down_multiplier, out.dtype)
 
 
 class GroupedQueryAttention(nn.Layer):
     """``num_heads`` query heads over ``num_kv_heads`` key/value heads of
-    ``head_dim``; q and k normed per head and rotated (rotate-half,
-    base ``theta``)."""
+    ``head_dim``; q and k normed per head (``qk_norm``; a model without
+    the norm has no such parameters) and rotated (rotate-half, base
+    ``theta``); ``key_scale`` multiplies the keys as projected."""
 
     def __init__(self, hidden: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, eps: float, theta: float, std: float,
-                 dtype=None):
+                 dtype=None, qk_norm: bool = True, key_scale: float = 1.0):
         super().__init__()
         self.head_dim, self.eps, self.theta = head_dim, eps, float(theta)
+        self.qk_norm, self.key_scale = bool(qk_norm), float(key_scale)
         self.q_proj = linear(hidden, num_heads * head_dim, std, dtype)
         self.k_proj = linear(hidden, num_kv_heads * head_dim, std, dtype)
         self.v_proj = linear(hidden, num_kv_heads * head_dim, std, dtype)
         self.out_proj = linear(num_heads * head_dim, hidden, std, dtype)
-        self.q_norm = nn.RMSNorm(head_dim, epsilon=eps)
-        self.k_norm = nn.RMSNorm(head_dim, epsilon=eps)
+        if self.qk_norm:
+            self.q_norm = nn.RMSNorm(head_dim, epsilon=eps)
+            self.k_norm = nn.RMSNorm(head_dim, epsilon=eps)
 
     def qkv(self, u, positions):
         """u ``[B, S, H]``, positions int ``[B, S]`` -> q ``[B, S, nh,
@@ -145,9 +192,12 @@ class GroupedQueryAttention(nn.Layer):
         hd = self.head_dim
         q = mm(u, self.q_proj).reshape(B, S, -1, hd)
         k = mm(u, self.k_proj).reshape(B, S, -1, hd)
+        if self.key_scale != 1.0:
+            k = k * jnp.asarray(self.key_scale, k.dtype)
         v = mm(u, self.v_proj).reshape(B, S, -1, hd)
-        q = rms_head(q, self.q_norm.weight._data, self.eps)
-        k = rms_head(k, self.k_norm.weight._data, self.eps)
+        if self.qk_norm:
+            q = rms_head(q, self.q_norm.weight._data, self.eps)
+            k = rms_head(k, self.k_norm.weight._data, self.eps)
         cos, sin = rope_tables(positions.reshape(-1), hd, self.theta)
         return fused_rope(q, cos, sin), fused_rope(k, cos, sin), v
 

@@ -434,12 +434,15 @@ class PagedKVCache:
     block holds (:attr:`block_bytes`, what the allocator's ledger and
     the pool's live share count) follow the row widths.
 
-    ``state_shape`` (with ``state_slots``) adds the second kind of
-    state: one pool ``[state layers, slots + 1, *per-layer shape]`` in
-    the cache's dtype for layers that keep a fixed-size recurrent state
-    per sequence (``state_shape = (layers, *per-layer shape)``; slot 0
-    is the padded rows' garbage slot). Slots are handed out by the
-    :class:`BlockAllocator`.
+    ``state_kinds`` (with ``state_slots``) adds the fixed-size state a
+    sequence keeps beside its blocks: an ordered mapping ``name ->
+    ((state layers, *per-layer shape), dtype)``, one pool ``[state
+    layers, slots + 1, *per-layer shape]`` a kind in :attr:`states`
+    (dtype ``None``: the cache's; slot 0 is the padded rows' garbage
+    slot). A model may keep several kinds of different shape and type in
+    one layer (a convolution's last inputs in the cache's dtype, a
+    recurrent state in float32): ONE slot id, handed out by the
+    :class:`BlockAllocator`, serves all of a sequence's kinds.
 
     ``tokens`` (``token_rows`` wide, the widest decode batch) is the
     last decode step's output tokens, kept on the device: the next step
@@ -461,7 +464,7 @@ class PagedKVCache:
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype="float32",
-                 state_shape=None, state_slots: int = 0,
+                 state_kinds=None, state_slots: int = 0,
                  token_rows: int = 1, block_length: Optional[int] = None,
                  kv_widths: Optional[Tuple[int, int]] = None):
         import jax
@@ -491,28 +494,31 @@ class PagedKVCache:
                                             device)
             self.block_masked = jax.device_put(jnp.zeros(shape, bool),
                                                device)
-        self.state = None
-        if state_shape is not None:
-            self.state = jnp.zeros(
-                (state_shape[0], int(state_slots) + 1)
-                + tuple(state_shape[1:]), self.dtype)
+        self.states = {
+            name: jnp.zeros((shape[0], int(state_slots) + 1)
+                            + tuple(shape[1:]), kind_dtype or self.dtype)
+            for name, (shape, kind_dtype) in (state_kinds or {}).items()}
 
     def write_state(self, slot: int, states) -> None:
-        """``state[:, slot] = states`` (one sequence's prefilled state,
-        ``[state layers, *per-layer shape]``): one jitted program with
-        the pool donated and the slot a runtime scalar."""
+        """``pool[:, slot] = state`` for every kind (one sequence's
+        prefilled states, in the kinds' order, each ``[state layers,
+        *per-layer shape]``): ONE jitted program with the pools donated
+        and the slot a runtime scalar."""
         import jax
         import jax.numpy as jnp
-        key = ("state", tuple(self.state.shape), str(self.state.dtype))
+        pools = tuple(self.states.values())
+        key = ("state",) + tuple((tuple(p.shape), str(p.dtype))
+                                 for p in pools)
         fn = _PREFILL_SCATTER_CACHE.get(key)
         if fn is None:
-            def p2t_state_write(pool, st, sl):
+            def p2t_state_write(pools, sts, sl):
                 with jax.named_scope("state_write"):
-                    return pool.at[:, sl].set(st.astype(pool.dtype))
+                    return tuple(pool.at[:, sl].set(st.astype(pool.dtype))
+                                 for pool, st in zip(pools, sts))
             fn = _PREFILL_SCATTER_CACHE[key] = jax.jit(
                 p2t_state_write, donate_argnums=(0,))
-        self.state = fn(self.state, states, jnp.asarray(int(slot),
-                                                        jnp.int32))
+        pools = fn(pools, tuple(states), jnp.asarray(int(slot), jnp.int32))
+        self.states = dict(zip(self.states, pools))
 
     def keep_first(self, row: int, out) -> None:
         """``firsts[row] = out[0]``: the first token of a prefill (the
@@ -537,6 +543,13 @@ class PagedKVCache:
 
     def bytes_for_blocks(self, n_blocks: int) -> int:
         return n_blocks * self.block_bytes
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes one sequence's slot holds across all state kinds and
+        their layers (0 for a model without such state)."""
+        return sum(p.size // p.shape[1] * p.dtype.itemsize
+                   for p in self.states.values())
 
     def contiguous_bytes(self, batch: int, max_seq_len: int) -> int:
         """What a contiguous per-request max-seq-len cache would
@@ -748,7 +761,7 @@ class HostKVTier:
 def audit_kv_ledger(allocator: BlockAllocator, live_block_lists,
                     prefix_cache: Optional["PrefixCache"] = None,
                     in_migration=(), host_tier: Optional[HostKVTier] = None,
-                    live_state_slots=()) -> Dict[str, int]:
+                    live_state_slots=(), state_pools=None) -> Dict[str, int]:
     """Cross-tier ownership audit (ISSUE 16): every usable block is
     owned EXACTLY once — on the free list, or referenced with a
     refcount equal to its claim multiplicity across the live tables,
@@ -758,7 +771,10 @@ def audit_kv_ledger(allocator: BlockAllocator, live_block_lists,
     by construction; the audit reports their count so the property
     test can close the whole ladder. The state slots close the same
     way: every slot 1..n is free or claimed by exactly one entry of
-    ``live_state_slots``. Raises :class:`BlockFreeError` on any
+    ``live_state_slots`` — ONE id for all of a sequence's state kinds,
+    so with ``state_pools`` (``PagedKVCache.states``) every kind's pool
+    must have exactly the allocator's slots (and the garbage slot).
+    Raises :class:`BlockFreeError` on any
     violation; returns the tier census when clean."""
     claims: Dict[int, int] = {}
     lists = [list(l) for l in live_block_lists]
@@ -804,11 +820,17 @@ def audit_kv_ledger(allocator: BlockAllocator, live_block_lists,
         raise BlockFreeError(
             f"state slots do not close: claimed {sorted(slots)}, free "
             f"{sorted(free_slots)}, of {allocator.state_slots}")
+    for name, pool in (state_pools or {}).items():
+        if pool.shape[1] != allocator.state_slots + 1:
+            raise BlockFreeError(
+                f"state kind {name!r} has {pool.shape[1] - 1} slots, the "
+                f"allocator hands out {allocator.state_slots}")
     return {"free": len(free), "claimed": len(claims),
             "host_tier": len(host_tier) if host_tier is not None else 0,
             "in_migration": len(list(in_migration)),
             "state_slots_free": len(free_slots),
-            "state_slots_claimed": len(slots)}
+            "state_slots_claimed": len(slots),
+            "state_kinds": len(state_pools or {})}
 
 
 class PrefixCache:

@@ -90,7 +90,7 @@ class DeepseekV2Family(ModelFamily):
         return (logits, stack, None, None,
                 jnp.stack(records) if records else None)
 
-    def decode(self, k_pool, v_pool, state_pool, ids, positions,
+    def decode(self, k_pool, v_pool, state_pools, ids, positions,
                block_tables, slots, block_size, interpret, split_pages):
         import jax
         import jax.numpy as jnp
@@ -121,5 +121,5 @@ class DeepseekV2Family(ModelFamily):
             x, record = layer.feed(x, valid, interpret)
             if record is not None:
                 records.append(record)
-        return (model.head(x), k_pool, v_pool, state_pool,
+        return (model.head(x), k_pool, v_pool, state_pools,
                 jnp.stack(records) if records else None)

@@ -91,6 +91,14 @@ class _First:
         return _place(self.seq) != self._at
 
 
+# most bytes of per-sequence state (``PagedKVCache.state_slot_bytes`` a
+# prefill) that admissions may leave in flight with their first tokens
+# un-read; past it the admission reads its token in place, which waits
+# for every prefill before it (16.9 MB a prefill at Falcon-H1's sizes:
+# 30 in flight; LFM2's 32 KB never reach it)
+STATE_AHEAD_BYTES = 512 << 20
+
+
 class _Step:
     """One decode step from its enqueueing to its delivery: who rode in
     it (``active``, in row order), what was sent (``arrays``, ``counts``
@@ -270,14 +278,15 @@ class ServingEngine:
             slots_per_step=family.row_positions)
         # two kinds of state, one manager: paged blocks for the layers
         # that keep keys and values, and (where the family has layers
-        # with a fixed-size state) one slot per running sequence; the
+        # with fixed-size states, of one kind or several) one slot per
+        # running sequence, the same id in every kind's pool; the
         # last step's tokens stay on the device as wide as the widest
         # decode batch
-        slots = self.config.max_batch if family.state_shape else 0
+        slots = self.config.max_batch if family.state_kinds else 0
         self.cache = PagedKVCache(
             family.attn_layers, self.config.num_blocks,
             self.config.block_size, family.num_kv_heads, family.head_dim,
-            dtype=self.config.kv_dtype, state_shape=family.state_shape,
+            dtype=self.config.kv_dtype, state_kinds=family.state_kinds,
             state_slots=slots, token_rows=sched_cfg.batch_buckets[-1],
             block_length=family.block_length, kv_widths=family.kv_widths)
         self.allocator = BlockAllocator(self.config.num_blocks,
@@ -313,6 +322,10 @@ class ServingEngine:
         self._ahead: Optional[_Step] = None
         self._firsts: List[_First] = []
         self._dropped_ahead = 0
+        # rows re-prefilled because a discarded step had moved their
+        # per-sequence state: since the last dispatch span, and in all
+        self._state_reprefills = 0
+        self.state_reprefills = 0
         # steps enqueued with the one before un-read, prefills left with
         # their first token un-read, and tokens in flight thrown away by
         # an eviction, a requeue or a failure
@@ -385,6 +398,12 @@ class ServingEngine:
         from ..jit.api import load as jit_load
         loaded = jit_load(artifact_path, params_path=params_path)
         model = served_classes(gpt_config)[0](gpt_config)
+        # the values the architecture was created with are about to be
+        # replaced: wait for them, or a loaded weight is allocated
+        # while the value it replaces still waits for its producer
+        # (twice the weights, where the host runs far enough ahead)
+        import jax
+        jax.block_until_ready([p._data for p in model.parameters()])
         state = loaded.state_dict()
         # the artifact's dtypes are the serving dtypes: a bf16
         # (amp O2) artifact is served in bf16, not widened back to the
@@ -685,11 +704,19 @@ class ServingEngine:
         seq.passes = [p for p in seq.passes if p[0] < n]
         padded = self.runner.prefill_padded_len(n) if n else 0
         first_row = len(self._firsts) if not self._block else None
+        # a prefill's outputs (its stacks, its per-sequence states) are
+        # allocated when it is ENQUEUED and live until their writes have
+        # run: admissions far ahead of the device (a warm-up of 128
+        # prompts on cached programs) would hold that many states
         ahead = n > 0 and not self._reads_back_first() and (
-            first_row is None or first_row < self.cache.firsts.shape[0])
+            first_row is None or (
+                first_row < self.cache.firsts.shape[0]
+                and (first_row + 1) * self.cache.state_slot_bytes
+                <= STATE_AHEAD_BYTES))
         with _span("prefill", req=seq.req_id, tokens=n, padded=padded,
                    ahead=int(ahead), **({"block_tokens": left}
-                                        if self._block else {})):
+                                        if self._block else {}),
+                   **family.prefill_counts(padded)):
             out = None
             if n:
                 # which execution this span enqueued: the ordinal the
@@ -731,7 +758,7 @@ class ServingEngine:
                         # too), so this is the state at its real last
                         # positions
                         self.cache.write_state(seq.table.state_slot,
-                                               state[0])
+                                               state)
                 # a pool each (the latent cache has one); none where a
                 # prefix hit covers the whole prompt
                 sp.set_metadata(launches=_launched(SCATTER_MODULE) - first)
@@ -1125,8 +1152,8 @@ class ServingEngine:
         positions = np.zeros((b_bucket,), np.int32)
         tables = np.full((b_bucket, p_bucket), GARBAGE_BLOCK, np.int32)
         # state slots of the rows (0: the padded rows' garbage slot)
-        slots = None if self.cache.state is None \
-            else np.zeros((b_bucket,), np.int32)
+        slots = np.zeros((b_bucket,), np.int32) if self.cache.states \
+            else None
         for i, (s, tok_in, pos) in enumerate(rows):
             ids[i, 0] = tok_in
             positions[i] = pos
@@ -1151,6 +1178,9 @@ class ServingEngine:
                                              live_pages),
             blocks_in_use=self.allocator.used_count,
             blocks_total=self.config.num_blocks, evicted=len(victims))
+        if self.cache.states:
+            # every real row reads and writes its slot of every kind
+            counts["state_bytes"] = 2 * rows * self.cache.state_slot_bytes
         return counts
 
     def _build_block_step(self, now: float, active: List[Sequence],
@@ -1234,6 +1264,7 @@ class ServingEngine:
                     "evictions": 0, "spec_accepted": 0, "spec_rejected": 0,
                     "dispatched": False, "cost": None}
         dropped, self._dropped_ahead = self._dropped_ahead, 0
+        moved, self._state_reprefills = self._state_reprefills, 0
         # runner.decode is the one call that enqueues (H2D and the
         # program); decode.readback, in which the host waits for the
         # step BEFORE it, nests here; the routing counts that arrive
@@ -1245,7 +1276,9 @@ class ServingEngine:
         with metrics.phase("compute"), \
                 _span("decode.dispatch", **(step.counts if step else {}),
                       ahead=int(step is not None and ahead is not None),
-                      dropped_ahead=dropped) as sp:
+                      dropped_ahead=dropped,
+                      **({"state_reprefills": moved}
+                         if self.cache.states else {})) as sp:
             if step is not None:
                 step.out = self.runner.decode(self.cache, *step.arrays)
                 if ahead is not None:
@@ -1299,11 +1332,15 @@ class ServingEngine:
                      if s.trace_id is not None]
         if chaos.maybe_drop_decode_step(self.engine_id):
             # transient step failure: the tokens are discarded and NO
-            # sequence state advances, so the next step recomputes the
-            # same positions (same inputs -> same tokens; the KV
-            # rewrite is idempotent; the drafts are a pure function of
-            # the unchanged token log) — retry costs one modeled step
+            # token log advances, so the next step recomputes the same
+            # positions (same inputs -> same tokens; the KV rewrite is
+            # idempotent; the drafts are a pure function of the
+            # unchanged token log) — retry costs one modeled step. A
+            # family's per-sequence STATE has moved with the discarded
+            # step, though: its rows are re-prefilled instead
             metrics.inc("serving_retries_total")
+            if self.cache.states:
+                self._reprefill_moved(step, now)
             _flight_record(event="decode_step_dropped",
                            engine=self.engine_id, t=now,
                            dur=modeled_s or 0.0,
@@ -1380,6 +1417,20 @@ class ServingEngine:
         metrics.step_end(tokens=emitted_total, **extra)
         return {"tokens": emitted_total, "spec_accepted": accepted_total,
                 "spec_rejected": rejected_total}
+
+    def _reprefill_moved(self, step: "_Step", now: float) -> None:
+        """The rows of a discarded step go back to the queue's front, as
+        after an eviction: their state slots hold the state AFTER the
+        step, which a repeat would read as the state before it (a
+        recurrence or a convolution window is not idempotent, a K/V
+        rewrite is). The re-prefill recomputes the tokens so far and
+        rewrites the state."""
+        moved = [s for s, kept in zip(step.active, step.kept)
+                 if kept and s.state is SeqState.RUNNING]
+        for s in reversed(moved):           # the oldest ends up in front
+            self.scheduler.requeue_moved(s, now=now)
+        self._state_reprefills += len(moved)
+        self.state_reprefills += len(moved)
 
     def _read_block_rows(self, step: "_Step", toks) -> Dict[str, int]:
         """What ``decode.dispatch`` says of a block family's step as it

@@ -7,8 +7,9 @@ one decode step, over TWO kinds of per-sequence state.
   those layers only, and the paged kernel reads query head ``h``
   against key/value head ``h // group``.
 * The convolution layers keep the last ``conv_L_cache - 1`` values of
-  ``z = B * X`` per sequence: one slot of the cache's state pool
-  ``[conv layers, slots + 1, taps - 1, hidden]``. Prefill returns each
+  ``z = B * X`` per sequence: one slot of the cache's ``conv`` pool
+  ``[conv layers, slots + 1, taps - 1, hidden]`` (the family's ONE state
+  kind, ``state_kinds``, in the cache's dtype). Prefill returns each
   conv layer's ``z`` at the REAL last positions (a runtime index, not
   the padded tail); a decode step gathers the batch's slots, steps the
   convolution, and scatters the shifted state back (pool donated).
@@ -43,8 +44,9 @@ class Lfm2MoeFamily(ModelFamily):
         self.num_kv_heads = cfg.num_key_value_heads
         self.head_dim = cfg.head_dim
         self.max_positions = cfg.max_position_embeddings
-        self.state_shape = (self.conv_layers, cfg.conv_L_cache - 1,
-                            cfg.hidden_size) if self.conv_layers else None
+        self.state_kinds = {"conv": (
+            (self.conv_layers, cfg.conv_L_cache - 1, cfg.hidden_size),
+            None)} if self.conv_layers else None
         self.routed = (sum(1 for l in layers if not l.is_dense),
                        cfg.num_experts_per_tok)
 
@@ -65,18 +67,19 @@ class Lfm2MoeFamily(ModelFamily):
         with jax.named_scope("state_write"):
             # z at positions last_idx - (taps - 2) .. last_idx; zeros
             # stand before the sequence, as in the convolution itself
-            state = jnp.stack([jax.lax.dynamic_slice_in_dim(
+            states = (jnp.stack([jax.lax.dynamic_slice_in_dim(
                 jnp.pad(z[0], ((taps - 1, 0), (0, 0))), last_idx + 1,
-                taps - 1, 0) for z in zs]) if zs else None
-        return (logits, k_stack, v_stack, state,
+                taps - 1, 0) for z in zs]),) if zs else None
+        return (logits, k_stack, v_stack, states,
                 jnp.stack(counts) if counts else None)
 
-    def decode(self, k_pool, v_pool, state_pool, ids, positions,
+    def decode(self, k_pool, v_pool, state_pools, ids, positions,
                block_tables, slots, block_size, interpret, split_pages):
         import jax
         import jax.numpy as jnp
         from .block_cache import PagedKVCache as _C
         trunk = self.model.model
+        state_pool = state_pools[0] if state_pools else None
         B = ids.shape[0]
         phys = jnp.take_along_axis(
             block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
@@ -115,5 +118,6 @@ class Lfm2MoeFamily(ModelFamily):
             x, c = layer.feed(x, valid, interpret)
             if c is not None:
                 counts.append(c)
-        return (trunk.head(x), k_pool, v_pool, state_pool,
+        return (trunk.head(x), k_pool, v_pool,
+                None if state_pool is None else (state_pool,),
                 jnp.stack(counts) if counts else None)

@@ -9,8 +9,8 @@ functions compiled with ``jax.jit``:
   param-swap pattern) — never baked in as constants;
 * the decode program is keyed by the scheduler's (batch, pages)
   bucket, so the program count is bounded by the bucket grid (the
-  bench gate), and DONATES the KV pools (and the state pool, where the
-  family keeps one) for in-place append;
+  bench gate), and DONATES the KV pools (and the state pools, where the
+  family keeps any) for in-place append;
 * prefill is keyed by the padded prompt length (rounded up to
   :data:`PREFILL_PAD`); causal masking makes the padded tail invisible
   to real rows, so padding is exact, and the real last position is a
@@ -33,8 +33,9 @@ functions compiled with ``jax.jit``:
 * builds run inside ``build`` spans and leave cost records.
 
 A family supplies its cache geometry (how many layers keep keys and
-values, how many key/value heads of what size, and the shape of any
-fixed-size per-sequence state) and ``prefill`` / ``decode``.
+values, how many key/value heads of what size, and the kinds of
+fixed-size per-sequence state with their shapes and types) and
+``prefill`` / ``decode``.
 :class:`GPTFamily` re-wires one GPT block step from the model's OWN
 sublayers (ln_1 -> fused qkv -> paged append -> paged attention ->
 out_proj -> mlp), mirroring ``GPTBlock.forward``'s head-major qkv
@@ -43,7 +44,8 @@ concat — exactly the contiguous layout paging replaces. Its prefill
 DOES go through ``decode_step`` (empty caches). The LFM2-MoE family is
 in ``lfm2_family.py``, the SDAR-MoE family in ``sdar_family.py``, the
 DeepSeek-V2 family (latent attention: one pool) in
-``deepseek_family.py``.
+``deepseek_family.py``, the Falcon-H1 family (a state-space mixer beside
+attention in every layer: two state kinds) in ``falcon_h1_family.py``.
 """
 
 from __future__ import annotations
@@ -75,28 +77,33 @@ class ModelFamily:
 
     Geometry: ``attn_layers`` (layers that keep keys and values — the
     pools' leading axis), ``num_heads`` / ``num_kv_heads`` /
-    ``head_dim``, ``max_positions``, and ``state_shape``: ``None``, or
-    ``(state layers, *per-layer shape)`` of a fixed-size state every
-    running sequence keeps beside its blocks (one slot of the cache's
-    state pool). ``kv_widths``: ``None``, or the pools' row widths where
-    they are not ``num_kv_heads * head_dim`` twice (``PagedKVCache``: a
-    latent cache has ONE pool, ``(row width, 0)``, and its ``v`` stack
-    and pool are ``None`` everywhere below). ``unsupported`` names
-    the :class:`EngineConfig`
-    features the family cannot serve yet (the engine refuses them at
-    construction).
+    ``head_dim``, ``max_positions``, and ``state_kinds``: ``None``, or
+    an ordered mapping ``name -> ((state layers, *per-layer shape),
+    dtype or None for the cache's)`` of the fixed-size states every
+    running sequence keeps beside its blocks (one slot, the same id in
+    every kind's pool: ``PagedKVCache.states``). ``kv_widths``:
+    ``None``, or the pools' row widths where they are not
+    ``num_kv_heads * head_dim`` twice (``PagedKVCache``: a latent cache
+    has ONE pool, ``(row width, 0)``, and its ``v`` stack and pool are
+    ``None`` everywhere below). ``unsupported`` names the
+    :class:`EngineConfig` features the family cannot serve yet (the
+    engine refuses them at construction).
 
     Steps, traced inside the runner's programs with the weights bound
     (``interpret`` / ``split_pages`` are the runner's kernel options):
 
     ``prefill(ids [1, P], last_idx, interpret)`` -> ``(logits [1, V] of
     the real last position, k_stack, v_stack [attn_layers, P, H_kv, D],
-    state [state layers, ...] or None, counts or None)``;
+    states — a tuple in the kinds' order, each [state layers, ...] at
+    the REAL last position — or None, counts or None)``;
 
-    ``decode(k_pool, v_pool, state_pool, ids [B, 1], positions [B],
+    ``decode(k_pool, v_pool, state_pools, ids [B, 1], positions [B],
     block_tables [B, pages], slots [B] or None, block_size, interpret,
-    split_pages)`` -> ``(logits [B, V], k_pool, v_pool, state_pool,
-    counts)``.
+    split_pages)`` -> ``(logits [B, V], k_pool, v_pool, state_pools,
+    counts)``, ``state_pools`` the tuple of the kinds' pools (None
+    without any). A decode step MOVES such state: a step whose tokens
+    are discarded cannot be repeated on it (the engine re-prefills its
+    rows, ``ServingEngine._reprefill_moved``).
 
     A family that generates by diffusion over blocks sets
     ``block_length`` (B) and ``mask_token_id``; its prefill returns
@@ -116,7 +123,7 @@ class ModelFamily:
     ``count_names`` names and, behind them, the experts chosen for each
     row (``DroplessExperts.route_and_run``'s record)."""
 
-    state_shape = None
+    state_kinds: Optional[Dict[str, tuple]] = None
     kv_widths: Optional[Tuple[int, int]] = None
     unsupported: Tuple[str, ...] = ()
     count_names: Tuple[str, ...] = ()
@@ -141,7 +148,12 @@ class ModelFamily:
     def prefill(self, ids, last_idx, interpret):
         raise NotImplementedError
 
-    def decode(self, k_pool, v_pool, state_pool, ids, positions,
+    def prefill_counts(self, padded: int) -> dict:
+        """What the admission's ``prefill`` span says of the family's
+        own work on a prompt padded to ``padded`` (nothing by default)."""
+        return {}
+
+    def decode(self, k_pool, v_pool, state_pools, ids, positions,
                block_tables, slots, block_size, interpret, split_pages):
         raise NotImplementedError
 
@@ -195,7 +207,7 @@ class GPTFamily(ModelFamily):
             v_stack = jnp.stack([c[1]._data[0] for c in caches])
         return logits, k_stack, v_stack, None, None
 
-    def decode(self, k_pool, v_pool, state_pool, ids, positions,
+    def decode(self, k_pool, v_pool, state_pools, ids, positions,
                block_tables, slots, block_size, interpret, split_pages):
         import jax
         import jax.numpy as jnp
@@ -243,7 +255,7 @@ class GPTFamily(ModelFamily):
             x = model.gpt.ln_f(x)
         with scope("head_ce"):
             logits = model._head(x)._data[:, -1]
-        return logits, k_pool, v_pool, state_pool, None
+        return logits, k_pool, v_pool, state_pools, None
 
 
 def served_classes(config) -> tuple:
@@ -252,17 +264,20 @@ def served_classes(config) -> tuple:
     rebuilds an artifact's architecture with the first and the runner
     reads the model through the second."""
     from ..models.deepseek import DeepseekV2Config, DeepseekV2ForCausalLM
+    from ..models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
     from ..models.gpt import GPTConfig, GPTForCausalLM
     from ..models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
     from ..models.sdar import SdarMoeConfig, SdarMoeForCausalLM
     from .deepseek_family import DeepseekV2Family
+    from .falcon_h1_family import FalconH1Family
     from .lfm2_family import Lfm2MoeFamily
     from .sdar_family import SdarMoeFamily
     for config_class, classes in (
             (GPTConfig, (GPTForCausalLM, GPTFamily)),
             (Lfm2MoeConfig, (Lfm2MoeForCausalLM, Lfm2MoeFamily)),
             (SdarMoeConfig, (SdarMoeForCausalLM, SdarMoeFamily)),
-            (DeepseekV2Config, (DeepseekV2ForCausalLM, DeepseekV2Family))):
+            (DeepseekV2Config, (DeepseekV2ForCausalLM, DeepseekV2Family)),
+            (FalconH1Config, (FalconH1ForCausalLM, FalconH1Family))):
         if isinstance(config, config_class):
             return classes
     raise TypeError(
@@ -434,7 +449,7 @@ class PagedRunner:
                 logits, k_stack, v_stack, state, counts = family.prefill(
                     ids, last_idx, self.interpret)
             out = (self._sample(logits, counts), k_stack, v_stack)
-            return out if state is None else out + (state,)
+            return out if state is None else out + tuple(state)
 
         return jax.jit(p2t_prefill)
 
@@ -444,7 +459,7 @@ class PagedRunner:
         use of the padded length). Returns (first token ``[1]`` still
         on the device — with the family's counts behind it, see
         :meth:`split_counts` —, k_stack, v_stack, and the family's
-        state where it keeps one) with stacks ``[attn layers,
+        states, one a kind, where it keeps any) with stacks ``[attn layers,
         padded_len, H_kv, D]`` — the caller scatters rows
         ``[:len(token_ids)]`` into blocks and reads the token back."""
         import jax.numpy as jnp
@@ -529,19 +544,21 @@ class PagedRunner:
             # ids [B,1] int32; positions [B] int32 (0-based slot of the
             # NEW token); block_tables [B,P] int32. Pools
             # [L, N, bs, H_kv*D], donated. A family with per-sequence
-            # state adds (state pool [Ls, slots+1, ...] donated, slots
-            # [B] int32). fed [R] int32: the tokens of the step before,
+            # state adds (a pool [Ls, slots+1, ...] a kind, donated,
+            # then slots [B] int32). fed [R] int32: the tokens of the
+            # step before,
             # firsts [R] int32: first tokens of prefills, neither read
             # by the host so far; an id below zero is a row of the two
             # end to end (-1 - row) and not a token.
-            state_pool, slots = state_args or (None, None)
+            state_pools, slots = (state_args[:-1], state_args[-1]) \
+                if state_args else (None, None)
             with jax.named_scope("embed"):
                 held = jnp.concatenate([fed, firsts])
                 taken = held[jnp.clip(-1 - ids, 0, held.shape[0] - 1)]
                 ids = jnp.where(ids < 0, taken, ids)
             with self.bound(weight_arrays):
-                logits, k_pool, v_pool, state_pool, counts = family.decode(
-                    k_pool, v_pool, state_pool, ids, positions,
+                logits, k_pool, v_pool, state_pools, counts = family.decode(
+                    k_pool, v_pool, state_pools, ids, positions,
                     block_tables, slots, block_size, self.interpret,
                     self.split_pages)
             tok = self._sample(logits, counts)
@@ -550,9 +567,10 @@ class PagedRunner:
                 # can follow any other without a new signature
                 fed = jnp.pad(tok[:batch], (0, fed.shape[0] - batch))
             out = (tok, fed, k_pool, v_pool)
-            return out if state_pool is None else out + (state_pool,)
+            return out if state_pools is None else out + tuple(state_pools)
 
-        donate = (1, 2) if family.state_shape is None else (1, 2, 8)
+        # the kinds' pools ride behind the eight fixed arguments
+        donate = (1, 2) + tuple(range(8, 8 + len(family.state_kinds or ())))
         return jax.jit(p2t_decode, donate_argnums=donate)
 
     def kernel_page_counts(self, cache, tables, live_pages) -> dict:
@@ -574,8 +592,9 @@ class PagedRunner:
                 cache.firsts, jnp.asarray(ids, jnp.int32),
                 jnp.asarray(positions, jnp.int32),
                 jnp.asarray(block_tables, jnp.int32))
-        if cache.state is not None:
-            args += (cache.state, jnp.asarray(arrays[3], jnp.int32))
+        if cache.states:
+            args += tuple(cache.states.values()) \
+                + (jnp.asarray(arrays[3], jnp.int32),)
         return args
 
     def decode(self, cache, *arrays):
@@ -619,8 +638,8 @@ class PagedRunner:
             tok, cache.block_ids, cache.block_masked, cache.k, cache.v = out
         else:
             tok, cache.tokens, cache.k, cache.v = out[:4]
-            if cache.state is not None:
-                cache.state = out[4]
+            if cache.states:
+                cache.states = dict(zip(cache.states, out[4:]))
         return tok
 
     # -- deterministic cost accounting (PR 7 cost model) -----------------

@@ -527,6 +527,18 @@ class ContinuousBatchingScheduler:
         seq.recoveries += 1
         self.requeue_front(seq, now=now, cause="corrupt")
 
+    def requeue_moved(self, seq: Sequence,
+                      now: Optional[float] = None) -> None:
+        """Pull a RUNNING sequence whose per-sequence state on the
+        device no longer matches its token log (a decode step moved it
+        and its tokens were discarded): blocks and state slot are
+        released as at an eviction, and the re-prefill from the token
+        log rewrites both. Counted as a recovery, not an eviction."""
+        self._running.remove(seq)
+        seq.table.release()
+        seq.recoveries += 1
+        self.requeue_front(seq, now=now, cause="state_moved")
+
     # -- completion ------------------------------------------------------
     def finish(self, seq: Sequence, now: float = 0.0) -> None:
         self._running.remove(seq)

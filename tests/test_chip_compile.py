@@ -275,6 +275,62 @@ def test_paged_decode_grouped_query_cell_shape(one_chip):
                 if " copy(" in ln and "[1,16384,16,512]" in ln]
 
 
+def test_paged_decode_group_of_five_cell_shape(one_chip):
+    """What `falconh1-serve-gen1k-backlog` runs: 20 query heads over 4
+    key/value heads of 128 — a group of 5, no power of two: the query
+    tile pads 20 rows to 24 — ONE decode program of 128 rows x 160 pages
+    (2,560 positions) over a 20,480-block pool, layer 3 of 4. 160 x (24
+    rows x 16 slots x 4 B of scores + 16 x 512 x 2 B of V) = 2.7 MiB of
+    the 8 MiB budget keeps it on the single-softmax body."""
+    assert pa.fits_single_softmax(160, 16, 128, BF16, None, 20, 4)
+    assert pa.kernel_pages_per_block(160, 16, 20, 128, BF16,
+                                     num_kv_heads=4) > 1
+    pool = ((4, 20480, 16, 4 * 128), BF16)
+    avals = (((128, 1, 20, 128), BF16), pool, pool,
+             ((128, 160), jnp.int32), ((128,), jnp.int32))
+    fn = functools.partial(pa.paged_attention_decode, interpret=False,
+                           layer=3)
+    text = _compile(one_chip, fn, *avals, kernels=["paged_decode"]).as_text()
+    assert "paged_decode_split" not in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[4,20480,16,512]" in ln]
+
+
+def test_ssm_state_step_cell_shape(one_chip):
+    """What `falconh1-serve-gen1k-backlog` runs: 128 rows against a
+    float32 pool of 4 layers x 129 slots x [32, 128, 256] (2.16 GB),
+    the slot ids scalar-prefetched, two layers' steps in one program.
+    The pool is updated where it lies: aliased through both calls (the
+    compiled program's aliased bytes are the pool's) and never copied."""
+    from paddle2_tpu.kernels import ssd
+    pool_shape = (4, 129, 32, 128, 256)
+
+    def two_layers(pool, slots, x, B, C, dt, A, D):
+        for layer in (0, 3):
+            pool, y = ssd.ssm_state_step(pool, layer, slots, x, B, C, dt,
+                                         A, D, interpret=False)
+        return pool, y
+
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (pool_shape, F32), ((128,), jnp.int32), ((128, 32, 128), BF16),
+        ((128, 2, 256), BF16), ((128, 2, 256), BF16), ((128, 32), F32),
+        ((32,), F32), ((32,), F32))]
+    compiled = jax.jit(two_layers, donate_argnums=(0,)).lower(
+        *avals).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    assert len(calls) == 2 and all(
+        re.match(r"\s*(ROOT )?%ssm_state_step(\.\d+)? = ", ln)
+        for ln in calls), [ln[:60] for ln in calls]
+    pool_bytes = math.prod(pool_shape) * 4
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert stats.temp_size_in_bytes < pool_bytes // 8
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[4,129,32,128,256]" in ln]
+
+
 def test_paged_decode_block_of_positions_cell_shape(one_chip):
     """What `sdar-serve-gen512-backlog` runs: a pass carries 4 positions
     of each of 64 sequences, 32 query heads over 4 key/value heads of

@@ -231,7 +231,7 @@ def test_reused_slot_leaks_nothing(bench, logit_tap):
     rng = np.random.default_rng(8)
     first = rng.integers(1, 503, 30).tolist()
     rids, rows = serve(engine, [first], 6, logit_tap)
-    state_after_first = np.asarray(engine.cache.state[:, 1])
+    state_after_first = np.asarray(engine.cache.states["conv"][:, 1])
     assert np.abs(state_after_first).max() > 0      # stale state is there
     second = rng.integers(1, 503, 3).tolist()
     rids2, rows2 = serve(engine, [second], 6, logit_tap)
@@ -243,7 +243,8 @@ def test_admission_waits_for_a_state_slot(bench):
     model, _, _ = build(bench, 9)
     engine = tiny_engine(model, max_batch=2)
     alloc = engine.allocator
-    assert alloc.state_slots == 2 and engine.cache.state.shape[1] == 3
+    assert alloc.state_slots == 2 \
+        and engine.cache.states["conv"].shape[1] == 3
     a, b = alloc.take_state_slot(), alloc.take_state_slot()
     assert not alloc.can_admit(1) and alloc.can_allocate(1)
     engine.submit([1, 2, 3], 2)
@@ -527,3 +528,44 @@ def test_paged_kernel_grouped_query(H, Hkv, D, bs, pages, pps):
     want = paged_attention_reference(q, kp[1], vp[1], bt, ctx)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-6, atol=2e-6)
+
+
+def test_dropped_step_rows_are_reprefilled_not_repeated(bench, monkeypatch):
+    """ROADMAP D13. A discarded decode step has already shifted the
+    convolution state its repeat would read. The seeded tiny model
+    serves one token over and over (the tied head finds the input's own
+    embedding), which is why its tokens "happen not to move"; with the
+    token table at 0.03 and the convolutions' output projections at 16
+    times their seeded scale the operator decides the next token, the
+    served tokens vary (11-14 distinct of 16) and a repeated step DOES
+    move them (this case fails where the step is simply repeated). The
+    engine re-prefills the dropped step's rows, as after an eviction,
+    and serves the tokens of an undisturbed run."""
+    from paddle2_tpu.distributed.fault_tolerance import chaos
+    model, _, _ = build(bench, 23)
+    for name, p in model.named_parameters():
+        scale = 0.03 if "embed_tokens" in name else \
+            16.0 if "conv.out_proj" in name else None
+        if scale:
+            p.set_value(paddle.Tensor(p._data * scale))
+    rng = np.random.default_rng(23)
+    prompts = [rng.integers(1, 503, n).tolist() for n in (9, 14, 20)]
+
+    def run(engine):
+        rids = [engine.submit(p, 16) for p in prompts]
+        now = 0.0
+        while not engine.idle():
+            now += 1.0
+            engine.tick(now)
+        return [list(engine.sequence(r).generated) for r in rids]
+
+    want = run(tiny_engine(model))
+    assert min(len(set(w)) for w in want) > 8
+    monkeypatch.setattr(chaos, "_ACTIVE", chaos.ChaosInjector(
+        "drop_decode_step:3,drop_decode_step:6,drop_decode_step:9,"
+        "drop_decode_step:12"))
+    engine = tiny_engine(model)
+    got = run(engine)
+    assert got == want
+    assert engine.state_reprefills >= 4
+    assert engine.allocator.state_slots_used == 0
