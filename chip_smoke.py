@@ -467,34 +467,56 @@ def gqa_parity(size: dict) -> dict:
 def gmm_parity(size: dict) -> dict:
     """The grouped matmul of the dropless expert layer against a plain
     loop over the experts: even loads, one expert taking everything,
-    and a skewed routing that leaves experts empty; a decode step's 256
-    rows and a prefill's thousands, LFM2-24B-A2B's widths where the
-    size allows."""
+    and a skewed routing that leaves experts empty; a decode step's
+    rows and a prefill's thousands, at the widths and expert counts of
+    the three MoE cells where the size allows (LFM2-24B-A2B: 64 experts
+    of 2048 x 1536; SDAR-30B-A3B: 128 of 2048 x 768, both products;
+    DeepSeek-V2: 20 held of 160 routed over, 5120 x 1536, the rest of
+    the rows parked behind them) — each at the tiles the kernel picks
+    there."""
     import jax.numpy as jnp
     from paddle2_tpu.kernels.moe_gmm import gmm_reference, moe_gmm
     big = size["hidden"] >= 1024
-    E, K, N = (64, 2048, 1536) if big else (8, 128, 256)
+    # (name, groups routed over, held, K, N, decode rows, prefill rows)
+    cells = ([("lfm2", 64, 64, 2048, 1536, 256, 4096),
+              ("sdar.up", 128, 128, 2048, 768, 2048, 4096),
+              ("sdar.down", 128, 128, 768, 2048, 2048, 4096),
+              ("dsv2", 160, 20, 5120, 1536, 768, 12288)] if big
+             else [("tiny", 8, 8, 128, 256, 256, 512),
+                   ("tiny.held", 16, 4, 128, 256, 96, 512)])
     rng = np.random.default_rng(2)
-    rhs = jnp.asarray(rng.normal(size=(E, K, N)) * 0.05, jnp.bfloat16)
     err = {}
-    for rows in (256, 4096 if big else 512):
-        skew = np.bincount(np.minimum(rng.geometric(0.2, rows) - 1, E - 1),
-                           minlength=E)
-        loads = {"even": np.full(E, rows // E), "skewed": skew,
-                 "one_expert": np.eye(E, dtype=np.int64)[E // 2] * rows}
-        lhs = jnp.asarray(rng.normal(size=(rows, K)), jnp.bfloat16)
-        for name, sizes in loads.items():
-            sizes = jnp.asarray(sizes, jnp.int32)
-            out = np.asarray(moe_gmm(lhs, rhs, sizes), np.float32)
-            ref = np.asarray(gmm_reference(lhs, rhs, sizes), np.float32)
-            gap = err[f"gmm.{name}.{rows}"] = float(np.abs(out - ref).max())
-            # both round one f32 accumulation to bf16: equal but for the
-            # order of the sum (a bf16 step of the largest output)
-            if not np.isfinite(out).all() \
-                    or gap > 2.0 ** -7 * max(1.0, float(np.abs(ref).max())):
-                raise AssertionError(
-                    f"moe_gmm ({name}, {rows} rows) off the plain loop "
-                    f"by {gap}")
+    for cell, E, held, K, N, few, many in cells:
+        rhs = jnp.asarray(rng.normal(size=(held, K, N)) * 0.05,
+                          jnp.bfloat16)
+        for rows in (few, many):
+            # of the rows, the share routed to a held expert (the rest
+            # are parked in a last group that nobody holds)
+            here = rows * held // E
+            skew = np.bincount(
+                np.minimum(rng.geometric(0.2, here) - 1, held - 1),
+                minlength=held)
+            even = np.full(held, here // held)
+            even[0] += here - even.sum()
+            loads = {"even": even, "skewed": skew,
+                     "one_expert": np.eye(held, dtype=np.int64)[held // 2]
+                     * here}
+            lhs = jnp.asarray(rng.normal(size=(rows, K)), jnp.bfloat16)
+            for name, sizes in loads.items():
+                sizes = jnp.asarray(np.append(sizes, rows - here),
+                                    jnp.int32)
+                out = np.asarray(moe_gmm(lhs, rhs, sizes), np.float32)
+                ref = np.asarray(gmm_reference(lhs, rhs, sizes), np.float32)
+                gap = err[f"gmm.{cell}.{name}.{rows}"] = float(
+                    np.abs(out - ref).max())
+                # both round one f32 accumulation to bf16: equal but for
+                # the order of the sum (a bf16 step of the largest output)
+                if not np.isfinite(out).all() or out[here:].any() \
+                        or gap > 2.0 ** -7 * max(1.0,
+                                                 float(np.abs(ref).max())):
+                    raise AssertionError(
+                        f"moe_gmm ({cell}, {name}, {rows} rows) off the "
+                        f"plain loop by {gap}")
     return err
 
 

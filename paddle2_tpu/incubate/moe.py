@@ -499,13 +499,14 @@ class DroplessExperts(nn.Layer):
     arrays; it also returns the layer's routing record, one int32
     array: the counts (:attr:`COUNT_NAMES`: assignments computed,
     distinct experts hit, largest load on one expert, rows with at least
-    one of their ``k`` experts held here, rows routed at all) and behind
+    one of their ``k`` experts held here, rows routed at all, rows the
+    grouped matmul's tiles multiply for those assignments) and behind
     them the ``k`` experts chosen for each row (what a router replay or
     a teacher-forced comparison needs: top-k is discontinuous, so which
     experts ran is part of the result)."""
 
     COUNT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_load_max",
-                   "moe_rows_routed_here", "moe_rows")
+                   "moe_rows_routed_here", "moe_rows", "moe_tile_rows")
 
     def __init__(self, hidden: int, width: int, num_experts: int, k: int,
                  use_bias: bool = True, norm_topk: bool = True,
@@ -545,7 +546,7 @@ class DroplessExperts(nn.Layer):
         computing (padding is skipped and not counted). Returns (out
         ``[T, H]`` in a's dtype, record int32 ``[len(COUNT_NAMES) + T *
         k]``: the counts, then the chosen expert ids row by row)."""
-        from ..kernels.moe_gmm import gmm_plan, moe_gmm
+        from ..kernels.moe_gmm import gmm_plan, moe_gmm, plan_tile_rows
         T, H = a.shape
         E, k = self.num_experts, self.k
         with jax.named_scope("router"):
@@ -581,6 +582,7 @@ class DroplessExperts(nn.Layer):
         with jax.named_scope("experts"):
             # one visit list for the layer's three products
             plan = gmm_plan(sizes, T * k, self.first, self.count)
+            counts = jnp.append(counts, plan_tile_rows(plan, T * k))
             up = moe_gmm(rows, self.w1._data, interpret=interpret, plan=plan)
             gate = moe_gmm(rows, self.w3._data, interpret=interpret,
                            plan=plan)

@@ -474,47 +474,67 @@ def test_flash_block_causal_prefill_shape(one_chip, seq):
 
 
 # ------------------------------------------------------- grouped matmul
+def _gmm_fn(lhs, rhs, sizes, first):
+    from paddle2_tpu.kernels import moe_gmm
+    return moe_gmm.moe_gmm(lhs, rhs, sizes, first, interpret=False)
+
+
 @pytest.mark.parametrize("rows,k,n", [
     (256, 2048, 1536), (256, 1536, 2048),       # a 64-row decode step
-    (12288, 2048, 1536), (12288, 1536, 2048),   # a 3072-token prefill
-    (4096, 2048, 1536)])
+    (4096, 2048, 1536), (4096, 1536, 2048),     # a 1024-token prefill
+    (8192, 2048, 1536), (8192, 1536, 2048),     # a 2048-token prefill
+    (12288, 2048, 1536), (12288, 1536, 2048)])  # a 3072-token prefill
 def test_moe_gmm_cell_shapes(one_chip, rows, k, n):
     """The dropless expert layer's grouped matmul at LFM2-24B-A2B's
     widths: all 64 experts held (and a 65th group where skipped rows
-    are parked), bf16, both projections, decode and prefill rows."""
-    from paddle2_tpu.kernels import moe_gmm
-
-    def fn(lhs, rhs, sizes, first):
-        return moe_gmm.moe_gmm(lhs, rhs, sizes, first, interpret=False)
-
-    _compile(one_chip, fn, ((rows, k), BF16), ((64, k, n), BF16),
+    are parked), bf16, both projections, decode and every prefill
+    bucket's rows — at the tiles the rule picks there (128 rows;
+    ``[K, 512]`` weight tiles at decode, ``[2048, 768]`` and ``[1536,
+    1024]`` at prefill)."""
+    _compile(one_chip, _gmm_fn, ((rows, k), BF16), ((64, k, n), BF16),
              ((65,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
 
 
 def test_moe_gmm_held_share(one_chip):
     """Eight of the 64 experts held: the chip's share of a deployment
     that divides a layer's experts over eight chips."""
-    from paddle2_tpu.kernels import moe_gmm
+    _compile(one_chip, _gmm_fn, ((2048, 2048), BF16),
+             ((8, 2048, 1536), BF16), ((65,), jnp.int32), ((), jnp.int32),
+             kernels=["moe_gmm"])
 
-    def fn(lhs, rhs, sizes, first):
-        return moe_gmm.moe_gmm(lhs, rhs, sizes, first, interpret=False)
 
-    _compile(one_chip, fn, ((2048, 2048), BF16), ((8, 2048, 1536), BF16),
-             ((65,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
+def test_moe_gmm_tall_tile(one_chip):
+    """Rows enough to fill a 256-row tile a group (8 experts, 512 rows
+    each): the tall tile beside the widest weight tile."""
+    _compile(one_chip, _gmm_fn, ((4096, 2048), BF16),
+             ((8, 2048, 1536), BF16), ((9,), jnp.int32), ((), jnp.int32),
+             kernels=["moe_gmm"])
 
 
 @pytest.mark.parametrize("rows,k,n", [
     (2048, 2048, 768), (2048, 768, 2048),       # a pass: 64 x 4 rows x 8
+    (4096, 2048, 768), (4096, 768, 2048),       # a 512-token prefill
+    (8192, 2048, 768), (8192, 768, 2048),       # a 1024-token prefill
     (16384, 2048, 768), (16384, 768, 2048)])    # a 2048-token prefill
 def test_moe_gmm_128_experts_cell_shapes(one_chip, rows, k, n):
-    """`sdar-serve-gen512-backlog`: 128 experts of width 768, 8 a row."""
-    from paddle2_tpu.kernels import moe_gmm
-
-    def fn(lhs, rhs, sizes, first):
-        return moe_gmm.moe_gmm(lhs, rhs, sizes, first, interpret=False)
-
-    _compile(one_chip, fn, ((rows, k), BF16), ((128, k, n), BF16),
+    """`sdar-serve-gen512-backlog`: 128 experts of width 768, 8 a row
+    (128 rows a tile; ``[2048, 768]`` and ``[768, 2048]`` weight tiles:
+    one column tile a product)."""
+    _compile(one_chip, _gmm_fn, ((rows, k), BF16), ((128, k, n), BF16),
              ((129,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    (768, 5120, 1536), (768, 1536, 5120),       # a step: 128 rows x 6
+    (12288, 5120, 1536), (12288, 1536, 5120),   # a 2048-token prefill
+    (18432, 5120, 1536), (18432, 1536, 5120),   # a 3072-token prefill
+    (30720, 5120, 1536), (30720, 1536, 5120)])  # a 5120-token prefill
+def test_moe_gmm_held_group_cell_shapes(one_chip, rows, k, n):
+    """`dsv2-serve-doc5k-backlog`: 20 experts held of 160 routed over
+    (a 161st group parks the rest), 6 a row (128 rows a tile; ``[5120,
+    256]`` and ``[1536, 1024]`` weight tiles)."""
+    _compile(one_chip, _gmm_fn, ((rows, k), BF16), ((20, k, n), BF16),
+             ((161,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
 
 
 @pytest.mark.parametrize("rows", [64, 3072])

@@ -48,7 +48,7 @@ def traced(tmp_path_factory):
 def test_rows_routed_and_rows_here_ride_with_the_routing_counts(traced):
     engine, spans = traced
     layers, k = engine.runner.family.routed
-    assert ROUTING[-2:] == ("moe_rows_routed_here", "moe_rows")
+    assert ROUTING[3:5] == ("moe_rows_routed_here", "moe_rows")
     delivered = [s[3] for s in spans
                  if s[0] == "prefill" and "tokens" not in s[3]]
     assert [c["req"] for c in delivered] == [0, 1, 2]
@@ -183,10 +183,10 @@ def test_the_metrics_plane_counts_the_rows(tmp_path):
         stats = ServingEngine._count_stats(
             {"moe_assignments": [5, 4], "moe_experts_hit": [2, 2],
              "moe_load_max": [3, 2], "moe_rows_routed_here": [4, 3],
-             "moe_rows": [9, 9]})
+             "moe_rows": [9, 9], "moe_tile_rows": [64, 32]})
         assert stats == {"moe_assignments": 9, "moe_experts_hit": 4,
                          "moe_load_max": 3, "moe_rows_routed_here": 7,
-                         "moe_rows": 18}
+                         "moe_rows": 18, "moe_tile_rows": 96}
         assert plane.counter("serving_moe_rows_total").value() == 18
         assert plane.counter(
             "serving_moe_rows_routed_here_total").value() == 7

@@ -504,9 +504,17 @@ def test_expert_shares_add_up_to_the_whole_layer(bench, router, E, k):
     assert int(counts[0]) == 40 * k
     # every row is routed, and holding all experts, routed here
     assert counts[3:5].tolist() == [40, 40]
+    # 40 x k rows in tiles of 32 or 64 (128 halved until it divides the
+    # rows): the (row tile, expert) pairs that share rows, times the tile
+    from test_moe_gmm_tiles import visits_by_hand
+    n_counts = len(DroplessExperts.COUNT_NAMES)
+    tm = 32 if k == 4 else 64
+    sizes = np.bincount(np.asarray(used).ravel(), minlength=E)
+    assert n_counts == 6 \
+        and int(counts[5]) == tm * visits_by_hand(sizes, 0, E, tm)
     # behind the counts, the experts chosen row by row
     np.testing.assert_array_equal(
-        np.sort(np.asarray(counts[5:]).reshape(40, k), -1),
+        np.sort(np.asarray(counts[n_counts:]).reshape(40, k), -1),
         np.sort(np.asarray(used), -1))
 
 

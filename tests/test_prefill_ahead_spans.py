@@ -177,3 +177,57 @@ def test_the_benchmarks_readers_keep_reading_what_they_read(
     # prefill_pad_pct: the admission's counts alone (9, 12, 14 pad to 16)
     assert readers.program_trace.prefill_pad_pct(readers.pt) \
         == pytest.approx(100.0 * (48 - sum(LFM2_PROMPTS)) / 48)
+
+
+# -- the rows the grouped matmul's tiles multiply (PR 40) ---------------------
+def tile_rows_by_hand(chosen, padded, E):
+    """``visits x tm`` of ``kernels.moe_gmm``'s plan, recomputed from
+    the experts chosen ``[rows, layers, k]`` of a program that ran
+    ``padded`` rows: a layer's assignments sorted by expert, the padding
+    parked behind the last one; a visit is a (row tile, expert) pair
+    that share rows."""
+    from paddle2_tpu.kernels.moe_gmm import _row_tile
+    from test_moe_gmm_tiles import visits_by_hand
+    rows, layers, k = chosen.shape
+    tm = _row_tile(padded * k, E + 1)
+    return tm * sum(
+        visits_by_hand(np.bincount(chosen[:, layer].ravel(), minlength=E),
+                       0, E, tm) for layer in range(layers))
+
+
+def test_tile_rows_ride_with_the_routing_counts(readers, lfm2_traced):
+    """``moe_tile_rows`` on the read-back ``prefill`` span and on every
+    ``decode.dispatch`` that read a step back is the plan's visits times
+    its row tile, summed over the expert layers — recomputed here from
+    the experts the engine says it chose — and
+    ``moe_gmm_tile_fill_pct.serve`` reads assignments over it."""
+    engine, spans = lfm2_traced
+    assert ROUTING[-1] == "moe_tile_rows"
+    E = engine.runner.model.cfg.num_experts
+    chosen = [engine.routed_experts(rid) for rid in range(3)]
+    _, delivered = split(spans)
+    for span, n, ch in zip(delivered, LFM2_PROMPTS, chosen):
+        # a prompt of 9, 12 or 14 tokens ran padded to 16 rows
+        assert span[3]["moe_tile_rows"] == tile_rows_by_hand(ch[:n], 16, E)
+        assert span[3]["moe_tile_rows"] >= span[3]["moe_assignments"]
+    steps = [s[3] for s in spans
+             if s[0] == "decode.dispatch" and "moe_tile_rows" in s[3]]
+    assert len(steps) == 3
+    for j, c in enumerate(steps):
+        # step j fed each request the token behind its prompt + j, in a
+        # batch of 4 rows
+        rows = np.stack([ch[n + j] for n, ch in zip(LFM2_PROMPTS, chosen)])
+        assert c["moe_tile_rows"] == tile_rows_by_hand(rows, 4, E)
+    spec = importlib.util.spec_from_file_location(
+        "tile_fill_reader", os.path.join(
+            BENCHMARK, "layer_metrics", "moe_gmm_tile_fill_pct.serve.py"))
+    fill = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fill)
+    counted = [s[3] for s in delivered] + steps
+    want = 100.0 * sum(c["moe_assignments"] for c in counted) \
+        / sum(c["moe_tile_rows"] for c in counted)
+    assert fill.read(readers.ctx) == pytest.approx(want) and 0 < want <= 100
+    # a program from before the count (the parent's spans): nothing
+    for s in readers.pt.spans:
+        s[3].pop("moe_tile_rows", None)
+    assert fill.read(readers.ctx) is None
