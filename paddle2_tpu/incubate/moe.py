@@ -417,13 +417,13 @@ class MoELayer(nn.Layer):
 
 # ---------------------------------------------------------------- dropless
 def sigmoid_topk_route(a, gate_w, bias, k: int, norm_topk: bool = True,
-                       scale: float = 1.0):
+                       scale: float = 1.0, norm_eps: float = 1e-6):
     """Sigmoid routing with a selection bias, in float32 whatever the
     layer's dtype: ``s = sigmoid(a W_g)``; the ``k`` experts are the
     largest ``s + bias`` (the bias selects only); the weights are the
-    unbiased scores, ``s_e / (sum_S s + 1e-6)`` when ``norm_topk``,
-    times ``scale``. Returns (ids ``[T, k]`` int32, weights ``[T, k]``
-    f32)."""
+    unbiased scores, ``s_e / (sum_S s + norm_eps)`` when ``norm_topk``
+    (a publication's own epsilon: 1e-6, 1e-20), times ``scale``. Returns
+    (ids ``[T, k]`` int32, weights ``[T, k]`` f32)."""
     s = jax.nn.sigmoid(jnp.dot(a.astype(jnp.float32),
                                gate_w.astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
@@ -431,7 +431,7 @@ def sigmoid_topk_route(a, gate_w, bias, k: int, norm_topk: bool = True,
     _, ids = jax.lax.top_k(pick, k)
     w = jnp.take_along_axis(s, ids, axis=-1)
     if norm_topk:
-        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, -1, keepdims=True) + norm_eps)
     return ids.astype(jnp.int32), w * scale
 
 
@@ -477,10 +477,20 @@ def softmax_group_limited_route(a, gate_w, k: int, n_group: int,
 
 
 class DroplessExperts(nn.Layer):
-    """Top-k routed SwiGLU experts with NO capacity: every assignment
-    is computed. Rows are sorted by expert and all experts held run as
+    """Top-k routed experts with NO capacity: every assignment is
+    computed. Rows are sorted by expert and all experts held run as
     one grouped matmul (``kernels.moe_gmm``) per projection, for
     thousands of prefill rows and a decode step's few hundred alike.
+
+    An expert is a SwiGLU, ``W2 (silu(W1 a) * (W3 a))`` (``gated``,
+    three matrices), or — ``gated=False, activation="relu2"`` — the
+    two-matrix ``W2 relu(W1 a)^2``, which has NO ``w3`` parameter.
+    ``width_align`` stores the experts' width in whole multiples of it
+    (1,856 lanes as 1,920 under 128): zero columns of ``W1`` and zero
+    rows of ``W2``, exact for an activation that maps 0 to 0 — the chip
+    keeps an array whose minor dimension 128 does not divide in another
+    layout than the kernel takes, and would copy the weights at every
+    call.
 
     ``router`` names how a row's experts and weights are found:
     ``"sigmoid"`` (:func:`sigmoid_topk_route`, with the selection bias
@@ -512,10 +522,16 @@ class DroplessExperts(nn.Layer):
                  use_bias: bool = True, norm_topk: bool = True,
                  scale: float = 1.0, held=None, std: float = 0.02,
                  dtype=None, router: str = "sigmoid", n_group: int = 1,
-                 topk_group: int = 1):
+                 topk_group: int = 1, gated: bool = True,
+                 activation: str = "silu", norm_eps: float = 1e-6,
+                 width_align: int = 1):
         super().__init__()
         if router not in ("sigmoid", "softmax", "softmax_group_limited"):
             raise ValueError(f"unknown router {router!r}")
+        if (bool(gated), activation) not in ((True, "silu"),
+                                             (False, "relu2")):
+            raise ValueError(f"no {'gated' if gated else 'ungated'} "
+                             f"experts with activation {activation!r}")
         if num_experts % n_group or not 1 <= topk_group <= n_group:
             raise ValueError(
                 f"{num_experts} experts in {n_group} groups, {topk_group} "
@@ -525,9 +541,11 @@ class DroplessExperts(nn.Layer):
         use_bias = use_bias and router == "sigmoid"
         self.num_experts, self.k = int(num_experts), int(k)
         self.norm_topk, self.scale = bool(norm_topk), float(scale)
+        self.norm_eps, self.gated = float(norm_eps), bool(gated)
         self.first, self.count = held or (0, self.num_experts)
         init = nn.initializer.Normal(0.0, std)
         n = self.count
+        stored = -(-width // width_align) * width_align
 
         def param(shape):
             return self.create_parameter(shape, dtype=dtype,
@@ -537,9 +555,13 @@ class DroplessExperts(nn.Layer):
         # published as a trained buffer; a parameter here so that a
         # checkpoint (and the benchmark's seeded weights) reach it
         self.expert_bias = param([self.num_experts]) if use_bias else None
-        self.w1 = param([n, hidden, width])
-        self.w3 = param([n, hidden, width])
-        self.w2 = param([n, width, hidden])
+        self.w1 = param([n, hidden, stored])
+        if gated:
+            self.w3 = param([n, hidden, stored])
+        self.w2 = param([n, stored, hidden])
+        if stored != width:
+            self.w1._replace_data(self.w1._data.at[..., width:].set(0))
+            self.w2._replace_data(self.w2._data.at[:, width:].set(0))
 
     def route_and_run(self, a, valid=None, interpret=None):
         """a ``[T, H]``; ``valid`` bool ``[T]`` marks the rows worth
@@ -562,7 +584,7 @@ class DroplessExperts(nn.Layer):
                     a, self.gate_weight._data,
                     None if self.expert_bias is None
                     else self.expert_bias._data,
-                    k, self.norm_topk, self.scale)
+                    k, self.norm_topk, self.scale, self.norm_eps)
         with jax.named_scope("dispatch"):
             flat = ids.reshape(-1)
             held = (flat >= self.first) & (flat < self.first + self.count)
@@ -580,15 +602,19 @@ class DroplessExperts(nn.Layer):
                                 jnp.max(sizes[:E]), rows_here,
                                 n_rows]).astype(jnp.int32)
         with jax.named_scope("experts"):
-            # one visit list for the layer's three products
+            # one visit list for the layer's products
             plan = gmm_plan(sizes, T * k, self.first, self.count)
             counts = jnp.append(counts, plan_tile_rows(plan, T * k))
             up = moe_gmm(rows, self.w1._data, interpret=interpret, plan=plan)
-            gate = moe_gmm(rows, self.w3._data, interpret=interpret,
-                           plan=plan)
-            h = (jax.nn.silu(up.astype(jnp.float32))
-                 * gate.astype(jnp.float32)).astype(a.dtype)
-            y = moe_gmm(h, self.w2._data, interpret=interpret, plan=plan)
+            up = up.astype(jnp.float32)
+            if self.gated:
+                h = jax.nn.silu(up) * moe_gmm(
+                    rows, self.w3._data, interpret=interpret,
+                    plan=plan).astype(jnp.float32)
+            else:
+                h = jnp.square(jax.nn.relu(up))
+            y = moe_gmm(h.astype(a.dtype), self.w2._data,
+                        interpret=interpret, plan=plan)
         with jax.named_scope("combine"):
             inv = jnp.argsort(order)
             y = y[inv].reshape(T, k, H).astype(jnp.float32)

@@ -1,12 +1,14 @@
-"""What the RMSNorm / rotary decoder families share (``models/lfm2.py``,
-``models/sdar.py``, ``models/deepseek.py``, ``models/falcon_h1.py``):
-bias-free projections created in the model's dtype, the pre-norm
-through the repo's kernel, rotary tables in the rotate-half layout
-(plain or YaRN-scaled frequencies), the SwiGLU feed-forward (with a
-model's gate and down multipliers, where it has them), and grouped-query
-attention with or without a per-head RMS norm of q and k and with a
-scale on the keys. Written once, on arrays; inference only (no autograd
-tape)."""
+"""What the RMSNorm decoder families share (``models/lfm2.py``,
+``models/sdar.py``, ``models/deepseek.py``, ``models/falcon_h1.py``,
+``models/nemotron_h.py``): bias-free projections created in the model's
+dtype, the pre-norm through the repo's kernel, rotary tables in the
+rotate-half layout (plain or YaRN-scaled frequencies), the SwiGLU
+feed-forward (with a model's gate and down multipliers, where it has
+them) and the two-matrix relu^2 one, grouped-query attention with or
+without a per-head RMS norm of q and k, a rotary embedding and a scale
+on the keys, and the Mamba-2 state-space mixer (with a model's µP
+multipliers, where it has them). Written once, on arrays; inference only
+(no autograd tape)."""
 
 from __future__ import annotations
 
@@ -22,8 +24,8 @@ from ..framework.tensor import Tensor
 from ..kernels.pallas_fused import fused_rms_norm, fused_rope
 from ..ops.linalg import _mxu_precision
 
-__all__ = ["GroupedQueryAttention", "NormalDraw", "SwiGLU", "created_in",
-           "linear", "mm",
+__all__ = ["GroupedQueryAttention", "Mamba2Mixer", "NormalDraw", "Relu2MLP",
+           "SwiGLU", "created_in", "linear", "mm",
            "pre_norm", "rms_head", "rope_tables", "rotate_half_rope",
            "rotate_half_rope_mxu", "yarn_inv_freq", "yarn_mscale"]
 
@@ -169,14 +171,18 @@ class GroupedQueryAttention(nn.Layer):
     """``num_heads`` query heads over ``num_kv_heads`` key/value heads of
     ``head_dim``; q and k normed per head (``qk_norm``; a model without
     the norm has no such parameters) and rotated (rotate-half, base
-    ``theta``); ``key_scale`` multiplies the keys as projected."""
+    ``theta``; ``rotary=False``: a model whose positions come from
+    elsewhere rotates nothing); ``key_scale`` multiplies the keys as
+    projected."""
 
     def __init__(self, hidden: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, eps: float, theta: float, std: float,
-                 dtype=None, qk_norm: bool = True, key_scale: float = 1.0):
+                 dtype=None, qk_norm: bool = True, key_scale: float = 1.0,
+                 rotary: bool = True):
         super().__init__()
         self.head_dim, self.eps, self.theta = head_dim, eps, float(theta)
         self.qk_norm, self.key_scale = bool(qk_norm), float(key_scale)
+        self.rotary = bool(rotary)
         self.q_proj = linear(hidden, num_heads * head_dim, std, dtype)
         self.k_proj = linear(hidden, num_kv_heads * head_dim, std, dtype)
         self.v_proj = linear(hidden, num_kv_heads * head_dim, std, dtype)
@@ -187,7 +193,8 @@ class GroupedQueryAttention(nn.Layer):
 
     def qkv(self, u, positions):
         """u ``[B, S, H]``, positions int ``[B, S]`` -> q ``[B, S, nh,
-        hd]``, k, v ``[B, S, nkv, hd]``; q and k normed and rotated."""
+        hd]``, k, v ``[B, S, nkv, hd]``; q and k normed and rotated
+        (where the model norms, where it rotates)."""
         B, S, _ = u.shape
         hd = self.head_dim
         q = mm(u, self.q_proj).reshape(B, S, -1, hd)
@@ -198,6 +205,8 @@ class GroupedQueryAttention(nn.Layer):
         if self.qk_norm:
             q = rms_head(q, self.q_norm.weight._data, self.eps)
             k = rms_head(k, self.k_norm.weight._data, self.eps)
+        if not self.rotary:
+            return q, k, v
         cos, sin = rope_tables(positions.reshape(-1), hd, self.theta)
         return fused_rope(q, cos, sin), fused_rope(k, cos, sin), v
 
@@ -217,7 +226,9 @@ class GroupedQueryAttention(nn.Layer):
 
     def project(self, a):
         """The heads' outputs ``[..., nh * hd]`` through ``W_o``."""
-        return mm(a.astype(self.out_proj.weight._data.dtype), self.out_proj)
+        with jax.named_scope("out"):
+            return mm(a.astype(self.out_proj.weight._data.dtype),
+                      self.out_proj)
 
 
 def rotate_half_rope_mxu(x, cos, sin):
@@ -234,3 +245,164 @@ def rotate_half_rope_mxu(x, cos, sin):
     turned = jnp.dot(x, turn, precision=_mxu_precision(x),
                      preferred_element_type=jnp.float32)
     return (x.astype(jnp.float32) * cos + turned * sin).astype(x.dtype)
+
+
+class Relu2MLP(nn.Layer):
+    """``relu(a W_up)^2 W_down``: two matrices, no gate; the square in
+    float32."""
+
+    def __init__(self, hidden: int, width: int, std: float, dtype=None):
+        super().__init__()
+        self.up_proj = linear(hidden, width, std, dtype)
+        self.down_proj = linear(width, hidden, std, dtype)
+
+    def run(self, a):
+        up = jax.nn.relu(mm(a, self.up_proj).astype(jnp.float32))
+        return mm((up * up).astype(a.dtype), self.down_proj)
+
+
+class Mamba2Mixer(nn.Layer):
+    """The Mamba-2 state-space mixer::
+
+        z | xBC | dt = split((u * in_multiplier) W_in * m)
+        xBC' = silu(b + depthwise causal conv_{taps}(xBC))
+        x [heads, d_head] | B [groups, d_state] | C [groups, d_state] = xBC'
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        H_t = exp(dt_t A) H_{t-1} + dt_t x_t (outer) B_t
+        y_t = H_t C_t + D x_t
+        g = y * silu(z);  RMS over each group's d_inner / groups lanes,
+        times the norm's weight
+        out = g W_out                              d_inner = heads x d_head
+
+    ``multipliers`` is a µP model's five factors on the lanes of z, x,
+    B, C, dt (``m``) and ``in_multiplier`` its factor on ``u``; both act
+    on activations at run time, and a model without them multiplies
+    nothing. The recurrence is float32 (``kernels/ssd.py``): the chunked
+    scan over a whole sequence (:meth:`full`), one step against kept
+    state (:meth:`step`). Scopes (under the caller's ``ssm``):
+    ``in_proj``, ``conv``, ``scan`` or ``step``, ``norm``, ``out``."""
+
+    def __init__(self, hidden: int, heads: int, d_head: int, groups: int,
+                 d_state: int, taps: int, chunk: int, eps: float, std: float,
+                 dtype=None, in_multiplier: float = 1.0, multipliers=None):
+        super().__init__()
+        self.heads, self.d_head, self.groups = heads, d_head, groups
+        self.d_state, self.taps, self.chunk, self.eps = (d_state, taps,
+                                                         chunk, eps)
+        d = self.d_inner = heads * d_head
+        gn = groups * d_state
+        self.conv_dim = d + 2 * gn
+        self.in_multiplier = float(in_multiplier)
+        self.in_proj = linear(hidden, d + self.conv_dim + heads, std, dtype)
+        normal = nn.initializer.Normal(0.0, std)
+
+        def small(shape, init=normal):
+            return self.create_parameter(shape, dtype=dtype,
+                                         default_initializer=init)
+
+        self.conv_weight = small([taps, self.conv_dim])
+        self.conv_bias = small([self.conv_dim])
+        self.dt_bias = small([heads])
+        self.A_log = small([heads])
+        self.D = small([heads], nn.initializer.Constant(1.0))
+        self.norm = nn.RMSNorm(d, epsilon=eps)
+        self.out_proj = linear(d, hidden, std, dtype)
+        # the µP vector: one multiplier a slice of the input projection
+        self._mup = None
+        if multipliers is not None:
+            mz, mx, mb, mc, mdt = (float(v) for v in multipliers)
+            self._mup = np.concatenate([
+                np.full(d, mz), np.full(d, mx), np.full(gn, mb),
+                np.full(gn, mc), np.full(heads, mdt)]).astype(np.float32)
+
+    # -- the pieces both forms share ------------------------------------
+    def project(self, u):
+        """u ``[..., H]`` -> z ``[..., d_inner]``, xBC ``[..., conv_dim]``
+        (before the convolution: what a decoder keeps the last ``taps -
+        1`` positions of), dt ``[..., heads]`` as projected."""
+        with jax.named_scope("in_proj"):
+            if self.in_multiplier != 1.0:
+                u = u * jnp.asarray(self.in_multiplier, u.dtype)
+            p = mm(u, self.in_proj)
+            if self._mup is not None:
+                p = p * jnp.asarray(self._mup, u.dtype)
+            d = self.d_inner
+            return (p[..., :d], p[..., d:d + self.conv_dim],
+                    p[..., d + self.conv_dim:])
+
+    def split_heads(self, xbc):
+        """The convolved ``[..., conv_dim]`` -> x ``[..., heads,
+        d_head]``, B, C ``[..., groups, d_state]``."""
+        d, gn = self.d_inner, self.groups * self.d_state
+        lead = xbc.shape[:-1]
+        return (xbc[..., :d].reshape(lead + (self.heads, self.d_head)),
+                xbc[..., d:d + gn].reshape(lead + (self.groups,
+                                                   self.d_state)),
+                xbc[..., d + gn:].reshape(lead + (self.groups,
+                                                  self.d_state)))
+
+    def step_size(self, dt):
+        """``softplus(dt + dt_bias)`` in float32 (no clamp: the
+        published ``time_step_limit`` is (0, inf))."""
+        return jax.nn.softplus(dt.astype(jnp.float32)
+                               + self.dt_bias._data.astype(jnp.float32))
+
+    def decay_rate(self):
+        return -jnp.exp(self.A_log._data.astype(jnp.float32))
+
+    def gate_and_project(self, y, z):
+        """``(RMS_grouped(y * silu(z)) * weight) W_out``; y float32
+        ``[..., heads, d_head]``, z ``[..., d_inner]``."""
+        with jax.named_scope("norm"):
+            g = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+            grouped = g.reshape(g.shape[:-1] + (self.groups, -1))
+            grouped = grouped * jax.lax.rsqrt(
+                jnp.mean(grouped * grouped, -1, keepdims=True) + self.eps)
+            g = (grouped.reshape(g.shape)
+                 * self.norm.weight._data.astype(jnp.float32)).astype(z.dtype)
+        with jax.named_scope("out"):
+            return mm(g, self.out_proj)
+
+    # -- a whole sequence -----------------------------------------------
+    def full(self, u, valid=None):
+        """u ``[B, S, H]`` -> (out ``[B, S, H]``, xBC ``[B, S,
+        conv_dim]`` before the convolution, the recurrent state ``[B,
+        heads, d_head, d_state]`` float32 after the last VALID position:
+        where ``valid [B, S]`` is false the step is 0 and the state
+        stands still)."""
+        from ..kernels.ssd import ssd_chunk_scan
+        z, xbc, dt = self.project(u)
+        with jax.named_scope("conv"):
+            S = xbc.shape[1]
+            padded = jnp.pad(xbc, ((0, 0), (self.taps - 1, 0), (0, 0)))
+            w = self.conv_weight._data
+            conv = self.conv_bias._data + sum(
+                w[j] * padded[:, j:j + S] for j in range(self.taps))
+            x, Bm, Cm = self.split_heads(jax.nn.silu(conv))
+        with jax.named_scope("scan"):
+            dt = self.step_size(dt)
+            if valid is not None:
+                dt = jnp.where(valid[..., None], dt, 0.0)
+            A, D = self.decay_rate(), self.D._data
+            y, H = jax.vmap(lambda *a: ssd_chunk_scan(
+                a[0], a[1], A, a[2], a[3], D, self.chunk))(x, dt, Bm, Cm)
+        return self.gate_and_project(y, z), xbc, H
+
+    # -- one token --------------------------------------------------------
+    def step(self, u, conv_state, recur):
+        """u ``[B, H]``, conv_state ``[B, taps - 1, conv_dim]`` (the
+        last xBC's, oldest first) -> (out ``[B, H]``, the state shifted
+        by this xBC). ``recur(x, B, C, dt, A, D) -> y`` steps the
+        recurrent state wherever it is kept (a dense array, a slot of
+        the serving pool) and returns y ``[B, heads, d_head]`` f32."""
+        z, xbc, dt = self.project(u)
+        with jax.named_scope("conv"):
+            window = jnp.concatenate(
+                [conv_state.astype(xbc.dtype), xbc[:, None]], axis=1)
+            conv = self.conv_bias._data + jnp.sum(
+                window * self.conv_weight._data[None], axis=1)
+            x, Bm, Cm = self.split_heads(jax.nn.silu(conv))
+        with jax.named_scope("step"):
+            y = recur(x, Bm, Cm, self.step_size(dt), self.decay_rate(),
+                      self.D._data)
+        return self.gate_and_project(y, z), window[:, 1:]

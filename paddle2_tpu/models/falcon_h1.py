@@ -13,24 +13,16 @@ residual add; a SwiGLU feed-forward follows::
 
 ``Attn``: ``models/_decoder.GroupedQueryAttention`` without the q/k norm,
 keys scaled by ``key_multiplier``, rotate-half rotary. ``FF``:
-``models/_decoder.SwiGLU`` with the two ``mlp_multipliers``. ``SSM``
-(:class:`FalconH1Mixer`)::
-
-    p = ((u * ssm_in_multiplier) W_in) * m      m: ssm_multipliers[0..4] on
-    z | xBC | dt = split(p)                     the lanes of z, x, B, C, dt
-    xBC' = silu(b + depthwise causal conv_{d_conv}(xBC))
-    x [heads, d_head] | B [groups, d_state] | C [groups, d_state] = xBC'
-    dt = softplus(dt + dt_bias);  A = -exp(A_log)
-    H_t = exp(dt_t A) H_{t-1} + dt_t x_t (outer) B_t;  y_t = H_t C_t + D x_t
-    g = y * silu(z);  RMS over each group's d_ssm / groups lanes, x weight
-    SSM = g W_out
+``models/_decoder.SwiGLU`` with the two ``mlp_multipliers``. ``SSM``:
+``models/_decoder.Mamba2Mixer`` (the Mamba-2 mixer every state-space
+family here uses) with ``ssm_in_multiplier`` on its input and the five
+``ssm_multipliers`` on the lanes of z, x, B, C, dt of its input
+projection (:class:`FalconH1Mixer`).
 
 Every multiplier acts on activations at run time; none is folded into a
-weight. The recurrence is float32 (``kernels/ssd.py``): the chunked
-scan over a whole sequence (:meth:`FalconH1Mixer.full`), one step
-against kept state (:meth:`FalconH1Mixer.step`). The config class takes
-the published ``config.json`` keys by their own names. Inference only;
-the serving family is ``serving/falcon_h1_family.py``.
+weight. The config class takes the published ``config.json`` keys by
+their own names. Inference only; the serving family is
+``serving/falcon_h1_family.py``.
 """
 
 from __future__ import annotations
@@ -40,15 +32,13 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import nn
 from ..framework.tensor import Tensor
 from ..kernels.pallas_fused import fused_rms_norm
-from ..kernels.ssd import ssd_chunk_scan
 from ..ops.linalg import _mxu_precision
-from ._decoder import (GroupedQueryAttention, NormalDraw, SwiGLU, created_in,
-                       linear, mm, pre_norm)
+from ._decoder import (GroupedQueryAttention, Mamba2Mixer, NormalDraw, SwiGLU,
+                       created_in, linear, pre_norm)
 
 __all__ = ["FalconH1Config", "FalconH1ForCausalLM", "falcon_h1_tiny"]
 
@@ -133,132 +123,16 @@ class FalconH1Config:
         return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
 
 
-def _small(layer, shape, cfg, init):
-    return layer.create_parameter(shape, dtype=cfg.dtype,
-                                  default_initializer=init)
-
-
-class FalconH1Mixer(nn.Layer):
-    """The Mamba-2 mixer. Scopes (under the caller's ``ssm``):
-    ``in_proj``, ``conv``, ``scan`` or ``step``, ``norm``, ``out``."""
+class FalconH1Mixer(Mamba2Mixer):
+    """``models/_decoder.Mamba2Mixer`` at this config's sizes, under its
+    µP multipliers."""
 
     def __init__(self, cfg: FalconH1Config):
-        super().__init__()
-        self.cfg = cfg
-        H, std = cfg.hidden_size, cfg.initializer_range
-        d, nh = cfg.mamba_d_ssm, cfg.mamba_n_heads
-        gn = cfg.mamba_n_groups * cfg.mamba_d_state
-        self.taps = cfg.mamba_d_conv
-        self.in_proj = linear(H, d + cfg.conv_dim + nh, std, cfg.dtype)
-        normal = nn.initializer.Normal(0.0, std)
-        self.conv_weight = _small(self, [self.taps, cfg.conv_dim], cfg,
-                                  normal)
-        self.conv_bias = _small(self, [cfg.conv_dim], cfg, normal)
-        self.dt_bias = _small(self, [nh], cfg, normal)
-        self.A_log = _small(self, [nh], cfg, normal)
-        self.D = _small(self, [nh], cfg, nn.initializer.Constant(1.0))
-        self.norm = nn.RMSNorm(d, epsilon=cfg.rms_norm_eps)
-        self.out_proj = linear(d, H, std, cfg.dtype)
-        # the µP vector: one multiplier a slice of the input projection
-        mz, mx, mb, mc, mdt = (float(v) for v in cfg.ssm_multipliers)
-        self._mup = np.concatenate([
-            np.full(d, mz), np.full(d, mx), np.full(gn, mb), np.full(gn, mc),
-            np.full(nh, mdt)]).astype(np.float32)
-
-    # -- the pieces both forms share ------------------------------------
-    def project(self, u):
-        """u ``[..., H]`` -> z ``[..., d_ssm]``, xBC ``[..., conv_dim]``
-        (before the convolution: what a decoder keeps the last
-        ``d_conv - 1`` positions of), dt ``[..., heads]`` as projected."""
-        cfg = self.cfg
-        with jax.named_scope("in_proj"):
-            u = u * jnp.asarray(cfg.ssm_in_multiplier, u.dtype)
-            p = mm(u, self.in_proj) * jnp.asarray(self._mup, u.dtype)
-            d = cfg.mamba_d_ssm
-            return (p[..., :d], p[..., d:d + cfg.conv_dim],
-                    p[..., d + cfg.conv_dim:])
-
-    def heads(self, xbc):
-        """The convolved ``[..., conv_dim]`` -> x ``[..., heads,
-        d_head]``, B, C ``[..., groups, d_state]``."""
-        cfg = self.cfg
-        d, gn = cfg.mamba_d_ssm, cfg.mamba_n_groups * cfg.mamba_d_state
-        lead = xbc.shape[:-1]
-        return (xbc[..., :d].reshape(lead + (cfg.mamba_n_heads,
-                                             cfg.mamba_d_head)),
-                xbc[..., d:d + gn].reshape(lead + (cfg.mamba_n_groups,
-                                                   cfg.mamba_d_state)),
-                xbc[..., d + gn:].reshape(lead + (cfg.mamba_n_groups,
-                                                  cfg.mamba_d_state)))
-
-    def step_size(self, dt):
-        """``softplus(dt + dt_bias)`` in float32 (no clamp: the
-        published ``time_step_limit`` is (0, inf))."""
-        return jax.nn.softplus(dt.astype(jnp.float32)
-                               + self.dt_bias._data.astype(jnp.float32))
-
-    def decay_rate(self):
-        return -jnp.exp(self.A_log._data.astype(jnp.float32))
-
-    def gate_and_project(self, y, z):
-        """``(RMS_grouped(y * silu(z)) * weight) W_out``; y float32
-        ``[..., heads, d_head]``, z ``[..., d_ssm]``."""
-        cfg = self.cfg
-        with jax.named_scope("norm"):
-            g = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
-            grouped = g.reshape(g.shape[:-1] + (cfg.mamba_n_groups, -1))
-            grouped = grouped * jax.lax.rsqrt(
-                jnp.mean(grouped * grouped, -1, keepdims=True)
-                + cfg.rms_norm_eps)
-            g = (grouped.reshape(g.shape)
-                 * self.norm.weight._data.astype(jnp.float32)).astype(z.dtype)
-        with jax.named_scope("out"):
-            return mm(g, self.out_proj)
-
-    # -- a whole sequence -----------------------------------------------
-    def full(self, u, valid=None):
-        """u ``[B, S, H]`` -> (SSM ``[B, S, H]``, xBC ``[B, S,
-        conv_dim]`` before the convolution, the recurrent state ``[B,
-        heads, d_head, d_state]`` float32 after the last VALID position:
-        where ``valid [B, S]`` is false the step is 0 and the state
-        stands still)."""
-        cfg = self.cfg
-        z, xbc, dt = self.project(u)
-        with jax.named_scope("conv"):
-            S = xbc.shape[1]
-            padded = jnp.pad(xbc, ((0, 0), (self.taps - 1, 0), (0, 0)))
-            w = self.conv_weight._data
-            conv = self.conv_bias._data + sum(
-                w[j] * padded[:, j:j + S] for j in range(self.taps))
-            x, Bm, Cm = self.heads(jax.nn.silu(conv))
-        with jax.named_scope("scan"):
-            dt = self.step_size(dt)
-            if valid is not None:
-                dt = jnp.where(valid[..., None], dt, 0.0)
-            A, D = self.decay_rate(), self.D._data
-            y, H = jax.vmap(lambda *a: ssd_chunk_scan(
-                a[0], a[1], A, a[2], a[3], D, cfg.mamba_chunk_size))(
-                    x, dt, Bm, Cm)
-        return self.gate_and_project(y, z), xbc, H
-
-    # -- one token --------------------------------------------------------
-    def step(self, u, conv_state, recur):
-        """u ``[B, H]``, conv_state ``[B, d_conv - 1, conv_dim]`` (the
-        last xBC's, oldest first) -> (SSM ``[B, H]``, the state shifted
-        by this xBC). ``recur(x, B, C, dt, A, D) -> y`` steps the
-        recurrent state wherever it is kept (a dense array, a slot of
-        the serving pool) and returns y ``[B, heads, d_head]`` f32."""
-        z, xbc, dt = self.project(u)
-        with jax.named_scope("conv"):
-            window = jnp.concatenate(
-                [conv_state.astype(xbc.dtype), xbc[:, None]], axis=1)
-            conv = self.conv_bias._data + jnp.sum(
-                window * self.conv_weight._data[None], axis=1)
-            x, Bm, Cm = self.heads(jax.nn.silu(conv))
-        with jax.named_scope("step"):
-            y = recur(x, Bm, Cm, self.step_size(dt), self.decay_rate(),
-                      self.D._data)
-        return self.gate_and_project(y, z), window[:, 1:]
+        super().__init__(
+            cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_head,
+            cfg.mamba_n_groups, cfg.mamba_d_state, cfg.mamba_d_conv,
+            cfg.mamba_chunk_size, cfg.rms_norm_eps, cfg.initializer_range,
+            cfg.dtype, cfg.ssm_in_multiplier, cfg.ssm_multipliers)
 
 
 class FalconH1DecoderLayer(nn.Layer):

@@ -45,7 +45,10 @@ DOES go through ``decode_step`` (empty caches). The LFM2-MoE family is
 in ``lfm2_family.py``, the SDAR-MoE family in ``sdar_family.py``, the
 DeepSeek-V2 family (latent attention: one pool) in
 ``deepseek_family.py``, the Falcon-H1 family (a state-space mixer beside
-attention in every layer: two state kinds) in ``falcon_h1_family.py``.
+attention in every layer: two state kinds) in ``falcon_h1_family.py``,
+the Nemotron-H family (ONE mixer a layer: state, K/V and the routing
+record count three different sets of layers) in
+``nemotron_h_family.py``.
 """
 
 from __future__ import annotations
@@ -162,7 +165,8 @@ class ModelFamily:
         """What ``decode.dispatch`` says of the paged kernel's work on a
         step's ``tables`` (``[rows, pages]``, ``live_pages`` a row):
         ``kernel_pages_per_block``, the pages the family's kernel
-        gathers per compute block in this page bucket's program."""
+        gathers per compute block in this page bucket's program (a
+        family adds what else the span should say of its step)."""
         from .paged_attention import kernel_pages_per_block
         # a block's positions ride the query tile as so many more heads
         return dict(kernel_pages_per_block=kernel_pages_per_block(
@@ -267,17 +271,20 @@ def served_classes(config) -> tuple:
     from ..models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
     from ..models.gpt import GPTConfig, GPTForCausalLM
     from ..models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
+    from ..models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
     from ..models.sdar import SdarMoeConfig, SdarMoeForCausalLM
     from .deepseek_family import DeepseekV2Family
     from .falcon_h1_family import FalconH1Family
     from .lfm2_family import Lfm2MoeFamily
+    from .nemotron_h_family import NemotronHFamily
     from .sdar_family import SdarMoeFamily
     for config_class, classes in (
             (GPTConfig, (GPTForCausalLM, GPTFamily)),
             (Lfm2MoeConfig, (Lfm2MoeForCausalLM, Lfm2MoeFamily)),
             (SdarMoeConfig, (SdarMoeForCausalLM, SdarMoeFamily)),
             (DeepseekV2Config, (DeepseekV2ForCausalLM, DeepseekV2Family)),
-            (FalconH1Config, (FalconH1ForCausalLM, FalconH1Family))):
+            (FalconH1Config, (FalconH1ForCausalLM, FalconH1Family)),
+            (NemotronHConfig, (NemotronHForCausalLM, NemotronHFamily))):
         if isinstance(config, config_class):
             return classes
     raise TypeError(

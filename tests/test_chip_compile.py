@@ -331,6 +331,53 @@ def test_ssm_state_step_cell_shape(one_chip):
                 if " copy(" in ln and "[4,129,32,128,256]" in ln]
 
 
+def test_paged_decode_group_of_sixteen_cell_shape(one_chip):
+    """What `nemotron3n-serve-reason2k-backlog` runs: 32 query heads
+    over 2 key/value heads of 128 — a group of 16, two 8-row tiles a
+    key/value head — ONE decode program of 256 rows x 256 pages (4,096
+    positions) over a 65,536-block pool of the model's one attention
+    layer, on the single-softmax body."""
+    assert pa._lane_group(2, 128, 32) == (1, 16, 128)
+    assert pa.fits_single_softmax(256, 16, 128, BF16, None, 32, 2)
+    pool = ((1, 65536, 16, 2 * 128), BF16)
+    avals = (((256, 1, 32, 128), BF16), pool, pool,
+             ((256, 256), jnp.int32), ((256,), jnp.int32))
+    fn = functools.partial(pa.paged_attention_decode, interpret=False,
+                           layer=0)
+    text = _compile(one_chip, fn, *avals, kernels=["paged_decode"]).as_text()
+    assert "paged_decode_split" not in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[1,65536,16,256]" in ln]
+
+
+def test_ssm_state_step_eight_groups_cell_shape(one_chip):
+    """What `nemotron3n-serve-reason2k-backlog` runs: 256 rows against a
+    float32 pool of 4 state-space layers x 257 slots x [64, 64, 128]
+    (2.16 GB), EIGHT groups (8 heads share a B and a C), updated where
+    it lies."""
+    from paddle2_tpu.kernels import ssd
+    pool_shape = (4, 257, 64, 64, 128)
+
+    def step(pool, slots, x, B, C, dt, A, D):
+        return ssd.ssm_state_step(pool, 3, slots, x, B, C, dt, A, D,
+                                  interpret=False)
+
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (pool_shape, F32), ((256,), jnp.int32), ((256, 64, 64), BF16),
+        ((256, 8, 128), BF16), ((256, 8, 128), BF16), ((256, 64), F32),
+        ((64,), F32), ((64,), F32))]
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(*avals).compile()
+    text = compiled.as_text()
+    assert [ln for ln in text.splitlines()
+            if "tpu_custom_call" in ln and "ssm_state_step" in ln]
+    pool_bytes = math.prod(pool_shape) * 4
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert stats.temp_size_in_bytes < pool_bytes // 8
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[4,257,64,64,128]" in ln]
+
+
 def test_paged_decode_block_of_positions_cell_shape(one_chip):
     """What `sdar-serve-gen512-backlog` runs: a pass carries 4 positions
     of each of 64 sequences, 32 query heads over 4 key/value heads of
@@ -535,6 +582,36 @@ def test_moe_gmm_held_group_cell_shapes(one_chip, rows, k, n):
     256]`` and ``[1536, 1024]`` weight tiles)."""
     _compile(one_chip, _gmm_fn, ((rows, k), BF16), ((20, k, n), BF16),
              ((161,), jnp.int32), ((), jnp.int32), kernels=["moe_gmm"])
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    (1536, 2688, 1920), (1536, 1920, 2688),     # a step: 256 rows x 6
+    (12288, 2688, 1920), (12288, 1920, 2688)])  # a 2048-token prefill
+def test_moe_gmm_contiguous_half_cell_shapes(one_chip, rows, k, n):
+    """`nemotron3n-serve-reason2k-backlog`: 64 experts held of 128 routed
+    over (a 129th group parks the rest), 6 a row; the experts' width of
+    1,856 is STORED as 1,920 lanes (128 rows a tile; ``[2688, 384]`` and
+    ``[1920, 384]`` weight tiles). Nothing of the weights' size is
+    copied on the way to the kernel."""
+    text = _compile(one_chip, _gmm_fn, ((rows, k), BF16), ((64, k, n), BF16),
+                    ((129,), jnp.int32), ((), jnp.int32),
+                    kernels=["moe_gmm"]).as_text()
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"bf16[64,{k},{n}]" in ln]
+
+
+def test_moe_gmm_refuses_the_unpadded_width(one_chip):
+    """Why the width is stored padded: at the published 1,856 lanes (14.5
+    x 128) no multiple of 128 divides the width, the column tile is all
+    of it, and two ``[2688, 1856]`` weight tiles overrun the scoped VMEM
+    (19.69 MB of 16). (A ragged last column tile compiles, but the chip
+    keeps ``bf16[64, 2688, 1856]`` with the 2,688 axis minor and the
+    program then copies all 638 MB of the weights before every call:
+    PERF.md section 6, PR 41.)"""
+    with pytest.raises(Exception, match="vmem"):
+        _compile(one_chip, _gmm_fn, ((1536, 2688), BF16),
+                 ((64, 2688, 1856), BF16), ((129,), jnp.int32),
+                 ((), jnp.int32), kernels=["moe_gmm"])
 
 
 @pytest.mark.parametrize("rows", [64, 3072])

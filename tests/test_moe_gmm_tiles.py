@@ -1,9 +1,10 @@
 """The grouped matmul's tile plan (``kernels/moe_gmm.py``, PERF.md
 section 6, PR 40): the row tile follows the rows a group gets, the
 column tile is the widest the fast memory allows, and a row's result
-does not depend on the tile it rode in — at the static shapes the three
+does not depend on the tile it rode in — at the static shapes the
 MoE cells reach (rows, groups, held share; K and N reduced for the
-interpreter), at every row tile the rule can return."""
+interpreter), at every row tile the rule can return. The fourth cell's
+shapes (64 of 128 experts held, 6 a row) ride beside them."""
 
 import numpy as np
 import pytest
@@ -23,6 +24,8 @@ CELL_SHAPES = [
     ("lfm2.prefill3072", 3072, 4, 64, 0, 64, 2048, 1536),
     ("dsv2.decode", 128, 6, 160, 0, 20, 5120, 1536),
     ("dsv2.prefill5120", 5120, 6, 160, 0, 20, 5120, 1536),
+    ("nemotron.decode", 256, 6, 128, 0, 64, 2688, 1920),
+    ("nemotron.prefill2048", 2048, 6, 128, 0, 64, 2688, 1920),
 ]
 ROW_TILES = (64, 128, 256)
 
@@ -96,6 +99,12 @@ PICKED = [
     ("dsv2.prefill3072.up", 18432, 161, 5120, 1536, 128, 256),
     ("dsv2.prefill5120.up", 30720, 161, 5120, 1536, 128, 256),
     ("dsv2.prefill5120.down", 30720, 161, 1536, 5120, 128, 1024),
+    # a width of 1,856 lanes stored as 1,920 (15 x 128)
+    ("nemotron.decode.up", 1536, 129, 2688, 1920, 128, 384),
+    ("nemotron.decode.down", 1536, 129, 1920, 2688, 128, 384),
+    ("nemotron.prefill512.up", 3072, 129, 2688, 1920, 128, 384),
+    ("nemotron.prefill2048.up", 12288, 129, 2688, 1920, 128, 384),
+    ("nemotron.prefill2048.down", 12288, 129, 1920, 2688, 128, 384),
     # a group's rows fill the tall tile: 8 experts, 512 rows each
     ("tall", 4096, 9, 2048, 1536, 256, 768),
     # rows that 128 does not divide, a width that 128 does not divide
