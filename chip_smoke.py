@@ -263,6 +263,7 @@ def kernel_parity(size: dict) -> dict:
                     f"reference by {gap}")
     err.update(gqa_parity(size))
     err.update(gmm_parity(size))
+    err.update(ssm_step_parity(size))
     err.update(flash_parity(size))
     err.update(mla_split(size))
     return err
@@ -517,6 +518,55 @@ def gmm_parity(size: dict) -> dict:
                     raise AssertionError(
                         f"moe_gmm ({cell}, {name}, {rows} rows) off the "
                         f"plain loop by {gap}")
+    return err
+
+
+def ssm_step_parity(size: dict) -> dict:
+    """The recurrent state step against its ``jnp`` formula at the two
+    state-space cells' mixer shapes and row buckets where the size
+    allows (Nemotron-3-Nano: 256 rows x 64 heads of ``[64, 128]`` in 8
+    groups; Falcon-H1: 128 rows x 32 heads of ``[128, 256]`` in 2), over
+    a shuffled pool with padded rows in slot 0. Only the chip can show
+    it: the kernel puts ``dt x`` on the state's lanes as a product of
+    three bfloat16 parts with ones, exact only while the compiler keeps
+    the parts as they were cut (PR 42: parts rounded through ``astype``
+    came out as ONE bfloat16 on the chip and equal on the CPU, ``y`` off
+    by 0.06, inside what the cells' ``correct`` allows)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle2_tpu.kernels.ssd import ssm_state_step, ssm_state_step_xla
+    big = size["hidden"] >= 1024
+    # (name, rows, heads, groups, P, N)
+    cells = ([("nemotron3n", 256, 64, 8, 64, 128),
+              ("falconh1", 128, 32, 2, 128, 256)] if big
+             else [("tiny", 6, 8, 4, 16, 128)])
+    rng = np.random.default_rng(3)
+    err = {}
+    for cell, R, nh, G, P, N in cells:
+        pool = jnp.asarray(rng.normal(size=(2, R + 1, nh, P, N)),
+                           jnp.float32)
+        slots = rng.permutation(np.arange(1, R + 1)).astype(np.int32)
+        slots[-2:] = 0                                  # padded rows
+        live = slots > 0
+        args = (jnp.asarray(slots),
+                jnp.asarray(rng.normal(size=(R, nh, P)), jnp.bfloat16),
+                jnp.asarray(rng.normal(size=(R, G, N)), jnp.float32),
+                jnp.asarray(rng.normal(size=(R, G, N)), jnp.float32),
+                jnp.asarray(rng.uniform(0.01, 0.3, (R, nh)), jnp.float32),
+                -jnp.asarray(rng.uniform(0.5, 2.0, nh), jnp.float32),
+                jnp.asarray(rng.normal(size=nh), jnp.float32))
+        want_pool, want_y = jax.jit(ssm_state_step_xla)(pool, 1, *args)
+        got_pool, got_y = jax.jit(ssm_state_step)(pool, 1, *args)
+        gap_h = float(jnp.abs(got_pool - want_pool)[:, 1:].max())
+        gap_y = float(jnp.abs(got_y - want_y)[live].max())
+        err[f"ssm_step.{cell}.state"] = gap_h
+        err[f"ssm_step.{cell}.y"] = gap_y
+        # float32 throughout: the state differs by a rounding of its
+        # two products at most, y by the order of a sum over N lanes
+        if not gap_h <= 1e-5 or not gap_y <= 1e-4:
+            raise AssertionError(
+                f"ssm_state_step ({cell}) off the jnp formula: state "
+                f"{gap_h}, y {gap_y}")
     return err
 
 

@@ -18,8 +18,9 @@ sums and the state are float32 throughout. Three forms:
   of per-sequence states (decode): a Pallas kernel named
   ``ssm_state_step`` that takes the rows' slot ids by scalar prefetch
   and updates the pool IN PLACE (the pool is aliased input -> output),
-  a row's state streamed through VMEM in blocks of heads: each state
-  byte is read once and written once.
+  a row's state streamed through VMEM in blocks of heads
+  (:func:`state_step_plan`: as many whole groups as a byte budget
+  holds): each state byte is read once and written once.
 """
 
 from __future__ import annotations
@@ -34,7 +35,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ._platform import interpret_default
 
 __all__ = ["ssm_recurrence", "ssd_chunk_scan", "ssm_state_step",
-           "ssm_state_step_xla"]
+           "ssm_state_step_xla", "state_step_plan", "state_step_vmem_bytes",
+           "state_step_counts"]
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -143,46 +145,128 @@ def ssm_state_step_xla(pool, layer, slots, x, B, C, dt, A, D):
     return pool.at[layer, slots].set(H), y
 
 
-def _step_kernel(slots_ref, layer_ref, h_ref, da_ref, dtx_ref, b_ref, c_ref,
-                 h_out, y_ref, *, heads_per_block, heads_per_group):
-    # one row's block of heads: h_ref [hb, P, N]; da_ref [hb, N] (the
-    # head's decay on every lane); dtx_ref [P, hb] (dt x, a head a
-    # lane: its column broadcasts over the state lanes); b_ref, c_ref
-    # [G, N]; y_ref [P, hb]
+# The most float32 state one grid step holds. On the chip (PERF.md
+# section 6, PR 42) a call's time falls with the block up to the chip's
+# own ceiling for a stream read and written (658 GB/s), reached at 1 MB
+# by a [128, 256] head and at 2 MB by a [64, 128] one; in and out, each
+# double-buffered, 2 MB is half of the default scoped VMEM.
+STATE_BLOCK_BYTES = 2 << 20
+_LANES = 128
+
+
+def state_step_plan(nh: int, G: int, P: int, N: int):
+    """(heads a grid step holds, grid steps a row) of :func:`ssm_state_step`
+    at ``nh`` heads of ``[P, N]`` in ``G`` groups: the LARGEST divisor
+    of ``nh`` that is a whole number of groups or a divisor of one
+    group, with its float32 state within ``STATE_BLOCK_BYTES`` (one head
+    where none is). Static shapes only."""
+    per_group = nh // G
+    fits = [hb for hb in range(1, nh + 1)
+            if nh % hb == 0 and (hb % per_group == 0 or per_group % hb == 0)
+            and hb * P * N * 4 <= STATE_BLOCK_BYTES]
+    hb = max(fits, default=1)
+    return hb, nh // hb
+
+
+def _heads_per_chunk(hb: int) -> int:
+    # heads whose dt x share one 128-lane tile: three bf16 a head
+    return max(c for c in range(1, _LANES // 3 + 1) if hb % c == 0)
+
+
+def state_step_vmem_bytes(nh: int, G: int, P: int, N: int) -> int:
+    """Scoped VMEM a call asks for under the plan: every block twice
+    (the pipeline's two buffers) — the state in and out, the decays on
+    every lane, dt x in three bf16 a head, B, C and y."""
+    hb, _ = state_step_plan(nh, G, P, N)
+    chunks = hb // _heads_per_chunk(hb)
+    return 2 * (2 * hb * P * N * 4 + hb * N * 4 + P * chunks * _LANES * 2
+                + 2 * G * N * 4 + P * hb * 4)
+
+
+def state_step_counts(rows: int, state_shape, G: int) -> dict:
+    """What ``decode.dispatch`` says of a step's calls over a bucket of
+    ``rows`` against a state kind of ``state_shape`` ``(layers, nh, P,
+    N)`` in ``G`` groups: the state a grid step holds, and the grid
+    steps of all its layers."""
+    layers, nh, P, N = state_shape
+    hb, steps = state_step_plan(nh, G, P, N)
+    return dict(ssm_block_bytes=hb * P * N * 4,
+                ssm_grid_steps=rows * layers * steps)
+
+
+def _split3(x):
+    """float32 -> three bfloat16 that sum to it exactly: each part is
+    the leading 8 bits of what the parts before left, CUT off as
+    integers. (Rounding through ``astype`` and back is what XLA's
+    ``xla_allow_excess_precision`` removes on the chip: the parts after
+    the first then come out zero, PERF.md section 6, PR 42.)"""
+    def leading(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32)
+
+    hi = leading(x)
+    mid = leading(x - hi)
+    lo = x - hi - mid
+    return tuple(part.astype(jnp.bfloat16) for part in (hi, mid, lo))
+
+
+def _step_kernel(slots_ref, layer_ref, h_ref, da_ref, d3_ref, b_ref, c_ref,
+                 h_out, y_ref, *, heads_per_group, steps):
+    # one row's block of heads, whole groups or part of one: h_ref [hb,
+    # P, N]; da_ref [hb, N] (the head's decay on every lane); d3_ref [P,
+    # chunks x 128] bf16 (a chunk of heads a 128-lane tile: dt x in
+    # three bf16 parts, hi | mid | lo | zeros, a head a lane of each);
+    # b_ref, c_ref [G, N]; y_ref [P, hb] (a head a lane)
     del slots_ref, layer_ref            # the index maps read them
-    j = pl.program_id(1)
-    g = (j * heads_per_block) // heads_per_group
-    b_row = b_ref[pl.ds(g, 1), :]                            # [1, N]
-    c_row = c_ref[pl.ds(g, 1), :]
-    for i in range(heads_per_block):
-        col = dtx_ref[:, i:i + 1]                            # [P, 1]
-        new = h_ref[i] * da_ref[i:i + 1, :] + col * b_row
+    hb, _, N = h_ref.shape
+    per_chunk = _heads_per_chunk(hb)
+    lanes = min(N, _LANES)
+    first = pl.program_id(1) * hb if steps > 1 else 0
+    g0 = first // heads_per_group
+    ones = jnp.ones((_LANES, lanes), jnp.bfloat16)
+    for i in range(hb):
+        if i % heads_per_group == 0:
+            g = g0 + i // heads_per_group
+            b_row = b_ref[pl.ds(g, 1), :]                        # [1, N]
+            c_row = c_ref[pl.ds(g, 1), :]
+        chunk, lane = divmod(i, per_chunk)
+        if lane == 0:
+            # two bf16 rows a 32-bit word: masked as integers
+            d3 = pltpu.bitcast(
+                d3_ref[:, chunk * _LANES:(chunk + 1) * _LANES], jnp.int32)
+            lane_of = jax.lax.broadcasted_iota(jnp.int32, d3.shape, 1) \
+                % per_chunk
+        # dt x of head i on every state lane: its three parts summed by
+        # the idle MXU against ones (bf16 x 1 into float32: exact) — the
+        # lane broadcast of a column would be one XLU permute a vreg,
+        # beside the lane reduction of y
+        own = pltpu.bitcast(jnp.where(lane_of == lane, d3, 0), jnp.bfloat16)
+        col = jnp.dot(own, ones, preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.DEFAULT)       # [P, lanes]
+        new = h_ref[i] * da_ref[i:i + 1, :] \
+            + jnp.concatenate([col] * (N // lanes), axis=1) * b_row
         h_out[i] = new
         y_ref[:, i:i + 1] = jnp.sum(new * c_row, axis=-1, keepdims=True)
-
-
-def _heads_per_block(heads_per_group: int, P: int, N: int) -> int:
-    """Heads a grid step holds: a divisor of the group's heads, at most
-    1 MB of float32 state (in and out, each double-buffered, stay under
-    a quarter of the default scoped VMEM)."""
-    hb = heads_per_group
-    while hb > 1 and hb % 2 == 0 and hb * P * N * 4 > (1 << 20):
-        hb //= 2
-    return hb
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _state_step(pool, layer, slots, da, dtx, B, C, *, interpret):
     # da [R, nh] the heads' decays, dtx [R, nh, P] = dt x; handed to
     # the kernel a block of hb heads at a time: da on every state lane
-    # [R, J, hb, N], dtx a head a lane [R, J, P, hb] (y comes back so)
+    # [R, J, hb, N], dtx as _step_kernel reads it [R, J, P, chunks x
+    # 128], y comes back a head a lane [R, J, P, hb]
     L, S, nh, P, N = pool.shape
     R = slots.shape[0]
     G = B.shape[1]
-    hb = _heads_per_block(nh // G, P, N)
-    J = nh // hb
+    hb, J = state_step_plan(nh, G, P, N)
+    per_chunk = _heads_per_chunk(hb)
+    chunks = hb // per_chunk
     da_rows = jnp.broadcast_to(da.reshape(R, J, hb, 1), (R, J, hb, N))
-    dtx_t = jnp.swapaxes(dtx.reshape(R, J, hb, P), 2, 3)
+    d3 = jnp.stack(_split3(dtx.reshape(R, J, chunks, per_chunk, P)), 3)
+    d3 = jnp.pad(d3.reshape(R, J, chunks, 3 * per_chunk, P),
+                 ((0, 0),) * 3 + ((0, _LANES - 3 * per_chunk), (0, 0)))
+    d3 = jnp.transpose(d3, (0, 1, 4, 2, 3)).reshape(R, J, P, chunks * _LANES)
 
     def state_block():
         return pl.BlockSpec(
@@ -198,18 +282,16 @@ def _state_step(pool, layer, slots, da, dtx, B, C, *, interpret):
                             lambda r, j, slots, layer: (r, j, 0, 0))
 
     pool, y = pl.pallas_call(
-        functools.partial(_step_kernel, heads_per_block=hb,
-                          heads_per_group=nh // G),
+        functools.partial(_step_kernel, heads_per_group=nh // G, steps=J),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(R, nh // hb),
+            grid=(R, J),
             in_specs=[state_block(), head_block((hb, N)),
-                      head_block((P, hb)), row_block((G, N)),
+                      head_block((P, chunks * _LANES)), row_block((G, N)),
                       row_block((G, N))],
             out_specs=[state_block(), head_block((P, hb))]),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-                   jax.ShapeDtypeStruct((R, nh // hb, P, hb),
-                                        jnp.float32)],
+                   jax.ShapeDtypeStruct((R, J, P, hb), jnp.float32)],
         # the pool is updated where it lies: operand 2 (behind the two
         # prefetched scalars) is output 0
         input_output_aliases={2: 0},
@@ -218,7 +300,7 @@ def _state_step(pool, layer, slots, da, dtx, B, C, *, interpret):
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ssm_state_step",
-    )(slots, layer.reshape(1), pool, da_rows, dtx_t, B, C)
+    )(slots, layer.reshape(1), pool, da_rows, d3, B, C)
     return pool, jnp.swapaxes(y, 2, 3).reshape(R, nh, P)
 
 

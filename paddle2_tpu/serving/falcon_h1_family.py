@@ -27,7 +27,7 @@ discarded step's rows are re-prefilled by the engine (ROADMAP D13).
 
 from __future__ import annotations
 
-from ..kernels.ssd import ssm_state_step
+from ..kernels.ssd import ssm_state_step, state_step_counts
 from .model_runner import ModelFamily
 from .paged_attention import paged_attention_decode
 
@@ -55,6 +55,15 @@ class FalconH1Family(ModelFamily):
 
     def prefill_counts(self, padded: int) -> dict:
         return {"scan_chunks": -(-padded // self.model.cfg.mamba_chunk_size)}
+
+    def kernel_page_counts(self, cache, tables, live_pages,
+                           split_pages) -> dict:
+        # and what a grid step of the state step holds, over the bucket
+        return dict(super().kernel_page_counts(cache, tables, live_pages,
+                                               split_pages),
+                    **state_step_counts(tables.shape[0],
+                                        self.state_kinds["ssm"][0],
+                                        self.model.cfg.mamba_n_groups))
 
     def prefill(self, ids, last_idx, interpret):
         import jax

@@ -26,7 +26,7 @@ are re-prefilled by the engine (ROADMAP D13).
 from __future__ import annotations
 
 from ..incubate.moe import DroplessExperts
-from ..kernels.ssd import ssm_state_step
+from ..kernels.ssd import ssm_state_step, state_step_counts
 from .model_runner import ModelFamily
 from .paged_attention import paged_attention_decode
 
@@ -64,9 +64,15 @@ class NemotronHFamily(ModelFamily):
 
     def kernel_page_counts(self, cache, tables, live_pages,
                            split_pages) -> dict:
-        return dict(super().kernel_page_counts(cache, tables, live_pages,
-                                               split_pages),
-                    **self.layer_counts)
+        counts = dict(super().kernel_page_counts(cache, tables, live_pages,
+                                                 split_pages),
+                      **self.layer_counts)
+        if self.state_kinds:
+            # what a grid step of the state step holds, over the bucket
+            counts.update(state_step_counts(
+                tables.shape[0], self.state_kinds["ssm"][0],
+                self.model.cfg.n_groups))
+        return counts
 
     def prefill(self, ids, last_idx, interpret):
         import jax

@@ -296,6 +296,23 @@ def test_paged_decode_group_of_five_cell_shape(one_chip):
                 if " copy(" in ln and "[4,20480,16,512]" in ln]
 
 
+def _state_step_fits_the_default_vmem(nh, G, P, N, calls):
+    """The call asks for no scoped-VMEM limit of its own, and what the
+    compiler gave it — the state block in and out, twice each — is what
+    the plan reckons (within 2 %) and well inside the 16 MiB a kernel
+    gets without asking. (A 4 MB block compiled and ran without a limit
+    too: PERF.md section 6, PR 42 — there is no refusal to pin.)"""
+    from paddle2_tpu.kernels import ssd
+    reckoned = ssd.state_step_vmem_bytes(nh, G, P, N)
+    for ln in calls:
+        assert '"scoped_memory_configs":[]' in ln
+        used = [int(n) for n in re.findall(
+            r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', ln)]
+        assert used and 4 * ssd.STATE_BLOCK_BYTES <= max(used) \
+            <= 1.02 * reckoned
+    assert reckoned < (16 << 20) * 0.55
+
+
 def test_ssm_state_step_cell_shape(one_chip):
     """What `falconh1-serve-gen1k-backlog` runs: 128 rows against a
     float32 pool of 4 layers x 129 slots x [32, 128, 256] (2.16 GB),
@@ -329,6 +346,12 @@ def test_ssm_state_step_cell_shape(one_chip):
     assert stats.temp_size_in_bytes < pool_bytes // 8
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and "[4,129,32,128,256]" in ln]
+    # the grid is the plan's: one group of 16 heads (2 MB) a grid step,
+    # two grid steps a row — y comes back [rows, steps, P, heads]
+    assert ssd.state_step_plan(32, 2, 128, 256) == (16, 2)
+    assert all("f32[128,2,128,16]" in ln.split(" custom-call(")[0]
+               for ln in calls)
+    _state_step_fits_the_default_vmem(32, 2, 128, 256, calls)
 
 
 def test_paged_decode_group_of_sixteen_cell_shape(one_chip):
@@ -368,14 +391,21 @@ def test_ssm_state_step_eight_groups_cell_shape(one_chip):
         ((64,), F32), ((64,), F32))]
     compiled = jax.jit(step, donate_argnums=(0,)).lower(*avals).compile()
     text = compiled.as_text()
-    assert [ln for ln in text.splitlines()
-            if "tpu_custom_call" in ln and "ssm_state_step" in ln]
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "ssm_state_step" in ln]
+    assert calls
     pool_bytes = math.prod(pool_shape) * 4
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= pool_bytes
     assert stats.temp_size_in_bytes < pool_bytes // 8
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and "[4,257,64,64,128]" in ln]
+    # the grid is the plan's: the whole row, all eight groups (2 MB), a
+    # grid step — 256 grid steps a call where one group a step was 2,048
+    assert ssd.state_step_plan(64, 8, 64, 128) == (64, 1)
+    assert all("f32[256,1,64,64]" in ln.split(" custom-call(")[0]
+               for ln in calls)
+    _state_step_fits_the_default_vmem(64, 8, 64, 128, calls)
 
 
 def test_paged_decode_block_of_positions_cell_shape(one_chip):

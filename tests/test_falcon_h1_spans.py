@@ -79,6 +79,7 @@ def test_a_family_without_state_says_nothing_of_it(tmp_path_factory):
     spans = serve_traced(tmp_path_factory, engine, prompts)
     for c in steps_of(spans):
         assert "state_bytes" not in c and "state_reprefills" not in c
+        assert "ssm_block_bytes" not in c and "ssm_grid_steps" not in c
     assert not any("scan_chunks" in s[3] for s in spans)
 
 
@@ -172,3 +173,67 @@ def test_the_roofline_reader_counts_the_engines_real_rows(readers,
 def test_the_scope_readers_say_nothing_without_device_ops(readers):
     assert readers.read("ssm_device_pct.serve") is None
     assert readers.read("prefill_ssm_device_pct.serve") is None
+
+
+# -- what a grid step of the state step holds (PR 42) -----------------------
+def test_dispatch_says_what_a_grid_step_of_the_state_step_holds(
+        falcon_traced):
+    """``ssm_block_bytes`` is the kernel's own plan at the mixer's shape
+    (here the whole row: 4 heads of [16, 16] float32), ``ssm_grid_steps``
+    the row BUCKET x the layers x the grid steps a row."""
+    from paddle2_tpu.kernels import ssd
+    engine, spans = falcon_traced
+    cfg = engine.model.cfg
+    shape = (cfg.mamba_n_heads, cfg.mamba_n_groups, cfg.mamba_d_head,
+             cfg.mamba_d_state)
+    hb, per_row = ssd.state_step_plan(*shape)
+    assert (hb, per_row) == (4, 1)
+    steps = steps_of(spans)
+    assert len(steps) == 3
+    for c in steps:
+        assert c["ssm_block_bytes"] == 4 * 16 * 16 * 4
+        assert c["ssm_grid_steps"] == c["row_bucket"] \
+            * cfg.num_hidden_layers * per_row
+
+
+def test_a_smaller_budget_shows_on_the_span(monkeypatch):
+    """The counts follow the plan, not a constant: under a budget of one
+    head the same engine says 1 KB blocks and four grid steps a row."""
+    from paddle2_tpu.kernels import ssd
+    engine = engine_of(FalconH1ForCausalLM, falcon_h1_tiny())
+    monkeypatch.setattr(ssd, "STATE_BLOCK_BYTES", 16 * 16 * 4)
+    counts = engine.runner.kernel_page_counts(
+        engine.cache, np.zeros((4, 4), np.int32), [1, 1, 1, 1])
+    assert counts["ssm_block_bytes"] == 16 * 16 * 4
+    assert counts["ssm_grid_steps"] \
+        == 4 * engine.model.cfg.num_hidden_layers * 4
+    assert "kernel_pages_per_block" in counts
+
+
+def test_the_block_reader_reads_the_plans_block(readers, falcon_traced):
+    _, spans = falcon_traced
+    assert readers.read("ssm_step_block_kb.serve") \
+        == pytest.approx(4 * 16 * 16 * 4 / 1024.0)
+    # a program whose spans lack the count (the parent's): None, no raise
+    import program_trace
+    pt = program_trace._LOADED["spans-of-the-test"]
+    pt.spans = [(n, a, b, {k: v for k, v in c.items()
+                           if not k.startswith("ssm_")})
+                for n, a, b, c in pt.spans]
+    assert readers.read("ssm_step_block_kb.serve") is None
+
+
+def test_the_block_metric_is_on_the_two_state_space_cells_lists():
+    import json
+    with open(os.path.join(os.path.dirname(BENCHMARK),
+                           "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry, = [m for m in manifest["per_layer"]
+              if m["name"] == "ssm_step_block_kb.serve"]
+    assert entry == {
+        "name": "ssm_step_block_kb.serve", "unit": "KB", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "serve_tokens_per_s",
+        "workloads": ["falconh1-serve-gen1k-backlog",
+                      "nemotron3n-serve-reason2k-backlog"]}
+    assert manifest["per_layer"][-1] is entry          # appended, last

@@ -227,3 +227,33 @@ def test_a_program_without_the_counts_says_nothing(readers):
         0: (2_000, 9)}
     assert readers.read("ssm_mixer_step_roofline_pct.serve") is None
     assert readers.read("moe_gmm2_roofline_pct.serve") is None
+
+
+# -- what a grid step of the state step holds (PR 42) -----------------------
+def test_dispatch_says_what_a_grid_step_of_the_state_step_holds(traced):
+    """Beside ``ssm_layers``: the plan's block at the mixer's shape (the
+    whole row here: 4 heads of [16, 16] float32 in 2 groups) and the
+    grid steps of the step's three state-space layers over the row
+    BUCKET."""
+    from paddle2_tpu.kernels import ssd
+    engine, spans = traced
+    cfg = engine.model.cfg
+    hb, per_row = ssd.state_step_plan(cfg.mamba_num_heads, cfg.n_groups,
+                                      cfg.mamba_head_dim, cfg.ssm_state_size)
+    assert (hb, per_row) == (4, 1)
+    for c in steps_of(spans):
+        assert c["ssm_block_bytes"] == 4 * 16 * 16 * 4
+        assert c["ssm_grid_steps"] == c["row_bucket"] \
+            * LAYERS["ssm_layers"] * per_row
+
+
+def test_the_block_reader_reads_the_plans_block(readers):
+    assert readers.read("ssm_step_block_kb.serve") \
+        == pytest.approx(4 * 16 * 16 * 4 / 1024.0)
+    import program_trace
+    pt = program_trace._LOADED["spans-of-the-test"]
+    pt.spans = [(n, a, b, {k: v for k, v in c.items()
+                           if k not in ("ssm_block_bytes", "ssm_grid_steps")})
+                for n, a, b, c in pt.spans]
+    # the parent's spans: ``ssm_layers`` but no block — None, no raise
+    assert readers.read("ssm_step_block_kb.serve") is None
