@@ -266,6 +266,7 @@ def kernel_parity(size: dict) -> dict:
     err.update(ssm_step_parity(size))
     err.update(flash_parity(size))
     err.update(mla_split(size))
+    err.update(paged_split(size))
     return err
 
 
@@ -360,6 +361,127 @@ def mla_split(size: dict) -> dict:
                 p.stop()
         out[f"mla.us_a_row.{name}"] = best / 20 / B * 1e6
     out["mla.live_pages_a_row"] = float(np.mean(-(-ctx // bs)))
+    return out
+
+
+def paged_split(size: dict) -> dict:
+    """The single-softmax paged body (``paged_decode``) where the size
+    allows at the decode shapes of the SDAR (64 rows x 4 positions, 32
+    query heads over 4 key/value heads of 128, 192 pages), Nemotron
+    (256 rows, 32 over 2, 256 pages) and Falcon-H1 (128 rows, 20 over
+    4, 160 pages) cells, on two tables each: ``cell`` — a prompt on one
+    ascending run of pages, then decoded tokens on scattered pages, as
+    the cells' allocator hands them out — and ``scattered`` — no two
+    consecutive pages anywhere. Against the dense reference, and its
+    time SPLIT as :func:`mla_split` splits the latent body's (PERF.md
+    section 6, PR 43, step 0): the whole body, its copies alone (scores,
+    softmax and values left out) and its arithmetic alone (no copy
+    started or waited for), us a row, best of 5 chains of twenty
+    distinct calls. Times are taken on the chip only."""
+    from unittest import mock
+    import jax
+    import jax.numpy as jnp
+    from paddle2_tpu.serving import paged_attention as pa
+    big = size["hidden"] >= 1024
+    bs = 16 if big else 8
+    # rows, positions a row, query heads, key/value heads, head dim,
+    # pages a row, prompt lengths
+    shapes = {
+        "sdar": (64, 4, 32, 4, 128, 192, (512, 1024, 2048)),
+        "nemotron": (256, 1, 32, 2, 128, 256, (512, 1024, 2048)),
+        "falconh1": (128, 1, 20, 4, 128, 160, (256, 512, 1024)),
+    } if big else {"tiny": (4, 2, 4, 2, 16, 32, (64, 128))}
+    f32 = jnp.float32
+
+    class NoCopy:
+        start = wait = staticmethod(lambda: None)
+
+    parts = {
+        "whole": [],
+        "copies_only": [mock.patch.multiple(
+            pa,
+            _block_scores=lambda q, k, *_: jnp.zeros(
+                (q.shape[0], k.shape[0]), f32),
+            _block_softmax=lambda s: s,
+            _block_values=lambda p, v: jnp.zeros(
+                (p.shape[0], v.shape[1]), f32))],
+        "arith_only": [mock.patch.object(pa.pltpu, "make_async_copy",
+                                         lambda *a, **k: NoCopy)],
+    }
+    out = {}
+    for shape, (B, Q, H, Hkv, D, P, prompt_lens) in shapes.items():
+        rng = np.random.default_rng(5)
+        prompts = rng.choice(prompt_lens, B)
+        ctx = (prompts + rng.integers(0, P * bs - max(prompt_lens) + 1, B)
+               ).astype(np.int32)
+        live = -(-ctx // bs)
+        n_blocks = B * P + 1
+        cell = np.zeros((B, P), np.int32)
+        behind = iter(rng.permutation(np.arange(B * P // 2, B * P)) + 1)
+        first = 1
+        for r in range(B):
+            run = prompts[r] // bs
+            cell[r, :run] = np.arange(first, first + run)
+            cell[r, run:live[r]] = [next(behind)
+                                    for _ in range(live[r] - run)]
+            first += run
+        # a row holds odd ids only or even ids only: no page follows
+        # its neighbour
+        ids = np.arange(1, n_blocks)
+        shuffled = np.concatenate([rng.permutation(ids[::2]),
+                                   rng.permutation(ids[1::2])])
+        tables = {"cell": cell,
+                  "scattered": shuffled.reshape(B, P).astype(np.int32)}
+        kp, vp = (jax.random.normal(jax.random.PRNGKey(k),
+                                    (1, n_blocks, bs, Hkv * D), jnp.bfloat16)
+                  for k in (0, 1))
+        q = jax.random.normal(jax.random.PRNGKey(2), (B, Q, H, D),
+                              jnp.bfloat16)
+        for kind, table in tables.items():
+            got = np.asarray(pa.paged_attention_decode(
+                q, kp, vp, table, ctx), np.float32)
+            # every position of a row sees the same context
+            ref = np.stack([np.asarray(pa.paged_attention_reference(
+                q[:4, p:p + 1], kp[0], vp[0], table[:4], ctx[:4]),
+                np.float32)[:, 0] for p in range(Q)], 1)
+            gap = out[f"paged.{shape}.{kind}"] = float(
+                np.abs(got[:4] - ref).max())
+            if not np.isfinite(got).all() or gap > 2e-2:
+                raise AssertionError(f"paged kernel ({shape}, {kind} table) "
+                                     f"off the dense reference by {gap}")
+        if not big:
+            continue
+        for name, patches in parts.items():
+            for p in patches:
+                p.start()
+            # the patched names are not in the key of the jitted call
+            pa._decode_single.clear_cache()
+            try:
+                @jax.jit
+                def chain(q, kp, vp, table, ctx):
+                    # distinct operands: identical calls would be merged
+                    return sum(pa.paged_attention_decode(
+                        jnp.roll(q, k, 0), kp, vp, table, ctx,
+                        interpret=False)[:, 0, 0, 0].astype(f32)
+                        for k in range(20))
+
+                for kind, table in tables.items():
+                    args = (q, kp, vp, jnp.asarray(table), jnp.asarray(ctx))
+                    chain(*args).block_until_ready()
+                    best = math.inf
+                    for _ in range(5):
+                        t0 = time.perf_counter()
+                        chain(*args).block_until_ready()
+                        best = min(best, time.perf_counter() - t0)
+                    out[f"paged.{shape}.{kind}.us_a_row.{name}"] = \
+                        best / 20 / B * 1e6
+            finally:
+                for p in patches:
+                    p.stop()
+                pa._decode_single.clear_cache()
+        out[f"paged.{shape}.live_pages_a_row"] = float(live.mean())
+        out[f"paged.{shape}.prompt_pages_a_row"] = float(
+            (prompts // bs).mean())
     return out
 
 

@@ -249,7 +249,7 @@ class BlockAllocator:
         # they were freed in — a table freed whole comes back ascending
         # where it was ascending, runs of consecutive pages that ONE
         # copy of a paged kernel fetches (paged_attention.
-        # mla_coalesced_pages); still LIFO by sequence
+        # coalesced_pages); still LIFO by sequence
         for b in reversed(blocks):
             self._rc[b] -= 1
             if self._rc[b] == 0:

@@ -28,7 +28,7 @@ from __future__ import annotations
 from ..incubate.moe import DroplessExperts
 from .block_cache import GARBAGE_BLOCK
 from .model_runner import ModelFamily
-from .paged_attention import (mla_coalesced_pages, mla_pages_per_block,
+from .paged_attention import (coalesced_pages, mla_pages_per_block,
                               mla_pages_per_copy, mla_row_width,
                               paged_mla_decode)
 
@@ -62,7 +62,7 @@ class DeepseekV2Family(ModelFamily):
             kernel_pages_per_block=mla_pages_per_block(*shape),
             # of the step's live pages, those the kernel fetches a run
             # of consecutive pages at a time
-            coalesced_pages=mla_coalesced_pages(
+            coalesced_pages=coalesced_pages(
                 tables[:len(live_pages)], live_pages,
                 mla_pages_per_copy(*shape)))
 

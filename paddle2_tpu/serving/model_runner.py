@@ -165,14 +165,21 @@ class ModelFamily:
         """What ``decode.dispatch`` says of the paged kernel's work on a
         step's ``tables`` (``[rows, pages]``, ``live_pages`` a row):
         ``kernel_pages_per_block``, the pages the family's kernel
-        gathers per compute block in this page bucket's program (a
-        family adds what else the span should say of its step)."""
-        from .paged_attention import kernel_pages_per_block
+        gathers per compute block in this page bucket's program, and
+        ``coalesced_pages``, those of the live pages it fetches a run
+        of consecutive pages at a time (a family adds what else the
+        span should say of its step)."""
+        from .paged_attention import (coalesced_pages, kernel_pages_per_block,
+                                      kernel_pages_per_copy)
         # a block's positions ride the query tile as so many more heads
-        return dict(kernel_pages_per_block=kernel_pages_per_block(
-            tables.shape[1], cache.block_size,
-            self.num_heads * self.row_positions, self.head_dim,
-            cache.dtype, split_pages, self.num_kv_heads))
+        shape = (tables.shape[1], cache.block_size,
+                 self.num_heads * self.row_positions, self.head_dim,
+                 cache.dtype, split_pages, self.num_kv_heads)
+        return dict(
+            kernel_pages_per_block=kernel_pages_per_block(*shape),
+            coalesced_pages=coalesced_pages(
+                tables[:len(live_pages)], live_pages,
+                kernel_pages_per_copy(*shape, cache.num_blocks)))
 
 
 class GPTFamily(ModelFamily):

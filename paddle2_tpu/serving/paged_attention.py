@@ -24,11 +24,16 @@ Two bodies behind ONE dispatcher (:func:`paged_attention_decode`):
   pools enter un-blocked (``memory_space=pl.ANY``) and the kernel
   walks the row's LIVE pages (``ceil(ctx / block_size)``; dead pages
   and the garbage block behind them never move) in *compute blocks*
-  of many pages: per page one async copy of K into half of a double
-  buffer and one of V to its place in a context-resident buffer,
-  driven by the block table, started one block ahead — the last block
-  of a row starts the first block of the next, so the copy latency is
-  paid once a call. All heads ride one query tile (row ``h`` holds
+  of many pages: K into half of a double buffer and V to its place
+  in a context-resident buffer, by async copies driven by the block
+  table — read in aligned groups of entries (:func:`_decode_plan`), of
+  which one that is all live and holds consecutive page ids arrives in
+  ONE copy of K and one of V (which groups are such runs is decided
+  from the table alone, once a program: :func:`_page_runs`), any other
+  page by copies of its own (:func:`_start_block`, shared with the
+  latent body) — started one block ahead: the last block of a row
+  starts the first block of the next, so the copy latency is paid once
+  a call. All heads ride one query tile (row ``h`` holds
   head ``h`` in its own lanes of the ``H*D`` row and zeros elsewhere),
   so ONE dot per block gives every head its lane-dense ``[R, tokens]``
   score rows and ONE dot gives every head its own output lanes. The
@@ -89,10 +94,11 @@ from ..kernels.pallas_flash import NEG_INF
 
 __all__ = ["paged_attention_decode", "paged_attention_reference",
            "paged_mla_decode", "paged_mla_reference", "mla_row_width",
-           "mla_pages_per_block", "mla_pages_per_copy", "mla_coalesced_pages",
+           "mla_pages_per_block", "mla_pages_per_copy", "coalesced_pages",
            "paged_attention_split_reference", "gathered_dense_kv",
            "decode_scratch_vmem_bytes", "fits_single_softmax",
            "auto_pages_per_split", "kernel_pages_per_block",
+           "kernel_pages_per_copy",
            "modeled_decode_latency_s",
            "VMEM_BYTES", "VMEM_FIT_BUDGET"]
 
@@ -158,21 +164,103 @@ def _block_softmax(s):
     return e / _page_sum(e, jnp.sum)
 
 
-def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   k_buf, v_buf, s_buf, slot_ref, sem, *, scale,
-                   block_size, pages_per_block, n_pages, batch):
+def _block_scores(q, k, scale, first_col, ctx):
+    """A compute block's masked scores ``[R, T]`` f32 of the query tile
+    ``q`` ``[R, W]`` against the block's keys ``k`` ``[T, W]`` (row
+    ``r``'s query is zero outside its own key/value head's lanes);
+    columns from ``ctx`` on hold ``finfo.min``."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        precision=_precision(q.dtype),
+        preferred_element_type=jnp.float32) * scale
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + first_col
+    return jnp.where(cols < ctx, s, jnp.finfo(jnp.float32).min)
+
+
+def _block_values(p, v):
+    """A compute block's ``p v``: probabilities ``[R, T]`` f32 over the
+    block's values ``[T, W]`` -> ``[R, W]`` f32."""
+    return jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        precision=_precision(v.dtype),
+        preferred_element_type=jnp.float32)
+
+
+def _pages(ref, lead, at, pages: int):
+    """``pages`` whole pages of ``ref`` from page ``at`` on, behind the
+    leading indices ``lead``: one page is an index, more are a slice."""
+    return ref.at[(*lead, at if pages == 1 else pl.ds(at, pages))]
+
+
+def _start_block(bt_ref, run_ref, row, i, n_live, ppb, ppc, copies):
+    """Start the copies of the ``n_live`` leading pages of compute block
+    ``i`` of ``row``. The table is read in aligned groups of ``ppc``
+    entries: a group that is all live and that ``run_ref`` marks as
+    consecutive page ids is ONE run, any other goes a page at a time —
+    all live: straight-line descriptors, whose address arithmetic the
+    scheduler interleaves (a third of the bundles of a loop step a
+    page); the ragged last group: a loop. ``copies(blk, at, pages)``:
+    the descriptors that bring ``pages`` pages from page ``blk`` of the
+    pool on to page ``at`` of the block; dead pages never move."""
+    def group(g, carry):
+        first = i * ppb + g * ppc
+        left = n_live - g * ppc
+        run = (left >= ppc) & (run_ref[row, i * (ppb // ppc) + g] == 1)
+
+        def page(j, c=None):
+            for cp in copies(bt_ref[row, first + j], g * ppc + j, 1):
+                cp.start()
+            return c
+
+        @pl.when(run)
+        def _run():
+            for cp in copies(bt_ref[row, first], g * ppc, ppc):
+                cp.start()
+
+        @pl.when(jnp.logical_not(run) & (left >= ppc))
+        def _scattered():
+            for j in range(ppc):
+                page(j)
+
+        @pl.when(left < ppc)
+        def _ragged():
+            jax.lax.fori_loop(0, left, page, 0)
+        return carry
+    jax.lax.fori_loop(0, (n_live + ppc - 1) // ppc, group, 0)
+
+
+def _wait_block(n_live, ppc, arrived):
+    """Wait for the ``n_live`` pages :func:`_start_block` started: the
+    semaphore counts bytes, so ``arrived(pages)`` — a descriptor of as
+    many pages — waits for them whatever copies brought them, a group
+    at a time and then the ragged rest a page at a time."""
+    def wait(pages):
+        def one(_, carry):
+            arrived(pages).wait()
+            return carry
+        return one
+    jax.lax.fori_loop(0, n_live // ppc, wait(ppc), 0)
+    jax.lax.fori_loop(0, n_live % ppc, wait(1), 0)
+
+
+def _decode_kernel(bt_ref, len_ref, run_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   o_ref, k_buf, v_buf, s_buf, slot_ref, sem, *, scale,
+                   block_size, pages_per_block, pages_per_copy, n_pages,
+                   batch):
     """One grid step = one sequence, all heads: walk the row's LIVE
     pages in compute blocks of ``pages_per_block`` pages. A block's K
     pages land in one half of the ``k_buf`` double buffer and its V
     pages at their place in the context-resident ``v_buf`` while the
-    block before it is scored; the row's last block starts the NEXT
-    row's first block, so the copy latency is exposed once per call,
-    not once per row. That one block of V arrives while this row's is
-    still being read, so the first block of every odd row lives in a
-    spare block behind the context's."""
+    block before it is scored — a run of ``pages_per_copy`` consecutive
+    pages in ONE copy of K and one of V, any other page by a copy of
+    its own (:func:`_start_block`); the row's last block starts the
+    NEXT row's first block, so the copy latency is exposed once per
+    call, not once per row. That one block of V arrives while this
+    row's is still being read, so the first block of every odd row
+    lives in a spare block behind the context's."""
     b = pl.program_id(0)
     layer = layer_ref[0]
-    ppb, bs = pages_per_block, block_size
+    ppb, ppc, bs = pages_per_block, pages_per_copy, block_size
     tokens = ppb * bs
     width = k_buf.shape[-1]
     fill = jnp.finfo(jnp.float32).min
@@ -180,36 +268,38 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
     def live_pages(row):
         return jnp.minimum((len_ref[row] + bs - 1) // bs, n_pages)
 
+    def block_pages(row, i):
+        # dead pages (and the garbage block behind them) never move
+        return jnp.clip(live_pages(row) - i * ppb, 0, ppb)
+
     def v_page(row, i):
         # where block i of this row starts in v_buf
         spare = v_buf.shape[0] - ppb
         return jnp.where((i == 0) & (row % 2 == 1), spare, i * ppb)
 
-    def page_copies(row, i, kslot, j):
-        # page j of compute block i: one contiguous [bs, H*D] copy
-        # each for K and V, straight out of the whole-model pool
-        blk = bt_ref[row, i * ppb + j]
-        return (pltpu.make_async_copy(k_hbm.at[layer, blk],
-                                      k_buf.at[kslot, j], sem.at[kslot]),
-                pltpu.make_async_copy(v_hbm.at[layer, blk],
-                                      v_buf.at[v_page(row, i) + j],
-                                      sem.at[kslot]))
-
-    def each_live_page(row, i, kslot, act):
-        # dead pages (and the garbage block behind them) never move
-        n_live = jnp.clip(live_pages(row) - i * ppb, 0, ppb)
-
-        def one(j, carry):
-            for cp in page_copies(row, i, kslot, j):
-                act(cp)
-            return carry
-        jax.lax.fori_loop(0, n_live, one, 0)
-
     def start(row, i, kslot):
-        each_live_page(row, i, kslot, lambda cp: cp.start())
+        v_first = v_page(row, i)
+
+        def copies(blk, at, pages):
+            # contiguous [pages, bs, H*D] of K and of V, straight out of
+            # the whole-model pools
+            return (pltpu.make_async_copy(
+                        _pages(k_hbm, (layer,), blk, pages),
+                        _pages(k_buf, (kslot,), at, pages), sem.at[kslot]),
+                    pltpu.make_async_copy(
+                        _pages(v_hbm, (layer,), blk, pages),
+                        _pages(v_buf, (), v_first + at, pages),
+                        sem.at[kslot]))
+        _start_block(bt_ref, run_ref, row, i, block_pages(row, i), ppb, ppc,
+                     copies)
 
     def wait(row, i, kslot):
-        each_live_page(row, i, kslot, lambda cp: cp.wait())
+        def arrived(pages):
+            # K's pages and V's: twice as many pages of one pool
+            return pltpu.make_async_copy(
+                v_hbm.at[layer, pl.ds(0, 2 * pages)],
+                v_buf.at[pl.ds(0, 2 * pages)], sem.at[kslot])
+        _wait_block(block_pages(row, i), ppc, arrived)
 
     @pl.when(b == 0)
     def _prime():
@@ -231,23 +321,17 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
     def score_block(i, kslot):
         nxt = 1 - kslot
 
-        @pl.when(i + 1 < n_blocks)
-        def _next_block():
-            start(b, i + 1, nxt)
+        more = i + 1 < n_blocks
 
-        @pl.when((i + 1 == n_blocks) & (b + 1 < batch))
-        def _next_row():
-            start(b + 1, 0, nxt)
+        @pl.when(more | (b + 1 < batch))
+        def _next():
+            # this row's next block, or the next row's first: ONE site
+            # for the copies' code, the longest of the body
+            start(jnp.where(more, b, b + 1), jnp.where(more, i + 1, 0), nxt)
 
         wait(b, i, kslot)
-        k = k_buf[kslot].reshape(tokens, width)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            precision=_precision(q.dtype),
-            preferred_element_type=jnp.float32) * scale   # (R, T)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
-            + i * tokens
-        s_buf[i] = jnp.where(cols < ctx, s, fill)
+        s_buf[i] = _block_scores(q, k_buf[kslot].reshape(tokens, width),
+                                 scale, i * tokens, ctx)
         return nxt
 
     slot_ref[0] = jax.lax.fori_loop(0, n_blocks, score_block,
@@ -256,10 +340,7 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def weigh_block(i, acc):
         v = v_buf[pl.ds(v_page(b, i), ppb)].reshape(tokens, width)
-        return acc + jax.lax.dot_general(
-            s_buf[i].astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            precision=_precision(v.dtype),
-            preferred_element_type=jnp.float32)           # (R, H*D)
+        return acc + _block_values(s_buf[i], v)
 
     o = jax.lax.fori_loop(0, n_blocks, weigh_block,
                           jnp.zeros(o_ref.shape, jnp.float32))
@@ -308,22 +389,52 @@ def _group_scratch_bytes(n_pages, block_size, rows, width, dtype) -> int:
 # What one compute block's K pages should weigh: large enough that a
 # block's copies take about a microsecond (far above the cost of a
 # loop step), small enough that a row's ragged tail wastes little of
-# the two dots.
+# the two dots. And the most ONE copy brings of a run of consecutive
+# pages, of K and again of V: a descriptor a page costs the scalar core
+# more than a page of 8-16 KB takes to arrive, in a loop nothing
+# overlaps (PERF.md section 6, PR 43).
 _BLOCK_TARGET_BYTES = 2 ** 20
+_COPY_BYTES = 2 ** 18
+
+
+def _block_plan(n_pages: int, block_size: int, width: int, dtype,
+                block_bytes: int, copy_bytes: int) -> tuple:
+    """``(pages a compute block, pages a copy)`` of a paged body, from
+    what the code can see (``width``: a row of the pool). Both are whole
+    128-lane score rows of tokens; a block weighs about ``block_bytes``
+    and is no wider than the table; a copy divides the block and weighs
+    at most ``copy_bytes``."""
+    lane_dense = 128 // math.gcd(128, int(block_size))
+    page_bytes = int(block_size) * int(width) * jnp.dtype(dtype).itemsize
+    k = max(min(block_bytes // page_bytes,
+                _tile_pad(n_pages, lane_dense)) // lane_dense, 1)
+    fit = max(copy_bytes // (lane_dense * page_bytes), 1)
+    d = max(d for d in range(1, k + 1) if k % d == 0 and d <= fit)
+    return k * lane_dense, d * lane_dense
+
+
+def _decode_plan(n_pages: int, block_size: int, width: int, dtype,
+                 pool_blocks: int = None) -> tuple:
+    """``(pages a compute block, pages a copy)`` of the single-softmax
+    body: a block's K pages weigh about :data:`_BLOCK_TARGET_BYTES` and
+    the K double buffer stays within an eighth of the scoped VMEM; a
+    copy weighs at most :data:`_COPY_BYTES` a pool. A pool of fewer
+    than two copies' blocks (an engine of a test's size) goes a page a
+    copy: the body waits for a group's K and V on one descriptor of
+    twice a copy's pages of the pool."""
+    ppb, ppc = _block_plan(n_pages, block_size, width, dtype,
+                           min(_BLOCK_TARGET_BYTES, VMEM_BYTES // 16),
+                           _COPY_BYTES)
+    if pool_blocks is not None and pool_blocks < 2 * ppc:
+        ppc = 1
+    return ppb, ppc
 
 
 def _pages_per_block(n_pages: int, block_size: int, width: int,
                      dtype) -> int:
-    """Pages per compute block of the single-softmax body, from what
-    the code can see: a block's tokens fill whole 128-lane score rows,
-    its K pages weigh about :data:`_BLOCK_TARGET_BYTES`, the K double
-    buffer stays within an eighth of the scoped VMEM, and no block is
-    wider than the table."""
-    lane_dense = 128 // math.gcd(128, int(block_size))
-    page_bytes = int(block_size) * int(width) * jnp.dtype(dtype).itemsize
-    want = min(_BLOCK_TARGET_BYTES, VMEM_BYTES // 16) // page_bytes
-    want = min(want, _tile_pad(n_pages, lane_dense))
-    return max(want // lane_dense, 1) * lane_dense
+    """Pages per compute block of the single-softmax body
+    (:func:`_decode_plan`)."""
+    return _decode_plan(n_pages, block_size, width, dtype)[0]
 
 
 def decode_scratch_vmem_bytes(n_pages: int, block_size: int,
@@ -536,6 +647,24 @@ def kernel_pages_per_block(n_pages: int, block_size: int, num_heads: int,
                             (num_kv_heads or num_heads) * head_dim, dtype)
 
 
+def kernel_pages_per_copy(n_pages: int, block_size: int, num_heads: int,
+                          head_dim: int, dtype, pages_per_split=None,
+                          num_kv_heads: int = None,
+                          pool_blocks: int = None) -> int:
+    """Pages ONE copy of :func:`paged_attention_decode` brings of K (and
+    one of V) where a row's table allows, at these shapes over a pool
+    of ``pool_blocks`` blocks: the table is read in aligned groups of
+    this many entries, and a group that is all live and holds
+    consecutive page ids is one copy (:func:`coalesced_pages`); 1 where
+    every page goes alone (split-K, a pool without room for a run)."""
+    if _split_width(n_pages, block_size, num_heads, head_dim, dtype,
+                    pages_per_split, num_kv_heads) < n_pages:
+        return 1
+    return _decode_plan(n_pages, block_size,
+                        (num_kv_heads or num_heads) * head_dim, dtype,
+                        pool_blocks)[1]
+
+
 def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
                            scale=None, interpret=None,
                            pages_per_split=None, layer=0):
@@ -599,15 +728,17 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
                                   H // Hkv)
         return out.astype(q.dtype)[:, None]
 
+    ppb, ppc = _decode_plan(n_pages, bs, Hkv * D, k_pool.dtype,
+                            k_pool.shape[1])
     return _decode_single(
         q, k_pool, v_pool, bt, ln, jnp.asarray(layer, jnp.int32),
-        scale=float(scale), interpret=interpret,
-        ppb=_pages_per_block(n_pages, bs, Hkv * D, k_pool.dtype))
+        scale=float(scale), interpret=interpret, ppb=ppb, ppc=ppc)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "ppb"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "ppb", "ppc"))
 def _decode_single(q, k_pool, v_pool, bt, ln, layer, *, scale, interpret,
-                   ppb):
+                   ppb, ppc):
     """The single-softmax body's call, jitted with the layer a traced
     scalar: the 24 calls of a decode program are ONE traced and lowered
     kernel called 24 times, not 24 (0.2 s each to trace and lower on
@@ -621,16 +752,22 @@ def _decode_single(q, k_pool, v_pool, bt, ln, layer, *, scale, interpret,
     rows, width = _tile_pad(H, 8), Hkv * D
     qr = _spread_query(q[:, 0], Hkv, rows, k_pool.dtype, H // Hkv)[:, 0]
     n_blocks = -(-n_pages // ppb)
+    # the table in whole compute blocks (the pad is never live), and
+    # which of its groups are runs: decided from the table alone, the
+    # same for every layer of a program
+    bt = jnp.pad(bt, ((0, 0), (0, n_blocks * ppb - n_pages)))
+    runs = _page_runs(bt, ppc).astype(jnp.int32)
 
     def tile():
         return pl.BlockSpec((None, rows, width),
-                            lambda b, bt, ln, layer: (b, 0, 0))
+                            lambda b, bt, ln, runs, layer: (b, 0, 0))
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, block_size=bs,
-                          pages_per_block=ppb, n_pages=n_pages, batch=B),
+                          pages_per_block=ppb, pages_per_copy=ppc,
+                          n_pages=n_pages, batch=B),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B,),
             # the pools stay where they are; the layer is an index the
             # copies take, so no program slices a layer out
@@ -651,7 +788,7 @@ def _decode_single(q, k_pool, v_pool, bt, ln, layer, *, scale, interpret,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
-    )(bt, ln, layer.reshape(1), qr, k_pool, v_pool)
+    )(bt, ln, runs, layer.reshape(1), qr, k_pool, v_pool)
     # [B, R, H_kv*D] -> own lanes [B, H, D] -> [B, 1, H, D]
     return _own_lanes(out, Hkv, D, H // Hkv).reshape(B, 1, H, D)
 
@@ -749,18 +886,10 @@ _MLA_COPY_BYTES = 5 * 2 ** 16
 
 def _mla_plan(n_pages: int, block_size: int, width: int, dtype) -> tuple:
     """``(pages a compute block, pages a copy)`` of
-    :func:`paged_mla_decode`, from what the code can see (``width``: a
-    row of the pool). Both are whole 128-lane score rows of tokens; a
-    block weighs about :data:`_MLA_BLOCK_BYTES` and is no wider than
-    the table; a copy divides the block and weighs at most
-    :data:`_MLA_COPY_BYTES`."""
-    lane_dense = 128 // math.gcd(128, int(block_size))
-    page_bytes = int(block_size) * int(width) * jnp.dtype(dtype).itemsize
-    k = max(min(_MLA_BLOCK_BYTES // page_bytes,
-                _tile_pad(n_pages, lane_dense)) // lane_dense, 1)
-    fit = max(_MLA_COPY_BYTES // (lane_dense * page_bytes), 1)
-    d = max(d for d in range(1, k + 1) if k % d == 0 and d <= fit)
-    return k * lane_dense, d * lane_dense
+    :func:`paged_mla_decode` (:func:`_block_plan` at
+    :data:`_MLA_BLOCK_BYTES` and :data:`_MLA_COPY_BYTES`)."""
+    return _block_plan(n_pages, block_size, width, dtype, _MLA_BLOCK_BYTES,
+                       _MLA_COPY_BYTES)
 
 
 def mla_pages_per_block(n_pages: int, block_size: int, width: int,
@@ -776,7 +905,7 @@ def mla_pages_per_copy(n_pages: int, block_size: int, width: int,
     """Pages ONE copy of :func:`paged_mla_decode` brings where a row's
     table allows: the table is read in aligned groups of this many
     entries, and a group that is all live and holds consecutive page
-    ids is one copy (:func:`mla_coalesced_pages`)."""
+    ids is one copy (:func:`coalesced_pages`)."""
     return _mla_plan(n_pages, block_size, width, dtype)[1]
 
 
@@ -789,14 +918,17 @@ def _page_runs(tables, pages_per_copy: int):
     return (g[..., 1:] - g[..., :-1] == 1).all(-1)
 
 
-def mla_coalesced_pages(tables, live_pages, pages_per_copy: int) -> int:
+def coalesced_pages(tables, live_pages, pages_per_copy: int) -> int:
     """Of the live pages of a step's rows (``tables`` ``[B, P]``,
-    ``live_pages`` a row), how many :func:`paged_mla_decode` fetches in
-    copies of a whole run: the pages of the aligned groups that are all
-    live and one run. On the host, from the tables the step is built
-    from: the count ``decode.dispatch`` carries."""
+    ``live_pages`` a row), how many a paged body fetches in copies of a
+    whole run: the pages of the aligned groups of ``pages_per_copy``
+    entries that are all live and one run (a copy of one page coalesces
+    nothing). On the host, from the tables the step is built from: the
+    count ``decode.dispatch`` carries."""
     tables = np.asarray(tables)
     ppc = int(pages_per_copy)
+    if ppc == 1:
+        return 0
     pad = _tile_pad(tables.shape[1], ppc) - tables.shape[1]
     runs = _page_runs(np.pad(tables, ((0, 0), (0, pad))), ppc)
     whole = (np.arange(runs.shape[1])[None] + 1) * ppc \
@@ -872,53 +1004,19 @@ def _mla_kernel(bt_ref, len_ref, run_ref, layer_ref, q_ref, pool_hbm, o_ref,
         return jnp.clip(live_pages(row) - i * ppb, 0, ppb)
 
     def start(row, i, slot):
-        n_live = block_pages(row, i)
-
-        def group(g, carry):
-            first = i * ppb + g * ppc
-            left = n_live - g * ppc
-            run = (left >= ppc) & (run_ref[row, i * (ppb // ppc) + g] == 1)
-
-            def page(j, c=None):
-                pltpu.make_async_copy(
-                    pool_hbm.at[layer, bt_ref[row, first + j]],
-                    buf.at[slot, g * ppc + j], sem.at[slot]).start()
-                return c
-
-            @pl.when(run)
-            def _run():
-                pltpu.make_async_copy(
-                    pool_hbm.at[layer, pl.ds(bt_ref[row, first], ppc)],
-                    buf.at[slot, pl.ds(g * ppc, ppc)], sem.at[slot]).start()
-
-            @pl.when(jnp.logical_not(run) & (left >= ppc))
-            def _scattered():
-                # all live, no run: straight-line descriptors, whose
-                # address arithmetic the scheduler interleaves (a third
-                # of the bundles of a loop step a page)
-                for j in range(ppc):
-                    page(j)
-
-            @pl.when(left < ppc)
-            def _ragged():
-                jax.lax.fori_loop(0, left, page, 0)
-            return carry
-        jax.lax.fori_loop(0, (n_live + ppc - 1) // ppc, group, 0)
+        def copies(blk, at, pages):
+            return (pltpu.make_async_copy(
+                _pages(pool_hbm, (layer,), blk, pages),
+                _pages(buf, (slot,), at, pages), sem.at[slot]),)
+        _start_block(bt_ref, run_ref, row, i, block_pages(row, i), ppb, ppc,
+                     copies)
 
     def wait(row, i, slot):
-        n_live = block_pages(row, i)
-
         def arrived(pages):
-            # the semaphore counts bytes: a descriptor of as many pages
-            # waits for them, whatever copies brought them
-            def wait_one(_, carry):
-                pltpu.make_async_copy(pool_hbm.at[layer, pl.ds(0, pages)],
-                                      buf.at[slot, pl.ds(0, pages)],
-                                      sem.at[slot]).wait()
-                return carry
-            return wait_one
-        jax.lax.fori_loop(0, n_live // ppc, arrived(ppc), 0)
-        jax.lax.fori_loop(0, n_live % ppc, arrived(1), 0)
+            return pltpu.make_async_copy(pool_hbm.at[layer, pl.ds(0, pages)],
+                                         buf.at[slot, pl.ds(0, pages)],
+                                         sem.at[slot])
+        _wait_block(block_pages(row, i), ppc, arrived)
 
     @pl.when(b == 0)
     def _prime():

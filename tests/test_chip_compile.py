@@ -229,6 +229,26 @@ def test_paged_decode_serving_cell_shape(one_chip, dtype):
                 if " copy(" in ln and pool in ln]
 
 
+def test_paged_decode_finds_its_runs_once_a_program(one_chip):
+    """Which groups of the table are runs of consecutive pages is
+    decided from the table alone inside the jitted call: a program of
+    several layers over ONE table computes it once (XLA merges the
+    identical computations), not once a layer."""
+    def layers(q, kp, vp, bt, ln):
+        for layer in range(3):
+            q = q + pa.paged_attention_decode(q, kp, vp, bt, ln,
+                                              interpret=False, layer=layer)
+        return q
+    assert pa.kernel_pages_per_copy(64, 16, 16, 64, BF16) == 8
+    text = _compile(one_chip, layers, *_paged_avals(64, 4096, 64, layers=24),
+                    kernels=["paged_decode"]).as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    runs = [ln for ln in text.splitlines()
+            if " fusion(" in ln and "= pred[64,8]" in ln]
+    assert len(calls) == 3 and len(runs) == 1, (len(calls), runs)
+
+
 def test_paged_decode_split_k_32k(one_chip):
     """A 32k context (2048 pages) auto-dispatches to split-K."""
     assert not pa.fits_single_softmax(2048, 16, 64, BF16)
@@ -264,6 +284,8 @@ def test_paged_decode_grouped_query_cell_shape(one_chip):
     assert pa.fits_single_softmax(256, 16, 64, BF16, None, 32, 8)
     assert pa.kernel_pages_per_block(256, 16, 32, 64, BF16,
                                      num_kv_heads=8) == 64
+    assert pa.kernel_pages_per_copy(256, 16, 32, 64, BF16, None, 8,
+                                    16384) == 16
     pool = ((1, 16384, 16, 8 * 64), BF16)
     avals = (((64, 1, 32, 64), BF16), pool, pool,
              ((64, 256), jnp.int32), ((64,), jnp.int32))
@@ -285,6 +307,8 @@ def test_paged_decode_group_of_five_cell_shape(one_chip):
     assert pa.fits_single_softmax(160, 16, 128, BF16, None, 20, 4)
     assert pa.kernel_pages_per_block(160, 16, 20, 128, BF16,
                                      num_kv_heads=4) > 1
+    assert pa.kernel_pages_per_copy(160, 16, 20, 128, BF16, None, 4,
+                                    20480) == 16
     pool = ((4, 20480, 16, 4 * 128), BF16)
     avals = (((128, 1, 20, 128), BF16), pool, pool,
              ((128, 160), jnp.int32), ((128,), jnp.int32))
@@ -421,6 +445,8 @@ def test_paged_decode_block_of_positions_cell_shape(one_chip):
     assert pa.fits_single_softmax(192, 16, 128, BF16, None, 4 * 32, 4)
     assert pa.kernel_pages_per_block(192, 16, 4 * 32, 128, BF16,
                                      num_kv_heads=4) == 64
+    assert pa.kernel_pages_per_copy(192, 16, 4 * 32, 128, BF16, None, 4,
+                                    12288) == 16
     pool = ((4, 12288, 16, 4 * 128), BF16)
     avals = (((64, 4, 32, 128), BF16), pool, pool,
              ((64, 192), jnp.int32), ((64,), jnp.int32))
@@ -460,6 +486,35 @@ def test_paged_mla_decode_cell_shape(one_chip):
                     kernels=["paged_mla_decode"]).as_text()
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and "[5,49152,16," in ln]
+
+
+def test_paged_mla_decode_module_is_pr_36s(one_chip, monkeypatch):
+    """``paged_mla_decode`` starts and waits for its copies through the
+    routine ``paged_decode`` shares since ISSUE 43 (``_start_block`` /
+    ``_wait_block``): at the DeepSeek cell's shape its Mosaic module,
+    printed without location info, is character for character the one
+    PR 36 measured — a change to the shared routine that moves it is a
+    change to that cell's kernel, and is then made on purpose (print
+    the new digest with this test and say so in PERF.md)."""
+    import hashlib
+    from jax._src.pallas.mosaic import pallas_call_registration as reg
+    lower = reg.lowering.lower_jaxpr_to_module
+    modules = []
+
+    def spy(*args, **kwargs):
+        module = lower(*args, **kwargs)
+        modules.append(module.operation.get_asm(enable_debug_info=False))
+        return module
+    monkeypatch.setattr(reg.lowering, "lower_jaxpr_to_module", spy)
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((128, 128, 512), BF16), ((128, 128, 64), BF16),
+        ((5, 49152, 16, 640), BF16), ((128, 384), jnp.int32),
+        ((128,), jnp.int32))]
+    jax.jit(functools.partial(pa.paged_mla_decode, scale=0.1147,
+                              interpret=False, layer=4)).lower(*avals)
+    assert len(modules) == 1
+    assert hashlib.sha256(modules[0].encode()).hexdigest() == (
+        "b7343ee667e961f88fff8e4d4bd79e89fbd2c378ac151a89d0099752c07b29f2")
 
 
 @pytest.mark.parametrize("entry", ["bshd", "bhsd"])

@@ -208,6 +208,7 @@ def test_a_smaller_budget_shows_on_the_span(monkeypatch):
     assert counts["ssm_grid_steps"] \
         == 4 * engine.model.cfg.num_hidden_layers * 4
     assert "kernel_pages_per_block" in counts
+    assert "coalesced_pages" in counts
 
 
 def test_the_block_reader_reads_the_plans_block(readers, falcon_traced):
@@ -236,4 +237,5 @@ def test_the_block_metric_is_on_the_two_state_space_cells_lists():
         "moves": "serve_tokens_per_s",
         "workloads": ["falconh1-serve-gen1k-backlog",
                       "nemotron3n-serve-reason2k-backlog"]}
-    assert manifest["per_layer"][-1] is entry          # appended, last
+    # appended behind the 52 entries PR 42 found (later PRs append too)
+    assert manifest["per_layer"].index(entry) == 52

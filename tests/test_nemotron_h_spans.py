@@ -114,6 +114,7 @@ def test_dispatch_counts_layers_rows_state_and_routing(traced):
         assert c["state_bytes"] == 2 * c["rows"] * slot
         assert c["state_reprefills"] == 0
         assert "kernel_pages_per_block" in c
+        assert "coalesced_pages" in c
     # the experts' counts ride on the dispatch that reads them back
     counted = [s[3] for s in spans if s[0] == "decode.dispatch"
                and "moe_assignments" in s[3]]

@@ -154,7 +154,7 @@ def test_a_pool_of_fewer_blocks_than_a_copy_group(dtype):
                               layer=0)
     check(got, pa.paged_mla_reference(qc, qr, pool[0], bt, ctx, 0.2), ctx,
           dtype)
-    assert pa.mla_coalesced_pages(bt, [9, 2, 0], 16) == 0
+    assert pa.coalesced_pages(bt, [9, 2, 0], 16) == 0
 
 
 def test_the_plan_follows_the_shapes():
@@ -193,5 +193,5 @@ def test_the_plan_follows_the_shapes():
     ("short_table", [[1, 2, 3, 4, 5, 6]], [6], 4),
 ])
 def test_coalesced_pages_by_hand(case, table, live, want):
-    assert pa.mla_coalesced_pages(np.asarray(table, np.int32), live, 4) \
+    assert pa.coalesced_pages(np.asarray(table, np.int32), live, 4) \
         == want

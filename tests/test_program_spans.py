@@ -169,6 +169,7 @@ def test_counts_equal_the_engines_own_info(traced):
     assert 0 < first["blocks_in_use"] <= 32
     assert set(first) == {"rows", "row_bucket", "page_bucket", "ctx_tokens",
                           "live_pages", "kernel_pages_per_block",
+                          "coalesced_pages",
                           "blocks_in_use", "blocks_total", "evicted",
                           "ahead", "dropped_ahead", "program", "launch"}
     steps = [s[3]["built"] for s in traced["spans"] if s[0] == "train.step"]
@@ -187,8 +188,78 @@ def test_dispatch_counts_what_the_paged_kernel_moves(traced):
     assert first["live_pages"] == 2 + 1
     assert first["live_pages"] <= first["row_bucket"] * first["page_bucket"]
     assert first["kernel_pages_per_block"] == 128 // 4
+    # a 32-block pool holds no two copies of 32 pages: a page a copy
+    assert first["coalesced_pages"] == 0
     # one more key a row each tick: 7 and 5 keys, 2 + 2 pages
     assert dispatch[1]["live_pages"] == 2 + 2
+
+
+def test_coalesced_pages_of_a_table_by_hand(monkeypatch):
+    """``coalesced_pages``: of the live pages, those the paged kernel
+    fetches a run of consecutive pages at a time — counted on the host
+    for the step's tables (``_step_counts`` -> runner -> family) with
+    the kernel's own plan: here (a copy's byte budget set to 32 of
+    these 1 KB pages) the table is read in aligned groups of 32
+    entries, in a pool with room for two such copies."""
+    import importlib.util
+    import sys
+    import types
+    from paddle2_tpu.serving import paged_attention as pa
+    monkeypatch.setattr(pa, "_COPY_BYTES", 32 * 1024)
+    paddle.seed(0)
+    model = GPTForCausalLM(gpt_tiny())
+    model.eval()
+    engine = ServingEngine(model, config=EngineConfig(
+        block_size=4, num_blocks=256, max_batch=4, max_model_len=64))
+    family = engine.runner.family
+    shape = (64, 4, family.num_heads, family.head_dim, engine.cache.dtype,
+             None, family.num_kv_heads)
+    assert pa.kernel_pages_per_copy(*shape, 256) == 32
+    assert pa.kernel_pages_per_copy(*shape, 63) == 1
+    tables = np.zeros((4, 64), np.int32)
+    tables[0] = np.arange(1, 65)             # one run, 40 pages live
+    tables[1] = np.arange(200, 136, -1)      # a run that descends
+    tables[2, :32] = np.arange(100, 132)     # a run, then scattered ids
+    tables[2, 32:] = np.arange(66, 130, 2)
+    live = [40, 64, 64]                      # row 3 is batch padding
+    counts = engine._step_counts(3, tables, 0, live, [])
+    assert counts["live_pages"] == 168
+    assert counts["coalesced_pages"] == 32 + 0 + 32
+    assert counts["kernel_pages_per_block"] == pa.kernel_pages_per_block(
+        *shape)
+    # the benchmark's reader: the steps' own ratio, nothing where no
+    # span carries the count (a program from before it)
+    spec = importlib.util.spec_from_file_location(
+        "reader_paged_pages_coalesced", os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+            "paged_pages_coalesced_pct.serve.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spans = [("decode.dispatch", 0, 1, dict(counts)),
+             ("decode.dispatch", 1, 2, dict(counts, coalesced_pages=0))]
+    # the reader's one import, stood in for by the spans themselves
+    monkeypatch.setitem(sys.modules, "program_trace", types.SimpleNamespace(
+        of=lambda ctx: ctx["spans"],
+        spans_named=lambda spans, name, window: [
+            s for s in spans if s[0] == name]))
+    spec.loader.exec_module(reader)
+    ctx = {"spans": spans, "trace": types.SimpleNamespace(window=None)}
+    assert reader.read(ctx) == pytest.approx(100.0 * 64 / (2 * 168))
+    for s in spans:
+        del s[3]["coalesced_pages"]
+    assert reader.read(ctx) is None
+    # and its entry: appended to the manifest, on the five cells whose
+    # decode program runs ``paged_decode``
+    import json
+    with open(os.path.join(os.path.dirname(__file__), "..",
+                           "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry, = [m for m in manifest["per_layer"]
+              if m["name"] == "paged_pages_coalesced_pct.serve"]
+    assert manifest["per_layer"].index(entry) == 53
+    assert entry["source"] == "program_counter"
+    assert sorted(entry["workloads"]) == sorted(
+        w["name"] for w in manifest["workloads"]
+        if "-serve-" in w["name"] and not w["name"].startswith("dsv2"))
 
 
 def test_build_log_one_record_per_program(traced):

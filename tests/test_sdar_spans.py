@@ -68,6 +68,10 @@ def test_dispatch_counts_the_step_it_enqueues_and_the_one_it_reads(traced):
     assert first["ahead"] == 0 and all(c["ahead"] == 1 for c in steps[1:])
     for c in steps:
         assert c["seqs"] == c["rows"] == c["denoise_rows"] + c["commit_rows"]
+        # the paged kernel's counts ride the block step too; a pool of
+        # a test's size holds no run: every page a copy of its own
+        assert c["kernel_pages_per_block"] > 0
+        assert c["coalesced_pages"] == 0
     # what was read back: every position fixed once, every token
     # committed once, the routing counts of B rows a sequence
     read = [s[3] for s in spans if s[0] == "decode.dispatch"

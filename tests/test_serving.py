@@ -222,6 +222,142 @@ def test_paged_reference_allclose_vs_flash_ragged():
         np.testing.assert_allclose(fa, ref[b:b + 1], rtol=2e-6, atol=2e-6)
 
 
+# ---- copies by run of consecutive pages: a compute block of 16 pages
+# read in aligned groups of 8 table entries (the plan's byte targets
+# set to so many of the case's pages), a table of 40 pages
+RUN_PPB, RUN_PPC, RUN_PAGES = 16, 8, 40
+# name -> (query positions, query heads, key/value heads)
+RUN_HEADS = {"multi_head": (1, 2, 2), "group_of_4": (1, 8, 2),
+             "group_of_5": (1, 5, 1), "group_of_16": (1, 16, 1),
+             "block_of_4": (4, 2, 1)}
+
+
+def _run_tables():
+    """name -> (rows' tables, contexts, pages fetched as runs by hand).
+    Ids start at 1 (0 is the garbage block); ``scattered`` ids step by
+    2, so no page follows its neighbour."""
+    P, bs = RUN_PAGES, 16
+    up = np.arange(P)
+    scattered = 200 + 2 * np.random.default_rng(8).permutation(P)
+
+    def broken(at):
+        return np.where(up < at, 100 + up, 150 + up)
+
+    def rows(*tables):
+        return np.stack(tables).astype(np.int32)
+    return {
+        "one_ascending_run": (rows(1 + up), [P * bs], 40),
+        "fully_scattered": (rows(scattered), [P * bs - 5], 0),
+        "run_broken_inside_a_group": (rows(broken(3)), [P * bs], 32),
+        "run_broken_at_a_groups_edge": (rows(broken(RUN_PPC)), [P * bs], 40),
+        "run_broken_at_a_blocks_edge": (rows(broken(RUN_PPB)), [P * bs - 1],
+                                        40),
+        "descending_run": (rows(60 - up), [P * bs], 0),
+        # 19 live pages: two whole groups, then three pages of a run
+        "context_ends_inside_a_run": (rows(1 + up), [18 * bs + 12], 16),
+        "empty_row_between_live_rows": (
+            rows(1 + up, np.zeros(P), scattered), [25 * bs, 0, 37], 24),
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _run_case_outputs(heads: str, small_pool: bool):
+    """Every table of :func:`_run_tables` as rows of ONE call (a small
+    pool: its own call over the tables' first 8 pages), float32 ->
+    (kernel output, reference, tables, contexts, pages a copy, row span
+    of each case)."""
+    from unittest import mock
+    Q, H, Hkv = RUN_HEADS[heads]
+    bs, D = 16, 16
+    page = bs * Hkv * D * 4
+    cases = _run_tables()
+    tables = np.concatenate([t for t, _, _ in cases.values()])
+    ctx = np.asarray(sum((c for _, c, _ in cases.values()), []), np.int32)
+    span, at = {}, 0
+    for name, (t, _, _) in cases.items():
+        span[name] = slice(at, at + len(t))
+        at += len(t)
+    num_blocks = 300
+    if small_pool:
+        # fewer blocks than two copies: ids folded into 1..11
+        num_blocks = 12
+        tables = (tables[:, :RUN_PPC] % 11 + 1).astype(np.int32)
+        ctx = np.minimum(ctx, RUN_PPC * bs)
+    rng = np.random.default_rng(9)
+    kp, vp = (jnp.asarray(rng.normal(size=(num_blocks, bs, Hkv * D)),
+                          jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(len(ctx), Q, H, D)), jnp.float32)
+    with mock.patch.object(pa, "_BLOCK_TARGET_BYTES", RUN_PPB * page), \
+            mock.patch.object(pa, "_COPY_BYTES", RUN_PPC * page):
+        ppb, ppc = pa._decode_plan(tables.shape[1], bs, Hkv * D, "float32",
+                                   num_blocks)
+        assert (ppb, ppc) == ((RUN_PPC, 1) if small_pool
+                              else (RUN_PPB, RUN_PPC))
+        assert pa.kernel_pages_per_copy(
+            tables.shape[1], bs, Q * H, D, "float32", None, Hkv,
+            num_blocks) == ppc
+        out = np.asarray(paged_attention_decode(q, kp[None], vp[None],
+                                                tables, ctx))
+    # every position of a row sees the same context
+    ref = np.stack([np.asarray(paged_attention_reference(
+        q[:, p:p + 1], kp, vp, tables, ctx))[:, 0] for p in range(Q)], 1)
+    return out, ref, tables, ctx, (ppb, ppc), span
+
+
+def _pages_started(tables, ctx, ppb, ppc):
+    """What the kernel's own start routine fetches for these rows, run
+    eagerly on the host: ``(row, block page, pool page, pages)`` a
+    copy."""
+    class Copy:
+        def __init__(self, log, *what):
+            self.start = lambda: log.append(what)
+    bs = 16
+    pad = -tables.shape[1] % ppb
+    bt = np.pad(tables, ((0, 0), (0, pad)))
+    runs = np.asarray(pa._page_runs(bt, ppc)).astype(np.int32)
+    log = []
+    with jax.disable_jit():
+        for row, c in enumerate(ctx):
+            live = min(blocks_for_tokens(int(c), bs), tables.shape[1])
+            for i in range(-(-live // ppb)):
+                pa._start_block(
+                    bt, runs, row, i, min(live - i * ppb, ppb), ppb, ppc,
+                    lambda blk, at, pages, row=row, i=i: [Copy(
+                        log, row, i * ppb + int(at), int(blk), pages)])
+    return log
+
+
+@pytest.mark.parametrize("heads", sorted(RUN_HEADS))
+@pytest.mark.parametrize("case", sorted(_run_tables()) + ["small_pool"])
+def test_paged_decode_copies_runs_of_consecutive_pages(case, heads):
+    """ISSUE 43: a compute block's live pages arrive a RUN of
+    consecutive page ids a copy where the table holds one in an aligned
+    group, a page a copy elsewhere — on every kind of table the output
+    is the dense reference's to KERNEL_TOL (float32), a row without a
+    key reads 0, the pages the start routine fetches are the table's
+    live pages, each once, and those it fetches as runs are what
+    ``coalesced_pages`` counts on the host."""
+    small = case == "small_pool"
+    out, ref, tables, ctx, (ppb, ppc), span = _run_case_outputs(heads,
+                                                                small)
+    rows = slice(None) if small else span[case]
+    assert np.isfinite(out[rows]).all()
+    keyed = ctx[rows] > 0
+    np.testing.assert_allclose(out[rows][keyed], ref[rows][keyed],
+                               **KERNEL_TOL)
+    assert not out[rows][~keyed].any()
+    started = _pages_started(tables[rows], ctx[rows], ppb, ppc)
+    live = [blocks_for_tokens(int(c), 16) for c in ctx[rows]]
+    fetched = sorted((row, at + j, blk + j)
+                     for row, at, blk, pages in started
+                     for j in range(pages))
+    assert fetched == [(row, j, int(tables[rows][row, j]))
+                       for row, n in enumerate(live) for j in range(n)]
+    as_runs = sum(pages for _, _, _, pages in started if pages > 1)
+    assert as_runs == pa.coalesced_pages(tables[rows], live, ppc)
+    assert as_runs == (0 if small else _run_tables()[case][2])
+
+
 def test_paged_decode_bf16_allclose():
     rng = np.random.default_rng(3)
     bs, B, H, D = 16, 2, 2, 16
