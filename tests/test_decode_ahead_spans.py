@@ -6,34 +6,14 @@ one before it un-read) and ``dropped_ahead`` (tokens in flight thrown
 away since the last dispatch); the routing counts arrive with the
 read-back and sit on every ``decode.dispatch`` that read a step back."""
 
-import jax
 import numpy as np
 import pytest
 
-from paddle2_tpu.incubate.moe import DroplessExperts
 from paddle2_tpu.models import Lfm2MoeForCausalLM, lfm2_moe_tiny
 from paddle2_tpu.serving import EngineConfig, ServingEngine
 from paddle2_tpu.serving.spec import SpeculativeConfig
-from test_program_spans import PARENT, PROMPTS, read_spans, tiny_engine
-
-ROUTING = DroplessExperts.COUNT_NAMES
-
-
-def serve_traced(tmp_path_factory, engine, requests):
-    """Serve ``requests`` ([(prompt, max new)]) to the end under a
-    profiler session; the program's spans."""
-    trace_dir = str(tmp_path_factory.mktemp("p2t_ahead"))
-    jax.profiler.start_trace(trace_dir)
-    try:
-        for prompt, max_new in requests:
-            engine.submit(prompt, max_new)
-        now = 0.0
-        while not engine.idle():
-            engine.tick(now)
-            now += 1.0
-    finally:
-        jax.profiler.stop_trace()
-    return read_spans(trace_dir)
+from served import (PARENT, PROMPTS, ROUTING, serve_traced,
+                    tiny_gpt_engine)
 
 
 def ticks_of(spans):
@@ -52,7 +32,7 @@ def ticks_of(spans):
 @pytest.fixture(scope="module")
 def gpt_ticks(tmp_path_factory):
     # 4 and 3 new tokens: steps 1..3, the shorter leaves after step 2
-    return ticks_of(serve_traced(tmp_path_factory, tiny_engine(),
+    return ticks_of(serve_traced(tmp_path_factory, tiny_gpt_engine(),
                                  [(PROMPTS[0], 4), (PROMPTS[1], 3)]))
 
 

@@ -9,7 +9,6 @@ pages at a time — and the benchmark's readers (``moe_rows_here_pct.serve``,
 ``paged_mla_roofline_pct.serve`` / ``flash_mla_prefill_roofline_pct.serve``
 take) read them off the engine's own spans."""
 
-import importlib.util
 import os
 import sys
 import types
@@ -17,12 +16,11 @@ import types
 import numpy as np
 import pytest
 
-import paddle2_tpu as paddle
 from paddle2_tpu.models import DeepseekV2ForCausalLM, deepseek_v2_tiny
 from paddle2_tpu.observability import metrics
-from paddle2_tpu.serving import EngineConfig, ServingEngine
+from paddle2_tpu.serving import ServingEngine
 from paddle2_tpu.serving import paged_attention as pa
-from test_decode_ahead_spans import ROUTING, serve_traced
+from served import ROUTING, reader, seeded_engine, serve_traced
 
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -32,12 +30,8 @@ NEW = 4
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    paddle.seed(0)
-    model = DeepseekV2ForCausalLM(deepseek_v2_tiny(held_group=1))
-    model.eval()
-    engine = ServingEngine(model, config=EngineConfig(
-        block_size=8, num_blocks=64, max_batch=4, max_model_len=96,
-        batch_buckets=(4,), page_buckets=(4,), interpret=True))
+    engine = seeded_engine(DeepseekV2ForCausalLM,
+                           deepseek_v2_tiny(held_group=1))
     rng = np.random.default_rng(0)
     spans = serve_traced(
         tmp_path_factory, engine,
@@ -112,14 +106,6 @@ def readers(monkeypatch, traced):
     for name in ("program_trace", "moe_trace", "trace_reduce", "common"):
         monkeypatch.delitem(sys.modules, name, raising=False)
     import program_trace
-
-    def reader(name):
-        spec = importlib.util.spec_from_file_location(
-            "reader_" + name.split(".")[0],
-            os.path.join(BENCHMARK, "layer_metrics", name + ".py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
 
     pt = program_trace.ProgramTrace()
     pt.spans = list(traced[1])

@@ -221,6 +221,7 @@ rank = int(os.environ.get("PADDLE_TRAINER_ID", 0))
 world = int(os.environ.get("PADDLE_TRAINERS_NUM", 1))
 restart = int(os.environ.get("PADDLE_ELASTIC_RESTART_COUNT", 0))
 ckpt_dir = {str(repr(str(ckpt)))}
+saved = {str(repr(str(tmp_path / "saved_step")))}   # rank 0's last save
 
 paddle.seed(0)
 m = nn.Linear(4, 1)
@@ -250,7 +251,16 @@ for step in range(start_step, 12):
     if rank == 0:
         state["step"] = step
         dck.save_state_dict(state, ckpt_dir)
+        with open(saved + ".tmp", "w") as f:
+            f.write(str(step))
+        os.replace(saved + ".tmp", saved)
     if rank == 1 and restart == 0 and step == 3:
+        # leave only once rank 0's checkpoint of step 2 or later is on
+        # disk: what the resume finds must not depend on the host's load
+        deadline = time.time() + 120
+        while time.time() < deadline and not (
+                os.path.exists(saved) and int(open(saved).read()) >= 2):
+            time.sleep(0.05)
         os._exit(1)                            # simulated dead rank
 if rank == 0:
     json.dump({{"world": world, "restart": restart,

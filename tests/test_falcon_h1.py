@@ -17,92 +17,21 @@ of room and is two orders under what a bf16-for-f32 substitution gives
 (``test_tolerance_rejects_bf16``). States are compared to ``STATE_TOL``
 = 2e-5 (absolute, on states of scale ~1)."""
 
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle2_tpu.distributed.fault_tolerance import chaos
 from paddle2_tpu.kernels import ssd
 from paddle2_tpu.models import FalconH1Config, falcon_h1_tiny
 from paddle2_tpu.serving.block_cache import BlockFreeError, audit_kv_ledger
-from paddle2_tpu.serving.model_runner import PagedRunner
 from paddle2_tpu.serving.spec import SpeculativeConfig
-# the logits tap, the drive to idle and the tiny engine are LFM2's
-from test_lfm2_moe import logit_tap, serve, tiny_engine  # noqa: F401
+from served import (TINY_ENGINE, LOGIT_TOL, STATE_TOL, build,  # noqa: F401
+                    run_to_idle, shared_programs, ref_logits_highest as
+                    ref_logits, tiny_engine)
+from served import falcon_h1_bench as bench
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-LOGIT_TOL = 5e-5
-STATE_TOL = 2e-5
-
-
-@pytest.fixture(scope="module")
-def bench():
-    """The benchmark's modules and the tiny (rehearsal) configuration."""
-    added = [p for p in (BENCH,) if p not in sys.path]
-    sys.path[:0] = added
-    import run as harness
-    from common import load_module
-    from drivers import program, serve_staged_dense
-    from weights import make_weights
-    with open(os.path.join(BENCH, "configs",
-                           "falcon-h1-34b-instruct.json")) as f:
-        cfg = json.load(f)
-    cfg = harness.merge(cfg, cfg["rehearsal"])
-    cfg["name"] = "falcon-h1-34b-instruct"
-    ref = load_module("reference", cfg["reference"])
-    yield {"cfg": cfg, "ref": ref, "program": program,
-           "driver": serve_staged_dense, "make_weights": make_weights}
-    for p in added:
-        sys.path.remove(p)
-
-
-def build(bench, seed, **overrides):
-    """(model with the seed's weights, its config, the reference's
-    float32 leaves of the same seed)."""
-    cfg = bench["cfg"]
-    model, mcfg = bench["program"].build_model(cfg, overrides)
-    model.eval()
-    bench["driver"].place_weights(model, cfg, "per_layer", bench["ref"],
-                                  seed)
-    params = bench["make_weights"](bench["ref"].leaf_specs(cfg), seed,
-                                   jnp.float32)
-    return model, mcfg, params
-
-
-def ref_logits(bench, params, seq):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(bench["ref"].logits(
-            params, jnp.asarray([seq], jnp.int32), bench["cfg"])[0])
-
-
-def check_against_reference(bench, params, engine, rids, rows):
-    worst = 0.0
-    for rid in rids:
-        seq = engine.sequence(rid)
-        prompt, gen = seq.request.prompt, seq.generated
-        # an evicted sequence's re-prefill yields its next token again
-        assert len(rows[rid]) >= len(gen)
-        ref = ref_logits(bench, params, list(prompt) + list(gen))
-        for j, row in enumerate(rows[rid][:len(gen)]):
-            worst = max(worst, float(np.abs(
-                row - ref[len(prompt) - 1 + j]).max()))
-    assert worst <= LOGIT_TOL, worst
-    return worst
-
-
-def run_to_idle(engine, prompts, max_new):
-    rids = [engine.submit(p, max_new) for p in prompts]
-    now = 0.0
-    while not engine.idle():
-        now += 1.0
-        engine.tick(now)
-    return [list(engine.sequence(r).generated) for r in rids]
+pytestmark = pytest.mark.usefixtures("shared_programs")
 
 
 def mixer_inputs(seed, T, nh=4, P=16, G=2, N=16):
@@ -259,144 +188,6 @@ def test_leaf_at_a_time_placement_gives_make_weights_values(bench):
     assert seen == 3 + 17 * cfg["num_hidden_layers"]
 
 
-# ------------------------------------------------- prefill + paged decode
-def test_prefill_then_paged_decode_logits(bench, logit_tap):
-    """Prompts that are no multiples of 16 (nor of the block size or
-    the chunk, 8), three sequences in one batch: every step's logits
-    against the reference's full forward over prompt + generated."""
-    model, _, params = build(bench, 5)
-    engine = tiny_engine(model)
-    rng = np.random.default_rng(5)
-    prompts = [rng.integers(1, 503, n).tolist() for n in (5, 21, 37)]
-    rids, rows = serve(engine, prompts, 7, logit_tap)
-    check_against_reference(bench, params, engine, rids, rows)
-    # blocks and slots are back with the manager
-    assert engine.allocator.used_count == 0
-    assert engine.allocator.state_slots_used == 0
-    audit_kv_ledger(engine.allocator, [], state_pools=engine.cache.states)
-
-
-def test_single_token_prompt_state_is_zero_padded(bench, logit_tap):
-    model, _, params = build(bench, 6)
-    engine = tiny_engine(model)
-    rids, rows = serve(engine, [[17]], 5, logit_tap)
-    check_against_reference(bench, params, engine, rids, rows)
-
-
-def test_prefill_state_is_the_state_at_the_last_real_position(bench):
-    """A 21-token prompt is padded to 32: the states handed to the slot
-    are those of an unpadded pass over the 21 tokens (the padded END
-    would read otherwise: the control of the same name)."""
-    model, _, _ = build(bench, 7)
-    runner = PagedRunner(model, interpret=True)
-    ids = np.random.default_rng(7).integers(1, 503, 21).tolist()
-    _, _, _, conv, ssm_state = runner.prefill(ids)
-    with runner.bound():
-        _, _, states = model.model.full(jnp.asarray([ids], jnp.int32))
-    for li, (xbc, H) in enumerate(states):
-        assert float(jnp.abs(ssm_state[li] - H[0]).max()) <= STATE_TOL
-        assert float(jnp.abs(conv[li] - xbc[0, -3:]).max()) <= STATE_TOL
-    with runner.bound():
-        padded = jnp.asarray([ids + [0] * 11], jnp.int32)
-        _, _, at_end = model.model.full(padded)
-    assert float(jnp.abs(at_end[0][1][0] - ssm_state[0]).max()) \
-        > 100 * STATE_TOL
-
-
-@pytest.mark.parametrize("n,m", [(5, 6), (16, 3), (23, 9)])
-def test_prefill_plus_decode_is_a_longer_prefill(bench, n, m):
-    """A prefill of n tokens + m decode steps leaves the slot's states,
-    and yields the tokens, of a prefill of n + m tokens."""
-    model, _, _ = build(bench, 8)
-    prompt = np.random.default_rng(n).integers(1, 503, n).tolist()
-    engine = tiny_engine(model, max_batch=1)
-    rid = engine.submit(prompt, m + 1)
-    now = 0.0
-    while len(engine.sequence(rid).generated) < m + 1:
-        now += 1.0
-        engine.tick(now)
-        if engine.sequence(rid).done:
-            break
-    gen = list(engine.sequence(rid).generated)
-    # the slot after m decode steps (the last token is not fed)
-    conv = np.asarray(engine.cache.states["conv"][:, 1])
-    ssm_state = np.asarray(engine.cache.states["ssm"][:, 1])
-    runner = PagedRunner(model, interpret=True)
-    first, _, _, conv2, ssm2 = runner.prefill(prompt + gen[:m])
-    assert first == gen[m]
-    assert np.abs(conv - np.asarray(conv2)).max() <= STATE_TOL
-    assert np.abs(ssm_state - np.asarray(ssm2)).max() <= STATE_TOL
-
-
-def test_eviction_and_readmission_give_same_logits(bench, logit_tap):
-    """A pool too small for the batch: sequences are evicted (blocks
-    AND slot freed) and re-prefilled from their token logs; every
-    logits row still matches the reference."""
-    model, _, params = build(bench, 9)
-    engine = tiny_engine(model, num_blocks=12, max_batch=3)
-    rng = np.random.default_rng(9)
-    prompts = [rng.integers(1, 503, n).tolist() for n in (19, 23, 27)]
-    rids, rows = serve(engine, prompts, 12, logit_tap)
-    assert engine.scheduler.total_evictions > 0
-    check_against_reference(bench, params, engine, rids, rows)
-    assert engine.allocator.state_slots_used == 0
-
-
-@pytest.mark.parametrize("fault", ["drop_decode_step:2",
-                                   "drop_decode_step:3,drop_decode_step:5"])
-def test_dropped_step_leaves_the_served_tokens(bench, fault, monkeypatch):
-    """ROADMAP D13: a discarded step has already moved the states its
-    repeat would read. Its rows are re-prefilled, and the served tokens
-    are those of an undisturbed run."""
-    model, _, _ = build(bench, 10)
-    rng = np.random.default_rng(10)
-    prompts = [rng.integers(1, 503, n).tolist() for n in (9, 14, 20)]
-    want = run_to_idle(tiny_engine(model), prompts, 10)
-    monkeypatch.setattr(chaos, "_ACTIVE", chaos.ChaosInjector(fault))
-    engine = tiny_engine(model)
-    got = run_to_idle(engine, prompts, 10)
-    assert engine.state_reprefills >= 3
-    assert got == want
-    assert engine.allocator.state_slots_used == 0
-
-
-def test_repeating_a_step_on_a_moved_state_would_differ(bench):
-    """The control of the test above: the same step run twice over the
-    pools gives other logits the second time (the first moved the
-    states), so a plain repeat is not a repair."""
-    model, _, _ = build(bench, 10)
-    engine = tiny_engine(model, max_batch=1)
-    prompt = np.random.default_rng(10).integers(1, 503, 9).tolist()
-    rid = engine.submit(prompt, 4)
-    engine.admit_and_prefill(0.0)
-    seq = engine.sequence(rid)
-    fam, cache = engine.runner.family, engine.cache
-    args = (jnp.asarray([[seq.tokens[-1]]], jnp.int32),
-            jnp.asarray([len(prompt)], jnp.int32),
-            jnp.asarray([seq.table.padded(2)], jnp.int32),
-            jnp.asarray([seq.table.state_slot], jnp.int32), 8, True, None)
-    with engine.runner.bound():
-        lg1, k, v, pools, _ = fam.decode(
-            cache.k, cache.v, tuple(cache.states.values()), *args)
-        lg2, *_ = fam.decode(k, v, pools, *args)
-    assert float(jnp.abs(lg1 - lg2).max()) > 100 * LOGIT_TOL
-
-
-def test_prefix_cache_hit_still_fills_the_state(bench, logit_tap):
-    """A prefix hit shares the K/V blocks but runs the whole prefill,
-    which is where both states come from."""
-    model, _, params = build(bench, 11)
-    engine = tiny_engine(model, enable_prefix_cache=True)
-    rng = np.random.default_rng(11)
-    shared = rng.integers(1, 503, 24).tolist()
-    prompts = [shared + rng.integers(1, 503, n).tolist() for n in (3, 6)]
-    rids, rows = serve(engine, prompts[:1], 4, logit_tap)
-    rids2, rows2 = serve(engine, prompts[1:], 4, logit_tap)
-    assert engine.sequence(rids2[0]).prefix_cached_tokens >= 16
-    check_against_reference(bench, params, engine, rids + rids2,
-                            {**rows, **rows2})
-
-
 def test_admissions_hold_a_bounded_number_of_states_in_flight(
         bench, monkeypatch):
     """A prefill's states live from its enqueueing to their write: past
@@ -480,9 +271,7 @@ def test_artifact_path_serves_the_family(bench, tmp_path):
     path = str(tmp_path / "model")
     paddle.jit.save(model, path)
     conf = inference.Config(path)
-    conf.enable_continuous_batching(block_size=8, num_blocks=64,
-                                    max_batch=4, max_model_len=96,
-                                    kv_dtype="float32", interpret=True)
+    conf.enable_continuous_batching(**TINY_ENGINE)
     engine = conf.create_serving_engine(gpt_config=mcfg)
     assert isinstance(engine.model, FalconH1ForCausalLM)
     assert run_to_idle(engine, [prompt], 5) == want

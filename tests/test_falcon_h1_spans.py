@@ -7,7 +7,6 @@ a dropped step had moved their state); the admission's ``prefill`` span
 carries ``scan_chunks`` (``padded`` / the chunk). The last tests run the
 benchmark's three new readers over the engine's own spans."""
 
-import importlib.util
 import os
 import sys
 import types
@@ -15,26 +14,16 @@ import types
 import numpy as np
 import pytest
 
-import paddle2_tpu as paddle
 from paddle2_tpu.distributed.fault_tolerance import chaos
 from paddle2_tpu.models import (FalconH1ForCausalLM, Lfm2MoeForCausalLM,
                                 falcon_h1_tiny, lfm2_moe_tiny)
-from paddle2_tpu.serving import EngineConfig, ServingEngine
-from test_decode_ahead_spans import serve_traced
+from served import (reader, seeded_engine, serve_traced,  # noqa: F401
+                    shared_programs)
 
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
 PROMPTS = (9, 12, 21)
-
-
-def engine_of(model_class, config, **kw):
-    paddle.seed(0)
-    model = model_class(config)
-    model.eval()
-    conf = dict(block_size=8, num_blocks=64, max_batch=4, max_model_len=96,
-                batch_buckets=(4,), page_buckets=(4,), interpret=True)
-    conf.update(kw)
-    return ServingEngine(model, config=EngineConfig(**conf))
+pytestmark = pytest.mark.usefixtures("shared_programs")
 
 
 def requests(max_new=4):
@@ -44,7 +33,7 @@ def requests(max_new=4):
 
 @pytest.fixture(scope="module")
 def falcon_traced(tmp_path_factory):
-    engine = engine_of(FalconH1ForCausalLM, falcon_h1_tiny())
+    engine = seeded_engine(FalconH1ForCausalLM, falcon_h1_tiny())
     return engine, serve_traced(tmp_path_factory, engine, requests())
 
 
@@ -73,7 +62,7 @@ def test_dispatch_counts_the_state_the_step_moves(falcon_traced):
 
 def test_a_family_without_state_says_nothing_of_it(tmp_path_factory):
     from paddle2_tpu.models import GPTForCausalLM, gpt_tiny
-    engine = engine_of(GPTForCausalLM, gpt_tiny(), batch_buckets=None,
+    engine = seeded_engine(GPTForCausalLM, gpt_tiny(), batch_buckets=None,
                        page_buckets=None, max_model_len=64)
     prompts = [(list(range(3, 3 + n)), 3) for n in PROMPTS]
     spans = serve_traced(tmp_path_factory, engine, prompts)
@@ -90,7 +79,7 @@ def test_a_dropped_steps_rows_are_counted_on_the_next_dispatch(
         tmp_path_factory, monkeypatch, model_class, config):
     monkeypatch.setattr(chaos, "_ACTIVE",
                         chaos.ChaosInjector("drop_decode_step:2"))
-    engine = engine_of(model_class, config())
+    engine = seeded_engine(model_class, config())
     spans = serve_traced(tmp_path_factory, engine, requests())
     moved = [c["state_reprefills"] for c in steps_of(spans)]
     assert sum(moved) == engine.state_reprefills == 3
@@ -102,15 +91,6 @@ def test_a_dropped_steps_rows_are_counted_on_the_next_dispatch(
 
 
 # -- the benchmark's new readers over the engine's real spans ---------------
-def reader(name):
-    spec = importlib.util.spec_from_file_location(
-        "reader_" + name.replace(".", "_"),
-        os.path.join(BENCHMARK, "layer_metrics", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture()
 def readers(monkeypatch, falcon_traced):
     """The three new readers with the engine's spans as the loaded trace
@@ -200,7 +180,7 @@ def test_a_smaller_budget_shows_on_the_span(monkeypatch):
     """The counts follow the plan, not a constant: under a budget of one
     head the same engine says 1 KB blocks and four grid steps a row."""
     from paddle2_tpu.kernels import ssd
-    engine = engine_of(FalconH1ForCausalLM, falcon_h1_tiny())
+    engine = seeded_engine(FalconH1ForCausalLM, falcon_h1_tiny())
     monkeypatch.setattr(ssd, "STATE_BLOCK_BYTES", 16 * 16 * 4)
     counts = engine.runner.kernel_page_counts(
         engine.cache, np.zeros((4, 4), np.int32), [1, 1, 1, 1])

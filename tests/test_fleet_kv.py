@@ -22,7 +22,10 @@ from paddle2_tpu.serving import (BlockAllocator, EngineConfig,
                                  simulate_serving)
 from paddle2_tpu.serving.simulate import cost_seconds
 
-pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
+from served import shared_programs  # noqa: F401,E402
+
+pytestmark = [pytest.mark.filterwarnings("ignore::DeprecationWarning"),
+              pytest.mark.usefixtures("shared_programs")]
 
 
 @pytest.fixture(autouse=True)

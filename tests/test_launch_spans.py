@@ -14,7 +14,6 @@ import os
 import sys
 import types
 
-import jax
 import numpy as np
 import pytest
 
@@ -26,8 +25,9 @@ from paddle2_tpu.serving import EngineConfig, ServingEngine, model_runner
 from paddle2_tpu.serving.block_cache import SCATTER_MODULE
 from paddle2_tpu.serving.model_runner import (DECODE_MODULE, PREFILL_MODULE,
                                               PagedRunner)
-from test_decode_ahead import armed, engine_of, prompts_of, step_by_step
-from test_program_spans import PROMPTS, read_spans, tiny_engine, tiny_trainer
+from served import (PROMPTS, armed, engine_of, prompts_of, read_spans,
+                    step_by_step, tiny_gpt_engine, tiny_trainer,
+                    trace_session)
 
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -77,9 +77,8 @@ def serve(engine, arrivals, trace_dir=None, max_ticks=400):
     """Submit ``arrivals`` ([(tick, prompt, max new)]) and tick until the
     engine is idle, under a profiler session when ``trace_dir`` is
     given; (the program's spans or None, the served tokens)."""
-    if trace_dir is not None:
-        jax.profiler.start_trace(str(trace_dir))
-    try:
+    with trace_session(trace_dir) if trace_dir is not None \
+            else contextlib.nullcontext():
         todo, rids, tick = sorted(arrivals, key=lambda a: a[0]), [], 0
         while todo or not engine.idle():
             while todo and todo[0][0] <= tick:
@@ -88,9 +87,6 @@ def serve(engine, arrivals, trace_dir=None, max_ticks=400):
             engine.tick(float(tick))
             tick += 1
             assert tick < max_ticks, "engine did not drain"
-    finally:
-        if trace_dir is not None:
-            jax.profiler.stop_trace()
     tokens = [list(engine.sequence(r).generated) for r in rids]
     return (read_spans(str(trace_dir)) if trace_dir else None), tokens
 
@@ -101,15 +97,12 @@ def traced(tmp_path_factory):
     GPT engine (a K and a V pool), one profiler session."""
     trace_dir = tmp_path_factory.mktemp("p2t_launch")
     before = {m: profiler.launched(m) for m in ENQUEUING.values()}
-    jax.profiler.start_trace(str(trace_dir))
-    try:
+    with trace_session(trace_dir):
         step, ids = tiny_trainer()
         for _ in range(3):
             step(ids, ids)
-        engine = tiny_engine()
+        engine = tiny_gpt_engine()
         _, tokens = serve(engine, [(0, PROMPTS[0], 5), (1, PROMPTS[1], 4)])
-    finally:
-        jax.profiler.stop_trace()
     after = {m: profiler.launched(m) for m in ENQUEUING.values()}
     return {"spans": read_spans(str(trace_dir)), "tokens": tokens,
             "before": before, "after": after}
@@ -158,7 +151,7 @@ def test_read_launch_is_the_step_before_when_ahead(traced):
 
 
 def test_read_launch_is_the_same_call_under_sync(tmp_path):
-    engine = tiny_engine()
+    engine = tiny_gpt_engine()
     with step_by_step():
         spans, _ = serve(engine, [(0, PROMPTS[0], 4)], tmp_path)
     disp = [s[3] for s in spans if s[0] == "decode.dispatch"]
@@ -262,7 +255,7 @@ def test_no_session_nothing_written_and_the_counter_changes_no_token(
     monkeypatch.chdir(tmp_path)
     n_events = len(profiler._collector.events)
     before = profiler.launched(DECODE_MODULE)
-    _, counted = serve(tiny_engine(),
+    _, counted = serve(tiny_gpt_engine(),
                        [(0, PROMPTS[0], 5), (1, PROMPTS[1], 4)])
     # five tokens: the prefill's and four decode steps
     assert profiler.launched(DECODE_MODULE) == before + 4
@@ -271,7 +264,7 @@ def test_no_session_nothing_written_and_the_counter_changes_no_token(
     monkeypatch.setattr(profiler, "launch", lambda program: 0)
     monkeypatch.setattr(train_step_mod, "_launch", lambda program: 0)
     frozen = {m: profiler.launched(m) for m in ENQUEUING.values()}
-    _, plain = serve(tiny_engine(),
+    _, plain = serve(tiny_gpt_engine(),
                      [(0, PROMPTS[0], 5), (1, PROMPTS[1], 4)])
     step, ids = tiny_trainer()
     step(ids, ids)

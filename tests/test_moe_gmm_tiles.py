@@ -12,6 +12,7 @@ import pytest
 import jax.numpy as jnp
 
 from paddle2_tpu.kernels import moe_gmm as G
+from served import visits_by_hand
 
 # (cell shape, rows routed T, k, groups routed over, first, held,
 #  hidden K, width N): m = T x k rows, sizes count one parking group more
@@ -38,14 +39,6 @@ def routed_sizes(rng, T, k, E, first, held):
     ids = np.argsort(-score, 1)[:, :k].ravel()
     ids = np.where((ids >= first) & (ids < first + held), ids, E)
     return np.bincount(ids, minlength=E + 1).astype(np.int32)
-
-
-def visits_by_hand(sizes, first, held, tm):
-    """(row tile, group) pairs that share rows, held groups only."""
-    ends = np.cumsum(sizes)
-    starts = ends - sizes
-    return sum(int((ends[g] - 1) // tm - starts[g] // tm + 1)
-               for g in range(first, first + held) if sizes[g])
 
 
 @pytest.mark.parametrize("tm", ROW_TILES)

@@ -24,82 +24,21 @@ magnitude of room and is two orders under what a bf16-for-f32
 substitution gives (``test_tolerance_rejects_bf16``). States are
 compared to ``STATE_TOL`` = 2e-5 (absolute, on states of scale ~1)."""
 
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle2_tpu as paddle
-from paddle2_tpu.distributed.fault_tolerance import chaos
 from paddle2_tpu.incubate.moe import DroplessExperts, sigmoid_topk_route
 from paddle2_tpu.models import (NemotronHConfig, NemotronHForCausalLM,
                                 nemotron_h_tiny)
 from paddle2_tpu.models._decoder import GroupedQueryAttention, Relu2MLP
-from paddle2_tpu.serving.block_cache import audit_kv_ledger
-from paddle2_tpu.serving.model_runner import PagedRunner
-from paddle2_tpu.serving.spec import SpeculativeConfig
-# the comparison of served logits and the drive to idle are Falcon-H1's
-# (same fixture keys, same tolerance); the logits tap and the tiny
-# engine are LFM2's
-from test_falcon_h1 import check_against_reference, run_to_idle
-from test_lfm2_moe import logit_tap, serve, tiny_engine  # noqa: F401
+from served import (LOGIT_TOL, build, shared_programs,  # noqa: F401
+                    ref_logits_highest as ref_logits)
+from served import nemotron_h_bench as bench, NEMOTRON_CUT as CUT
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-LOGIT_TOL = 5e-5
-STATE_TOL = 2e-5
-CUT = "MEMEM*EME"          # the benchmark's cut, at the rehearsal's widths
-PATTERN = CUT[:6]          # what the engine tests serve: every kind, 6 layers
-
-
-@pytest.fixture(scope="module")
-def bench():
-    """The benchmark's modules and the tiny (rehearsal) configuration."""
-    added = [p for p in (BENCH,) if p not in sys.path]
-    sys.path[:0] = added
-    import run as harness
-    from common import load_module
-    from drivers import program, serve_staged_dense
-    from weights import make_weights
-    with open(os.path.join(BENCH, "configs",
-                           "nemotron-3-nano-30b-a3b.json")) as f:
-        published = json.load(f)
-    whole = harness.merge(published, published["rehearsal"])
-    whole["name"] = "nemotron-3-nano-30b-a3b"
-    assert whole["hybrid_override_pattern"] == CUT
-    # the layout names a leaf by its layer's index: a prefix of the
-    # pattern is served through the same file
-    cfg = dict(whole, hybrid_override_pattern=PATTERN,
-               num_hidden_layers=len(PATTERN))
-    ref = load_module("reference", cfg["reference"])
-    yield {"cfg": cfg, "whole": whole, "published": published, "ref": ref,
-           "program": program, "driver": serve_staged_dense,
-           "make_weights": make_weights}
-    for p in added:
-        sys.path.remove(p)
-
-
-def build(bench, seed, cfg=None, **overrides):
-    """(model with the seed's weights, its config, the reference's
-    float32 leaves of the same seed)."""
-    cfg = cfg or bench["cfg"]
-    model, mcfg = bench["program"].build_model(cfg, overrides)
-    model.eval()
-    bench["driver"].place_weights(model, cfg, "per_layer", bench["ref"],
-                                  seed)
-    params = bench["make_weights"](bench["ref"].leaf_specs(cfg), seed,
-                                   jnp.float32)
-    return model, mcfg, params
-
-
-def ref_logits(bench, params, seq, cfg=None):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(bench["ref"].logits(
-            params, jnp.asarray([seq], jnp.int32), cfg or bench["cfg"])[0])
+pytestmark = pytest.mark.usefixtures("shared_programs")
 
 
 # ------------------------------------------------------------- the pieces
@@ -196,6 +135,7 @@ def test_config_takes_the_published_keys(bench):
     the config class under its own name, and the published values are
     its defaults."""
     pub = bench["published"]
+    assert bench["whole"]["hybrid_override_pattern"] == CUT
     kwargs = pub["program"]["config_kwargs"]
     cfg = NemotronHConfig()
     assert len(cfg.hybrid_override_pattern) == cfg.num_hidden_layers == 52
@@ -368,219 +308,3 @@ def test_two_shares_add_up_to_the_uncut_layer(bench):
     # each share alone is NOT the layer
     assert np.abs(parts[0] + np.asarray(shared)
                   - np.asarray(want - x)).max() > 100 * LOGIT_TOL
-
-
-# ------------------------------------------------- prefill + paged decode
-def test_prefill_then_paged_decode_logits(bench, logit_tap):
-    """Prompts that are no multiples of 16 (nor of the block size or
-    the chunk, 8), three sequences in one batch: every step's logits
-    against the reference's full forward over prompt + generated."""
-    model, _, params = build(bench, 5)
-    engine = tiny_engine(model)
-    rng = np.random.default_rng(5)
-    prompts = [rng.integers(1, 503, n).tolist() for n in (5, 21, 37)]
-    rids, rows = serve(engine, prompts, 7, logit_tap)
-    check_against_reference(bench, params, engine, rids, rows)
-    # blocks and slots are back with the manager
-    assert engine.allocator.used_count == 0
-    assert engine.allocator.state_slots_used == 0
-    audit_kv_ledger(engine.allocator, [], state_pools=engine.cache.states)
-
-
-def test_single_token_prompt_state_is_zero_padded(bench, logit_tap):
-    model, _, params = build(bench, 6)
-    engine = tiny_engine(model)
-    rids, rows = serve(engine, [[17]], 5, logit_tap)
-    check_against_reference(bench, params, engine, rids, rows)
-
-
-def test_prefill_state_is_the_state_at_the_last_real_position(bench):
-    """A 21-token prompt is padded to 32: the states handed to the slot
-    are those of an unpadded pass over the 21 tokens, and the padded
-    tail is not routed."""
-    model, _, _ = build(bench, 7)
-    runner = PagedRunner(model, interpret=True)
-    ids = np.random.default_rng(7).integers(1, 503, 21).tolist()
-    _, _, _, conv, ssm_state = runner.prefill(ids)
-    with runner.bound():
-        _, _, states, records = model.model.full(
-            jnp.asarray([ids], jnp.int32), interpret=True)
-    assert len(states) == PATTERN.count("M") == conv.shape[0]
-    for li, (xbc, H) in enumerate(states):
-        assert float(jnp.abs(ssm_state[li] - H[0]).max()) <= STATE_TOL
-        assert float(jnp.abs(conv[li] - xbc[0, -3:]).max()) <= STATE_TOL
-    with runner.bound():
-        padded = jnp.asarray([ids + [0] * 11], jnp.int32)
-        _, _, at_end, _ = model.model.full(padded, interpret=True)
-        valid = (jnp.arange(32) <= 20)[None]
-        _, _, _, routed = model.model.full(padded, valid, interpret=True)
-    assert float(jnp.abs(at_end[0][1][0] - ssm_state[0]).max()) \
-        > 100 * STATE_TOL
-    rows = DroplessExperts.COUNT_NAMES.index("moe_rows")
-    assert [int(r[rows]) for r in routed] == [21] * PATTERN.count("E")
-    assert [int(r[rows]) for r in records] == [21] * PATTERN.count("E")
-
-
-@pytest.mark.parametrize("n,m", [(5, 6), (16, 3), (23, 9)])
-def test_prefill_plus_decode_is_a_longer_prefill(bench, n, m):
-    """A prefill of n tokens + m decode steps leaves the slot's states,
-    and yields the tokens, of a prefill of n + m tokens."""
-    model, _, _ = build(bench, 8)
-    prompt = np.random.default_rng(n).integers(1, 503, n).tolist()
-    engine = tiny_engine(model, max_batch=1)
-    rid = engine.submit(prompt, m + 1)
-    now = 0.0
-    while len(engine.sequence(rid).generated) < m + 1:
-        now += 1.0
-        engine.tick(now)
-        if engine.sequence(rid).done:
-            break
-    gen = list(engine.sequence(rid).generated)
-    # the slot after m decode steps (the last token is not fed)
-    conv = np.asarray(engine.cache.states["conv"][:, 1])
-    ssm_state = np.asarray(engine.cache.states["ssm"][:, 1])
-    runner = PagedRunner(model, interpret=True)
-    first, _, _, conv2, ssm2 = runner.prefill(prompt + gen[:m])
-    assert first == gen[m]
-    assert np.abs(conv - np.asarray(conv2)).max() <= STATE_TOL
-    assert np.abs(ssm_state - np.asarray(ssm2)).max() <= STATE_TOL
-
-
-def test_eviction_and_readmission_give_same_logits(bench, logit_tap):
-    """A pool too small for the batch: sequences are evicted (blocks
-    AND slot freed) and re-prefilled from their token logs; every
-    logits row still matches the reference."""
-    model, _, params = build(bench, 9)
-    engine = tiny_engine(model, num_blocks=12, max_batch=3)
-    rng = np.random.default_rng(9)
-    prompts = [rng.integers(1, 503, n).tolist() for n in (19, 23, 27)]
-    rids, rows = serve(engine, prompts, 12, logit_tap)
-    assert engine.scheduler.total_evictions > 0
-    check_against_reference(bench, params, engine, rids, rows)
-    assert engine.allocator.state_slots_used == 0
-
-
-@pytest.mark.parametrize("fault", ["drop_decode_step:2",
-                                   "drop_decode_step:3,drop_decode_step:5"])
-def test_dropped_step_leaves_the_served_tokens(bench, fault, monkeypatch):
-    """ROADMAP D13: a discarded step has already moved the states its
-    repeat would read. Its rows are re-prefilled, and the served tokens
-    are those of an undisturbed run."""
-    model, _, _ = build(bench, 10)
-    rng = np.random.default_rng(10)
-    prompts = [rng.integers(1, 503, n).tolist() for n in (9, 14, 20)]
-    want = run_to_idle(tiny_engine(model), prompts, 10)
-    monkeypatch.setattr(chaos, "_ACTIVE", chaos.ChaosInjector(fault))
-    engine = tiny_engine(model)
-    got = run_to_idle(engine, prompts, 10)
-    assert engine.state_reprefills >= 3
-    assert got == want
-    assert engine.allocator.state_slots_used == 0
-
-
-def test_served_experts_are_the_references_choice(bench):
-    """``engine.routed_experts``: per fed token and expert layer the
-    experts the served path chose — the reference's own top k (deficit 0
-    in its biased scores) in float32."""
-    model, _, params = build(bench, 12)
-    engine = tiny_engine(model)
-    prompt = np.random.default_rng(12).integers(1, 503, 19).tolist()
-    rid = engine.submit(prompt, 6)
-    now = 0.0
-    while not engine.idle():
-        now += 1.0
-        engine.tick(now)
-    routed = engine.routed_experts(rid)
-    seq = prompt + list(engine.sequence(rid).generated)
-    n = len(seq) - 1
-    assert routed.shape == (n, PATTERN.count("E"), 2)
-    ref, cfg = bench["ref"], bench["cfg"]
-    with jax.default_matmul_precision("highest"):
-        _, _, deficit = ref.forward(
-            params, jnp.asarray([seq[:n]], jnp.int32), cfg,
-            forced=jnp.asarray(routed[None]))
-    assert float(deficit.max()) <= 1e-6
-
-
-# ------------------------------------------------ what the pools count
-@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16"])
-def test_pools_count_three_different_sets_of_layers(kv_dtype):
-    """State pools count the ``M`` layers, the K/V pools the ``*``
-    layers, the routing record the ``E`` layers; ``conv`` in the cache's
-    dtype, ``ssm`` float32 whatever it is; the ledger closes."""
-    paddle.seed(0)
-    mcfg = nemotron_h_tiny()
-    assert mcfg.hybrid_override_pattern == "MEM*EME"
-    model = NemotronHForCausalLM(mcfg)
-    model.eval()
-    engine = tiny_engine(model, max_batch=2, kv_dtype=kv_dtype)
-    cache, alloc, family = engine.cache, engine.allocator, \
-        engine.runner.family
-    assert family.layer_counts == {"ssm_layers": 3, "attn_layers": 1,
-                                   "moe_layers": 3}
-    assert family.routed == (3, 2)
-    assert cache.k.shape[0] == cache.v.shape[0] == 1
-    assert cache.k.shape[-1] == 2 * 16
-    assert list(cache.states) == ["conv", "ssm"]
-    assert cache.states["conv"].shape == (3, 3, 3, mcfg.conv_dim)
-    assert cache.states["conv"].dtype == jnp.dtype(kv_dtype)
-    assert cache.states["ssm"].shape == (3, 3, 4, 16, 16)
-    assert cache.states["ssm"].dtype == jnp.float32
-    assert cache.state_slot_bytes == 3 * (
-        3 * mcfg.conv_dim * jnp.dtype(kv_dtype).itemsize + 4 * 16 * 16 * 4)
-    rid = engine.submit([5, 6, 7], 3)
-    engine.admit_and_prefill(0.0)
-    slot = engine.sequence(rid).table.state_slot
-    census = audit_kv_ledger(
-        alloc, [engine.sequence(rid).table.blocks],
-        live_state_slots=[slot], state_pools=cache.states)
-    assert census["state_kinds"] == 2 and census["state_slots_claimed"] == 1
-    now = 0.0
-    while not engine.idle():
-        now += 1.0
-        engine.tick(now)
-    assert engine.routed_experts(rid).shape == (3 + 3 - 1, 3, 2)
-
-
-@pytest.mark.parametrize("feature", [
-    dict(weight_only_int8=True), dict(weight_only_lm_head=True),
-    dict(spec=SpeculativeConfig(num_draft_tokens=2)),
-    dict(enable_prefix_cache=True, enable_kv_spill=True)])
-def test_engine_refuses_what_the_family_lacks(feature):
-    paddle.seed(0)
-    model = NemotronHForCausalLM(nemotron_h_tiny())
-    with pytest.raises(ValueError, match="not served with"):
-        tiny_engine(model, **feature)
-
-
-def test_served_tokens_are_the_models_own_argmax():
-    """No reference weights: the tiny preset served through the engine
-    yields the argmax of the model's own full forward over prompt +
-    stream."""
-    paddle.seed(5)
-    model = NemotronHForCausalLM(nemotron_h_tiny())
-    model.eval()
-    prompt = np.random.default_rng(5).integers(1, 503, 21).tolist()
-    (gen,) = run_to_idle(tiny_engine(model), [prompt], 6)
-    lg = np.asarray(model(paddle.to_tensor(
-        np.asarray([prompt + gen], np.int32)))._data)[0]
-    assert gen == [int(lg[len(prompt) - 1 + i].argmax())
-                   for i in range(len(gen))]
-
-
-def test_artifact_path_serves_the_family(bench, tmp_path):
-    """jit.save -> inference.Config -> create_serving_engine: the tokens
-    of the live-model engine."""
-    from paddle2_tpu import inference
-    model, mcfg, _ = build(bench, 14)
-    prompt = np.random.default_rng(14).integers(1, 503, 13).tolist()
-    want = run_to_idle(tiny_engine(model), [prompt], 5)
-    path = str(tmp_path / "model")
-    paddle.jit.save(model, path)
-    conf = inference.Config(path)
-    conf.enable_continuous_batching(block_size=8, num_blocks=64,
-                                    max_batch=4, max_model_len=96,
-                                    kv_dtype="float32", interpret=True)
-    engine = conf.create_serving_engine(gpt_config=mcfg)
-    assert isinstance(engine.model, NemotronHForCausalLM)
-    assert run_to_idle(engine, [prompt], 5) == want

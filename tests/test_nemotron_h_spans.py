@@ -19,28 +19,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle2_tpu as paddle
 from paddle2_tpu.incubate.moe import DroplessExperts
 from paddle2_tpu.models import NemotronHForCausalLM, nemotron_h_tiny
-from paddle2_tpu.serving import EngineConfig, ServingEngine
-from test_decode_ahead_spans import serve_traced
-from test_falcon_h1_spans import reader
-from test_program_spans import scope_in
+from served import (reader, scope_in, seeded_engine,  # noqa: F401
+                    serve_traced, shared_programs)
 
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
 PROMPTS = (9, 12, 21)
+pytestmark = pytest.mark.usefixtures("shared_programs")
 LAYERS = {"ssm_layers": 3, "attn_layers": 1, "moe_layers": 3}
 
 
 def tiny_engine(**kw):
-    paddle.seed(0)
-    model = NemotronHForCausalLM(nemotron_h_tiny())
-    model.eval()
-    conf = dict(block_size=8, num_blocks=64, max_batch=4, max_model_len=96,
-                batch_buckets=(4,), page_buckets=(4,), interpret=True)
-    conf.update(kw)
-    return ServingEngine(model, config=EngineConfig(**conf))
+    return seeded_engine(NemotronHForCausalLM, nemotron_h_tiny(), **kw)
 
 
 @pytest.fixture(scope="module")

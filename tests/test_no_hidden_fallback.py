@@ -218,6 +218,8 @@ def test_cache_env_var_never_updates_jax_config(monkeypatch):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     flags._apply_compilation_cache("")
     assert "jax_compilation_cache_dir" in seen
+    # that call switched the cache off: hand the run's back (conftest.py)
+    flags._apply_compilation_cache(flags.compile_cache_dir())
 
 
 def test_cache_repo_override_and_off():

@@ -17,11 +17,11 @@ from paddle2_tpu import profiler
 from paddle2_tpu.models import GPTForCausalLM, gpt_tiny
 from paddle2_tpu.serving.scheduler import SeqState
 from paddle2_tpu.serving.spec import SpeculativeConfig
-from test_decode_ahead import (FAMILIES, NEVER, armed, assert_same, both,
-                               drive, engine_of, models, prompts_of,
-                               step_by_step)
+from served import (FAMILIES, NEVER, armed, assert_same, both,  # noqa: F401
+                    drive, engine_of, models, own_programs,
+                    prompts_of, shared_programs, step_by_step)
 
-__all__ = ["models"]            # the fixture, shared with the decode tests
+pytestmark = pytest.mark.usefixtures("shared_programs")
 
 
 def streams(engine, rids):
@@ -302,7 +302,8 @@ def test_swap_weights_delivers_the_first_token_first(models):
 
 # -- no program beyond the grid, nothing built after the warm-up -------------
 @pytest.mark.parametrize("family", FAMILIES)
-def test_nothing_is_built_after_the_warm_up(models, family, caplog):
+def test_nothing_is_built_after_the_warm_up(models, family, caplog,
+                                            own_programs):
     """The warm-up's shape (a full batch, two tokens each) reaches every
     program a window of mixed iterations calls: no build record, no
     compilation of a ``p2t_`` program, the same program counts as the

@@ -9,7 +9,6 @@ place, inside the ``admit`` — so that the benchmark's readers, which may
 not be edited and take counts with ``.get``, keep reading what they read:
 the last test runs their own code over the engine's spans."""
 
-import importlib.util
 import os
 import sys
 import types
@@ -21,8 +20,8 @@ import paddle2_tpu as paddle
 from paddle2_tpu.models import Lfm2MoeForCausalLM, lfm2_moe_tiny
 from paddle2_tpu.serving import EngineConfig, ServingEngine
 from paddle2_tpu.serving.spec import SpeculativeConfig
-from test_decode_ahead_spans import ROUTING, serve_traced
-from test_program_spans import PROMPTS, tiny_engine
+from served import (PROMPTS, ROUTING, reader, seeded_engine, serve_traced,
+                    tiny_gpt_engine, visits_by_hand)
 
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -42,18 +41,13 @@ def split(spans):
 
 @pytest.fixture(scope="module")
 def gpt_spans(tmp_path_factory):
-    return serve_traced(tmp_path_factory, tiny_engine(),
+    return serve_traced(tmp_path_factory, tiny_gpt_engine(),
                         [(PROMPTS[0], 4), (PROMPTS[1], 3)])
 
 
 @pytest.fixture(scope="module")
 def lfm2_traced(tmp_path_factory):
-    paddle.seed(0)
-    model = Lfm2MoeForCausalLM(lfm2_moe_tiny())
-    model.eval()
-    engine = ServingEngine(model, config=EngineConfig(
-        block_size=8, num_blocks=64, max_batch=4, max_model_len=96,
-        batch_buckets=(4,), page_buckets=(4,), interpret=True))
+    engine = seeded_engine(Lfm2MoeForCausalLM, lfm2_moe_tiny())
     rng = np.random.default_rng(0)
     spans = serve_traced(
         tmp_path_factory, engine,
@@ -135,11 +129,7 @@ def readers(monkeypatch, lfm2_traced):
         monkeypatch.delitem(sys.modules, name, raising=False)
     import moe_trace
     import program_trace
-    spec = importlib.util.spec_from_file_location(
-        "prefill_span_reader", os.path.join(
-            BENCHMARK, "layer_metrics", "prefill_span_ms_per_ktok.serve.py"))
-    per_ktok = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(per_ktok)
+    per_ktok = reader("prefill_span_ms_per_ktok.serve")
     pt = program_trace.ProgramTrace()
     pt.spans = list(lfm2_traced[1])
     ctx = {"cell": {"trace_dir": "spans-of-the-test"},
@@ -187,7 +177,6 @@ def tile_rows_by_hand(chosen, padded, E):
     parked behind the last one; a visit is a (row tile, expert) pair
     that share rows."""
     from paddle2_tpu.kernels.moe_gmm import _row_tile
-    from test_moe_gmm_tiles import visits_by_hand
     rows, layers, k = chosen.shape
     tm = _row_tile(padded * k, E + 1)
     return tm * sum(
@@ -218,11 +207,7 @@ def test_tile_rows_ride_with_the_routing_counts(readers, lfm2_traced):
         # batch of 4 rows
         rows = np.stack([ch[n + j] for n, ch in zip(LFM2_PROMPTS, chosen)])
         assert c["moe_tile_rows"] == tile_rows_by_hand(rows, 4, E)
-    spec = importlib.util.spec_from_file_location(
-        "tile_fill_reader", os.path.join(
-            BENCHMARK, "layer_metrics", "moe_gmm_tile_fill_pct.serve.py"))
-    fill = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(fill)
+    fill = reader("moe_gmm_tile_fill_pct.serve")
     counted = [s[3] for s in delivered] + steps
     want = 100.0 * sum(c["moe_assignments"] for c in counted) \
         / sum(c["moe_tile_rows"] for c in counted)

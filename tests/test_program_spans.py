@@ -5,9 +5,7 @@ contract (PERF.md section 3 lists them), nested and counted; with no
 session nothing is written; scopes and names change no arithmetic."""
 
 import contextlib
-import glob
 import os
-import re
 
 import jax
 import numpy as np
@@ -18,6 +16,8 @@ from paddle2_tpu import profiler
 from paddle2_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 from paddle2_tpu.serving import block_cache
 from paddle2_tpu.serving.engine import EngineConfig, ServingEngine
+from served import (PARENT, PROMPTS, read_spans, scope_in, tiny_gpt_engine,
+                    tiny_trainer, trace_session)
 
 TRAIN_SPANS = ["train.step", "train.prepare", "train.dispatch",
                "train.rebind"]
@@ -26,40 +26,6 @@ SERVE_SPANS = ["submit", "admit", "admit.schedule", "prefill",
                "decode", "decode.select", "decode.build_batch",
                "decode.dispatch", "decode.readback", "decode.emit"]
 BUILD_SPANS = ["build", "build.cost"]
-PARENT = {
-    "train.prepare": "train.step", "train.dispatch": "train.step",
-    "train.rebind": "train.step",
-    # a prefill's first token is read back inside a second `prefill`
-    # span, in the `decode` that enqueued the step consuming it
-    "admit.schedule": "admit", "prefill": ("admit", "decode"),
-    "prefill.dispatch": "prefill", "prefill.readback": "prefill",
-    "prefill.scatter": "prefill",
-    "decode.select": "decode", "decode.build_batch": "decode",
-    "decode.dispatch": "decode", "decode.readback": "decode.dispatch",
-    "decode.emit": "decode", "build.cost": "build",
-}
-PROMPTS = ([1, 2, 3, 4, 5], [6, 7, 8])
-
-
-def tiny_trainer(**cfg):
-    paddle.seed(0)
-    model = GPTForCausalLM(gpt_tiny(**cfg))
-    opt = paddle.optimizer.AdamW(
-        learning_rate=1e-3, parameters=model.parameters(),
-        grad_clip=paddle.nn.ClipGradByGlobalNorm(1.0))
-    step = paddle.jit.train_step(
-        lambda ids, labels: model(ids, labels=labels)[-1], opt)
-    ids = paddle.to_tensor(
-        np.random.default_rng(0).integers(0, 128, (2, 16)).astype("int32"))
-    return step, ids
-
-
-def tiny_engine():
-    paddle.seed(0)
-    model = GPTForCausalLM(gpt_tiny())
-    model.eval()
-    return ServingEngine(model, config=EngineConfig(
-        block_size=4, num_blocks=32, max_batch=4, max_model_len=64))
 
 
 def run_both():
@@ -67,7 +33,7 @@ def run_both():
     (losses, served tokens, the first tick's info dict)."""
     step, ids = tiny_trainer()
     losses = [float(step(ids, ids)) for _ in range(2)]
-    engine = tiny_engine()
+    engine = tiny_gpt_engine()
     rids = [engine.submit(PROMPTS[0], 4, trace_id=77),
             engine.submit(PROMPTS[1], 3)]
     first = engine.tick(0.0)
@@ -79,23 +45,6 @@ def run_both():
     return losses, tokens, first
 
 
-def read_spans(trace_dir):
-    """[(name without the prefix, start_ns, end_ns, counts)] of the
-    program's spans in the newest trace under ``trace_dir``."""
-    from jax.profiler import ProfileData
-    path = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
-    out = []
-    for plane in ProfileData.from_file(path).planes:
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith(profiler.SPAN_PREFIX):
-                    out.append((e.name[len(profiler.SPAN_PREFIX):],
-                                e.start_ns, e.start_ns + e.duration_ns,
-                                dict(e.stats)))
-    return sorted(out, key=lambda s: (s[1], -s[2]))
-
-
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     trace_dir = str(tmp_path_factory.mktemp("p2t_trace"))
@@ -104,11 +53,8 @@ def traced(tmp_path_factory):
     # first in this worker with the same engine would leave nothing to
     # build here, and the build log's test below counts builds
     block_cache._PREFILL_SCATTER_CACHE.clear()
-    jax.profiler.start_trace(trace_dir)
-    try:
+    with trace_session(trace_dir):
         losses, tokens, first = run_both()
-    finally:
-        jax.profiler.stop_trace()
     return {"spans": read_spans(trace_dir), "losses": losses,
             "tokens": tokens, "first_tick": first,
             "builds": profiler.builds()}
@@ -350,7 +296,7 @@ def test_no_session_nothing_recorded_nothing_written(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     step, ids = tiny_trainer()
     step(ids, ids)
-    engine = tiny_engine()
+    engine = tiny_gpt_engine()
     engine.submit(PROMPTS[0], 3)
     engine.tick(0.0)
     n_builds = len(profiler.builds())
@@ -381,15 +327,6 @@ def test_scopes_and_spans_change_no_arithmetic(traced, monkeypatch):
     assert tokens == traced["tokens"]
 
 
-def scope_in(text: str, scope: str) -> bool:
-    """A scope of the program somewhere in an op's name-stack path, as
-    it is or wrapped by a transformation: ``/attn/``, ``jvp(attn)``,
-    ``transpose(jvp(attn))``; or at the path's end, where the scope
-    holds one op that lowers to a call (``.../sample"``), which the
-    trace reader's ``_SCOPE_TOKEN`` takes too."""
-    return re.search(r"[/(\"]" + scope + r"[/)\"]", text) is not None
-
-
 @pytest.fixture(scope="module")
 def lowered_train_text():
     step, ids = tiny_trainer(use_recompute=True,
@@ -415,7 +352,7 @@ def test_lowered_train_step_is_named(lowered_train_text):
 @pytest.fixture(scope="module")
 def lowered_serving_texts():
     import jax.numpy as jnp
-    engine = tiny_engine()
+    engine = tiny_gpt_engine()
     runner, cache = engine.runner, engine.cache
     decode = runner._build_decode(2, 2, cache.block_size)
     dec = decode.lower(*runner._decode_args(

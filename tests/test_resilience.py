@@ -182,7 +182,10 @@ class TestWorkerSelfHealing:
         assert out == list(range(21))       # ordered, exactly once
 
     def test_killed_before_first_batch_respawns(self):
-        dl = DataLoader(_IdxDataset(16, delay=0.02), batch_size=2,
+        # 1.6 s of work a worker: one that a loaded host lets finish its
+        # share before the kill lands needs no respawn (seen once, PR 44,
+        # at 0.16 s a worker: `_restarts[0]` 0)
+        dl = DataLoader(_IdxDataset(16, delay=0.2), batch_size=2,
                         num_workers=2)
         it = iter(dl)
         os.kill(it._procs[0], signal.SIGKILL)

@@ -7,7 +7,6 @@ context once a pass), and of the step it READS BACK ``tokens_fixed`` and
 ``tokens`` / ``padded`` and gains ``block_tokens``. The last test runs
 the benchmark's new readers' own code over the engine's spans."""
 
-import importlib.util
 import os
 import sys
 import types
@@ -15,10 +14,8 @@ import types
 import numpy as np
 import pytest
 
-import paddle2_tpu as paddle
 from paddle2_tpu.models import SdarMoeForCausalLM, sdar_moe_tiny
-from paddle2_tpu.serving import EngineConfig, ServingEngine
-from test_decode_ahead_spans import ROUTING, serve_traced
+from served import ROUTING, reader, seeded_engine, serve_traced
 
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -27,13 +24,9 @@ PROMPTS, NEW = (9, 12, 14), (8, 7, 4)           # left over: 1, 0, 2
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    paddle.seed(0)
-    model = SdarMoeForCausalLM(sdar_moe_tiny(num_hidden_layers=2))
-    model.eval()
-    engine = ServingEngine(model, config=EngineConfig(
-        block_size=8, num_blocks=64, max_batch=4, max_model_len=96,
-        batch_buckets=(4,), page_buckets=(4,), interpret=True,
-        denoising_steps=2))
+    engine = seeded_engine(SdarMoeForCausalLM,
+                           sdar_moe_tiny(num_hidden_layers=2),
+                           denoising_steps=2)
     rng = np.random.default_rng(0)
     spans = serve_traced(
         tmp_path_factory, engine,
@@ -101,15 +94,7 @@ def readers(monkeypatch, traced):
                         lambda trace_dir: trace_dir)
     monkeypatch.setitem(program_trace._LOADED, "spans-of-the-test", pt)
 
-    def reader(name):
-        spec = importlib.util.spec_from_file_location(
-            name.replace(".", "_"),
-            os.path.join(BENCHMARK, "layer_metrics", name + ".py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
-
-    yield reader
+    yield lambda name: reader(name).read
     for name in mods:
         sys.modules.pop(name, None)
 

@@ -24,7 +24,10 @@ from paddle2_tpu.serving import (
     ServingEngine, SeqState, poisson_trace, simulate_router)
 from paddle2_tpu.tools import perf_doctor, serve_doctor
 
-pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
+from served import shared_programs  # noqa: F401,E402
+
+pytestmark = [pytest.mark.filterwarnings("ignore::DeprecationWarning"),
+              pytest.mark.usefixtures("shared_programs")]
 
 
 @pytest.fixture(autouse=True)
