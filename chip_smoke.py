@@ -262,6 +262,8 @@ def kernel_parity(size: dict) -> dict:
                     f"paged {name} kernel ({case}) off the dense "
                     f"reference by {gap}")
     err.update(gqa_parity(size))
+    err.update(ring_parity(size))
+    err.update(long_table_parity(size))
     err.update(gmm_parity(size))
     err.update(ssm_step_parity(size))
     err.update(flash_parity(size))
@@ -585,6 +587,79 @@ def gqa_parity(size: dict) -> dict:
         raise AssertionError(f"paged GQA kernel off the dense reference "
                              f"by {gap}")
     return {"gqa.single": gap}
+
+
+def ring_parity(size: dict) -> dict:
+    """The sliding-window layers' ring walk (``window_decode``: the paged
+    single-softmax body over a slot's ring seen as pages) at the
+    K-EXAONE cell's decode shape where the size allows: 128 rows, 64
+    query over 8 key/value heads of 128, a window of 128, rings of 129
+    slots x 2 layers; contexts of 1, the window and 9,216 (every row of
+    the ring live) among ragged ones, slots shuffled."""
+    import jax.numpy as jnp
+    from paddle2_tpu.serving.exaone_moe_family import ring_walk
+    from paddle2_tpu.serving.paged_attention import paged_attention_reference
+    big = size["hidden"] >= 1024
+    rows, H, Hkv, D, W = (128, 64, 8, 128, 128) if big else (8, 4, 2, 16, 16)
+    rng = np.random.default_rng(3)
+    ctx = rng.integers(1, 9217, rows)
+    ctx[:5] = [1, 17, W - 1, W, 9216]
+    live = np.minimum(ctx, W).astype(np.int32)
+    slots = rng.permutation(np.arange(1, rows + 1)).astype(np.int32)
+    ring_k, ring_v = (jnp.asarray(
+        rng.normal(size=(2, rows + 1, W, Hkv * D)), jnp.bfloat16)
+        for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(rows, 1, H, D)), jnp.bfloat16)
+    out = np.asarray(ring_walk(q, ring_k, ring_v, 1, jnp.asarray(slots),
+                               jnp.asarray(live)), np.float32)
+    # the dense reference over the same rings, a slot one page of W rows
+    ref = np.concatenate([np.asarray(paged_attention_reference(
+        q[r:r + 8], ring_k[1], ring_v[1], slots[r:r + 8, None],
+        live[r:r + 8]), np.float32) for r in range(0, rows, 8)])
+    gap = float(np.abs(out - ref).max())
+    if out.shape != ref.shape or not np.isfinite(out).all() or gap > 2e-2:
+        raise AssertionError(f"ring walk off the dense reference by {gap}")
+    return {"ring.window_decode": gap}
+
+
+def long_table_parity(size: dict) -> dict:
+    """The K-EXAONE cell's global layer where the size allows: a table of
+    576 pages (9,216 positions) of 1,024-lane rows, past the
+    single-softmax body's fit budget, so the dispatcher hands it that
+    body under the raised scoped-VMEM limit; 16 rows, contexts of 1, a
+    compute block's edge, past 4,096 and the whole table among ragged
+    ones, on a shuffled pool."""
+    import jax.numpy as jnp
+    from paddle2_tpu.serving import paged_attention as pa
+    big = size["hidden"] >= 1024
+    H, Hkv, D, bs, n_pages, rows = (64, 8, 128, 16, 576, 16) if big \
+        else (4, 2, 16, 8, 12, 8)
+    if big:
+        assert not pa.fits_single_softmax(n_pages, bs, D, jnp.bfloat16, None,
+                                          H, Hkv)
+        assert pa.kernel_pages_per_block(n_pages, bs, H, D, jnp.bfloat16,
+                                         num_kv_heads=Hkv) > 1
+    seq = n_pages * bs
+    rng = np.random.default_rng(4)
+    ctx = rng.integers(1, seq + 1, rows)
+    ctx[:5] = [1, bs * 32, bs * 32 + 1, seq // 2 + 3, seq]
+    ctx = np.minimum(ctx, seq)
+    n_blocks = rows * n_pages + 1
+    tables = (rng.permutation(np.arange(1, n_blocks))
+              .reshape(rows, n_pages).astype(np.int32))
+    kp, vp = (jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv * D)),
+                          jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(rows, 1, H, D)), jnp.bfloat16)
+    ref = np.concatenate([np.asarray(pa.paged_attention_reference(
+        q[r:r + 8], kp, vp, tables[r:r + 8], ctx[r:r + 8]), np.float32)
+        for r in range(0, rows, 8)])
+    out = np.asarray(pa.paged_attention_decode(q, kp[None], vp[None], tables,
+                                               ctx), np.float32)
+    gap = float(np.abs(out - ref).max())
+    if not np.isfinite(out).all() or gap > 2e-2:
+        raise AssertionError(f"paged kernel over a 576-page table off the "
+                             f"dense reference by {gap}")
+    return {"gqa.long_table": gap}
 
 
 def gmm_parity(size: dict) -> dict:

@@ -1162,6 +1162,7 @@ class ServingEngine:
                 slots[i] = s.table.state_slot
         counts = self._step_counts(len(rows), tables, ctx_tokens,
                                    live_pages, victims)
+        counts.update(self.runner.family.decode_counts(positions[:len(rows)]))
         return _Step(now, active, drafts, (b_bucket, p_bucket),
                      (ids, positions, tables)
                      + (() if slots is None else (slots,)), counts)

@@ -48,7 +48,9 @@ DeepSeek-V2 family (latent attention: one pool) in
 attention in every layer: two state kinds) in ``falcon_h1_family.py``,
 the Nemotron-H family (ONE mixer a layer: state, K/V and the routing
 record count three different sets of layers) in
-``nemotron_h_family.py``.
+``nemotron_h_family.py``, the EXAONE-MoE family (sliding-window layers
+keep a ring of keys and values as state, global layers page) in
+``exaone_moe_family.py``.
 """
 
 from __future__ import annotations
@@ -154,6 +156,12 @@ class ModelFamily:
     def prefill_counts(self, padded: int) -> dict:
         """What the admission's ``prefill`` span says of the family's
         own work on a prompt padded to ``padded`` (nothing by default)."""
+        return {}
+
+    def decode_counts(self, positions) -> dict:
+        """What ``decode.dispatch`` says of the family's own work on a
+        step whose real rows stand at ``positions`` (int array; a row at
+        position p sees p + 1 keys): nothing by default."""
         return {}
 
     def decode(self, k_pool, v_pool, state_pools, ids, positions,
@@ -275,12 +283,14 @@ def served_classes(config) -> tuple:
     rebuilds an artifact's architecture with the first and the runner
     reads the model through the second."""
     from ..models.deepseek import DeepseekV2Config, DeepseekV2ForCausalLM
+    from ..models.exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM
     from ..models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
     from ..models.gpt import GPTConfig, GPTForCausalLM
     from ..models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
     from ..models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
     from ..models.sdar import SdarMoeConfig, SdarMoeForCausalLM
     from .deepseek_family import DeepseekV2Family
+    from .exaone_moe_family import ExaoneMoeFamily
     from .falcon_h1_family import FalconH1Family
     from .lfm2_family import Lfm2MoeFamily
     from .nemotron_h_family import NemotronHFamily
@@ -291,7 +301,8 @@ def served_classes(config) -> tuple:
             (SdarMoeConfig, (SdarMoeForCausalLM, SdarMoeFamily)),
             (DeepseekV2Config, (DeepseekV2ForCausalLM, DeepseekV2Family)),
             (FalconH1Config, (FalconH1ForCausalLM, FalconH1Family)),
-            (NemotronHConfig, (NemotronHForCausalLM, NemotronHFamily))):
+            (NemotronHConfig, (NemotronHForCausalLM, NemotronHFamily)),
+            (ExaoneMoeConfig, (ExaoneMoeForCausalLM, ExaoneMoeFamily))):
         if isinstance(config, config_class):
             return classes
     raise TypeError(

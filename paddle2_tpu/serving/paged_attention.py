@@ -49,7 +49,8 @@ Two bodies behind ONE dispatcher (:func:`paged_attention_decode`):
   Pad slots hold ``finfo.min`` scores (exactly-0.0 probability).
   VMEM scales with the context (scores and the resident V:
   :func:`decode_scratch_vmem_bytes`): past :data:`VMEM_FIT_BUDGET`
-  this body is not dispatched, and at twice the whole limit the
+  this body is dispatched under :data:`VMEM_RAISED_BYTES` as long as
+  half of that holds the scratch, at twice the compiler's own limit the
   compiler refuses it — 32k contexts are what the split body exists
   for.
 
@@ -71,9 +72,9 @@ Two bodies behind ONE dispatcher (:func:`paged_attention_decode`):
   (PERF.md section 6, PR 24). No benchmark cell reaches it.
 
 Dispatch: ``pages_per_split=None`` (the default) picks the
-single-split body whenever its scratch fits the VMEM budget and falls
-over to split-K with an auto-halved split width beyond it
-(:func:`auto_pages_per_split`). The deterministic accounting
+single-split body whenever its scratch fits the VMEM budget (or half
+of the raised limit, which it then asks for) and falls over to split-K
+with an auto-halved split width beyond it (:func:`auto_pages_per_split`). The deterministic accounting
 (:func:`decode_scratch_vmem_bytes`, :func:`modeled_decode_latency_s`)
 is what ``bench.py --serving-throughput`` gates the 32k story on.
 """
@@ -111,6 +112,12 @@ __all__ = ["paged_attention_decode", "paged_attention_reference",
 # share the rest.
 VMEM_BYTES = 16 * 2 ** 20
 VMEM_FIT_BUDGET = VMEM_BYTES // 2
+# A table whose context-resident scratch is past that budget still takes
+# the single-softmax body, under a scoped limit raised as the flash walk
+# raises its own (kernels/pallas_flash._walk_params: half of the core's
+# 128 MiB), as long as the scratch fits half of THAT: 576 pages of 1,024
+# lanes in bf16 (9,216 positions, 21 MB) do, 32k positions do not.
+VMEM_RAISED_BYTES = 64 * 2 ** 20
 
 
 def _precision(dtype):
@@ -624,13 +631,16 @@ def _own_lanes(x, hg: int, head_dim: int, kv_group: int = 1):
 
 def _split_width(n_pages, block_size, num_heads, head_dim, dtype,
                  pages_per_split, num_kv_heads=None):
-    """Pages per split; ``n_pages`` means the single-softmax body."""
+    """Pages per split; ``n_pages`` means the single-softmax body, under
+    the raised limit where only that holds the table (a table it cannot
+    hold is split in two at least, whatever a split could hold)."""
     if pages_per_split is not None:
         return max(1, min(int(pages_per_split), n_pages))
-    fit = (block_size, head_dim, dtype, None, num_heads, num_kv_heads)
-    if fits_single_softmax(n_pages, *fit):
+    if fits_single_softmax(n_pages, block_size, head_dim, dtype,
+                           VMEM_RAISED_BYTES // 2, num_heads, num_kv_heads):
         return n_pages
-    return auto_pages_per_split(n_pages, *fit)
+    fit = (block_size, head_dim, dtype, None, num_heads, num_kv_heads)
+    return min(auto_pages_per_split(n_pages, *fit), -(-n_pages // 2))
 
 
 def kernel_pages_per_block(n_pages: int, block_size: int, num_heads: int,
@@ -667,7 +677,7 @@ def kernel_pages_per_copy(n_pages: int, block_size: int, num_heads: int,
 
 def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
                            scale=None, interpret=None,
-                           pages_per_split=None, layer=0):
+                           pages_per_split=None, layer=0, name="paged_decode"):
     """Paged decode attention.
 
     q: ``[B, Q, H, D]`` (paddle layout) — ``Q`` query positions per
@@ -691,11 +701,12 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
     ctx_lens: int32 ``[B]`` valid keys per sequence (including the
     token just appended). Returns ``[B, Q, H, D]``.
 
-    ``pages_per_split``: split-K width for the flash-decode body.
-    ``None`` auto-dispatches — the single-split global-softmax body
-    whenever its whole-context scratch fits the VMEM budget, else
-    :func:`auto_pages_per_split`. An explicit value forces split-K
-    whenever more than one split results.
+    ``pages_per_split``: split-K width for the flash-decode body; ``None``
+    auto-dispatches (the single-softmax body whenever its whole-context
+    scratch fits the VMEM budget or, under the limit it then asks for,
+    half of :data:`VMEM_RAISED_BYTES`; else :func:`auto_pages_per_split`),
+    a value forces split-K whenever more than one split results. ``name``:
+    the single-softmax body's kernel name (a caller's own walk, own name).
     """
     B, Q, H, D = q.shape
     layer = int(layer)
@@ -730,15 +741,19 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, ctx_lens,
 
     ppb, ppc = _decode_plan(n_pages, bs, Hkv * D, k_pool.dtype,
                             k_pool.shape[1])
+    # past the fit budget the body asks for the raised limit (a forced
+    # single softmax keeps the compiler's own)
+    vmem = None if pages_per_split is not None or fits_single_softmax(
+        n_pages, bs, D, k_pool.dtype, None, H, Hkv) else VMEM_RAISED_BYTES
     return _decode_single(
-        q, k_pool, v_pool, bt, ln, jnp.asarray(layer, jnp.int32),
-        scale=float(scale), interpret=interpret, ppb=ppb, ppc=ppc)
+        q, k_pool, v_pool, bt, ln, jnp.asarray(layer, jnp.int32), name=name,
+        scale=float(scale), interpret=interpret, ppb=ppb, ppc=ppc, vmem=vmem)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "ppb", "ppc"))
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "interpret", "ppb", "ppc", "name", "vmem"))
 def _decode_single(q, k_pool, v_pool, bt, ln, layer, *, scale, interpret,
-                   ppb, ppc):
+                   ppb, ppc, name="paged_decode", vmem=None):
     """The single-softmax body's call, jitted with the layer a traced
     scalar: the 24 calls of a decode program are ONE traced and lowered
     kernel called 24 times, not 24 (0.2 s each to trace and lower on
@@ -785,9 +800,9 @@ def _decode_single(q, k_pool, v_pool, bt, ln, layer, *, scale, interpret,
         out_shape=jax.ShapeDtypeStruct((B, rows, width), q.dtype),
         # rows run in order: each starts the copies of the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
         interpret=interpret,
-        name="paged_decode",
+        name=name,
     )(bt, ln, runs, layer.reshape(1), qr, k_pool, v_pool)
     # [B, R, H_kv*D] -> own lanes [B, H, D] -> [B, 1, H, D]
     return _own_lanes(out, Hkv, D, H // Hkv).reshape(B, 1, H, D)

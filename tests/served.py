@@ -1,5 +1,6 @@
 """The served-family test harness: what the family files (``test_lfm2_moe``,
-``test_deepseek``, ``test_sdar``, ``test_falcon_h1``, ``test_nemotron_h``),
+``test_deepseek``, ``test_sdar``, ``test_falcon_h1``, ``test_nemotron_h``,
+``test_exaone_moe``),
 the run-ahead files (``test_decode_ahead``, ``test_prefill_ahead``) and the
 ``*_spans`` files share. Imported, never collected: no test lives here, and
 no test file imports another.
@@ -126,6 +127,17 @@ nemotron_h_bench = bench_fixture(
     hybrid_override_pattern=NEMOTRON_PATTERN,
     num_hidden_layers=len(NEMOTRON_PATTERN))
 
+# the benchmark's cut without its last layer, at the rehearsal's widths
+# (window 8): a dense sliding layer, two sliding expert layers and the
+# global expert layer
+EXAONE_LAYERS = 4
+exaone_moe_bench = bench_fixture(
+    "k-exaone-236b-a23b", driver="serve_staged_dense",
+    num_hidden_layers=EXAONE_LAYERS,
+    layer_types=["sliding_attention"] * 3 + ["full_attention"],
+    mlp_layer_types=["dense"] + ["sparse"] * 3,
+    sliding_windows=[8, 8, 8, 0])
+
 
 def build(bench, seed, cfg=None, fresh=False, **overrides):
     """(model with the seed's weights, its config, the reference's
@@ -159,19 +171,23 @@ def build_as_read(bench, seed, **config):
     return model, cfg, params
 
 
-def ref_logits(bench, params, seq, cfg=None, precision=None):
+def ref_logits(bench, params, seq, cfg=None, precision=None, pad_to=None):
     """The reference's logits over ``seq``; ``precision``: the matmul
     precision the family's reference is read under (``"highest"`` where a
-    recurrence compounds the default's rounding)."""
+    recurrence compounds the default's rounding); ``pad_to``: a causal
+    reference run at ONE length whatever the sequence's (it runs op by
+    op, and every new length compiles every op again)."""
+    ids = list(seq) + [0] * max(0, (pad_to or 0) - len(seq))
     with jax.default_matmul_precision(precision) if precision \
             else contextlib.nullcontext():
         return np.asarray(bench["ref"].logits(
-            params, jnp.asarray([seq], jnp.int32), cfg or bench["cfg"])[0])
+            params, jnp.asarray([ids], jnp.int32),
+            cfg or bench["cfg"])[0, :len(seq)])
 
 
 def check_against_reference(bench, params, engine, rids, rows,
                             tol=LOGIT_TOL, precision=None,
-                            reprefilled=False):
+                            reprefilled=False, pad_to=None):
     """Every served logits row against the reference's full forward over
     prompt + generated; the widest difference. ``reprefilled``: an evicted
     sequence's re-prefill yields its next token again, so a request may
@@ -185,7 +201,7 @@ def check_against_reference(bench, params, engine, rids, rows,
         else:
             assert len(rows[rid]) == len(gen)
         ref = ref_logits(bench, params, list(prompt) + list(gen),
-                         precision=precision)
+                         precision=precision, pad_to=pad_to)
         for j, row in enumerate(rows[rid][:len(gen)]):
             worst = max(worst, float(np.abs(
                 row - ref[len(prompt) - 1 + j]).max()))

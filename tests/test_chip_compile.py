@@ -832,3 +832,99 @@ def test_explicit_compiled_kernel_never_takes_the_xla_dot():
         pallas_matmul.int8_matmul(x[:256], w, interpret=False)
     # the default stays the XLA lowering off-TPU
     assert pallas_matmul.int8_matmul(x, w).shape == (300, 33)
+
+
+# ---------------------------------- kexaone-serve-mixed8k-backlog's shapes
+def test_window_decode_ring_cell_shape(one_chip):
+    """`kexaone-serve-mixed8k-backlog`'s sliding layers: 128 rows, 64
+    query over 8 key/value heads of 128, the rings of 129 slots x 4
+    layers ``[4, 129, 128, 1024]`` seen as 8 pages of 16 a slot — the
+    paged single-softmax body under its OWN name (``window_decode``: the
+    pattern ``paged_decode`` must read the global layers alone), a whole
+    ring one compute block and one copy, and no copy of anything
+    ring-sized around it (the view is a bitcast)."""
+    from paddle2_tpu.serving.exaone_moe_family import ring_walk
+    assert pa._decode_plan(8, 16, 1024, BF16, 129 * 8) == (8, 8)
+
+    def walk(q, ring_k, ring_v, slots, ctx):
+        return ring_walk(q, ring_k, ring_v, 3, slots, ctx, False)
+
+    ring = ((4, 129, 128, 1024), BF16)
+    text = _compile(one_chip, walk, ((128, 1, 64, 128), BF16), ring, ring,
+                    ((128,), jnp.int32), ((128,), jnp.int32),
+                    kernels=["window_decode"]).as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    assert len(calls) == 1 and "%paged_decode" not in calls[0]
+    assert not [ln for ln in text.splitlines() if " copy(" in ln
+                and ("[4,129,128,1024]" in ln or "[4,1032,16,1024]" in ln)]
+
+
+def test_paged_decode_global_layer_under_the_raised_limit(one_chip):
+    """`kexaone-serve-mixed8k-backlog`'s ONE global layer: 128 rows x 576
+    pages (9,216 positions) over a 73,728-block pool of 1,024-lane rows.
+    Context-resident V for 576 pages (18 MiB) is past the single-softmax
+    body's fit budget and within half of the raised limit, which the
+    dispatcher then asks for (ROADMAP M11's first repair; through the
+    split body a decode step took 101 ms, 88 of them this walk); at the
+    compiler's own limit the same call is refused."""
+    shape = (576, 16, 64, 128, BF16)
+    assert not pa.fits_single_softmax(576, 16, 128, BF16, None, 64, 8)
+    assert pa.fits_single_softmax(576, 16, 128, BF16,
+                                  pa.VMEM_RAISED_BYTES // 2, 64, 8)
+    assert pa.kernel_pages_per_block(*shape, num_kv_heads=8) == 32
+    assert pa.kernel_pages_per_copy(*shape, None, 8, 73728) == 8
+    pool = ((1, 73728, 16, 1024), BF16)
+    avals = (((128, 1, 64, 128), BF16), pool, pool,
+             ((128, 576), jnp.int32), ((128,), jnp.int32))
+    fn = functools.partial(pa.paged_attention_decode, interpret=False,
+                           layer=0)
+    text = _compile(one_chip, fn, *avals, kernels=["paged_decode"]).as_text()
+    assert "paged_decode_split" not in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[1,73728,16,1024]" in ln]
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(one_chip, functools.partial(fn, pages_per_split=576), *avals)
+
+
+@pytest.mark.parametrize("cell,shape,kv", [
+    ("gpt2m-serve-chat-steady", (64, 16, 16, 64), None),
+    ("gpt2m-serve-longdoc-backlog", (64, 16, 16, 64), None),
+    ("lfm2moe-serve-doc3k-backlog", (256, 16, 32, 64), 8),
+    ("sdar-serve-gen512-backlog", (192, 16, 128, 128), 4),
+    ("falconh1-serve-gen1k-backlog", (160, 16, 20, 128), 4),
+    ("nemotron3n-serve-reason2k-backlog", (256, 16, 32, 128), 2)])
+def test_accepted_cells_keep_the_compilers_own_limit(cell, shape, kv):
+    """Every accepted cell's table (pages, block, query heads, head size;
+    key/value heads) is within the fit budget: the single-softmax body,
+    the compiler's own scoped limit, the program it was."""
+    pages, block, heads, head_dim = shape
+    assert pa._split_width(*shape, BF16, None, kv) == pages
+    assert pa.fits_single_softmax(pages, block, head_dim, BF16, None, heads,
+                                  kv)
+
+
+def test_flash_grid_forward_at_8192(one_chip):
+    """The global layer's prefill at the cell's longest prompt: 64 heads
+    of 128 over 8,192 positions, causal — past 4,096 the GRID forward,
+    which no cell had run since PR 33."""
+    qkv = ((1, 8192, 64, 128), BF16)
+    fn = functools.partial(pallas_flash.flash_attention_bshd, causal=True,
+                           interpret=False)
+    _compile(one_chip, fn, qkv, qkv, qkv, kernels=["flash_fwd"])
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    (1024, 6144, 2048), (1024, 2048, 6144),     # a step: 128 rows x 8
+    (4096, 6144, 2048), (4096, 2048, 6144),     # a 512-token prefill
+    (65536, 6144, 2048), (65536, 2048, 6144)])  # an 8,192-token prefill
+def test_moe_gmm_contiguous_eighth_cell_shapes(one_chip, rows, k, n):
+    """`kexaone-serve-mixed8k-backlog`: 16 experts held of 128 routed
+    over (a 129th group parks the rest), 8 a row, SwiGLU experts of
+    width 2,048 under a hidden of 6,144. Nothing of the weights' size is
+    copied on the way to the kernel."""
+    text = _compile(one_chip, _gmm_fn, ((rows, k), BF16), ((16, k, n), BF16),
+                    ((129,), jnp.int32), ((), jnp.int32),
+                    kernels=["moe_gmm"]).as_text()
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"bf16[16,{k},{n}]" in ln]
