@@ -269,6 +269,7 @@ def kernel_parity(size: dict) -> dict:
     err.update(flash_parity(size))
     err.update(mla_split(size))
     err.update(paged_split(size))
+    err.update(moe_routing_split(size))
     return err
 
 
@@ -765,6 +766,146 @@ def ssm_step_parity(size: dict) -> dict:
                 f"ssm_state_step ({cell}) off the jnp formula: state "
                 f"{gap_h}, y {gap_y}")
     return err
+
+
+def device_ms(calls: dict, repeats: int = 5) -> dict:
+    """{name: median device ms of one execution} of the jitted
+    ``calls`` ({name: (fn, args)}, each compiled under its name), off
+    the ``XLA Modules`` line of ONE profiler trace: a piece of a few
+    tens of microseconds is far under what the host's clock resolves
+    around a call (~0.2 ms here)."""
+    import glob
+    import shutil
+    import tempfile
+    import jax
+    from jax.profiler import ProfileData
+    jitted = {}
+    for name, (fn, args) in calls.items():
+        # a function of its own: one jitted before keeps its old name
+        def named(*a, _fn=fn):
+            return _fn(*a)
+        named.__name__ = name
+        jitted[name] = (jax.jit(named), args)
+        jax.block_until_ready(jitted[name][0](*args))
+    where = tempfile.mkdtemp(prefix="p2t_split_")
+    try:
+        jax.profiler.start_trace(where)
+        for fn, args in jitted.values():
+            for _ in range(repeats):
+                jax.block_until_ready(fn(*args))
+        jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(where, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        taken = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/device:TPU:0":
+                continue
+            for line in plane.lines:
+                if line.name != "XLA Modules":
+                    continue
+                for event in line.events:
+                    name = event.name.split("(")[0].removeprefix("jit_")
+                    taken.setdefault(name, []).append(event.duration_ns)
+    finally:
+        shutil.rmtree(where, ignore_errors=True)
+    return {name: float(np.median(taken[name])) / 1e6 for name in calls}
+
+
+def moe_routing_split(size: dict) -> dict:
+    """What an expert layer builds around its grouped matmuls, each
+    piece ALONE at the prefill and decode shapes of the three cells
+    whose layers hold a share of the experts (K-EXAONE: 16 of 128 held,
+    8 a row, hidden 6,144; DeepSeek-V2: 20 of 160, 6 a row, 5,120;
+    Nemotron: 64 of 128, 6 a row, 2,688), the form every layer had
+    before PR 47 beside the held-prefix form (PERF.md section 6, PR 47,
+    step 0): the sort (``argsort`` + ``bincount``, the same in both),
+    the row gather over all ``T x k`` assignments against
+    ``moe._gather_held`` over the held prefix, and the combine as a
+    second ``argsort`` + a gather of all result rows + a float32 sum
+    over ``[T, k, H]`` against ``moe._combine_held``; the new forms are
+    held to the old ones' results. Device ms of one execution; on the
+    chip only."""
+    import jax
+    import jax.numpy as jnp
+    from paddle2_tpu.incubate import moe
+    big = size["hidden"] >= 1024
+    # rows, experts a row, hidden, experts routed over, held
+    shapes = {
+        "kexaone.512": (512, 8, 6144, 128, 16),
+        "kexaone.2048": (2048, 8, 6144, 128, 16),
+        "kexaone.8192": (8192, 8, 6144, 128, 16),
+        "dsv2.5120": (5120, 6, 5120, 160, 20),
+        "nemotron.2048": (2048, 6, 2688, 128, 64),
+        "kexaone.step128": (128, 8, 6144, 128, 16),
+        "dsv2.step128": (128, 6, 5120, 160, 20),
+        "nemotron.step256": (256, 6, 2688, 128, 64),
+    } if big else {"tiny": (24, 2, 32, 8, 2)}
+    f32 = jnp.float32
+    out = {}
+    for shape, (T, k, H, E, held_n) in shapes.items():
+        chunk = math.gcd(T, moe.HELD_CHUNK)
+        _, ids = jax.lax.top_k(
+            jax.random.uniform(jax.random.PRNGKey(1), (T, E)), k)
+        w = jax.random.uniform(jax.random.PRNGKey(4), (T, k), f32)
+        a = jax.random.normal(jax.random.PRNGKey(2), (T, H), jnp.bfloat16)
+
+        def sort(ids):
+            flat = ids.reshape(-1)
+            held = flat < held_n
+            flat = jnp.where(held, flat, E)
+            return (jnp.argsort(flat, stable=True).astype(jnp.int32),
+                    jnp.bincount(flat, length=E + 1).astype(jnp.int32),
+                    held.reshape(T, k))
+
+        order, sizes, held = jax.jit(sort)(ids)
+        n_held = jnp.sum(sizes[:E])
+        n = int(n_held)
+        # the experts' output: anything on the prefix, unwritten behind
+        y = jax.random.normal(jax.random.PRNGKey(3), (T * k, H),
+                              jnp.bfloat16)
+        y = jnp.where((jnp.arange(T * k) < n)[:, None], y, jnp.nan)
+
+        def gather_all(a, order):
+            return a[order // k]
+
+        def gather_held(a, order, n_held):
+            return moe._gather_held(a, order, k, n_held, chunk)[0]
+
+        def combine_all(y, order, w):
+            z = y[jnp.argsort(order)].reshape(T, k, H).astype(f32)
+            return jnp.sum(z * w[..., None], axis=1).astype(y.dtype)
+
+        def combine_held(y, order, w, held):
+            return moe._combine_held(y, order, w, held, chunk, y.dtype)
+
+        rows = gather_held(a, order, n_held)
+        if not (rows[:n] == gather_all(a, order)[:n]).all():
+            raise AssertionError(f"held gather ({shape}) off the whole one")
+        got, trips = combine_held(y, order, w, held)
+        want = combine_all(jnp.where(jnp.isnan(y), 0, y), order, w)
+        gap = out[f"moe_split.{shape}.combine_gap"] = float(
+            jnp.abs(got.astype(f32) - want.astype(f32)).max())
+        # one float32 sum in another order, rounded to bfloat16 once
+        if not gap <= 2.0 ** -7 * max(1.0, float(jnp.abs(want).max())):
+            raise AssertionError(
+                f"held combine ({shape}) off the whole one by {gap}")
+        out[f"moe_split.{shape}.held"] = n
+        out[f"moe_split.{shape}.rows_moved_pct"] = 100.0 * (
+            (-(-n // chunk) + int(trips)) * chunk + T) / (2 * T * k)
+        if not big:
+            continue
+        tag = shape.replace(".", "_")
+        times = device_ms({
+            f"p47_sort_{tag}": (sort, (ids,)),
+            f"p47_gather_all_{tag}": (gather_all, (a, order)),
+            f"p47_gather_held_{tag}": (gather_held, (a, order, n_held)),
+            f"p47_combine_all_{tag}": (combine_all, (y, order, w)),
+            f"p47_combine_held_{tag}": (combine_held, (y, order, w, held)),
+        })
+        for name, ms in times.items():
+            piece = name.removeprefix("p47_").removesuffix("_" + tag)
+            out[f"moe_split.{shape}.ms.{piece}"] = ms
+    return out
 
 
 def bf16_ulp(x: float) -> float:

@@ -476,6 +476,89 @@ def softmax_group_limited_route(a, gate_w, k: int, n_group: int,
     return ids.astype(jnp.int32), w * scale
 
 
+# Rows one trip of the held-prefix loops moves (6.3 MB of K-EXAONE's
+# bf16 rows against ~20 us of loop), and the fewest rows for which the
+# loops run at all: a decode step's few hundred rows are moved whole
+# (PERF.md section 6, PR 47, step 0).
+HELD_CHUNK = 512
+
+
+def _gather_held(a, order, k: int, n_held, chunk: int):
+    """``rows[i] = a[order[i] // k]`` for the first ``n_held`` sorted
+    assignments, ``chunk`` rows a trip; the rows behind the last trip
+    are left as the allocator hands them over (no expert reads them).
+    Returns (rows ``[T x k, H]``, the trips)."""
+    m = order.shape[0]
+    trips = -(-n_held // chunk)
+
+    def trip(carry):
+        i, rows = carry
+        tok = jax.lax.dynamic_slice(order, (i * chunk,), (chunk,)) // k
+        return i + 1, jax.lax.dynamic_update_slice(
+            rows, a.at[tok].get(mode="promise_in_bounds"), (i * chunk, 0))
+
+    return jax.lax.while_loop(
+        lambda carry: carry[0] < trips, trip,
+        (jnp.int32(0), jax.lax.empty((m, a.shape[1]), a.dtype)))[1], trips
+
+
+def _combine_held(y, order, w, held, chunk: int, dtype):
+    """``out[t] = sum over the held j of w[t, j] * y[at[t, j]]`` in
+    float32, ``at`` the place of assignment ``(t, j)`` in the sorted
+    order, by row gathers alone (the chip gathers rows at the memory's
+    rate and scatter-adds them at a tenth of it: PERF.md section 6,
+    PR 47). The tokens are ranked by how many of their terms are held,
+    so a chunk of ``chunk`` ranked tokens needs as many gathers as its
+    FIRST token has terms: the chunk's float32 sum stays in the loop,
+    is rounded to ``dtype`` once and written once; a chunk past the
+    last token with a term is written as zeros without a gather. Any
+    number of held terms from none to all ``T x k``. Returns (out
+    ``[T, H]`` in the tokens' own order, the gathers)."""
+    T, k = w.shape
+    H = y.shape[1]
+    at = jnp.argsort(order).astype(jnp.int32).reshape(T, k)
+    # a token's held terms first (term r of a token is its r-th held
+    # assignment: picked by comparison, k x k selects a token — a gather
+    # of T x k scalars costs more than the rows' own), then the tokens
+    # by falling count
+    nth = jnp.cumsum(held, axis=1) - 1
+    pick = held[:, None, :] & (nth[:, None, :] == jnp.arange(k)[:, None])
+    at = jnp.sum(jnp.where(pick, at[:, None, :], 0), axis=2)
+    wt = jnp.sum(jnp.where(pick, w[:, None, :], 0.0), axis=2)
+    # (ONE sort carries the columns along: a gather of T rows of k
+    # numbers costs ten sorts)
+    ranked = jax.lax.sort(
+        (-jnp.sum(held, axis=1), jnp.arange(T), *at.T, *wt.T),
+        num_keys=1, is_stable=True)
+    terms, rank = -ranked[0], ranked[1]
+    at, wt = jnp.stack(ranked[2:2 + k]), jnp.stack(ranked[2 + k:])
+
+    def one_chunk(i, carry):
+        gathers, out = carry
+        lo = i * chunk
+        count = jax.lax.dynamic_slice(terms, (lo,), (chunk,))
+
+        def term(r_acc):
+            r, acc = r_acc
+            src = jax.lax.dynamic_slice(at, (r, lo), (1, chunk))[0]
+            rows = y.at[src].get(mode="promise_in_bounds")
+            weighted = rows.astype(jnp.float32) * jax.lax.dynamic_slice(
+                wt, (r, lo), (1, chunk))[0][:, None]
+            # a token with fewer terms: its row lies behind the prefix
+            return r + 1, acc + jnp.where((r < count)[:, None], weighted, 0.0)
+
+        n, acc = jax.lax.while_loop(
+            lambda r_acc: r_acc[0] < count[0], term,
+            (jnp.int32(0), jnp.zeros((chunk, H), jnp.float32)))
+        return gathers + n, jax.lax.dynamic_update_slice(
+            out, acc.astype(dtype), (lo, 0))
+
+    gathers, out = jax.lax.fori_loop(
+        0, T // chunk, one_chunk,
+        (jnp.int32(0), jax.lax.empty((T, H), dtype)))
+    return out[jnp.argsort(rank)], gathers
+
+
 class DroplessExperts(nn.Layer):
     """Top-k routed experts with NO capacity: every assignment is
     computed. Rows are sorted by expert and all experts held run as
@@ -504,19 +587,36 @@ class DroplessExperts(nn.Layer):
     ``num_experts`` this layer holds weights for: routing is always over
     all experts, the output is the part its own experts contribute
     (everything when it holds all), and nothing stands in for the rest.
+    A layer that holds a SHARE (``count < num_experts``) and is given
+    ``HELD_CHUNK`` rows or more moves only what it holds: the
+    assignments nobody here computes are parked behind the last expert,
+    so after the stable sort the held ones are the PREFIX ``[0,
+    n_held)`` of the sorted order; the row gather walks that prefix and
+    leaves the rows behind it unwritten (no visit of the grouped matmul
+    reaches them and the last product's rows there are never read), and
+    the weighted sum adds the prefix's rows alone (:func:`_gather_held`,
+    :func:`_combine_held`). The trip counts are DATA, not a capacity:
+    any ``n_held`` from 0 to ``T x k`` is computed exactly, no
+    assignment is dropped. A layer that holds every expert (nothing is
+    parked) sorts, gathers and sums all ``T x k`` assignments as before,
+    in the program it had before.
 
     Array-level (serving) API: :meth:`route_and_run` on ``[T, H]``
     arrays; it also returns the layer's routing record, one int32
     array: the counts (:attr:`COUNT_NAMES`: assignments computed,
     distinct experts hit, largest load on one expert, rows with at least
     one of their ``k`` experts held here, rows routed at all, rows the
-    grouped matmul's tiles multiply for those assignments) and behind
-    them the ``k`` experts chosen for each row (what a router replay or
-    a teacher-forced comparison needs: top-k is discontinuous, so which
-    experts ran is part of the result)."""
+    grouped matmul's tiles multiply for those assignments, rows moved
+    to the experts and back — ``2 x T x k`` where everything is, the
+    loops' trips times their chunk plus the ``T`` sums' way back on the
+    held-prefix path) and behind them the ``k`` experts chosen for each
+    row (what a router replay or a teacher-forced comparison needs:
+    top-k is discontinuous, so which experts ran is part of the
+    result)."""
 
     COUNT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_load_max",
-                   "moe_rows_routed_here", "moe_rows", "moe_tile_rows")
+                   "moe_rows_routed_here", "moe_rows", "moe_tile_rows",
+                   "moe_rows_moved")
 
     def __init__(self, hidden: int, width: int, num_experts: int, k: int,
                  use_bias: bool = True, norm_topk: bool = True,
@@ -563,28 +663,46 @@ class DroplessExperts(nn.Layer):
             self.w1._replace_data(self.w1._data.at[..., width:].set(0))
             self.w2._replace_data(self.w2._data.at[:, width:].set(0))
 
+    def route(self, a):
+        """The ``k`` experts of each row of ``a`` and their weights, by
+        the layer's ``router``: (ids ``[T, k]`` int32, weights ``[T, k]``
+        f32)."""
+        gate, k = self.gate_weight._data, self.k
+        if self.router == "softmax":
+            return softmax_topk_route(a, gate, k, self.norm_topk, self.scale)
+        if self.router == "softmax_group_limited":
+            return softmax_group_limited_route(
+                a, gate, k, self.n_group, self.topk_group, self.norm_topk,
+                self.scale)
+        return sigmoid_topk_route(
+            a, gate,
+            None if self.expert_bias is None else self.expert_bias._data,
+            k, self.norm_topk, self.scale, self.norm_eps)
+
     def route_and_run(self, a, valid=None, interpret=None):
         """a ``[T, H]``; ``valid`` bool ``[T]`` marks the rows worth
         computing (padding is skipped and not counted). Returns (out
         ``[T, H]`` in a's dtype, record int32 ``[len(COUNT_NAMES) + T *
-        k]``: the counts, then the chosen expert ids row by row)."""
+        k]``: the counts, then the chosen expert ids row by row).
+
+        Which path runs is read off the layer's own static shapes
+        (``prefix`` below): a share of the experts held and at least
+        ``HELD_CHUNK`` rows take the held-prefix loops, everything else
+        the whole gather and sum. On the held-prefix path ``rows``
+        behind ``n_held`` is an allocation nobody fills (``lax.empty``:
+        zeroing 805 MB a layer costs more than the gather it spares)
+        and ``y`` behind it whatever the kernel's output buffer held:
+        the grouped matmul's visits stop at the held groups' last row
+        tile, a tile's rows are independent, and the sum masks every
+        row that is not a held term."""
         from ..kernels.moe_gmm import gmm_plan, moe_gmm, plan_tile_rows
         T, H = a.shape
         E, k = self.num_experts, self.k
+        # the trips divide the rows: no trip straddles their end
+        chunk = math.gcd(T, HELD_CHUNK)
+        prefix = self.count < E and T >= HELD_CHUNK
         with jax.named_scope("router"):
-            if self.router == "softmax":
-                ids, w = softmax_topk_route(a, self.gate_weight._data, k,
-                                            self.norm_topk, self.scale)
-            elif self.router == "softmax_group_limited":
-                ids, w = softmax_group_limited_route(
-                    a, self.gate_weight._data, k, self.n_group,
-                    self.topk_group, self.norm_topk, self.scale)
-            else:
-                ids, w = sigmoid_topk_route(
-                    a, self.gate_weight._data,
-                    None if self.expert_bias is None
-                    else self.expert_bias._data,
-                    k, self.norm_topk, self.scale, self.norm_eps)
+            ids, w = self.route(a)
         with jax.named_scope("dispatch"):
             flat = ids.reshape(-1)
             held = (flat >= self.first) & (flat < self.first + self.count)
@@ -595,9 +713,18 @@ class DroplessExperts(nn.Layer):
             # rows nobody here computes are parked behind the last expert
             flat = jnp.where(held, flat, E)
             order = jnp.argsort(flat, stable=True)
-            sizes = jnp.bincount(flat, length=E + 1).astype(jnp.int32)
-            rows = a[order // k]
-            counts = jnp.stack([jnp.sum(sizes[:E]),
+            if prefix:
+                # (a comparison an expert: ``bincount`` is a scatter-add
+                # an assignment, the longest op of this scope)
+                sizes = jnp.sum(flat[:, None] == jnp.arange(E + 1),
+                                axis=0, dtype=jnp.int32)
+                n_held = jnp.sum(sizes[:E])
+                rows, trips = _gather_held(a, order, k, n_held, chunk)
+            else:
+                sizes = jnp.bincount(flat, length=E + 1).astype(jnp.int32)
+                n_held = jnp.sum(sizes[:E])
+                rows = a[order // k]
+            counts = jnp.stack([n_held,
                                 jnp.sum(sizes[:E] > 0),
                                 jnp.max(sizes[:E]), rows_here,
                                 n_rows]).astype(jnp.int32)
@@ -613,12 +740,22 @@ class DroplessExperts(nn.Layer):
                     plan=plan).astype(jnp.float32)
             else:
                 h = jnp.square(jax.nn.relu(up))
+            # the held prefix's sum reads no row behind it
             y = moe_gmm(h.astype(a.dtype), self.w2._data,
-                        interpret=interpret, plan=plan)
+                        interpret=interpret, plan=plan,
+                        zero_rest=not prefix)
         with jax.named_scope("combine"):
-            inv = jnp.argsort(order)
-            y = y[inv].reshape(T, k, H).astype(jnp.float32)
-            out = jnp.sum(y * w[..., None], axis=1).astype(a.dtype)
+            if prefix:
+                out, added = _combine_held(y, order, w, held.reshape(T, k),
+                                           chunk, a.dtype)
+                # ... and the sums' way back to their tokens
+                moved = (trips + added) * chunk + T
+            else:
+                inv = jnp.argsort(order)
+                y = y[inv].reshape(T, k, H).astype(jnp.float32)
+                out = jnp.sum(y * w[..., None], axis=1).astype(a.dtype)
+                moved = 2 * T * k
+        counts = jnp.append(counts, jnp.asarray(moved, jnp.int32))
         return out, jnp.concatenate([counts, ids.reshape(-1)])
 
     def forward(self, x):

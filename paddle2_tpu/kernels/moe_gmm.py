@@ -121,8 +121,8 @@ def _plan_row_tile(plan, m: int) -> int:
     return _row_tile(m, plan[0].shape[0] - 1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _gmm(lhs, rhs, plan, *, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "zero_rest"))
+def _gmm(lhs, rhs, plan, *, interpret, zero_rest=True):
     m, k = lhs.shape
     held, _, n = rhs.shape
     offsets, gids, mtiles, visits, first = plan
@@ -147,6 +147,8 @@ def _gmm(lhs, rhs, plan, *, interpret):
         interpret=interpret,
         name="moe_gmm",
     )(offsets, gids, mtiles, first, lhs, rhs)
+    if not zero_rest:
+        return out
     # rows of groups not held were never visited
     row = jnp.arange(m)
     mine = (row >= offsets[first[0]]) & (row < offsets[first[0] + held])
@@ -154,17 +156,22 @@ def _gmm(lhs, rhs, plan, *, interpret):
 
 
 def moe_gmm(lhs, rhs, group_sizes=None, first=0, interpret=None,
-            plan=None):
+            plan=None, zero_rest: bool = True):
     """lhs ``[m, k]`` (rows sorted by group), rhs ``[held, k, n]``,
     group_sizes int32 ``[groups]`` adding up to ``m`` -> ``[m, n]`` in
     lhs's dtype (f32 accumulation). ``m`` is a multiple of 8. Pass the
     ``plan`` (:func:`gmm_plan` of the same sizes, rows, ``first`` and
-    ``held``) where several products share one routing."""
+    ``held``) where several products share one routing. The rows of
+    groups not held come back as zeros; ``zero_rest=False`` leaves them
+    as the kernel's output buffer has them, UNWRITTEN (anything, NaN
+    too), for a caller that reads the held groups' rows only and need
+    not pay a pass over ``[m, n]``."""
     if interpret is None:
         interpret = interpret_default()
     if plan is None:
         plan = gmm_plan(group_sizes, lhs.shape[0], first, rhs.shape[0])
-    return _gmm(lhs, rhs, plan, interpret=bool(interpret))
+    return _gmm(lhs, rhs, plan, interpret=bool(interpret),
+                zero_rest=bool(zero_rest))
 
 
 def plan_tile_rows(plan, m: int):

@@ -928,3 +928,69 @@ def test_moe_gmm_contiguous_eighth_cell_shapes(one_chip, rows, k, n):
                     kernels=["moe_gmm"]).as_text()
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and f"bf16[16,{k},{n}]" in ln]
+
+
+@pytest.mark.parametrize("cell,rows,k,hidden,width,experts,held,router", [
+    ("kexaone", 8192, 8, 6144, 2048, 128, 16, {}),
+    ("dsv2", 5120, 6, 5120, 1536, 160, 20,
+     dict(router="softmax_group_limited", n_group=8, topk_group=3))])
+def test_held_prefix_expert_layer_cell_shapes(one_chip, cell, rows, k,
+                                              hidden, width, experts, held,
+                                              router):
+    """The expert layer of `kexaone-serve-mixed8k-backlog`'s 8,192-token
+    prefill and of `dsv2-serve-doc5k-backlog`'s 5,120 one, holding an
+    eighth of the experts: the loops over the held prefix compile for the
+    chip beside the grouped matmuls, the rows behind the prefix are an
+    allocation nobody fills, and the program's temporaries are those of
+    the formulation it replaced (every assignment gathered, gathered back
+    and summed as `[T, k, H]` float32)."""
+    from paddle2_tpu.incubate.moe import DroplessExperts
+    from test_moe_held_prefix import parent_route_and_run
+    # a layer of the cell's counts; its weights arrive as arguments
+    layer = DroplessExperts(8, 8, experts, k, held=(0, held), dtype="bfloat16",
+                            **router)
+    params = list(layer.parameters())
+
+    def aval(p):
+        shape = list(p._data.shape)
+        if len(shape) == 3:
+            shape[1:] = [hidden, width] if p is not layer.w2 \
+                else [width, hidden]
+        elif len(shape) == 2:
+            shape[0] = hidden
+        return jax.ShapeDtypeStruct(tuple(shape), BF16, sharding=one_chip)
+
+    def bound(fn):
+        def run(weights, a):
+            kept = [p._data for p in params]
+            for p, w in zip(params, weights):
+                p._data = w
+            try:
+                return fn(a)
+            finally:
+                for p, w in zip(params, kept):
+                    p._data = w
+        return run
+
+    avals = [aval(p) for p in params]
+    a = jax.ShapeDtypeStruct((rows, hidden), BF16, sharding=one_chip)
+    new = jax.jit(bound(lambda x: layer.route_and_run(
+        x, interpret=False))).lower(avals, a).compile()
+    old = jax.jit(bound(lambda x: parent_route_and_run(
+        layer, x, interpret=False))).lower(avals, a).compile()
+    text = new.as_text()
+    assert text.count("moe_gmm") >= 3 and "AllocateBuffer" in text
+    # (the plan's search for the tiles' groups loops in both)
+    loop = (r' while\(.*op_name="[^"]*/(dispatch|combine)/'
+            r'(?:while/body/closed_call/)?while"')
+    # the gather's loop; the sum's over chunks of ranked rows and, in it,
+    # the one over a chunk's terms — and no scatter of rows or of counts
+    # in either
+    assert sorted(m.group(1) for m in re.finditer(loop, text)) \
+        == ["combine", "combine", "dispatch"]
+    assert not re.search(r"/(dispatch|combine)/[^\"]*scatter", text)
+    assert not re.search(loop, old.as_text())
+    # both peak at the gathered rows beside the three products' outputs
+    # (1.61 GB at 8,192 rows); the loops' chunk buffers add 0.3 %
+    assert new.memory_analysis().temp_size_in_bytes \
+        <= 1.01 * old.memory_analysis().temp_size_in_bytes

@@ -134,11 +134,12 @@ def test_ring_after_a_padded_prefill_is_the_ring_at_last_idx(bench):
         assert exact[3][kind].shape == (3, 8, 2 * 16)
         np.testing.assert_allclose(padded[3][kind], exact[3][kind],
                                    rtol=1e-5, atol=1e-5)
-    # the routing counts ride first in each layer's record; the last of
-    # them counts the tiles' rows, which the padded length sets
+    # the routing counts ride first in each layer's record; the last two
+    # count the tiles' rows and the rows moved, which the padded length
+    # sets
     names = PagedRunner(model).family.count_names
-    assert names[-1] == "moe_tile_rows"
-    counted = len(names) - 1
+    assert names[-2:] == ("moe_tile_rows", "moe_rows_moved")
+    counted = len(names) - 2
     np.testing.assert_array_equal(padded[4][:, :counted],
                                   exact[4][:, :counted])
 

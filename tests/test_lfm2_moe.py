@@ -216,8 +216,10 @@ def test_expert_shares_add_up_to_the_whole_layer(bench, router, E, k):
     n_counts = len(DroplessExperts.COUNT_NAMES)
     tm = 32 if k == 4 else 64
     sizes = np.bincount(np.asarray(used).ravel(), minlength=E)
-    assert n_counts == 6 \
+    assert n_counts == 7 \
         and int(counts[5]) == tm * visits_by_hand(sizes, 0, E, tm)
+    # holding every expert, every assignment is moved there and back
+    assert int(counts[6]) == 2 * 40 * k
     # behind the counts, the experts chosen row by row
     np.testing.assert_array_equal(
         np.sort(np.asarray(counts[n_counts:]).reshape(40, k), -1),

@@ -191,7 +191,7 @@ def test_tile_rows_ride_with_the_routing_counts(readers, lfm2_traced):
     the experts the engine says it chose — and
     ``moe_gmm_tile_fill_pct.serve`` reads assignments over it."""
     engine, spans = lfm2_traced
-    assert ROUTING[-1] == "moe_tile_rows"
+    assert ROUTING[-2:] == ("moe_tile_rows", "moe_rows_moved")
     E = engine.runner.model.cfg.num_experts
     chosen = [engine.routed_experts(rid) for rid in range(3)]
     _, delivered = split(spans)
