@@ -270,6 +270,7 @@ def kernel_parity(size: dict) -> dict:
     err.update(mla_split(size))
     err.update(paged_split(size))
     err.update(moe_routing_split(size))
+    err.update(window_band_split(size))
     return err
 
 
@@ -768,12 +769,14 @@ def ssm_step_parity(size: dict) -> dict:
     return err
 
 
-def device_ms(calls: dict, repeats: int = 5) -> dict:
+def device_ms(calls: dict, repeats: int = 5, ops: dict = None) -> dict:
     """{name: median device ms of one execution} of the jitted
     ``calls`` ({name: (fn, args)}, each compiled under its name), off
     the ``XLA Modules`` line of ONE profiler trace: a piece of a few
     tens of microseconds is far under what the host's clock resolves
-    around a call (~0.2 ms here)."""
+    around a call (~0.2 ms here). ``ops``, if given, takes {name: the
+    call's eight largest ops as (ms an execution, op)} off the ``XLA
+    Ops`` line."""
     import glob
     import shutil
     import tempfile
@@ -796,18 +799,34 @@ def device_ms(calls: dict, repeats: int = 5) -> dict:
         jax.profiler.stop_trace()
         path, = glob.glob(os.path.join(where, "plugins", "profile", "*",
                                        "*.xplane.pb"))
-        taken = {}
+        taken, spans, op_events = {}, [], []
         for plane in ProfileData.from_file(path).planes:
             if plane.name != "/device:TPU:0":
                 continue
             for line in plane.lines:
+                if line.name == "XLA Ops":
+                    op_events = [(e.start_ns, e.duration_ns, e.name)
+                                 for e in line.events]
                 if line.name != "XLA Modules":
                     continue
                 for event in line.events:
                     name = event.name.split("(")[0].removeprefix("jit_")
                     taken.setdefault(name, []).append(event.duration_ns)
+                    spans.append((event.start_ns, event.start_ns
+                                  + event.duration_ns, name))
     finally:
         shutil.rmtree(where, ignore_errors=True)
+    if ops is not None:
+        by_call = {name: {} for name in calls}
+        for lo, hi, name in spans:
+            for start, ns, op in op_events:
+                if name in by_call and lo <= start < hi:
+                    by_call[name][op] = by_call[name].get(op, 0.0) \
+                        + ns / 1e6 / repeats
+        for name, by_op in by_call.items():
+            ops[name] = sorted(((round(ms, 4), op[:80])
+                                for op, ms in by_op.items()),
+                               reverse=True)[:8]
     return {name: float(np.median(taken[name])) / 1e6 for name in calls}
 
 
@@ -905,6 +924,95 @@ def moe_routing_split(size: dict) -> dict:
         for name, ms in times.items():
             piece = name.removeprefix("p47_").removesuffix("_" + tag)
             out[f"moe_split.{shape}.ms.{piece}"] = ms
+    return out
+
+
+def window_band_split(size: dict) -> dict:
+    """A sliding-window layer's attention at prefill, at the K-EXAONE
+    cell's three buckets (64 query over 8 key/value heads of 128, a
+    window of 128, hidden 6,144; PERF.md section 6, PR 48, step 0): the
+    band ALONE as the einsums (``local_window_attention``, with its
+    largest ops) beside the kernel (``window_fwd``) on q, k, v given;
+    then ONE sublayer (``ExaoneMoeAttention.full``: projections, q/k
+    norm, rotation, the band, the output projection; parameters as
+    arguments) in the parent's form (q normed and rotated as plain XLA,
+    the einsums) beside the form a TPU takes (q normed and rotated in the
+    kernel), which is held to the parent's output. Device ms of one
+    execution; on the chip only."""
+    import jax
+    import jax.numpy as jnp
+    from paddle2_tpu.kernels import pallas_band
+    from paddle2_tpu.models import exaone_moe as em
+    big = size["hidden"] >= 1024
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    window = 128 if big else 8
+    cfg = em.ExaoneMoeConfig(dtype="bfloat16", num_hidden_layers=4) \
+        if big else em.exaone_moe_tiny(dtype="bfloat16")
+    layer = em.ExaoneMoeAttention(cfg, window)
+    params = list(layer.parameters())
+    weights = [0.02 * jax.random.normal(jax.random.PRNGKey(i),
+                                        p._data.shape, f32).astype(bf16)
+               if p._data.ndim > 1 else jnp.ones(p._data.shape, bf16)
+               for i, p in enumerate(params)]
+
+    def sublayer(attend):
+        def run(weights, u):
+            kept = [p._data for p in params]
+            for p, w in zip(params, weights):
+                p._data = w
+            chosen = em.sliding_attention_kernel
+            em.sliding_attention_kernel = attend
+            try:
+                return layer.full(u)[0]
+            finally:
+                em.sliding_attention_kernel = chosen
+                for p, w in zip(params, kept):
+                    p._data = w
+        return run
+
+    nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, \
+        cfg.head_dim
+    out = {}
+    for S in (512, 2048, 8192) if big else (24,):
+        keys = jax.random.split(jax.random.PRNGKey(S), 4)
+        q = jax.random.normal(keys[0], (1, S, nh, hd), bf16)
+        k, v = (jax.random.normal(key, (1, S, nkv, hd), bf16)
+                for key in keys[1:3])
+        u = jax.random.normal(keys[3], (1, S, cfg.hidden_size), bf16)
+
+        def einsums(q, k, v):
+            return em.local_window_attention(q, k, v, window)
+
+        def kernel(q, k, v):
+            return pallas_band.band_attention(q, k, v, window, hd)
+
+        # the kernel's layout: the heads side by side in the lanes
+        flat = tuple(x.reshape(1, S, -1) for x in (q, k, v))
+        gap = out[f"band.{S}.kernel_gap"] = float(jnp.abs(
+            kernel(*flat).reshape(q.shape).astype(f32)
+            - einsums(q, k, v).astype(f32)).max())
+        parent, fused = (sublayer(em.sliding_attention),
+                         sublayer(em.sliding_attention_kernel))
+        want = jax.jit(parent)(weights, u).astype(f32)
+        layer_gap = out[f"band.{S}.sublayer_gap"] = float(jnp.abs(
+            jax.jit(fused)(weights, u).astype(f32) - want).max())
+        # bfloat16 probabilities and outputs on O(1) values; the
+        # sublayer's output is a sum over 8,192 of them times 0.02
+        if gap > 2e-2 or layer_gap > 2e-2 * float(jnp.abs(want).max()):
+            raise AssertionError(
+                f"window_fwd off the einsums at {S}: {gap}, {layer_gap}")
+        if not big:
+            continue
+        pieces = {"band_einsums": (einsums, (q, k, v)),
+                  "band_kernel": (kernel, flat),
+                  "sublayer_parent": (parent, (weights, u)),
+                  "sublayer_kernel": (fused, (weights, u))}
+        ops = {}
+        times = device_ms({f"p48_{piece}_{S}": call
+                           for piece, call in pieces.items()}, ops=ops)
+        for piece in pieces:
+            out[f"band.{S}.ms.{piece}"] = times[f"p48_{piece}_{S}"]
+        out[f"band.{S}.einsums_largest_ops"] = ops[f"p48_band_einsums_{S}"]
     return out
 
 

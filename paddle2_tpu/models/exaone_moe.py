@@ -24,9 +24,16 @@ expert every row takes. Final RMSNorm, untied head.
 
 So a sliding layer keeps a BOUNDED history, ``sliding_window`` keys and
 values whatever the context, and its attention over a whole sequence
-costs O(S x window): :func:`local_window_attention`, chunks of a window
-each of which sees itself under the band and the chunk before it. A
-global layer keeps every key and value.
+costs O(S x window), in one of two forms (``ExaoneMoeAttention.full``
+chooses from the device and the head width, no argument does):
+:func:`sliding_attention_kernel` on a TPU — ONE Pallas kernel
+(``kernels/pallas_band.py``, ``window_fwd``) that reads q where its
+projection wrote it, norms and rotates it in VMEM and keeps the band's
+scores there — and :func:`sliding_attention` everywhere else, as the
+definition the kernel is tested against and as its gradient: q normed
+and rotated as plain XLA, then :func:`local_window_attention`, chunks of
+a window each of which sees itself under the band and the chunk before
+it. A global layer keeps every key and value.
 
 ``held_experts = (first, count)`` makes this model ONE chip's share of a
 deployment that spreads the routed experts over chips by contiguous
@@ -43,6 +50,7 @@ family is ``serving/exaone_moe_family.py``."""
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
@@ -52,9 +60,11 @@ import jax.numpy as jnp
 from .. import nn
 from ..framework.tensor import Tensor
 from ..incubate.moe import DroplessExperts
+from ..kernels import _platform, pallas_band
 from ..ops.linalg import _mxu_precision
 from ._decoder import (GroupedQueryAttention, NormalDraw, SwiGLU, created_in,
-                       linear, pre_norm, rope_tables, rotate_half_rope)
+                       linear, mm, pre_norm, rms_head, rope_tables,
+                       rotate_half_rope)
 
 __all__ = ["ExaoneMoeConfig", "ExaoneMoeForCausalLM", "exaone_moe_tiny",
            "local_window_attention"]
@@ -163,7 +173,9 @@ def local_window_attention(q, k, v, window: int):
     x ``[S / w, 2 w]`` scores, plain XLA), the band cut out of that
     rectangle. q ``[B, S, nh, hd]``, k, v ``[B, S, nkv, hd]`` -> ``[B, S,
     nh, hd]``; scores and softmax in float32, scaled by ``1 /
-    sqrt(hd)``."""
+    sqrt(hd)``. The form of the CPU and of every gradient; on a TPU a
+    prefill takes ``window_fwd`` instead (at 8,192 positions x 64 heads
+    these scores are 537 MB of float32 a layer in HBM)."""
     B, S, nh, hd = q.shape
     nkv = k.shape[2]
     w = int(window)
@@ -196,6 +208,43 @@ def local_window_attention(q, k, v, window: int):
     return a.reshape(B, C * w, nh, hd)[:, :S]
 
 
+def sliding_attention(q, gain, k, v, cos, sin, window: int, eps: float):
+    """A sliding layer's attention from q as its projection wrote it: q
+    ``[B, S, nh x hd]`` takes its per-head RMS norm (``gain [hd]``,
+    ``eps``) and its rotation (``cos``, ``sin`` ``[S, hd]``), then the
+    band against k, v ``[B, S, nkv, hd]`` as they are kept -> ``[B, S, nh
+    x hd]``. Plain XLA: the definition, the form off the chip, and the
+    gradient of :func:`sliding_attention_kernel`."""
+    B, S, _ = q.shape
+    q = rms_head(q.reshape(B, S, -1, k.shape[-1]), gain, eps)
+    q = rotate_half_rope(q, cos[:, None], sin[:, None])
+    return local_window_attention(q, k, v, window).reshape(B, S, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def sliding_attention_kernel(q, gain, k, v, cos, sin, window, eps):
+    """:func:`sliding_attention` as ONE Pallas kernel (``window_fwd``,
+    ``kernels/pallas_band.py``): q is read once where its projection
+    wrote it, normed and rotated in VMEM, and no score reaches HBM."""
+    B, S = k.shape[:2]
+    return pallas_band.band_attention(
+        q, k.reshape(B, S, -1), v.reshape(B, S, -1), window, k.shape[-1],
+        q_gain=gain, eps=eps, rope=(cos, pallas_band.signed_sin(sin)))
+
+
+def _sliding_fwd(q, gain, k, v, cos, sin, window, eps):
+    return (sliding_attention_kernel(q, gain, k, v, cos, sin, window, eps),
+            (q, gain, k, v, cos, sin))
+
+
+def _sliding_bwd(window, eps, kept, g):
+    return jax.vjp(functools.partial(sliding_attention, window=window,
+                                     eps=eps), *kept)[1](g)
+
+
+sliding_attention_kernel.defvjp(_sliding_fwd, _sliding_bwd)
+
+
 class ExaoneMoeAttention(GroupedQueryAttention):
     """``_decoder.GroupedQueryAttention`` (q and k normed per head) with
     a ``window``: a sliding layer rotates q and k and sees ``window``
@@ -215,7 +264,8 @@ class ExaoneMoeAttention(GroupedQueryAttention):
         then rotated as plain XLA, which the compiler fuses into the norm
         before it (the ``rope`` kernel's block of 64 heads x 128 lanes
         overruns the scoped VMEM at 8,192 rows: compiled for a described
-        v5e, PR 45)."""
+        v5e, PR 45). What a decode step and a ring's re-walk call;
+        :meth:`full` makes its own q, k, v."""
         q, k, v = super().qkv(u, positions)
         if self.window is None:
             return q, k, v
@@ -225,13 +275,25 @@ class ExaoneMoeAttention(GroupedQueryAttention):
 
     def full(self, u):
         """Attention over a whole sequence -> (Op, k, v); the keys as
-        they are kept (a sliding layer's rotated)."""
+        they are kept (a sliding layer's rotated). A sliding layer's band
+        is the kernel on a TPU, for heads of whole lanes (the kernel
+        slices a head out of a block by lanes), and the einsums
+        elsewhere: chosen from what the layer sees, by no argument."""
         if self.window is None:
             return super().full(u)
         B, S, _ = u.shape
-        q, k, v = self.qkv(u, jnp.broadcast_to(jnp.arange(S), (B, S)))
-        a = local_window_attention(q, k, v, self.window)
-        return self.project(a.reshape(B, S, -1)), k, v
+        hd = self.head_dim
+        cos, sin = rope_tables(jnp.arange(S), hd, self.theta)
+        k = rms_head(mm(u, self.k_proj).reshape(B, S, -1, hd),
+                     self.k_norm.weight._data, self.eps)
+        k = rotate_half_rope(k, cos[:, None], sin[:, None])
+        v = mm(u, self.v_proj).reshape(B, S, -1, hd)
+        attend = sliding_attention_kernel \
+            if _platform.on_tpu() and hd % pallas_band.LANES == 0 \
+            else sliding_attention
+        a = attend(mm(u, self.q_proj), self.q_norm.weight._data, k, v, cos,
+                   sin, self.window, self.eps)
+        return self.project(a), k, v
 
 
 class ExaoneMoeSparseBlock(nn.Layer):

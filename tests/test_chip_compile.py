@@ -75,6 +75,39 @@ def _compile(one_chip, fn, *avals, kernels=()):
     return compiled
 
 
+def _with_weights(params, fn):
+    """``fn`` with the layers' parameters handed in as arguments."""
+    def run(weights, *args):
+        kept = [p._data for p in params]
+        for p, w in zip(params, weights):
+            p._data = w
+        try:
+            return fn(*args)
+        finally:
+            for p, w in zip(params, kept):
+                p._data = w
+    return run
+
+
+def _big_ops(text, seq, least):
+    """(name, op, scope path) of the ENTRY's arrays that have ``seq``
+    among their dims and ``least`` elements or more, parameters and
+    bitcasts apart."""
+    entry = text[text.index("\nENTRY "):]
+    big = []
+    for ln in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \(?\w+\[([\d,]+)\]\S* "
+                     r"([\w-]+)\(", ln)
+        if m and m.group(3) not in ("parameter", "bitcast",
+                                    "get-tuple-element"):
+            dims = [int(d) for d in m.group(2).split(",")]
+            if seq in dims and math.prod(dims) >= least:
+                path = re.search(r'op_name="([^"]*)"', ln)
+                big.append((m.group(1), m.group(3),
+                            path.group(1) if path else ""))
+    return big
+
+
 # ---------------------------------------------------------------- flash
 @pytest.mark.parametrize("seq", [1024, 4096])
 def test_flash_fwd_bwd(one_chip, seq):
@@ -553,33 +586,16 @@ def test_mla_prefill_attention_moves_no_activation(one_chip, monkeypatch):
     layer = LatentAttention(DeepseekV2Config(dtype="bfloat16"))
     params = list(layer.parameters())
 
-    def prefill_attention(weights, u):
-        kept = [p._data for p in params]
-        for p, w in zip(params, weights):
-            p._data = w
-        try:
-            with jax.named_scope("attn"):
-                return u + layer.full(u)[0]
-        finally:
-            for p, w in zip(params, kept):
-                p._data = w
+    def prefill_attention(u):
+        with jax.named_scope("attn"):
+            return u + layer.full(u)[0]
 
     avals = [jax.ShapeDtypeStruct(p._data.shape, p._data.dtype,
                                   sharding=one_chip) for p in params]
     u = jax.ShapeDtypeStruct((1, S, 5120), BF16, sharding=one_chip)
-    text = jax.jit(prefill_attention).lower(avals, u).compile().as_text()
-    entry = text[text.index("\nENTRY "):]
-    big = []
-    for ln in entry.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%(\S+) = \(?bf16\[([\d,]+)\]\S* "
-                     r"([\w-]+)\(", ln)
-        if m and m.group(3) not in ("parameter", "bitcast",
-                                    "get-tuple-element"):
-            dims = [int(d) for d in m.group(2).split(",")]
-            if S in dims and math.prod(dims) >= S * 128 * 128:
-                path = re.search(r'op_name="([^"]*)"', ln)
-                big.append((m.group(1), m.group(3),
-                            path.group(1) if path else ""))
+    text = jax.jit(_with_weights(params, prefill_attention)).lower(
+        avals, u).compile().as_text()
+    big = _big_ops(text, S, S * 128 * 128)
     assert not [b for b in big if b[1] in ("copy", "transpose", "slice",
                                            "concatenate")], big
     assert all(op == "custom-call" and "flash_fwd" in path
@@ -994,3 +1010,61 @@ def test_held_prefix_expert_layer_cell_shapes(one_chip, cell, rows, k,
     # (1.61 GB at 8,192 rows); the loops' chunk buffers add 0.3 %
     assert new.memory_analysis().temp_size_in_bytes \
         <= 1.01 * old.memory_analysis().temp_size_in_bytes
+
+
+# ------------------------------------------------- the sliding layers' band
+@pytest.mark.parametrize("seq", [512, 2048, 8192])
+def test_window_band_kernel_cell_shapes(one_chip, seq):
+    """`kexaone-serve-mixed8k-backlog`'s three prefill buckets: 64 query
+    over 8 key/value heads of 128 lanes side by side, a window of 128,
+    q normed and rotated in the step — under the name `window_fwd`, with
+    nothing copied or transposed around the call."""
+    from paddle2_tpu.kernels import pallas_band
+
+    def band(q, k, v, gain, cos, sin):
+        return pallas_band.band_attention(
+            q, k, v, 128, 128, q_gain=gain, eps=1e-5, rope=(cos, sin),
+            interpret=False)
+
+    text = _compile(one_chip, band, ((1, seq, 8192), BF16),
+                    ((1, seq, 1024), BF16), ((1, seq, 1024), BF16),
+                    ((128,), BF16), ((seq, 128), F32), ((seq, 128), F32),
+                    kernels=["window_fwd"]).as_text()
+    assert " copy(" not in text and " transpose(" not in text
+
+
+def test_sliding_prefill_attention_moves_no_activation(one_chip,
+                                                       monkeypatch):
+    """One sliding layer of the published widths over a 2,048-token
+    prompt, compiled as the prefill program holds it: of the arrays of S
+    x 64 heads x 128 lanes or more, each is written by a matmul (q, the
+    output projection) or by the band kernel — q reaches the kernel as
+    its projection wrote it, and the kernel's output is the output
+    projection's operand. The parent's form (q normed and rotated as
+    plain XLA, the band as two einsums) holds float32 copies of q in two
+    layouts and the scores: 0.35 GB of temporaries at this length, 1.39
+    GB at 8,192."""
+    from paddle2_tpu.kernels import _platform, pallas_band
+    from paddle2_tpu.models.exaone_moe import (ExaoneMoeAttention,
+                                               ExaoneMoeConfig)
+    monkeypatch.setattr(_platform, "device_platform", lambda: "tpu")
+    monkeypatch.setattr(pallas_band, "interpret_default", lambda: False)
+    S = 2048
+    layer = ExaoneMoeAttention(ExaoneMoeConfig(dtype="bfloat16",
+                                               num_hidden_layers=4), 128)
+    params = list(layer.parameters())
+
+    def prefill_attention(u):
+        with jax.named_scope("attn"), jax.named_scope("window"):
+            op, k, v = layer.full(u)
+            return u + op, k, v
+
+    avals = [jax.ShapeDtypeStruct(p._data.shape, p._data.dtype,
+                                  sharding=one_chip) for p in params]
+    u = jax.ShapeDtypeStruct((1, S, 6144), BF16, sharding=one_chip)
+    compiled = jax.jit(_with_weights(params, prefill_attention)).lower(
+        avals, u).compile()
+    big = _big_ops(compiled.as_text(), S, S * 64 * 128)
+    assert [op for _, op, _ in big] == ["fusion", "custom-call"], big
+    assert "dot_general" in big[0][2] and "window_fwd" in big[1][2], big
+    assert compiled.memory_analysis().temp_size_in_bytes < 100 << 20

@@ -164,7 +164,7 @@ def readers(monkeypatch, traced):
     cfg = engine.model.cfg
     config = {k: getattr(cfg, k) for k in (
         "hidden_size", "moe_intermediate_size", "num_attention_heads",
-        "num_key_value_heads", "head_dim", "sliding_window")}
+        "num_key_value_heads", "head_dim", "sliding_window", "layer_types")}
     lo = min(s[1] for s in spans)
     hi = max(s[2] for s in spans)
     ctx = {"cell": {"trace_dir": "spans-of-the-test", "name": "a-cell",
@@ -197,6 +197,44 @@ def test_the_ring_reader_counts_window_tokens_off_the_span(readers):
     nbytes = 2 * tokens * LAYERS["window_layers"] * 32 * 2
     assert readers.read("window_decode_roofline_pct.serve") \
         == pytest.approx(100.0 * (nbytes / 819e9) / 2e-6)
+
+
+def test_the_band_reader_takes_each_prompts_padded_length(readers):
+    """``window_prefill_roofline_pct.serve``: the band's required time a
+    prompt from ``padded`` on its ``prefill`` span (prompts of 3, 12 and
+    21 padded to 16, 16 and 32; a window of 8; three sliding layers),
+    over the ``window_fwd`` events' device time. No such event (a CPU,
+    the parent commit, whose band is plain XLA): None and no raise."""
+    name = "window_prefill_roofline_pct.serve"
+    assert readers.read(name) is None
+    asked = []
+
+    def pattern_time(trace, pattern):
+        asked.append(pattern)
+        return {0: (3_000, 9)}
+
+    readers.ctx["reduce"].pattern_time = pattern_time
+    need_s = 0.0
+    for padded in (16, 16, 32):
+        pairs = (padded - WINDOW) * WINDOW + WINDOW * (WINDOW + 1) // 2
+        flops = 4 * 16 * 4 * pairs          # 4 query heads of 16 lanes
+        nbytes = 2 * padded * 16 * (2 * 4 + 2 * 2)    # q, o; k, v: bf16
+        need_s += LAYERS["window_layers"] * max(flops / 197e12,
+                                                nbytes / 819e9)
+    assert readers.read(name) == pytest.approx(100.0 * need_s / 3e-6)
+    # by its own pattern: the kernel's own line, neither the global
+    # layer's kernel nor an op that takes the kernel's output
+    import re
+    pattern, = asked
+    assert re.search(pattern, "%window_fwd.1 = bf16[1,8192,8192]{2,1,0} "
+                     "custom-call(bf16[1,8192,8192]{2,1,0} %fusion.3, ")
+    for line in ("%flash_fwd.2 = bf16[1,64,8192,128]{3,2,1,0} custom-call(",
+                 "%fusion.139 = bf16[1,8192,6144]{2,1,0} fusion(bf16[1,8192,"
+                 "8192]{2,1,0} %window_fwd.1, bf16[8192,6144]{1,0} %p.4)"):
+        assert not re.search(pattern, line)
+    # a family without sliding layers has nothing to read
+    readers.ctx["cell"]["config"]["layer_types"] = ["full_attention"] * 4
+    assert readers.read(name) is None
 
 
 def test_a_program_without_the_counts_says_nothing(readers):
